@@ -289,17 +289,19 @@ def test_close_idempotent_and_post_close_errors():
         im._fused.score(b, None)
 
 
-def test_pallas_interpret_matches_jnp_scan():
+@pytest.mark.parametrize("u", [64, 256])
+def test_pallas_interpret_matches_jnp_scan(u):
     """The Pallas tile-scan kernel (interpret mode, so it runs on the
-    CPU backend) must reproduce the lax.scan core bit for bit."""
-    pytest.importorskip("jax.experimental.pallas")
+    CPU backend) must reproduce the lax.scan core bit for bit — also
+    for the one 64-wide tile bucket, which the wrapper pads up to a
+    full 128-lane block (it used to skip the kernel silently)."""
     import jax.numpy as jnp
 
     from theia_tpu.analytics.streaming import init_state
     from theia_tpu.ops import fused_detector as fd
 
     rng = np.random.default_rng(11)
-    t, u, cap = 3, 256, 512
+    t, cap = 3, 512
     state = init_state(cap)
     slots = np.arange(u, dtype=np.int32)
     x = rng.normal(5.0, 2.0, size=(t, u)).astype(np.float32)
@@ -307,12 +309,9 @@ def test_pallas_interpret_matches_jnp_scan():
     sub = type(state)(*(a[jnp.asarray(slots)] for a in state))
     ref_state, ref_anom = fd._scan_tile(sub, jnp.asarray(x),
                                         jnp.asarray(active), 0.5)
-    try:
-        pl_state, pl_anom = fd._scan_tile_pallas(
-            sub, jnp.asarray(x), jnp.asarray(active), 0.5,
-            interpret=True)
-    except Exception as e:   # noqa: BLE001 — interpreter support varies by jax version
-        pytest.skip(f"pallas interpret unavailable: {e}")
+    pl_state, pl_anom = fd._scan_tile_pallas(
+        sub, jnp.asarray(x), jnp.asarray(active), 0.5,
+        interpret=True)
     np.testing.assert_array_equal(np.asarray(ref_anom),
                                   np.asarray(pl_anom))
     for a, b2 in zip(ref_state, pl_state):

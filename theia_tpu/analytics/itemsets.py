@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..parallel.mesh import shard_map
 from ..schema import ColumnarBatch
 
 DEFAULT_COLUMNS = (
@@ -276,7 +275,7 @@ def _counts_over(rows: np.ndarray, mesh: Optional[jax.sharding.Mesh],
     def worker(shard, *rest):
         return jax.lax.psum(fn(shard, *rest), axis)
 
-    counts = shard_map(worker, mesh=mesh, in_specs=in_specs,
+    counts = jax.shard_map(worker, mesh=mesh, in_specs=in_specs,
                            out_specs=P())(
         jnp.asarray(rows), *((extra,) if extra is not None else ()))
     counts = np.asarray(counts).copy()

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..parallel.mesh import ROWS_AXIS, shard_map
+from ..parallel.mesh import ROWS_AXIS
 
 _SENTINEL = np.iinfo(np.int32).max
 
@@ -127,7 +127,7 @@ def make_sharded_distinct(mesh: jax.sharding.Mesh):
     """
     from jax.sharding import PartitionSpec as P
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         _sharded_distinct_step, mesh=mesh,
         in_specs=(P(ROWS_AXIS, None),),
         out_specs=(P(), P(), P()),

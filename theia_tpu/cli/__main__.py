@@ -625,7 +625,18 @@ def ingest_cmd(args) -> None:
     """Produce synthetic flow batches to POST /ingest through the
     exactly-once client (stream+seq stamping, Retry-After honored
     with jittered capped backoff) — the operator's load/drill tool
-    and the smallest correct producer to crib from."""
+    and the smallest correct producer to crib from.
+
+    One synthetic run of `--series` connections is generated from
+    `--seed`, `--points × --batches` records per connection; batch b
+    carries every connection's b-th window of `--points` records —
+    what one Flow Aggregator commit interval delivers — so the store
+    ends up holding real per-connection time series for the jobs to
+    score. `--json` prints the producer's own ledger (per-ack
+    rows/alerts/seconds, rows and octets sent) as the last line, for
+    drivers that check the server against it."""
+    import numpy as np
+
     from ..data.synth import SynthConfig, generate_flows
     from ..ingest import make_block_encoder
     from ..ingest.client import IngestClient, IngestError
@@ -633,19 +644,33 @@ def ingest_cmd(args) -> None:
     # TBLK by default; THEIA_INGEST_FORMAT=tfb2 keeps the legacy
     # dictionary-delta stream for drills against old managers
     enc = make_block_encoder()
-    batch = generate_flows(SynthConfig(
-        n_series=args.series, points_per_series=args.points,
-        anomaly_fraction=args.anomaly_fraction, seed=args.seed),
+    total_points = args.points * args.batches
+    flows = generate_flows(SynthConfig(
+        n_series=args.series, points_per_series=total_points,
+        anomaly_fraction=args.anomaly_fraction,
+        base_throughput=args.base_throughput,
+        anomaly_magnitude=args.anomaly_magnitude, seed=args.seed),
         dicts=enc.dicts)
+    # rows are series-major: series s, point t sits at s*total + t
+    window = (np.arange(args.series)[:, None] * total_points
+              + np.arange(args.points)[None, :]).ravel()
     client = IngestClient(args.manager_addr,
                           stream=args.stream or None,
                           token=_TOKEN, ca_cert=_CA_CERT or None)
-    alerts = 0
+    acks = []
+    rows_sent = octets_sent = 0
     t0 = time.time()
     try:
         for i in range(args.batches):
+            batch = flows.take(window + i * args.points)
+            t_send = time.time()
             out = client.send(enc.encode(batch))
-            alerts += int(out.get("alerts", 0))
+            rows_sent += len(batch)
+            octets_sent += int(np.asarray(
+                batch["octetDeltaCount"], np.int64).sum())
+            acks.append({"rows": int(out.get("rows", 0)),
+                         "alerts": int(out.get("alerts", 0)),
+                         "seconds": time.time() - t_send})
             if args.interval > 0 and i + 1 < args.batches:
                 time.sleep(args.interval)
     except IngestError as e:
@@ -653,11 +678,16 @@ def ingest_cmd(args) -> None:
         raise SystemExit(1)
     dt = max(time.time() - t0, 1e-9)
     s = client.summary()
+    alerts = sum(a["alerts"] for a in acks)
     print(f"stream {s['stream']}: acked {s['rowsAcked']} rows in "
           f"{s['batchesAcked']} batches ({s['rowsAcked'] / dt:,.0f} "
           f"rows/s), {alerts} alerts, {s['duplicates']} duplicate "
           f"acks, {s['rejected429']} over-capacity retries, "
           f"{s['transientRetries']} transient retries")
+    if args.json:
+        print(json.dumps({
+            **s, "rowsSent": rows_sent, "octetsSent": octets_sent,
+            "seconds": dt, "acks": acks}))
 
 
 # -- query (filtered aggregations over the store — the vectorized
@@ -1935,12 +1965,25 @@ def build_parser() -> argparse.ArgumentParser:
     ing.add_argument("--series", type=int, default=64,
                      help="synthetic connection series per batch")
     ing.add_argument("--points", type=int, default=30,
-                     help="points per series per batch")
+                     help="points per series per batch (successive "
+                          "batches carry successive time windows)")
     ing.add_argument("--anomaly-fraction", dest="anomaly_fraction",
                      type=float, default=0.1)
+    ing.add_argument("--base-throughput", dest="base_throughput",
+                     type=float, default=1.0e6,
+                     help="bytes/s scale of a connection (DBSCAN's "
+                          "fixed eps of 2.5e8 only sees spikes from "
+                          "about 1e7 up)")
+    ing.add_argument("--anomaly-magnitude", dest="anomaly_magnitude",
+                     type=float, default=20.0,
+                     help="spike height as a multiple of the base")
     ing.add_argument("--interval", type=float, default=0.0,
                      help="seconds between batches (0 = flat out)")
     ing.add_argument("--seed", type=int, default=0)
+    ing.add_argument("--json", action="store_true",
+                     help="print the producer's ledger as one JSON "
+                          "line last (per-ack rows/alerts, rows and "
+                          "octets sent)")
     ing.set_defaults(fn=ingest_cmd)
 
     q = sub.add_parser(

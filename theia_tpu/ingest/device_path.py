@@ -480,19 +480,13 @@ class FusedDetectorEngine:
         return _Step(items, work, outputs, t0)
 
     def _call_kernel(self, states, inputs):
-        if self._use_pallas:
-            try:
-                return _ops.fused_step(states, inputs,
-                                       alpha=self.alpha,
-                                       use_pallas=True,
-                                       interpret=self._interpret)
-            except Exception as e:   # noqa: BLE001
-                logger.error(
-                    "Pallas fused kernel failed (%s); falling back to "
-                    "the jnp scan permanently for this engine", e)
-                self._use_pallas = False
+        # No fallback between scan cores: a Pallas kernel that fails to
+        # compile or run fails the step (and its requests) loudly —
+        # which core runs is decided once, from the backend
+        # (ops.fused_detector.pallas_mode), never from a caught error.
         return _ops.fused_step(states, inputs, alpha=self.alpha,
-                               use_pallas=False)
+                               use_pallas=self._use_pallas,
+                               interpret=self._interpret)
 
     def _finish(self, step: Optional[_Step]) -> None:
         if step is None:
@@ -571,17 +565,6 @@ class FusedDetectorEngine:
         except Exception as e:   # noqa: BLE001 — fail the step's batches, not the loop
             logger.error("fused step resolve failed: %s", e,
                          exc_info=True)
-            if self._use_pallas:
-                # Async dispatch means a Pallas kernel that compiles
-                # but fails at EXECUTION surfaces here (device_get),
-                # not in _call_kernel — disable it so the next step
-                # takes the jnp path instead of re-dispatching the
-                # same broken kernel forever.
-                logger.error(
-                    "disabling the Pallas fused kernel after a "
-                    "resolve-time failure; subsequent steps use the "
-                    "jnp scan")
-                self._use_pallas = False
             for it in items:
                 if not it.future.done():
                     it.future.set_exception(e)

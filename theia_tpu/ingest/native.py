@@ -33,6 +33,9 @@ import numpy as np
 from ..schema import FLOW_SCHEMA, ColumnarBatch, ColumnKind, \
     StringDictionary
 from ..store import wire as _wire
+from ..utils.logging import get_logger
+
+logger = get_logger("native")
 
 _KIND_CODE = {"int": 0, "float": 1, "string": 2}
 
@@ -83,11 +86,17 @@ def _load_library() -> Optional[ctypes.CDLL]:
             if not os.path.exists(so):
                 _compile(so)
             _lib = _bind(ctypes.CDLL(so))
+            return _lib
         except (OSError, subprocess.CalledProcessError,
                 AttributeError) as e:
             detail = getattr(e, "stderr", "") or str(e)
             _build_error = f"native ingest unavailable: {detail}"
-        return _lib
+    # The pure-Python decoder / numpy tensorizer take over from here;
+    # say so once, loudly (outside the lock) — the same fact is on the
+    # entry points' start-up line and /healthz ingest.native.
+    logger.error("%s — using the pure-Python decoder and the numpy "
+                 "series builder", _build_error)
+    return None
 
 
 def _compile(so: str) -> None:
@@ -165,6 +174,14 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 def native_available() -> bool:
     return _load_library() is not None
+
+
+def native_status() -> str:
+    """One word for logs and health docs: `loaded`, or `unavailable`
+    with the build/load error (the Python paths are in use)."""
+    if native_available():
+        return "loaded"
+    return f"unavailable ({(_build_error or '').strip()[-200:]})"
 
 
 class TsvDecoder:

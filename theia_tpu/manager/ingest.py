@@ -77,7 +77,8 @@ import numpy as np
 
 from ..analytics.heavy_hitters import HeavyHitterDetector
 from ..analytics.streaming import StreamingDetector
-from ..ingest.native import BLOCK_MAGIC, BLOCK_MAGIC_V1, TsvDecoder
+from ..ingest.native import (BLOCK_MAGIC, BLOCK_MAGIC_V1, TsvDecoder,
+                             native_available)
 from ..store import wire as _wire
 from ..store.wal import RECORD_MAGIC
 from ..obs import metrics as _metrics
@@ -170,18 +171,15 @@ def default_detector_engine() -> str:
 
 
 def resolve_auto_engine() -> str:
-    """`auto` → concrete engine for this host: the fused single-
-    dispatch pipeline wins on accelerator backends, while CPU-only
-    hosts measure faster on the sharded per-lock path (448k vs 642k
-    rows/s detector-leg on the 2-core reference host — the crossover
-    docs/ingest.md records). Unprobeable backend resolves sharded:
-    the conservative engine is the one that cannot need a device."""
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        return "sharded"
-    return "fused" if backend in ("tpu", "gpu") else "sharded"
+    """`auto` → concrete engine for this host, from the backend JAX
+    reports: the fused single-dispatch pipeline on accelerator
+    backends, the sharded per-lock path on CPU-only hosts (448k vs
+    642k rows/s detector-leg on the 2-core reference host — the CPU
+    crossover docs/ingest.md records). A backend that fails to
+    initialize is an error here, not a reason to pick an engine."""
+    import jax
+    return ("fused" if jax.default_backend() in ("tpu", "gpu")
+            else "sharded")
 
 
 class StreamCapacityError(Exception):
@@ -1208,6 +1206,9 @@ class IngestManager:
             "streams": len(self._streams),
             "rowsIngested": self.rows_ingested,
             "engine": engine,
+            # which decoder/tensorizer this process runs: a failed
+            # g++ build or load leaves the pure-Python paths serving
+            "native": native_available(),
             "perShard": per_shard,
         }
 

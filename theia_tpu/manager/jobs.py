@@ -46,6 +46,7 @@ import uuid
 import zlib
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ..analytics import (TadQuerySpec, run_drop_detection, run_npr,
@@ -219,6 +220,9 @@ class JobController:
         self.alert_sink = alert_sink
         # One job owns the accelerator at a time in subprocess mode:
         # two children would interleave compilations and thrash HBM.
+        # (Children against each other only — a manager whose own
+        # detectors hold a one-chip host's chip leaves none for any
+        # child; see _run_subprocess.)
         self._device_lock = named_lock("jobs.device")
         self._records: Dict[str, JobRecord] = {}
         self._lock = named_lock("jobs.controller")
@@ -708,7 +712,14 @@ class JobController:
                                            progress_file)
             pkg_root = os.path.dirname(os.path.dirname(
                 os.path.dirname(os.path.abspath(__file__))))
+            # The child is told the manager's platform explicitly:
+            # with JAX_PLATFORMS unset, a child that cannot get the
+            # accelerator would carry on on the CPU unannounced. On a
+            # one-chip host the manager itself holds the chip, so the
+            # child FAILS (libtpu's "already in use" lands in
+            # runner_log_tail) — such hosts run --dispatch thread.
             env = {**os.environ,
+                   "JAX_PLATFORMS": jax.default_backend(),
                    "PYTHONPATH": pkg_root + os.pathsep +
                    os.environ.get("PYTHONPATH", "")}
             # Snapshot outside the device lock (table scans are

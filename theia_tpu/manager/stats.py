@@ -134,6 +134,11 @@ class StatsProvider:
                 mem = dev.memory_stats() or {}
                 if "bytes_in_use" in mem:
                     info["memoryBytesInUse"] = str(mem["bytes_in_use"])
+                if "peak_bytes_in_use" in mem:
+                    # high-water mark: what a finished job held here
+                    # (on a multi-chip host, proof its shards landed)
+                    info["memoryPeakBytesInUse"] = str(
+                        mem["peak_bytes_in_use"])
                 if "bytes_limit" in mem:
                     info["memoryBytesLimit"] = str(mem["bytes_limit"])
                     limit = max(int(mem["bytes_limit"]), 1)

@@ -40,12 +40,10 @@ import jax.numpy as jnp
 from ..analytics.streaming import StreamState, _update as _stream_tick
 from .ewma import DEFAULT_ALPHA
 from .sketch import CmsState, KMeansState, cms_query, cms_update, kmeans_step
-from ..utils import get_logger
 
-logger = get_logger("fused_detector")
-
-#: Pallas lane width: the tile scan kernel blocks the slot axis by this
-#: (slot tiles are already padded to powers of two >= 64).
+#: Pallas lane width: the tile scan kernel blocks the slot axis by this.
+#: Slot tiles arrive padded to powers of two >= 64; the one 64-wide
+#: bucket is padded up to a full lane block inside the kernel wrapper.
 PALLAS_BLOCK_U = 128
 
 
@@ -99,7 +97,15 @@ def _scan_tile_pallas(sub: StreamState, x: jnp.ndarray,
     Math is kept line-for-line identical to streaming._update."""
     from jax.experimental import pallas as pl
 
-    t, u = x.shape
+    t, u_in = x.shape
+    pad = -u_in % PALLAS_BLOCK_U
+    if pad:
+        # inactive padding columns: their state is carried through
+        # untouched and sliced away below
+        sub = StreamState(*(jnp.pad(a, (0, pad)) for a in sub))
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+        active = jnp.pad(active, ((0, 0), (0, pad)))
+    u = u_in + pad
     alpha = float(alpha)
     one_minus = 1.0 - alpha
 
@@ -149,8 +155,8 @@ def _scan_tile_pallas(sub: StreamState, x: jnp.ndarray,
         interpret=interpret,
     )(sub.ewma[None, :], sub.count[None, :], sub.mean[None, :],
       sub.m2[None, :], x, active)
-    ewma_n, count_n, mean_n, m2_n, anom = outs
-    return StreamState(ewma_n[0], count_n[0], mean_n[0], m2_n[0]), anom
+    state = StreamState(*(o[0, :u_in] for o in outs[:4]))
+    return state, outs[4][:, :u_in]
 
 
 def _stream_half(stream: StreamState, inp: ShardInputs, alpha,
@@ -161,7 +167,7 @@ def _stream_half(stream: StreamState, inp: ShardInputs, alpha,
     Padding slots hold `capacity`: the gather clamps harmlessly and
     the scatter DROPS them (XLA's documented OOB semantics)."""
     sub = StreamState(*(a[inp.slots] for a in stream))
-    if use_pallas and inp.x.shape[1] % PALLAS_BLOCK_U == 0:
+    if use_pallas:
         sub, anomalies = _scan_tile_pallas(sub, inp.x, inp.active,
                                            alpha, interpret)
     else:
@@ -241,8 +247,4 @@ def pallas_mode() -> Tuple[bool, bool]:
         return True, True
     if raw in ("1", "force", "on", "yes"):
         return True, False
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return False, False
-    return backend == "tpu", False
+    return jax.default_backend() == "tpu", False

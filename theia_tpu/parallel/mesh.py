@@ -30,26 +30,6 @@ TIME_AXIS = "time"
 ROWS_AXIS = "rows"
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None):
-    """`jax.shard_map` across jax versions.
-
-    Newer jax exposes it at top level with a `check_vma` flag; older
-    releases only have `jax.experimental.shard_map.shard_map`, where
-    the same flag is spelled `check_rep`. Every shard_map call in the
-    tree routes through here so kernels run on whichever jax the host
-    ships."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, **kwargs)
-
-
 def make_mesh(n_devices: Optional[int] = None,
               time_shards: int = 1,
               devices: Optional[Sequence] = None) -> Mesh:

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.ewma import DEFAULT_ALPHA
-from .mesh import SERIES_AXIS, TIME_AXIS, Mesh, shard_map
+from .mesh import SERIES_AXIS, TIME_AXIS, Mesh
 
 
 def _local_scan(a, b):
@@ -95,7 +95,7 @@ def make_sharded_ewma(mesh: Mesh, alpha: float = DEFAULT_ALPHA):
     n_time = mesh.shape[TIME_AXIS]
     step = functools.partial(_sharded_step, alpha=alpha,
                              n_time_shards=n_time)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(SERIES_AXIS, TIME_AXIS), P(SERIES_AXIS, TIME_AXIS)),
         out_specs=(P(SERIES_AXIS, TIME_AXIS), P(SERIES_AXIS),
@@ -121,7 +121,7 @@ def make_series_sharded(mesh: Mesh, kernel):
     to the single-device kernel (same computation graph per series).
     The time axis of the mesh (if >1) replicates.
     """
-    mapped = shard_map(
+    mapped = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P(SERIES_AXIS, None), P(SERIES_AXIS, None)),
         out_specs=(P(SERIES_AXIS, None), P(SERIES_AXIS),
@@ -192,7 +192,7 @@ def make_sharded_points_dbscan(mesh: Mesh, eps: float,
         return valid_loc & ~core_loc & ~reach
 
     from jax.sharding import PartitionSpec as P2
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P2(ROWS_AXIS, None), P2(ROWS_AXIS)),
         out_specs=P2(ROWS_AXIS),

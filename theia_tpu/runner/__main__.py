@@ -308,15 +308,6 @@ def run_spatial_job(args) -> str:
 
 
 def main(argv=None) -> None:
-    # Honor an explicit JAX_PLATFORMS before any backend initializes
-    # (deployment sitecustomize hooks may pin the platform
-    # programmatically, overriding the env var) — the manager spawns
-    # runner children with the platform it wants them on.
-    import os
-    plats = os.environ.get("JAX_PLATFORMS", "").strip()
-    if plats:
-        import jax
-        jax.config.update("jax_platforms", plats)
     args = build_parser().parse_args(argv)
     # Fault point shared with thread dispatch: THEIA_FAULTS reaches
     # this child through the env the controller spawned it with. An
@@ -331,6 +322,13 @@ def main(argv=None) -> None:
     except faults.FaultError as e:
         print(str(e), file=sys.stderr)
         raise SystemExit(TRANSIENT_EXIT_CODE)
+    # Before the first JAX call: the persistent compile cache, then one
+    # line saying what this process runs on (it lands in the
+    # controller's runner_log_tail). The platform is whatever
+    # JAX_PLATFORMS says — the controller hands children its own.
+    from ..utils.device import enable_compile_cache, runtime_banner
+    enable_compile_cache()
+    print(f"theia-runner runtime: {runtime_banner()}", file=sys.stderr)
     runners = {"tad": run_tad_job, "npr": run_npr_job,
                "dropdetection": run_dd_job,
                "patterns": run_patterns_job,

@@ -146,8 +146,7 @@ class Table:
         self.bytes_inserted_total = 0
         # Cached source-dict → table-dict code mappings: a producer
         # streaming blocks with its own dictionaries pays string
-        # re-encode only for NEW entries, not per block (the 6.6x
-        # per-block store overhead of BENCH_r04).
+        # re-encode only for NEW entries, not per block.
         self._adopt_maps: Dict[str, DictionaryMapper] = {
             name: DictionaryMapper(d) for name, d in self.dicts.items()}
         self._adopt_lock = named_lock("store.table_adopt")
