@@ -410,6 +410,11 @@ def test_ingest_ack_fast_path_serialization():
         {"rows": 320, "alerts": 121, "traceId": "ab" * 16},
         {"rows": 0, "alerts": 0},
         {"rows": 5, "alerts": 0, "duplicate": True, "traceId": "0" * 32},
+        {"rows": 320, "alerts": 9,
+         "alertsByKind": {"heavy_hitter": 2, "connection_anomaly": 7},
+         "traceId": "cd" * 16},
+        {"rows": 1, "alerts": 0,
+         "alertsByKind": {"heavy_hitter": 0, "connection_anomaly": 0}},
     ]
     for doc in hot:
         raw = _fast_ack_bytes(doc)
@@ -423,6 +428,7 @@ def test_ingest_ack_fast_path_serialization():
         {"rows": "5", "alerts": 0},
         {"rows": 5, "alerts": 0, "duplicate": False},
         {"rows": 5, "alerts": 0, "traceId": 'a"b'},
+        {"rows": 5, "alerts": 1, "alertsByKind": {"heavy_hitter": 1}},
     ]
     for doc in cold:
         assert _fast_ack_bytes(doc) is None

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 from ..ops.ewma import DEFAULT_ALPHA
 from ..schema import ColumnarBatch
 
@@ -58,6 +59,29 @@ _M_DROPPED = _metrics.counter(
     "theia_detector_series_dropped_total",
     "New connection series dropped because every streaming-detector "
     "slot was taken (the series is never scored)")
+
+
+# The detector leg of a request, by stage (self time; one observation
+# per shard slice for the per-shard stages, so _sum / blocks is seconds
+# a block and _count{stage="dispatch"} / blocks is dispatches a block).
+# Declared here, the lowest module of the leg; manager/ingest.py takes
+# the children of its own stages from it.
+DETECTOR_STAGE = _metrics.histogram(
+    "theia_detector_stage_seconds",
+    "Host time of the detector leg by stage: remap (dictionary remap "
+    "incl. the wait for its lock), partition, lock_wait (blocked on a "
+    "busy shard), heavy_hitters, plan (key to slot + tick tile), "
+    "dispatch (tile to device + the step call returning), fetch "
+    "(wait for the device + copy back), alerts",
+    labelnames=("stage",))
+_M_PLAN = DETECTOR_STAGE.labels(stage="plan")
+_M_DISPATCH = DETECTOR_STAGE.labels(stage="dispatch")
+_M_FETCH = DETECTOR_STAGE.labels(stage="fetch")
+_M_ALERTS = DETECTOR_STAGE.labels(stage="alerts")
+H2D_BYTES = _metrics.counter(
+    "theia_detector_h2d_bytes_total",
+    "Bytes of slots + x + active handed to the streaming detector's "
+    "device step, power-of-two padding included")
 
 
 class StreamState(NamedTuple):
@@ -120,17 +144,20 @@ def stream_update_sparse(state: StreamState, slots: jnp.ndarray,
 
     Returns (new state, anomaly [T, U]).
     """
-    sub = StreamState(*(a[slots] for a in state))
+    with jax.named_scope("gather"):
+        sub = StreamState(*(a[slots] for a in state))
 
     def step(carry, inp):
         x_t, act_t = inp
         new, anomaly = _update(carry, x_t, act_t, alpha)
         return new, anomaly
 
-    sub, anomalies = jax.lax.scan(step, sub, (x, active))
-    new_state = StreamState(*(
-        full.at[slots].set(part, mode="drop")
-        for full, part in zip(state, sub)))
+    with jax.named_scope("scan"):
+        sub, anomalies = jax.lax.scan(step, sub, (x, active))
+    with jax.named_scope("scatter"):
+        new_state = StreamState(*(
+            full.at[slots].set(part, mode="drop")
+            for full, part in zip(state, sub)))
     return new_state, anomalies
 
 
@@ -317,23 +344,30 @@ class StreamingDetector:
         if len(batch) == 0:
             return []
         t_arrival = self.clock()
-        keys = np.stack(
-            [np.asarray(batch[c], np.int64)
-             for c in CONNECTION_KEY_COLUMNS], axis=1)
-        values = np.asarray(batch[self.value_column], np.float64)
-        times = np.asarray(batch["flowEndSeconds"], np.int64)
-        plan = self.build_plan(keys, values)
+        with _trace.stage("detector.plan", _M_PLAN):
+            keys = np.stack(
+                [np.asarray(batch[c], np.int64)
+                 for c in CONNECTION_KEY_COLUMNS], axis=1)
+            values = np.asarray(batch[self.value_column], np.float64)
+            times = np.asarray(batch["flowEndSeconds"], np.int64)
+            plan = self.build_plan(keys, values)
         if plan is None:
             return []
-        self.state, anomaly = stream_update_sparse(
-            self.state, jnp.asarray(plan.slots), jnp.asarray(plan.x),
-            jnp.asarray(plan.active), self.alpha)
-
-        hits = np.argwhere(np.asarray(anomaly))
-        if not hits.size:
-            return []
-        latency = self.clock() - t_arrival
-        return plan_alerts(plan, hits, times, values, latency)
+        with _trace.stage("detector.dispatch", _M_DISPATCH):
+            H2D_BYTES.inc(plan.slots.nbytes + plan.x.nbytes
+                          + plan.active.nbytes)
+            self.state, anomaly = stream_update_sparse(
+                self.state, jnp.asarray(plan.slots),
+                jnp.asarray(plan.x), jnp.asarray(plan.active),
+                self.alpha)
+        with _trace.stage("detector.fetch", _M_FETCH):
+            anomaly = np.asarray(anomaly)
+        with _trace.stage("detector.alerts", _M_ALERTS):
+            hits = np.argwhere(anomaly)
+            if not hits.size:
+                return []
+            latency = self.clock() - t_arrival
+            return plan_alerts(plan, hits, times, values, latency)
 
     def describe_alert(self, batch: ColumnarBatch,
                        alert: Dict[str, object]) -> Dict[str, object]:

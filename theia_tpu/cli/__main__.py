@@ -608,9 +608,18 @@ def profile(args) -> None:
     """Capture an XLA profiler trace from the manager (no reference
     equivalent — its closest surface is the ClickHouse stack-trace
     dump)."""
+    if args.summarize:
+        # offline: a downloaded capture (tar.gz), a trace directory or
+        # an .xplane.pb — device busy/idle and the longest idle gaps,
+        # each named by the program's spans open during it
+        from ..obs import xplane
+        print(xplane.render(xplane.summarize(args.summarize)))
+        return
     path = "/apis/system.theia.antrea.io/v1alpha1/profiles"
-    _request(args.manager_addr, "POST", path,
-             {"durationSeconds": args.duration})
+    body = {"durationSeconds": args.duration}
+    if args.python_tracer:
+        body["pythonTracer"] = True
+    _request(args.manager_addr, "POST", path, body)
     out = args.file or "theia-profile.tar.gz"
     n = _poll_and_download(args.manager_addr, path,
                            args.duration + 120, out, "profile")
@@ -2032,6 +2041,14 @@ def build_parser() -> argparse.ArgumentParser:
                                "the manager")
     prof.add_argument("-d", "--duration", type=float, default=3.0)
     prof.add_argument("-f", "--file", default="")
+    prof.add_argument("--python-tracer", action="store_true",
+                      help="also trace Python functions (slows the "
+                           "manager severalfold while it runs)")
+    prof.add_argument("--summarize", metavar="PATH", default="",
+                      help="no capture: summarize a downloaded one "
+                           "(tar.gz, trace directory or .xplane.pb) — "
+                           "device busy/idle and the longest idle "
+                           "gaps by host span")
     prof.set_defaults(fn=profile)
 
     tp = sub.add_parser("top",

@@ -11,23 +11,67 @@ materialized view (store/views.py) and reduces over dictionary codes —
 same data contract, no SQL engine in the path.
 
 Every function returns plain-JSON data (lists/dicts), consumed by both
-the HTML renderer (web.py) and the /dashboards/api endpoints.
+the HTML renderer (web.py) and the /dashboards/api endpoints
+(`panel_json`, which also times a request: span `dashboard.panel`,
+stages dash.scan / dash.aggregate / dash.encode).
 """
 
 from __future__ import annotations
 
+import inspect
+import json
 import os
-from typing import Dict, List, Optional, Tuple
+import time
+from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
+from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 from ..store import FlowDatabase
 from ..store.views import group_reduce
 
 FLOW_TYPE_TO_EXTERNAL = 3
 
+_M_PANEL = _metrics.histogram(
+    "theia_dashboard_panel_seconds",
+    "One /dashboards/api/<panel> request, server side: scan, "
+    "aggregation and JSON encode", labelnames=("panel",))
+_M_STAGE = _metrics.histogram(
+    "theia_dashboard_stage_seconds",
+    "A dashboard request by stage (self time): scan (table or view "
+    "to a column batch), aggregate (the panel's body), encode "
+    "(json.dumps)", labelnames=("stage",))
+_M_SCAN = _M_STAGE.labels(stage="scan")
+_M_AGGREGATE = _M_STAGE.labels(stage="aggregate")
+_M_ENCODE = _M_STAGE.labels(stage="encode")
+_M_ROWS = _metrics.counter(
+    "theia_dashboard_rows_scanned_total",
+    "Rows of the scans behind dashboard panels (per panel: the "
+    "`rows` attribute of its dashboard.panel span)")
+
+
+def _scanned(batch):
+    """Count one scan's rows, in total and on the enclosing span."""
+    n = len(batch)
+    _M_ROWS.inc(n)
+    sp = _trace.current_span()
+    if sp is not None:
+        sp.attrs["rows"] = sp.attrs.get("rows", 0) + n
+    return batch
+
+
+def _flows_scan(db):
+    with _trace.stage("dash.scan", _M_SCAN):
+        return _scanned(db.flows.scan())
+
 
 def _view_scan(db, name: str):
+    with _trace.stage("dash.scan", _M_SCAN):
+        return _scanned(_view_batch(db, name))
+
+
+def _view_batch(db, name: str):
     """One materialized view in the ViewTable.scan() shape, routed by
     THEIA_DASHBOARD_ROLLUP: unset/0 reads the legacy in-memory view
     table; `1` reads the rollup-backed `__rollup__:<view>` aggregate
@@ -103,7 +147,7 @@ def homepage(db: FlowDatabase) -> Dict[str, object]:
     """Cluster summary (reference homepage.json: 12 stat panels +
     bargauge of top namespaces + cluster-throughput timeseries +
     dashlist — the dashlist is the nav bar on every page)."""
-    flows = db.flows.scan()
+    flows = _flows_scan(db)
     out: Dict[str, object] = {
         "flowCount": len(flows),
         "tadAnomalies": 0,
@@ -159,7 +203,7 @@ def flow_records(db: FlowDatabase, limit: int = 100,
                  start: Optional[int] = None,
                  end: Optional[int] = None) -> List[Dict[str, object]]:
     """Raw recent records (reference flow_records_dashboard.json:90)."""
-    flows = db.flows.scan()
+    flows = _flows_scan(db)
     mask = _time_window(np.asarray(flows["flowEndSeconds"]), start, end)
     sub = flows.filter(mask)
     order = np.argsort(-np.asarray(sub["flowEndSeconds"]))[:limit]
@@ -264,7 +308,7 @@ def networkpolicy(db: FlowDatabase, k: int = 10, start=None, end=None):
 def network_topology(db: FlowDatabase, start=None, end=None):
     """Namespace-level dependency edges (reference
     network_topology_dashboard's mermaid graph, DependencyPanel.tsx)."""
-    flows = db.flows.scan()
+    flows = _flows_scan(db)
     mask = _time_window(np.asarray(flows["flowEndSeconds"]), start, end)
     src = np.asarray(flows["sourcePodNamespace"], np.int64)[mask]
     dst_ns = np.asarray(flows["destinationPodNamespace"],
@@ -295,3 +339,25 @@ DASHBOARDS = {
     "networkpolicy": networkpolicy,
     "network_topology": network_topology,
 }
+
+
+def panel_json(db: FlowDatabase, name: str, query: Mapping[str, str],
+               traceparent: Optional[str] = None) -> bytes:
+    """The encoded answer of GET /dashboards/api/<name>: the panel's
+    data for the integer parameters of `query` it accepts (start, end,
+    limit, k). An unknown panel raises KeyError before anything is
+    timed."""
+    fn = DASHBOARDS[name]
+    accepted = inspect.signature(fn).parameters
+    kwargs = {k: int(query[k]) for k in ("start", "end", "limit", "k")
+              if k in query and k in accepted}
+    t0 = time.perf_counter()
+    with _trace.ingress_span("dashboard.panel", traceparent=traceparent,
+                             panel=name):
+        with _trace.stage("dash.aggregate", _M_AGGREGATE):
+            data = fn(db, **kwargs)
+        with _trace.stage("dash.encode", _M_ENCODE):
+            raw = json.dumps({"dashboard": name, "data": data},
+                             default=str).encode()
+    _M_PANEL.labels(panel=name).observe(time.perf_counter() - t0)
+    return raw

@@ -58,6 +58,7 @@ import numpy as np
 from ..analytics import heavy_hitters as _hh
 from ..analytics.streaming import (
     CONNECTION_KEY_COLUMNS,
+    H2D_BYTES,
     StreamPlan,
     alert_record,
 )
@@ -456,6 +457,8 @@ class FusedDetectorEngine:
                 slots=splan.slots, x=splan.x, active=splan.active,
                 keys=hplan.keys, vols=hplan.vols, q=hplan.q,
                 feats=hplan.feats, valid=hplan.valid))
+            H2D_BYTES.inc(splan.slots.nbytes + splan.x.nbytes
+                          + splan.active.nbytes)
             work.append(_ShardWork(shard, splan, hplan, times, vals,
                                    item_of, row_of, segments,
                                    k6[:, _KEY_DST], n_s))

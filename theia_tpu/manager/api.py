@@ -53,6 +53,8 @@ from ..obs import metrics as _obs_metrics
 from ..obs import prom as _obs_prom
 from ..obs import trace as _obs_trace
 from .jobs import (
+    JOB_RESULT_BYTES,
+    JOB_RESULT_SECONDS,
     KIND_DD,
     KIND_FPM,
     KIND_NPR,
@@ -102,20 +104,22 @@ _KIND_NAMES = {
     KIND_SPATIAL: "SpatialAnomalyDetection",
 }
 
-# Pre-serialized fragments for the two hot ingest ack shapes
-# ({"rows","alerts"[,"traceId"]} and the duplicate variant). The
-# ingest ingress answers every batch with one of these; building a
-# fresh dict walk + json.dumps per request showed up in profiles next
-# to the actual socket write.
+# Pre-serialized fragments for the hot ingest ack shapes
+# ({"rows","alerts"[,"alertsByKind"][,"traceId"]} and the duplicate
+# variant). The ingest ingress answers every batch with one of these;
+# building a fresh dict walk + json.dumps per request showed up in
+# profiles next to the actual socket write.
 _ACK_ROWS = b'{"rows":'
 _ACK_ALERTS = b',"alerts":'
+_ACK_KIND_HH = b',"alertsByKind":{"heavy_hitter":'
+_ACK_KIND_CONN = b',"connection_anomaly":'
 _ACK_DUP = b',"duplicate":true'
 _ACK_TRACE = b',"traceId":"'
 
 
 def _fast_ack_bytes(doc: Dict[str, object]) -> Optional[bytes]:
     """Serialize an ingest ack from cached fragments when it has one
-    of the two fixed hot shapes; None for anything else (forwardedRows,
+    of the fixed hot shapes; None for anything else (forwardedRows,
     degraded, parked...) — the caller falls back to json.dumps. The
     output is byte-identical to json.dumps(doc, separators=(',',':'))
     for the covered shapes."""
@@ -124,15 +128,25 @@ def _fast_ack_bytes(doc: Dict[str, object]) -> Optional[bytes]:
         alerts = doc["alerts"]
     except KeyError:
         return None
+    kinds = doc.get("alertsByKind")
     dup = doc.get("duplicate")
     trace = doc.get("traceId")
-    if len(doc) != 2 + (dup is not None) + (trace is not None):
+    if len(doc) != (2 + (kinds is not None) + (dup is not None)
+                    + (trace is not None)):
         return None
     if type(rows) is not int or type(alerts) is not int \
             or dup not in (None, True):
         return None
     parts = [_ACK_ROWS, str(rows).encode(), _ACK_ALERTS,
              str(alerts).encode()]
+    if kinds is not None:
+        if type(kinds) is not dict or list(kinds) != [
+                "heavy_hitter", "connection_anomaly"] \
+                or any(type(v) is not int for v in kinds.values()):
+            return None
+        parts += [_ACK_KIND_HH, str(kinds["heavy_hitter"]).encode(),
+                  _ACK_KIND_CONN,
+                  str(kinds["connection_anomaly"]).encode(), b"}"]
     if dup:
         parts.append(_ACK_DUP)
     if trace is not None:
@@ -420,27 +434,42 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
 
     # -- helpers ---------------------------------------------------------
 
-    def _send_json(self, doc, code: int = 200) -> None:
-        raw = json.dumps(doc, default=str).encode()
+    def _send_raw_json(self, raw: bytes, code: int = 200) -> None:
         self.send_response(code)
         self.send_header("Content-Type", "application/json")
         self.send_header("Content-Length", str(len(raw)))
         self.end_headers()
         self.wfile.write(raw)
 
+    def _send_json(self, doc, code: int = 200) -> None:
+        self._send_raw_json(json.dumps(doc, default=str).encode(),
+                            code)
+
     def _send_ingest_ack(self, doc: Dict[str, object]) -> None:
         """200 ack on the ingest hot path: cached-fragment
-        serialization for the two fixed ack shapes, json.dumps
-        fallback for the rest."""
+        serialization for the fixed ack shapes, json.dumps fallback
+        for the rest."""
         raw = _fast_ack_bytes(doc)
         if raw is None:
             self._send_json(doc)
             return
-        self.send_response(200)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(raw)))
-        self.end_headers()
-        self.wfile.write(raw)
+        self._send_raw_json(raw)
+
+    def _send_job_result(self, doc, kind: str) -> None:
+        """The answer that carries a completed job's result rows, its
+        encode and its socket write timed apart (the row scan is timed
+        where it happens, JobController._result_stats): with the
+        client's polling these are the whole of a job's turn-around
+        outside its run."""
+        with _obs_trace.stage("job.result.encode",
+                              JOB_RESULT_SECONDS.labels(
+                                  kind=kind, phase="encode")):
+            raw = json.dumps(doc, default=str).encode()
+        JOB_RESULT_BYTES.labels(kind=kind).inc(len(raw))
+        with _obs_trace.stage("job.result.send",
+                              JOB_RESULT_SECONDS.labels(
+                                  kind=kind, phase="send")):
+            self._send_raw_json(raw)
 
     def _send_error_json(self, code: int, message: str) -> None:
         # Error paths can fire BEFORE the request body was consumed
@@ -962,21 +991,16 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
         # the data server-side), so the whole surface is token-gated
         # when auth is configured.
         self._require_auth()
-        import inspect
-
-        from ..dashboards import DASHBOARDS, grafana_dashboard, render
+        from ..dashboards import grafana_dashboard, render
+        from ..dashboards.queries import panel_json
         if len(parts) >= 3 and parts[1] == "api":
             qs = self._query()
             if qs.get("format") == "grafana":
                 self._send_json(grafana_dashboard(parts[2]))
                 return
-            fn = DASHBOARDS[parts[2]]
-            accepted = inspect.signature(fn).parameters
-            kwargs = {name: int(qs[name]) for name
-                      in ("start", "end", "limit", "k")
-                      if name in qs and name in accepted}
-            self._send_json({"dashboard": parts[2],
-                             "data": fn(self.controller.db, **kwargs)})
+            self._send_raw_json(panel_json(
+                self.controller.db, parts[2], qs,
+                traceparent=self.headers.get("traceparent")))
             return
         name = parts[1] if len(parts) > 1 else "homepage"
         page = render(name, self.controller.db).encode()
@@ -1000,8 +1024,12 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
             record = self.controller.get(parts[4])
             if record.kind != kind:
                 raise KeyError(parts[4])
-            self._send_json(record_to_api(record, self.controller,
-                                          with_result=True))
+            doc = record_to_api(record, self.controller,
+                                with_result=True)
+            if "stats" in doc:
+                self._send_job_result(doc, kind)
+            else:
+                self._send_json(doc)
         else:
             raise KeyError(self.path)
 
@@ -1064,6 +1092,12 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
         if len(parts) >= 4 and parts[3] == "profiles":
             if len(parts) == 6 and parts[5] == "download":
                 stream(self.profiles.data(), "profile")
+                return
+            if len(parts) == 6 and parts[5] == "summary":
+                doc = self.profiles.summary()
+                if doc is None:
+                    raise KeyError("profile not collected")
+                self._send_json(doc)
                 return
             self._send_json(self.profiles.to_api())
             return
@@ -1194,7 +1228,8 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
                 and parts[3] == "profiles":
             body = self._read_body()
             self._send_json(self.profiles.create(
-                float(body.get("durationSeconds", 3.0) or 3.0)), 201)
+                float(body.get("durationSeconds", 3.0) or 3.0),
+                python_tracer=body.get("pythonTracer") is True), 201)
             return
         raise KeyError(self.path)
 
@@ -1574,6 +1609,9 @@ class TheiaManagerServer:
             self.cluster.start()
         if self.history is not None:
             self.history.start()
+        # full collections stop a request thread mid-flight: report
+        # them beside the other housekeeping (bg.gc)
+        _obs_trace.watch_gc()
         self._thread: Optional[threading.Thread] = None
         self._serving = False
 
@@ -1606,5 +1644,6 @@ class TheiaManagerServer:
             self.cluster.stop()
         self.ingest.close()
         self.controller.shutdown()
+        _obs_trace.unwatch_gc()
         if self._thread:
             self._thread.join(timeout=2)

@@ -8,7 +8,9 @@ producers POST flow batches to the manager —
     POST /ingest
         body: a TFB2 binary columnar block (application/octet-stream)
               or TabSeparated rows (text/tab-separated-values)
-        response: {"rows": N, "alerts": K}
+        response: {"rows": N, "alerts": K,
+                   "alertsByKind": {"heavy_hitter": H,
+                                    "connection_anomaly": C}}
 
 Every ingested batch fans out to the store (materialized views, TTL)
 AND advances the streaming detectors — the heavy-hitter / DDoS sketch
@@ -76,7 +78,7 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..analytics.heavy_hitters import HeavyHitterDetector
-from ..analytics.streaming import StreamingDetector
+from ..analytics.streaming import DETECTOR_STAGE, StreamingDetector
 from ..ingest.native import (BLOCK_MAGIC, BLOCK_MAGIC_V1, TsvDecoder,
                              native_available)
 from ..store import wire as _wire
@@ -110,6 +112,22 @@ _M_STAGE_DET = _M_STAGE.labels(stage="detector")
 _M_REQUEST = _metrics.histogram(
     "theia_ingest_request_seconds",
     "Whole POST /ingest request latency (decode + max(legs))")
+# Thread CPU time beside the wall times above: wall - cpu - lock wait
+# - device fetch is what the request thread spent waiting for the
+# interpreter (four request threads share one) or the OS.
+_M_STAGE_CPU_DET = _metrics.histogram(
+    "theia_ingest_stage_cpu_seconds",
+    "Thread CPU time of an ingest stage on the request thread",
+    labelnames=("stage",)).labels(stage="detector")
+_M_REQUEST_CPU = _metrics.histogram(
+    "theia_ingest_request_cpu_seconds",
+    "Thread CPU time of one acked POST /ingest on its request thread "
+    "(the store-insert leg runs on a pool thread and is not in it)")
+_M_DET_REMAP = DETECTOR_STAGE.labels(stage="remap")
+_M_DET_PARTITION = DETECTOR_STAGE.labels(stage="partition")
+_M_DET_LOCK_WAIT = DETECTOR_STAGE.labels(stage="lock_wait")
+_M_DET_HEAVY = DETECTOR_STAGE.labels(stage="heavy_hitters")
+_M_DET_ALERTS = DETECTOR_STAGE.labels(stage="alerts")
 _M_ROWS = _metrics.counter(
     "theia_ingest_rows_total", "Rows acked on the ingest path")
 _M_BATCHES = _metrics.counter(
@@ -593,6 +611,9 @@ class IngestManager:
                                  sample_env="THEIA_TRACE_SAMPLE_INGEST",
                                  stream=stream) as sp:
             out = self._ingest_span_body(payload, stream, seq)
+            if "duplicate" not in out:
+                # one observation per acked batch, like _M_REQUEST
+                _M_REQUEST_CPU.observe(sp.cpu_seconds())
             sp.attrs["rows"] = out.get("rows", 0)
             if out.get("alerts"):
                 sp.attrs["alerts"] = out["alerts"]
@@ -896,8 +917,10 @@ class IngestManager:
         elif scored:
             try:
                 t_det = time.perf_counter()
+                c_det = time.thread_time()
                 alerts, conn_alerts, n_conn = self.score_batch(batch)
                 _M_STAGE_DET.observe(time.perf_counter() - t_det)
+                _M_STAGE_CPU_DET.observe(time.thread_time() - c_det)
             except Exception:
                 _M_ERRORS.labels(stage="detector").inc()
                 # await the insert leg even when scoring raised: an
@@ -962,14 +985,16 @@ class IngestManager:
             _M_ALERTS.labels(kind="heavy_hitter").inc(len(alerts))
         if n_conn:
             _M_ALERTS.labels(kind="connection_anomaly").inc(n_conn)
-        dt_req = time.perf_counter() - t_req
-        _M_REQUEST.observe(dt_req)
-        # the enclosing ingress span (ingest()) is the flight record
-        # now — sampled requests publish with trace context attached;
-        # tune THEIA_TRACE_SAMPLE down instead of a slow-only filter
+        _M_REQUEST.observe(time.perf_counter() - t_req)
         if n_alerts:
             logger.v(1).info("ingested %d rows, %d alerts", n, n_alerts)
-        out: Dict[str, object] = {"rows": total, "alerts": n_alerts}
+        # `alerts` stays the total; the split lets a client compare
+        # connection decisions block by block (heavy_hitter counts the
+        # volume and traffic-shape kinds together, like the metric)
+        out: Dict[str, object] = {
+            "rows": total, "alerts": n_alerts,
+            "alertsByKind": {"heavy_hitter": len(alerts),
+                             "connection_anomaly": n_conn}}
         if remote_rows:
             # rows this node forwarded to their owner-shard peers
             # (scored and alert-ringed THERE, not here)
@@ -1008,7 +1033,8 @@ class IngestManager:
         wait on each other."""
         if len(batch) == 0:
             return [], [], 0
-        scored, shard_ids = self._remap_global(batch)
+        with _trace.stage("detector.remap", _M_DET_REMAP):
+            scored, shard_ids = self._remap_global(batch)
         if self._fused is not None:
             # Fused engine: the remapped batch rides the coalescing
             # device pipeline (ingest/device_path.py) — no shard
@@ -1025,8 +1051,9 @@ class IngestManager:
         # visit order across shards is free to vary because slices of
         # one batch hold disjoint key sets — per-connection order is
         # enforced by the shard lock alone.
-        pending: Deque = collections.deque(
-            self._partition(scored, shard_ids))
+        with _trace.stage("detector.partition", _M_DET_PARTITION):
+            pending: Deque = collections.deque(
+                self._partition(scored, shard_ids))
         while pending:
             progressed = False
             for _ in range(len(pending)):
@@ -1046,20 +1073,26 @@ class IngestManager:
                 # opportunistic pass exists to avoid
                 _M_LOCK_WAIT.inc()
                 shard, part = pending.popleft()
-                with shard.lock:
+                with _trace.stage("detector.lock_wait",
+                                  _M_DET_LOCK_WAIT):
+                    shard.lock.acquire()
+                try:
                     n_conn += self._score_shard(
                         shard, part, hh_alerts, raw_alerts)
+                finally:
+                    shard.lock.release()
         # The ring keeps MAX_ALERTS; in an alert storm only the newest
         # survive, so only those are worth decoding — capped over the
         # WHOLE batch, not per shard slice, and decoded outside any
         # shard lock (describe_alert only reads the slice + dicts).
         conn_alerts: List[Dict[str, object]] = []
-        for shard, part, a in raw_alerts[-MAX_ALERTS:]:
-            described = shard.streaming.describe_alert(part, a)
-            # "row" is batch-local; meaningless once published
-            described.pop("row", None)
-            described["kind"] = "connection_anomaly"
-            conn_alerts.append(described)
+        with _trace.stage("detector.alerts", _M_DET_ALERTS):
+            for shard, part, a in raw_alerts[-MAX_ALERTS:]:
+                described = shard.streaming.describe_alert(part, a)
+                # "row" is batch-local; meaningless once published
+                described.pop("row", None)
+                described["kind"] = "connection_anomaly"
+                conn_alerts.append(described)
         return hh_alerts, conn_alerts, n_conn
 
     def _score_shard(self, shard: DetectorShard, part: ColumnarBatch,
@@ -1079,7 +1112,9 @@ class IngestManager:
         _M_SCORED.inc(len(part), stripe=shard.index)
         extra = float(self._shard_totals.sum()
                       - self._shard_totals[shard.index])
-        hh_alerts.extend(shard.heavy.update(part, extra_total=extra))
+        with _trace.stage("detector.heavy_hitters", _M_DET_HEAVY):
+            hh_alerts.extend(shard.heavy.update(part,
+                                                extra_total=extra))
         self._shard_totals[shard.index] = shard.heavy.total_volume
         raw_conn = shard.streaming.ingest(part)
         raw_alerts.extend((shard, part, a) for a in raw_conn)

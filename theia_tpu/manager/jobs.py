@@ -80,6 +80,18 @@ _M_JOBS = _obs_metrics.counter(
 _M_RETRIES = _obs_metrics.counter(
     "theia_job_retries_total",
     "Transient job failures re-queued with backoff")
+# What a job's turn-around holds outside its run and that is the
+# program's: the answer that carries the result rows (manager/api.py
+# observes encode and send).
+JOB_RESULT_SECONDS = _obs_metrics.histogram(
+    "theia_job_result_seconds",
+    "One answer carrying a completed job's result rows, by phase: "
+    "rows (result table scan to row dicts), encode (json.dumps), "
+    "send (socket write)", labelnames=("kind", "phase"))
+JOB_RESULT_BYTES = _obs_metrics.counter(
+    "theia_job_result_bytes_total",
+    "Bytes of JSON sent in answers carrying job result rows",
+    labelnames=("kind",))
 _M_DEADLINE_KILLS = _obs_metrics.counter(
     "theia_job_deadline_kills_total",
     "Runner children killed at deadlineSeconds")
@@ -340,12 +352,15 @@ class JobController:
         """Result rows for a job as string-typed stat entries
         (reference getTADetectorResult, rest.go:249-310)."""
         job_id = job_id_from_name(kind, name)
-        data = table.scan()
-        if not len(data):
-            return []
-        rows = data.filter(data.strings("id") == job_id)
-        return [{k: str(v) for k, v in row.items()}
-                for row in rows.to_rows()]
+        with _obs_trace.stage("job.result.rows",
+                              JOB_RESULT_SECONDS.labels(
+                                  kind=kind, phase="rows")):
+            data = table.scan()
+            if not len(data):
+                return []
+            rows = data.filter(data.strings("id") == job_id)
+            return [{k: str(v) for k, v in row.items()}
+                    for row in rows.to_rows()]
 
     def tad_stats(self, name: str) -> List[Dict[str, str]]:
         return self._result_stats(KIND_TAD, self.db.tadetector, name)
@@ -529,7 +544,8 @@ class JobController:
         spec = record.spec
         if record.kind == KIND_FPM:
             from ..analytics.itemsets import DEFAULT_COLUMNS
-            record.progress = JobProgress(record.job_id, FPM_STAGES)
+            record.progress = JobProgress(record.job_id, FPM_STAGES,
+                                          kind=record.kind)
             run_pattern_mining(
                 self.db,
                 min_support=int(spec.get("minSupport", 0) or 0),
@@ -543,8 +559,8 @@ class JobController:
         if record.kind == KIND_SPATIAL:
             from ..analytics.spatial import (DEFAULT_EPS,
                                              DEFAULT_MIN_SAMPLES)
-            record.progress = JobProgress(record.job_id,
-                                          SPATIAL_STAGES)
+            record.progress = JobProgress(record.job_id, SPATIAL_STAGES,
+                                          kind=record.kind)
             run_spatial(
                 self.db,
                 eps=float(spec.get("eps") or DEFAULT_EPS),
@@ -556,7 +572,8 @@ class JobController:
                 progress=record.progress)
             return
         if record.kind == KIND_TAD:
-            record.progress = JobProgress(record.job_id, TAD_STAGES)
+            record.progress = JobProgress(record.job_id, TAD_STAGES,
+                                          kind=record.kind)
             run_tad(
                 self.db, str(spec.get("jobType", "EWMA")),
                 TadQuerySpec(
@@ -579,7 +596,8 @@ class JobController:
                 tad_id=record.job_id,
                 progress=record.progress)
         elif record.kind == KIND_DD:
-            record.progress = JobProgress(record.job_id, DD_STAGES)
+            record.progress = JobProgress(record.job_id, DD_STAGES,
+                                          kind=record.kind)
             run_drop_detection(
                 self.db,
                 job_type=str(spec.get("jobType", "initial")),
@@ -589,7 +607,8 @@ class JobController:
                 cluster_uuid=str(spec.get("clusterUUID", "") or ""),
                 progress=record.progress)
         else:
-            record.progress = JobProgress(record.job_id, NPR_STAGES)
+            record.progress = JobProgress(record.job_id, NPR_STAGES,
+                                          kind=record.kind)
             policy_type = validate_policy_type(
                 str(spec.get("policyType", "anp-deny-applied")))
             option = POLICY_TYPE_OPTION[policy_type]

@@ -8,7 +8,11 @@ plane for the reproduction:
     built for the ingest hot path (striped counters, power-of-two
     numpy-backed histograms).
   * `obs.trace`   — lightweight spans with per-thread context, a
-    bounded ring of recent spans, and slowest-span exemplars per op.
+    bounded ring of recent spans, slowest-span exemplars per op, and
+    stage self-times inside a span (profiler annotations while a
+    capture runs).
+  * `obs.xplane`  — reads a profiler capture back: device busy/idle
+    and each long idle gap named by the host spans open during it.
   * `obs.prom`    — Prometheus text exposition (`GET /metrics` on the
     manager) and the parser `theia top` diffs into live rates.
 """
@@ -29,6 +33,6 @@ from .trace import (  # noqa: F401
     current_context,
     ingress_span,
     span,
-    traced,
+    stage,
     traceparent,
 )

@@ -60,6 +60,7 @@ from ..utils.backoff import capped_backoff
 from ..utils.env import env_float, env_int
 from ..utils.logging import get_logger
 from . import metrics as _metrics
+from . import trace as _trace
 
 logger = get_logger("obs.history")
 
@@ -307,7 +308,6 @@ class MetricsHistoryLoop:
     def node_id(self) -> str:
         if self._node is not None:
             return self._node
-        from . import trace as _trace
         return _trace.node_id() or ""
 
     def scrape(self, now: Optional[int] = None) -> int:
@@ -367,8 +367,9 @@ class MetricsHistoryLoop:
     def run_once(self, now: Optional[int] = None) -> int:
         """One supervised tick; returns rows recorded (0 on failure)."""
         try:
-            recorded = self.scrape(now)
-            self.maintain(now)
+            with _trace.background("metrics_history"):
+                recorded = self.scrape(now)
+                self.maintain(now)
         except Exception as e:
             self.failures += 1
             self.consecutive_failures += 1

@@ -1,13 +1,13 @@
 """Tracing: trace-aware spans with cross-node context propagation, a
 bounded ring of recent spans, and slowest-span exemplars per operation.
 
-Two layers share one ring:
+Three layers share one ring:
 
   * **Flight recorder** (PR 3): any code wraps itself in `span("op")`
-    (context manager) or `@traced` (decorator); finished spans land in
-    a fixed-size ring (newest first on read) and the slowest span per
-    operation is kept as an exemplar. Per-thread nesting links a span
-    to the operation that enclosed it (`parent`).
+    (context manager); finished spans land in a fixed-size ring
+    (newest first on read) and the slowest span per operation is kept
+    as an exemplar. Per-thread nesting links a span to the operation
+    that enclosed it (`parent`).
   * **Distributed traces** (PR 11): each ingress — a producer
     `POST /ingest`, a coordinator `/query`, a job run, a replication
     ship — mints a W3C-traceparent-style context (128-bit trace id,
@@ -18,6 +18,32 @@ Two layers share one ring:
     span id plus its parent's, so the rings of every node in a cluster
     hold the pieces of one cross-node tree — `GET
     /debug/traces?trace=<id>` stitches them (manager/api.py).
+
+  * **Stages** (PR 26): `stage(name, hist)` brackets one part of the
+    work INSIDE a span with a single `perf_counter` pair. Its self
+    time (its own duration minus the stages nested in it) is summed
+    into the enclosing span's `stagesMs[name]` (a stage that repeats,
+    once per shard say, adds up) and observed on the histogram handed
+    to it, so the slowest-span exemplar of an op carries the stage
+    breakdown of its slowest request. `StageMarks` is the same for
+    code that already announces its boundaries (`JobProgress.stage`).
+    An ingress span also records `cpuMs` (`time.thread_time()` delta):
+    wall − cpu − lock wait − device fetch is what the thread spent
+    waiting for the interpreter or the OS. `background(task)` is a
+    span `bg.<task>` that also feeds `theia_background_seconds`, for
+    the housekeeping that competes with requests (metrics-history
+    tick, retention round, WAL segment roll, checkpoint, parts
+    seal/merge) and `watch_gc()` reports generation-2 collections
+    the same way.
+
+While a profiler capture runs (manager/profiling.py hands this module
+an annotation factory with `set_annotation_factory`), every span and
+stage is ALSO a `jax.profiler.TraceAnnotation` of the same name, so
+the program's spans sit on the host lines of the same `.xplane.pb`
+as the device planes, on the profiler's clock (obs/xplane.py reads
+them back and names each idle gap of the device by the host stage
+open during it). With no capture the cost is one test of a module
+attribute, and this package still imports no jax.
 
 Sampling is **head-based and deterministic**: the mint-time decision
 is a pure function of the trace id and `THEIA_TRACE_SAMPLE` (default
@@ -30,6 +56,8 @@ without tracing.
 Span records are plain dicts (JSON-ready for GET /debug/traces):
 
     {"op", "startTime", "durationMs", "parent", "thread",
+     # when stages ran inside it / on an ingress span:
+     "stagesMs": {name: ms}, "cpuMs",
      # present under a sampled trace context:
      "traceId", "spanId", "parentSpanId", "node", ...attrs}
 
@@ -50,12 +78,12 @@ plane). Mutating an attr on the yielded span inside the `with` body
 from __future__ import annotations
 
 import collections
-import functools
+import gc
 import os
 import random
 import threading
 import time
-from typing import Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 from . import metrics as _metrics
 from ..analysis.lockdep import named_lock
@@ -90,6 +118,17 @@ _ring: Deque[Dict[str, object]] = collections.deque(
 _slowest: Dict[str, Dict[str, object]] = {}
 _local = threading.local()
 
+#: set while a profiler capture runs (manager/profiling.py): name →
+#: a context manager that writes the span into the profiler's trace
+#: (jax.profiler.TraceAnnotation). None = no capture: nothing is built.
+_annotate: Optional[Callable[[str], object]] = None
+
+_M_BACKGROUND = _metrics.histogram(
+    "theia_background_seconds",
+    "Housekeeping that shares the interpreter with requests: one "
+    "observation per run of a background task (also a span "
+    "bg.<task>)", labelnames=("task",))
+
 #: this process's node id, stamped on every trace-context span (set by
 #: the manager when a cluster is configured; "" on standalone nodes)
 _node_id = ""
@@ -102,6 +141,15 @@ def set_node_id(node_id: str) -> None:
 
 def node_id() -> str:
     return _node_id
+
+
+def set_annotation_factory(
+        factory: Optional[Callable[[str], object]]) -> None:
+    """Turn profiler annotations on (a factory) or off (None). The
+    profiler's owner calls this around start_trace/stop_trace; spans
+    and stages already open keep the state they started with."""
+    global _annotate
+    _annotate = factory
 
 
 # -- trace context (W3C traceparent style) ---------------------------------
@@ -218,7 +266,8 @@ class Span:
 
     __slots__ = ("op", "attrs", "_t0", "_start", "parent", "context",
                  "_parent_span_id", "_ingress", "_traceparent",
-                 "_explicit_ctx", "_sample_env")
+                 "_explicit_ctx", "_sample_env", "stages", "_stage",
+                 "_cpu0", "_ann", "_hist")
 
     def __init__(self, op: str, attrs: Dict[str, object],
                  ingress: bool = False,
@@ -236,6 +285,12 @@ class Span:
         self._sample_env = sample_env
         self._t0 = 0.0
         self._start = 0.0
+        #: stage name → summed self seconds (None until one runs)
+        self.stages: Optional[Dict[str, float]] = None
+        self._stage: Optional["Stage"] = None   # innermost open stage
+        self._cpu0 = 0.0
+        self._ann = None
+        self._hist = None
 
     def _bind_context(self, enclosing: Optional["Span"]) -> None:
         if self._ingress:
@@ -269,6 +324,10 @@ class Span:
         self.context = TraceContext(parent_ctx.trace_id, new_span_id(),
                                     True)
 
+    def cpu_seconds(self) -> float:
+        """Thread CPU time since an ingress span was entered."""
+        return time.thread_time() - self._cpu0
+
     def __enter__(self) -> "Span":
         stack = getattr(_local, "stack", None)
         if stack is None:
@@ -277,15 +336,26 @@ class Span:
         self.parent = enclosing.op if enclosing is not None else None
         self._bind_context(enclosing)
         stack.append(self)
+        factory = _annotate
+        if factory is not None:
+            self._ann = factory(self.op)
+            self._ann.__enter__()
         self._start = time.time()
+        if self._ingress:
+            self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         duration = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = getattr(_local, "stack", None)
         if stack:
             stack.pop()
+        if self._hist is not None:
+            self._hist.observe(duration)
         if not _metrics.enabled():
             return
         if self.context is not None and not self.context.sampled:
@@ -303,13 +373,106 @@ class Span:
             if self._parent_span_id:
                 record["parentSpanId"] = self._parent_span_id
             record["node"] = _node_id
+        if self._ingress:
+            record["cpuMs"] = round(self.cpu_seconds() * 1e3, 4)
+        if self.stages:
+            record["stagesMs"] = {k: round(v * 1e3, 4)
+                                  for k, v in self.stages.items()}
         if exc_type is not None:
             record["error"] = exc_type.__name__
         record.update(self.attrs)
         _publish(record)
 
 
+class Stage:
+    """One timed part of the work inside the thread's innermost span
+    (see the module docstring). Reusable only sequentially."""
+
+    __slots__ = ("name", "hist", "_span", "_outer", "_t0", "_nested",
+                 "_ann")
+
+    def __init__(self, name: str, hist=None) -> None:
+        self.name = name
+        self.hist = hist
+        self._span: Optional[Span] = None
+        self._outer: Optional["Stage"] = None
+        self._nested = 0.0
+        self._ann = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "Stage":
+        stack = getattr(_local, "stack", None)
+        sp = self._span = stack[-1] if stack else None
+        if sp is not None:
+            self._outer = sp._stage
+            sp._stage = self
+        self._nested = 0.0
+        factory = _annotate
+        if factory is not None:
+            self._ann = factory(self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        whole = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        own = whole - self._nested
+        sp = self._span
+        if sp is not None:
+            sp._stage = self._outer
+            if self._outer is not None:
+                self._outer._nested += whole
+            if sp.stages is None:
+                sp.stages = {self.name: own}
+            else:
+                sp.stages[self.name] = sp.stages.get(self.name,
+                                                     0.0) + own
+            self._span = self._outer = None
+        if self.hist is not None:
+            self.hist.observe(own)
+
+
+def stage(name: str, hist=None) -> Stage:
+    """Context manager around one stage of the enclosing span:
+
+        with stage("detector.plan", _M_PLAN):
+            plan = self.build_plan(keys, values)
+
+    `hist` is a histogram child (or None)."""
+    return Stage(name, hist)
+
+
+class StageMarks:
+    """Stages for code that announces boundaries instead of nesting
+    (`mark("job.read", h1) … mark("job.score", h2) … end()`): each
+    mark closes the stage before it. The mark that closes a stage
+    comes from the thread that opened it."""
+
+    __slots__ = ("_open",)
+
+    def __init__(self) -> None:
+        self._open: Optional[Stage] = None
+
+    def mark(self, name: str, hist=None) -> None:
+        self.end()
+        self._open = Stage(name, hist).__enter__()
+
+    def end(self) -> None:
+        st, self._open = self._open, None
+        if st is not None:
+            st.__exit__(None, None, None)
+
+
 def _publish(record: Dict[str, object]) -> None:
+    if _gc_done:
+        _flush_gc()
+    _retain(record)
+
+
+def _retain(record: Dict[str, object]) -> None:
     # THEIA_TRACE_RING=0 promises NO span retention — exemplars are
     # retained state too (attrs carry stream ids and job names), so
     # the knob turns them off with the ring.
@@ -324,35 +487,6 @@ def _publish(record: Dict[str, object]) -> None:
                 _slowest[op] = record
         elif record["durationMs"] > best["durationMs"]:
             _slowest[op] = record
-
-
-def record(op: str, start_time: float, duration_s: float,
-           **attrs: object) -> None:
-    """Publish an already-timed span (hot paths that keep their own
-    stopwatches and only record the interesting tail). Under a sampled
-    trace context the record joins the trace; under an unsampled one
-    it is dropped with the rest of the trace."""
-    if not _metrics.enabled():
-        return
-    rec: Dict[str, object] = {
-        "op": op,
-        "startTime": start_time,
-        "durationMs": round(duration_s * 1e3, 4),
-        "parent": current_op(),
-        "thread": threading.current_thread().name,
-    }
-    stack = getattr(_local, "stack", None)
-    if stack:
-        ctx = stack[-1].context
-        if ctx is not None:
-            if not ctx.sampled:
-                return
-            rec["traceId"] = ctx.trace_id
-            rec["spanId"] = new_span_id()
-            rec["parentSpanId"] = ctx.span_id
-            rec["node"] = _node_id
-    rec.update(attrs)
-    _publish(rec)
 
 
 def span(op: str, **attrs: object) -> Span:
@@ -389,18 +523,79 @@ def child_span(op: str, ctx: Optional[TraceContext],
     return Span(op, dict(attrs), ctx=ctx)
 
 
-def traced(op: Optional[str] = None):
-    """Decorator form of span(); the op name defaults to the function's
-    qualified name."""
-    def wrap(fn):
-        name = op or fn.__qualname__
+def background(task: str, **attrs: object) -> Span:
+    """Span `bg.<task>` around one run of a housekeeping task; its
+    duration also lands in theia_background_seconds{task=...}."""
+    sp = Span("bg." + task, dict(attrs))
+    sp._hist = _M_BACKGROUND.labels(task=task)
+    return sp
 
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with span(name):
-                return fn(*args, **kwargs)
-        return inner
-    return wrap
+
+# -- generation-2 garbage collections ---------------------------------------
+# A full collection stops whichever thread tripped it, mid-request and
+# possibly while that thread holds this module's ring lock or a
+# histogram's. So the callback takes no lock and allocates next to
+# nothing: it notes the collection on a deque, and the next span to
+# publish (or reader of the ring) turns the notes into `bg.gc` spans
+# and theia_background_seconds{task="gc"} observations.
+_gc_open: Optional[tuple] = None
+_gc_done: Deque[tuple] = collections.deque(maxlen=64)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    global _gc_open
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        ann = None
+        factory = _annotate
+        if factory is not None:
+            ann = factory("bg.gc")
+            ann.__enter__()
+        _gc_open = (time.time(), time.perf_counter(), ann)
+    elif _gc_open is not None:
+        start, t0, ann = _gc_open
+        _gc_open = None
+        duration = time.perf_counter() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _gc_done.append((start, duration, info.get("collected", 0),
+                         current_op(),
+                         threading.current_thread().name))
+
+
+def _flush_gc() -> None:
+    hist = _M_BACKGROUND.labels(task="gc")
+    while True:
+        try:
+            start, duration, collected, parent, thread = \
+                _gc_done.popleft()
+        except IndexError:
+            return
+        hist.observe(duration)
+        if _metrics.enabled():
+            _retain({"op": "bg.gc", "startTime": start,
+                     "durationMs": round(duration * 1e3, 4),
+                     "parent": parent, "thread": thread,
+                     "collected": collected})
+
+
+def watch_gc() -> None:
+    """Report every generation-2 collection as a `bg.gc` span (the
+    manager calls this once at start; idempotent)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def unwatch_gc() -> None:
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+def current_span() -> Optional[Span]:
+    """The innermost open span on this thread (None outside any)."""
+    stack = getattr(_local, "stack", None)
+    return stack[-1] if stack else None
 
 
 def current_op() -> Optional[str]:
@@ -411,6 +606,7 @@ def current_op() -> Optional[str]:
 
 def recent(limit: int = 100) -> List[Dict[str, object]]:
     """Most recent finished spans, newest first."""
+    _flush_gc()
     with _lock:
         out = list(_ring)
     out.reverse()
@@ -428,6 +624,7 @@ def spans_for_trace(trace_id: str) -> List[Dict[str, object]]:
 
 def slowest() -> Dict[str, Dict[str, object]]:
     """op → its slowest recorded span (the exemplar)."""
+    _flush_gc()
     with _lock:
         return {op: dict(rec) for op, rec in sorted(_slowest.items())}
 

@@ -37,7 +37,8 @@ def ewma(x: jnp.ndarray, alpha: float = DEFAULT_ALPHA) -> jnp.ndarray:
         a2, b2 = rhs
         return a1 * a2, a2 * b1 + b2
 
-    _, e = jax.lax.associative_scan(combine, (a, b), axis=-1)
+    with jax.named_scope("ewma_scan"):
+        _, e = jax.lax.associative_scan(combine, (a, b), axis=-1)
     return e
 
 
@@ -55,7 +56,8 @@ def ewma_scores(x: jnp.ndarray, mask: jnp.ndarray,
     """
     xz = jnp.where(mask, x, 0.0)
     e = ewma(xz, alpha)
-    std = masked_stddev_samp(x, mask)
+    with jax.named_scope("stddev"):
+        std = masked_stddev_samp(x, mask)
     # NaN stddev (fewer than 2 points) compares False, matching the
     # reference's "too few values" → not anomalous path (:198-201).
     anomaly = (jnp.abs(xz - e) > std[..., None]) & mask

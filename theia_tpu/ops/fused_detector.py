@@ -166,15 +166,18 @@ def _stream_half(stream: StreamState, inp: ShardInputs, alpha,
     stream_update_sparse shape, Pallas-optional scan core).
     Padding slots hold `capacity`: the gather clamps harmlessly and
     the scatter DROPS them (XLA's documented OOB semantics)."""
-    sub = StreamState(*(a[inp.slots] for a in stream))
-    if use_pallas:
-        sub, anomalies = _scan_tile_pallas(sub, inp.x, inp.active,
-                                           alpha, interpret)
-    else:
-        sub, anomalies = _scan_tile(sub, inp.x, inp.active, alpha)
-    new = StreamState(*(
-        full.at[inp.slots].set(part, mode="drop")
-        for full, part in zip(stream, sub)))
+    with jax.named_scope("gather"):
+        sub = StreamState(*(a[inp.slots] for a in stream))
+    with jax.named_scope("scan"):
+        if use_pallas:
+            sub, anomalies = _scan_tile_pallas(sub, inp.x, inp.active,
+                                               alpha, interpret)
+        else:
+            sub, anomalies = _scan_tile(sub, inp.x, inp.active, alpha)
+    with jax.named_scope("scatter"):
+        new = StreamState(*(
+            full.at[inp.slots].set(part, mode="drop")
+            for full, part in zip(stream, sub)))
     return new, anomalies
 
 
@@ -183,9 +186,11 @@ def _shard_step(state: ShardStepState, inp: ShardInputs, alpha,
                 ) -> Tuple[ShardStepState, ShardOutputs]:
     new_stream, anomaly = _stream_half(state.stream, inp, alpha,
                                        use_pallas, interpret)
-    cms = cms_update(state.cms, inp.keys, inp.vols)
-    est = cms_query(cms, inp.q)
-    km, _, dist = kmeans_step(state.km, inp.feats, inp.valid)
+    with jax.named_scope("sketch"):
+        cms = cms_update(state.cms, inp.keys, inp.vols)
+        est = cms_query(cms, inp.q)
+    with jax.named_scope("kmeans"):
+        km, _, dist = kmeans_step(state.km, inp.feats, inp.valid)
     return (ShardStepState(new_stream, cms, km),
             ShardOutputs(anomaly, est, cms.total, dist))
 

@@ -6,6 +6,11 @@ totalStages into CRD status).
 The runner updates a JSON document after every stage; it is written
 atomically to a file (for the file-based manager/controller seam) and
 kept in memory for in-process callers.
+
+Every stage is also timed, here and nowhere else, for every job kind:
+`theia_job_stage_seconds{kind,stage}`, the same seconds as `stagesMs`
+on the enclosing `job.run` span, and a profiler annotation
+`job.<stage>` while a capture runs (obs/trace.py StageMarks).
 """
 
 from __future__ import annotations
@@ -15,8 +20,16 @@ import threading
 import time
 from typing import List, Optional
 
-from ..utils import atomic_write
 from ..analysis.lockdep import named_lock
+from ..obs import metrics as _metrics
+from ..obs import trace as _trace
+from ..utils import atomic_write
+
+_M_STAGE = _metrics.histogram(
+    "theia_job_stage_seconds",
+    "Wall time of one stage of a job run (read, tensorize, score, "
+    "write, ...: the stages JobProgress announces)",
+    labelnames=("kind", "stage"))
 
 
 class JobProgress:
@@ -27,10 +40,12 @@ class JobProgress:
     """
 
     def __init__(self, job_id: str, stages: List[str],
-                 path: Optional[str] = None) -> None:
+                 path: Optional[str] = None, kind: str = "") -> None:
         self.job_id = job_id
         self.stages = list(stages)
         self.path = path
+        self.kind = kind
+        self._marks = _trace.StageMarks()
         self._completed = 0
         self._state = "RUNNING"
         self._error = ""
@@ -40,6 +55,8 @@ class JobProgress:
         self._flush()
 
     def stage(self, name: str) -> None:
+        self._marks.mark("job." + name, _M_STAGE.labels(
+            kind=self.kind, stage=name))
         with self._lock:
             if self._current:
                 self._completed += 1
@@ -47,6 +64,7 @@ class JobProgress:
         self._flush()
 
     def done(self) -> None:
+        self._marks.end()
         with self._lock:
             self._completed = len(self.stages)
             self._current = ""
@@ -54,6 +72,7 @@ class JobProgress:
         self._flush()
 
     def fail(self, error: str) -> None:
+        self._marks.end()
         with self._lock:
             self._state = "FAILED"
             self._error = error

@@ -24,6 +24,7 @@ import threading
 import time
 from typing import Optional, Tuple
 
+from ..obs import trace as _trace
 from ..utils import get_logger
 from ..utils.faults import fire as _fire_fault
 
@@ -143,7 +144,8 @@ class Checkpointer:
         if fp == self._last_fingerprint:
             return False
         _fire_fault("checkpoint.save", path=self.path)
-        stamp = self.db.save(self.path, compress=self.compress)
+        with _trace.background("checkpoint"):
+            stamp = self.db.save(self.path, compress=self.compress)
         self._last_fingerprint = fp
         self.checkpoints_written += 1
         self.last_checkpoint_time = time.time()

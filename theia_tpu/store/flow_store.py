@@ -63,6 +63,7 @@ RESULT_TABLE_SCHEMAS = (
     (METRICS_TABLE, METRICS_SCHEMA),
 )
 from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 from ..utils.backoff import capped_backoff
 from ..utils.env import env_float
 from ..utils.faults import fire as _fire_fault
@@ -642,7 +643,8 @@ class RetentionLoop:
         """One supervised round; returns rows deleted (0 on a failed
         round). Public so tests drive the schedule synchronously."""
         try:
-            deleted = self.monitor.tick()
+            with _trace.background("retention"):
+                deleted = self.monitor.tick()
         except Exception as e:   # a bad round must not kill the loop
             self.failures += 1
             self.consecutive_failures += 1

@@ -109,6 +109,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 from ..schema import ColumnarBatch
 from ..utils.backoff import capped_backoff
 from ..utils.env import env_float, env_int
@@ -777,10 +778,11 @@ class PartTable(Table):
                 segments = [
                     batch.take(np.arange(bounds[i], bounds[i + 1]))
                     for i in range(len(bounds) - 1)]
-        for seg in segments:
-            self._parts.append(self._build_part(seg))
-            self.parts_sealed += 1
-            _M_SEALED.inc()
+        with _trace.background("parts_seal", rows=len(batch)):
+            for seg in segments:
+                self._parts.append(self._build_part(seg))
+                self.parts_sealed += 1
+                _M_SEALED.inc()
 
     def _build_part(self, batch: ColumnarBatch,
                     write_file: bool = True,
@@ -1574,6 +1576,11 @@ class PartTable(Table):
         return upgraded
 
     def _merge_run(self, refs: List[Part], tier: str) -> bool:
+        with _trace.background("parts_merge", parts=len(refs),
+                               tier=tier):
+            return self._merge_run_body(refs, tier)
+
+    def _merge_run_body(self, refs: List[Part], tier: str) -> bool:
         """Compact one run into a single part of the SAME tier. A cold
         run's replacement is written straight to disk and registered
         cold (chunks None) — a long-retention tier coalesces its tiny

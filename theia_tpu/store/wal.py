@@ -94,6 +94,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 from ..schema import ColumnarBatch, StringDictionary
 from ..utils.env import env_int
 from ..utils.faults import fire as _fire_fault
@@ -673,19 +674,22 @@ class WriteAheadLog:
         append die with a bare 'I/O operation on closed file' that
         nothing maps back to the rotation failure."""
         _fire_fault("wal.rotate", segment=self._seg_path)
-        self._file.flush()
-        if self.policy.mode != "never":
-            os.fsync(self._file.fileno())
-            self.synced_lsn = self.last_lsn
-            self._dirty_records = 0
-            self._dirty_bytes = 0
-        self._file.close()
-        try:
-            self._open_segment_locked(self._next_lsn)
-        except Exception as e:
-            self._file = None
-            self._broken = f"segment rotation failed: {e}"
-            raise WalError(self._broken)
+        # a span, unlike the per-block fsync (which keeps its
+        # histogram only: one span a block would churn the ring)
+        with _trace.background("wal_roll"):
+            self._file.flush()
+            if self.policy.mode != "never":
+                os.fsync(self._file.fileno())
+                self.synced_lsn = self.last_lsn
+                self._dirty_records = 0
+                self._dirty_bytes = 0
+            self._file.close()
+            try:
+                self._open_segment_locked(self._next_lsn)
+            except Exception as e:
+                self._file = None
+                self._broken = f"segment rotation failed: {e}"
+                raise WalError(self._broken)
 
     def _policy_sync(self) -> None:
         if self.policy.mode == "always":
