@@ -12,9 +12,12 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
+from theia_tpu.analytics.streaming import CONNECTION_KEY_COLUMNS
 from theia_tpu.data.synth import SynthConfig, generate_flows
 from theia_tpu.ingest import BlockEncoder
+from theia_tpu.manager import ingest as ingest_mod
 from theia_tpu.manager.ingest import IngestManager
 from theia_tpu.schema import FLOW_SCHEMA, ColumnarBatch
 from theia_tpu.store import FlowDatabase
@@ -301,6 +304,173 @@ def test_shard_partition_is_stable():
     assert len({ims[0].shard_of_destination(d) for d in dests}) > 1
     for im in ims:
         im.close()
+
+
+# -- one grouping of a block by shard (`IngestManager._partition`) ---------
+
+def _whole_column_slices(im, scored, shard_ids):
+    """What `_partition` was before it grouped a block once: one
+    `flatnonzero` and one 52-column `take` per shard. Kept here as the
+    plain reference for the projected slices."""
+    for s in range(im.n_shards):
+        idx = np.flatnonzero(shard_ids == s)
+        if idx.size:
+            yield im.shards[s], scored.take(idx)
+
+
+def _declared(im):
+    """column → dtype the manager's own detectors declare."""
+    return {**im.shards[0].heavy.reads, **im.shards[0].streaming.reads}
+
+
+def _remapped_block(im, seed=5, n_series=160, points=4):
+    batch = generate_flows(SynthConfig(n_series=n_series,
+                                       points_per_series=points,
+                                       seed=seed))
+    # interleave the connections' points so that a slice has to keep
+    # batch order, not series order
+    order = np.random.default_rng(seed).permutation(len(batch))
+    return im._remap_global(batch.take(order))
+
+
+@pytest.mark.parametrize("seed", (5, 6))
+def test_partition_slices_equal_whole_column_take_on_declared_columns(
+        seed):
+    im = IngestManager(FlowDatabase(), n_shards=8)
+    declared = _declared(im)
+    assert len(declared) == 10, sorted(declared)
+    scored, shard_ids = _remapped_block(im, seed=seed)
+    parts = list(im._partition(scored, shard_ids))
+    assert [s.index for s, _ in parts] == sorted(
+        set(shard_ids.tolist()))
+    assert len(parts) > 1
+    assert sum(len(p) for _, p in parts) == len(scored)
+    for shard, part in parts:
+        ref = scored.take(np.flatnonzero(shard_ids == shard.index))
+        assert set(part.column_names) == set(declared)
+        for c, dtype in declared.items():
+            # same values in the same (batch) order, already in the
+            # dtype the detector converts to, and a view of the
+            # grouped column: nothing copied per shard
+            assert part[c].dtype == dtype, c
+            assert np.array_equal(part[c], ref[c]), c
+            assert part[c].base is not None, c
+            assert np.asarray(part[c], dtype) is part[c], c
+        for c in ("sourceIP", "destinationIP"):
+            assert part.dicts[c] is im._global_dicts[c]
+    im.close()
+
+
+def _strip(alert):
+    return {k: v for k, v in alert.items()
+            if k not in ("time", "latency_s")}
+
+
+def test_projected_slices_give_the_alerts_of_whole_column_slices():
+    """Eight shards, six blocks so that state carries: every ack's
+    count and split, and every published alert record, equal those of
+    a manager that still slices all 52 columns per shard."""
+    n_blocks = 6
+    payloads = _spike_payloads(2, n_blocks, rows_per_block=96)
+    twins = _spike_payloads(2, n_blocks, rows_per_block=96)
+    im_new = IngestManager(FlowDatabase(), n_shards=8)
+    im_old = IngestManager(FlowDatabase(), n_shards=8)
+    im_old._partition = (
+        lambda scored, ids: _whole_column_slices(im_old, scored, ids))
+    n_conn = 0
+    for b in range(n_blocks):
+        for sid in range(2):
+            new = im_new.ingest(payloads[sid][b], stream=f"s{sid}")
+            old = im_old.ingest(twins[sid][b], stream=f"s{sid}")
+            assert new["alerts"] == old["alerts"], (b, sid)
+            assert new["alertsByKind"] == old["alertsByKind"], (b, sid)
+            n_conn += new["alertsByKind"]["connection_anomaly"]
+    assert n_conn > 0, "expected connection_anomaly alerts"
+    got = [_strip(a) for a in im_new.recent_alerts(10_000)]
+    want = [_strip(a) for a in im_old.recent_alerts(10_000)]
+    assert got == want
+    assert _conn_alert_sequences(im_new) == _conn_alert_sequences(im_old)
+    for a, b in zip(im_new.shards, im_old.shards):
+        assert a.streaming.n_series == b.streaming.n_series
+        assert a.heavy.total_volume == b.heavy.total_volume
+    im_new.close()
+    im_old.close()
+
+
+class _UndeclaredHeavy:
+    """A heavy-hitter double that says nothing about what it reads."""
+    total_volume = 0.0
+
+    def __init__(self):
+        self.seen = []
+
+    def update(self, batch, extra_total=0.0):
+        self.seen.append(batch)
+        return []
+
+
+def test_a_detector_that_declares_no_columns_receives_all_52():
+    im = IngestManager(FlowDatabase(), n_shards=4)
+    double = _UndeclaredHeavy()
+    im.shards[2].heavy = double            # one undeclared is enough
+    assert im._slice_columns() is None
+    scored, shard_ids = _remapped_block(im)
+    before = ingest_mod._M_PARTITION_BYTES.value()
+    parts = list(im._partition(scored, shard_ids))
+    assert len(parts) == 4
+    gathered = 0
+    for shard, part in parts:
+        ref = scored.take(np.flatnonzero(shard_ids == shard.index))
+        assert list(part.column_names) == list(scored.column_names)
+        assert len(part.columns) == 52
+        for c in scored.column_names:
+            assert part[c].dtype == scored[c].dtype, c
+            assert np.array_equal(part[c], ref[c]), c
+            gathered += part[c].nbytes
+        assert part.dicts == scored.dicts
+    assert ingest_mod._M_PARTITION_BYTES.value() - before == gathered
+    assert gathered == sum(v.nbytes for v in scored.columns.values())
+    # and the leg runs on such slices: the double gets its shard's
+    im.score_batch(generate_flows(SynthConfig(
+        n_series=160, points_per_series=2, seed=9)))
+    assert len(double.seen) == 1 and len(double.seen[0].columns) == 52
+    im.close()
+
+
+def test_partition_shortcuts_yield_the_block_itself():
+    one = IngestManager(FlowDatabase(), n_shards=1)
+    scored, shard_ids = _remapped_block(one)
+    assert shard_ids is None
+    assert list(one._partition(scored, None)) == [(one.shards[0],
+                                                   scored)]
+    one.close()
+    im = IngestManager(FlowDatabase(), n_shards=8)
+    scored, shard_ids = _remapped_block(im)
+    home = int(shard_ids[0])
+    mine = scored.take(np.flatnonzero(shard_ids == home))
+    before = ingest_mod._M_PARTITION_BYTES.value()
+    parts = list(im._partition(mine, np.full(len(mine), home)))
+    assert len(parts) == 1
+    assert parts[0][0] is im.shards[home] and parts[0][1] is mine
+    assert ingest_mod._M_PARTITION_BYTES.value() == before
+    im.close()
+
+
+def test_describe_alert_on_a_projected_slice_decodes_the_same_keys():
+    im = IngestManager(FlowDatabase(), n_shards=8)
+    scored, shard_ids = _remapped_block(im)
+    for (shard, part), (_, ref) in zip(
+            im._partition(scored, shard_ids),
+            _whole_column_slices(im, scored, shard_ids)):
+        for row in (0, len(part) // 2, len(part) - 1):
+            got = shard.streaming.describe_alert(part, {"row": row})
+            want = shard.streaming.describe_alert(ref, {"row": row})
+            assert got == want
+            assert isinstance(got["sourceIP"], str)
+            assert isinstance(got["destinationIP"], str)
+            assert [type(got[c]) for c in CONNECTION_KEY_COLUMNS] == [
+                type(want[c]) for c in CONNECTION_KEY_COLUMNS]
+    im.close()
 
 
 def test_pipelined_insert_leg_errors_surface():

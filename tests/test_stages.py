@@ -274,6 +274,40 @@ def test_h2d_bytes_count_the_padded_tile():
         plan.slots.nbytes + plan.x.nbytes + plan.active.nbytes)
 
 
+@pytest.mark.parametrize("n_shards,bytes_a_row", [(8, 80), (1, 0)])
+def test_partition_bytes_count_what_the_slices_hold(n_shards,
+                                                    bytes_a_row):
+    """`theia_detector_partition_bytes_total` grows by the slices'
+    bytes once a block, inside the partition stage: ten 8-byte columns
+    a row over several shards, nothing for a block taken whole."""
+    batch, payload = _block(n_series=96, points=4)
+    mgr = IngestManager(FlowDatabase(), n_shards=n_shards)
+    try:
+        scored, shard_ids = mgr._remap_global(batch)
+        c0 = ingest_mod._M_PARTITION_BYTES.value()
+        parts = list(mgr._partition(scored, shard_ids))
+        held = sum(col.nbytes for _, part in parts
+                   for col in part.columns.values())
+        grown = ingest_mod._M_PARTITION_BYTES.value() - c0
+        assert grown == len(batch) * bytes_a_row
+        if n_shards > 1:
+            assert len(parts) > 1 and grown == held
+            # under half of what 52-column slices would copy
+            assert grown < 0.5 * sum(
+                v.nbytes for v in scored.columns.values())
+        # the request path counts the same, once a block
+        c1 = ingest_mod._M_PARTITION_BYTES.value()
+        n0 = _hist("theia_detector_stage_seconds",
+                   stage="partition")[1]
+        mgr.ingest(payload, stream="s")
+        assert (ingest_mod._M_PARTITION_BYTES.value() - c1
+                == len(batch) * bytes_a_row)
+        assert _hist("theia_detector_stage_seconds",
+                     stage="partition")[1] == n0 + 1
+    finally:
+        mgr.close()
+
+
 # -- jobs ----------------------------------------------------------------
 
 def test_job_progress_stage_seconds_sum_to_the_run():

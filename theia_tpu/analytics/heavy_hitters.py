@@ -153,6 +153,15 @@ def build_hh_plan(dst_codes: np.ndarray, src_codes: np.ndarray,
 class HeavyHitterDetector:
     """Device-resident CMS + online k-means over ingest micro-batches."""
 
+    #: The columns `update` reads, in `build_hh_plan`'s argument order,
+    #: each with the dtype it is converted to. Whoever slices a block
+    #: for this detector (manager/ingest.py `_partition`) may hand
+    #: over only these, already in that dtype: the conversion below
+    #: then returns the array it is given.
+    reads = {"destinationIP": np.int64, "sourceIP": np.int64,
+             "octetDeltaCount": np.float64,
+             "packetDeltaCount": np.float64}
+
     def __init__(self, depth: int = 4, width: int = 8192,
                  k: int = 8, hh_fraction: float = 0.10,
                  ddos_sigma: float = 4.0, seed: int = 0) -> None:
@@ -183,11 +192,8 @@ class HeavyHitterDetector:
         when the key space is partitioned."""
         if len(batch) == 0:
             return []
-        plan = build_hh_plan(
-            np.asarray(batch["destinationIP"], np.int64),
-            np.asarray(batch["sourceIP"], np.int64),
-            np.asarray(batch["octetDeltaCount"], np.float64),
-            np.asarray(batch["packetDeltaCount"], np.float64))
+        plan = build_hh_plan(*(np.asarray(batch[c], dtype)
+                               for c, dtype in self.reads.items()))
 
         # One dispatch, one fetch. Host arrays go in raw: jit batches
         # the transfers into the call instead of one device_put round

@@ -245,6 +245,16 @@ class StreamingDetector:
     def n_series(self) -> int:
         return self._n_alloc
 
+    @property
+    def reads(self) -> Dict[str, type]:
+        """The columns `ingest` and `describe_alert` read, each with
+        the dtype `ingest` converts it to (the same declaration as
+        `HeavyHitterDetector.reads`, per instance because of
+        `value_column`)."""
+        return {**dict.fromkeys(CONNECTION_KEY_COLUMNS, np.int64),
+                self.value_column: np.float64,
+                "flowEndSeconds": np.int64}
+
     def _slot_for(self, key: bytes) -> int:
         slot = self._slots.get(key)
         if slot is None:
