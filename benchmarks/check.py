@@ -31,6 +31,11 @@ Which checks a cell runs is its traffic file's `checks` list:
                   the float64 TAD EWMA reference
   panels          every answer of a panel over its closed range is the
                   same bytes, and equals the reference's panel
+
+Those are the built-in checks (`CHECKS`). Any other name in the list is
+a file `benchmarks/checks/<name>.py` (extend.py) with `check(ctx, rep)`;
+`producer_records`, `streams` and `limit` below are what such a file
+builds on, and `ctx` is what harness.run_cell gathered (README.md).
 """
 
 from __future__ import annotations
@@ -40,8 +45,11 @@ from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
+from . import extend as _extend
+from . import gen as _gen
 from . import reference as _ref
-from .gen import DEFAULT_START, PORT_SPAN, ProducerStream, cluster_uuid
+from .extend import RunFailed
+from .gen import DEFAULT_START, PORT_SPAN, cluster_uuid
 
 ALERTS_SERIES = 'theia_ingest_alerts_total{kind="connection_anomaly"}'
 
@@ -49,6 +57,7 @@ ALERTS_SERIES = 'theia_ingest_alerts_total{kind="connection_anomaly"}'
 class Report:
     def __init__(self) -> None:
         self.lines: List[str] = []
+        self.numbers: Dict[str, Dict] = {}    # name → value and limit
         self.correct = True
         self.attempted = 0
         self.failed = 0
@@ -56,13 +65,20 @@ class Report:
     def compare(self, name: str, value, limit, detail: str = "") -> None:
         ok = bool(value <= limit)
         self.correct &= ok
+        self.numbers[name] = {"value": _plain(value), "limit": _plain(limit)}
         self.lines.append(
             f"check {name}: value={value!r} limit={limit!r} "
             f"{'ok' if ok else 'FAILED'}" + (f" ({detail})" if detail else ""))
 
     def doc(self) -> Dict:
-        return {"lines": self.lines, "correct": self.correct,
+        return {"lines": self.lines, "numbers": self.numbers,
+                "correct": self.correct,
                 "attempted": self.attempted, "failed": self.failed}
+
+
+def _plain(x):
+    """A numpy scalar as the Python number json can write."""
+    return x.item() if hasattr(x, "item") else x
 
 
 def config_preconditions(config: Dict, health: Dict) -> None:
@@ -75,12 +91,21 @@ def config_preconditions(config: Dict, health: Dict) -> None:
            "detector_shards": health["ingest"]["shards"]}
     for key, want in expect.items():
         if want is not None and got.get(key) != want:
-            from .harness import RunFailed
             raise RunFailed(f"configuration expects {key}={want!r}, the "
                             f"manager reports {got.get(key)!r}")
 
 
-def _producer_records(ctx: Dict) -> List[Tuple[Dict, List[Dict]]]:
+def limit(traffic: Dict, name: str):
+    """A tolerance of the traffic file's `limits`. One that is not
+    there is a broken run, never a default."""
+    try:
+        return traffic["limits"][name]
+    except KeyError:
+        raise RunFailed(f"traffic file {traffic.get('name')!r} states no "
+                        f"limit {name!r}") from None
+
+
+def producer_records(ctx: Dict) -> List[Tuple[Dict, List[Dict]]]:
     """(spec, all of that producer's records in order) per producer."""
     out = []
     for i, spec in enumerate(ctx["specs"]):
@@ -93,39 +118,43 @@ def _producer_records(ctx: Dict) -> List[Tuple[Dict, List[Dict]]]:
     return out
 
 
-def _streams(ctx: Dict) -> List[Tuple[ProducerStream, int, List[Dict]]]:
+def streams(ctx: Dict) -> List[Tuple["_CachedStream", int, List[Dict]]]:
     """(stream, number of blocks acked, records) per producer. Blocks
     are sent in order one at a time, so the acked blocks are a prefix
     unless a request failed (then `acks` has already failed the run)."""
     if "_streams" not in ctx:        # one regeneration for all checks
         out = []
-        for spec, recs in _producer_records(ctx):
-            stream = _CachedStream(ctx["traffic"], ctx["seed"],
-                                   spec["producer"])
+        for spec, recs in producer_records(ctx):
+            stream = _CachedStream(_gen.stream(
+                ctx["traffic"], ctx["seed"], spec["producer"]))
             n = sum(1 for r in recs if r["status"] == 200)
             out.append((stream, n, recs))
         ctx["_streams"] = out
     return ctx["_streams"]
 
 
-class _CachedStream(ProducerStream):
-    """Keeps each block's values: several checks walk the same blocks."""
+class _CachedStream:
+    """A stream of whatever key law, keeping each block's values:
+    several checks walk the same blocks."""
 
-    def __init__(self, *args) -> None:
-        super().__init__(*args)
+    def __init__(self, stream) -> None:
+        self._stream = stream
         self._values: Dict[int, Dict] = {}
+
+    def __getattr__(self, name: str):
+        return getattr(self._stream, name)
 
     def values(self, b: int) -> Dict:
         v = self._values.get(b)
         if v is None:
-            v = self._values[b] = super().values(b)
+            v = self._values[b] = self._stream.values(b)
         return v
 
 
 def check_acks(ctx: Dict, rep: Report) -> None:
     bad = n = 0
     first = ""
-    for _, recs in _producer_records(ctx):
+    for _, recs in producer_records(ctx):
         for r in recs:
             n += 1
             if (r["status"] != 200 or r.get("rows") != r["rows_sent"]
@@ -149,7 +178,7 @@ def check_store_totals(ctx: Dict, rep: Report) -> None:
            for r in doc["rows"]}
     rows_gap = octets_gap = 0
     want_rows = 0
-    for stream, n, _ in _streams(ctx):
+    for stream, n, _ in streams(ctx):
         rows, octets = _ref.block_totals(stream, n)
         want_rows += rows
         g = got.pop(cluster_uuid(stream.producer), (0, 0))
@@ -166,8 +195,9 @@ def check_detector_series(ctx: Dict, rep: Report) -> None:
     dropped = sum(s["droppedSeries"] for s in shards)
     series = sum(s["series"] for s in shards)
     want = 0
-    for stream, n, _ in _streams(ctx):
-        want += len({stream.slice_of(b) for b in range(n)}) * stream.cpb
+    for stream, n, _ in streams(ctx):
+        sent = [stream.conn_index(b) for b in range(n)]
+        want += np.unique(np.concatenate(sent)).size if sent else 0
     rep.compare("detector_series_dropped", dropped, 0)
     rep.compare("detector_series_gap", abs(series - want), 0,
                 f"{want} distinct connections sent")
@@ -205,7 +235,7 @@ def check_detector_alerts(ctx: Dict, rep: Report) -> None:
                       if k.startswith("theia_ingest_alerts_total{")))
     acked_alerts = want = points = 0
     probe_diff = probe_points = probes = 0
-    for stream, n, recs in _streams(ctx):
+    for stream, n, recs in streams(ctx):
         ref_counts = _ref.detector_alerts(stream, n)
         want += int(ref_counts.sum())
         points += n * stream.rows
@@ -216,19 +246,18 @@ def check_detector_alerts(ctx: Dict, rep: Report) -> None:
                 probes += 1
                 probe_diff += abs(int(r["conn_alerts"]) - int(c))
                 probe_points += stream.rows
-    limits = ctx["traffic"]["limits"]
     rep.compare("ack_alerts_sum_gap", abs(acked_alerts - counted), 0,
                 f"acks {acked_alerts}, counters {counted} of which "
                 f"{counted - raised} of other kinds")
     rep.compare("alert_count_gap", abs(raised - want) / max(points, 1),
-                limits["alert_count_gap"],
+                limit(ctx["traffic"], "alert_count_gap"),
                 f"{raised} raised, reference {want}, {points} points")
     want_probes = sum(int(s.get("probe_blocks", 0)) for s in ctx["specs"]
                       if s["role"] == "producer")
     rep.compare("probe_blocks_missing", want_probes - probes, 0,
                 f"{want_probes} probe blocks, counters read around each")
     rep.compare("alert_probe_block_gap", probe_diff / max(probe_points, 1),
-                limits["alert_probe_block_gap"],
+                limit(ctx["traffic"], "alert_probe_block_gap"),
                 f"sum over probe blocks |counted - reference| "
                 f"{probe_diff}, {probe_points} points")
 
@@ -270,7 +299,7 @@ def check_jobs(ctx: Dict, rep: Report) -> None:
                                                    DEFAULT_START)))
     want = set()
     scored = 0
-    for stream, n, _ in _streams(ctx):
+    for stream, n, _ in streams(ctx):
         vals, times, mask = _ref.series_of(stream, n)
         anom = _ref.tad_ewma(vals, mask)
         scored += int(mask.sum())
@@ -279,7 +308,7 @@ def check_jobs(ctx: Dict, rep: Report) -> None:
                         times[c, t].tolist()))
     share = len(got ^ want) / max(scored, 1)
     rep.compare("tad_decision_mismatch", share,
-                ctx["traffic"]["limits"]["tad_decision_mismatch"],
+                limit(ctx["traffic"], "tad_decision_mismatch"),
                 f"{len(got)} decisions, reference {len(want)}, "
                 f"{scored} points scored")
 
@@ -291,13 +320,13 @@ def check_panels(ctx: Dict, rep: Report) -> None:
     # the closed ranges lie inside the preloaded seconds: the reference
     # needs the preloaded blocks alone
     pre = [(s, int(spec.get("preload_blocks", 0)))
-           for (spec, _), (s, _, _) in zip(_producer_records(ctx),
-                                           _streams(ctx))]
+           for (spec, _), (s, _, _) in zip(producer_records(ctx),
+                                           streams(ctx))]
     # what the panels without a closed range must add up to, now that
     # nothing moves
-    rows = sum(n_acked * s.rows for s, n_acked, _ in _streams(ctx))
+    rows = sum(n_acked * s.rows for s, n_acked, _ in streams(ctx))
     octets = sum(_ref.block_totals(s, n_acked)[1]
-                 for s, n_acked, _ in _streams(ctx))
+                 for s, n_acked, _ in streams(ctx))
     for i, spec in enumerate(ctx["specs"]):
         if spec["role"] != "reader":
             continue
@@ -342,8 +371,30 @@ CHECKS: Dict[str, Callable[[Dict, Report], None]] = {
 }
 
 
+#: the tolerances a built-in check reads from the traffic file
+LIMITS: Dict[str, Tuple[str, ...]] = {
+    "detector_alerts": ("alert_count_gap", "alert_probe_block_gap"),
+    "jobs": ("tad_decision_mismatch",),
+}
+
+
+def resolve_all(traffic: Dict) -> List[Callable[[Dict, Report], None]]:
+    """The traffic file's checks, each found by name, and every
+    tolerance they declare present in its `limits`: what can be known
+    to be missing is known before the run starts."""
+    found = []
+    for name in traffic["checks"]:
+        mod = _extend.module("check", name)
+        found.append(CHECKS[name] if mod is None else mod.check)
+        wanted = LIMITS.get(name, ()) if mod is None \
+            else getattr(mod, "limits", ())
+        for tol in wanted:
+            limit(traffic, tol)
+    return found
+
+
 def run_checks(ctx: Dict) -> Dict:
     rep = Report()
-    for name in ctx["traffic"]["checks"]:
-        CHECKS[name](ctx, rep)
+    for fn in resolve_all(ctx["traffic"]):
+        fn(ctx, rep)
     return rep.doc()

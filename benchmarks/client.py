@@ -25,6 +25,13 @@ Every request is recorded with its due time, send time and completion
 time; the records go to the file the spec names (they can be large),
 and the harness reduces them. A refused or failed request is recorded
 as failed, never retried inside the window: a retry would hide it.
+
+`ROLES` has the built-in roles. Any other `role` of a traffic file's
+`workers` is a file benchmarks/roles/<name>.py (extend.py) with a class
+`Role`: `Role(spec)` prepares, `handle(cmd)` answers `preload`, `warm`,
+`run T S [H L]` and `probe N` with {"event": "preloaded" | "warmed" |
+"done" | "probed", "records": [...], ...}; a record that a reduction
+is to read carries `due`, `send`, `ack` and `status`.
 """
 
 from __future__ import annotations
@@ -37,6 +44,8 @@ import sys
 import time
 import urllib.parse
 from typing import Dict, List, Optional, Tuple
+
+from . import extend
 
 INTELLIGENCE = "/apis/intelligence.theia.antrea.io/v1alpha1"
 ALERTS_TOTAL = "theia_ingest_alerts_total"
@@ -97,11 +106,11 @@ class Producer:
     every block it delays."""
 
     def __init__(self, spec: Dict) -> None:
-        from .gen import ProducerStream
+        from . import gen
         self.spec = spec
         self.http = Http(spec["addr"])
-        self.stream = ProducerStream(spec["traffic"], spec["seed"],
-                                     spec["producer"])
+        self.stream = gen.stream(spec["traffic"], spec["seed"],
+                                 spec["producer"])
         self.name = f"bench-{spec['producer']}"
         self.sent = 0
         self.ready: List[Tuple[bytes, Dict]] = []
@@ -360,8 +369,9 @@ ROLES = {"producer": Producer, "reader": Reader, "jobs": JobClient}
 def main(argv: List[str]) -> int:
     with open(argv[1]) as f:
         spec = json.load(f)
+    extend.use(spec["base"])
     t0 = time.monotonic()
-    worker = ROLES[spec["role"]](spec)
+    worker = extend.resolve("role", spec["role"])(spec)
     emit({"event": "ready", "prepare_s": time.monotonic() - t0})
     for line in sys.stdin:
         cmd = line.split()

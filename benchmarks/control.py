@@ -11,7 +11,11 @@ traffic file's `probe_blocks` follow them) prints, per seed, each
 number the cell's check compares with a tolerance, computed as
 check.py computes it, as the control gives it, beside the limit. PERF.md records
 the readings the limits were set from. benchmarks/tests keeps the same
-control at a size a test run can hold."""
+control at a size a test run can hold.
+
+A check that came as a file (benchmarks/checks/<name>.py) brings the
+control of its own tolerances: `control(traffic, seed, n_blocks,
+precision) -> {number: value}`, merged with the built-in ones here."""
 
 from __future__ import annotations
 
@@ -21,9 +25,8 @@ from typing import Dict, List
 
 import numpy as np
 
-from . import manifest
+from . import check, extend, gen, manifest
 from . import reference as ref
-from .gen import ProducerStream
 
 
 def control_numbers(traffic: Dict, seed: int, n_blocks: int,
@@ -38,7 +41,7 @@ def control_numbers(traffic: Dict, seed: int, n_blocks: int,
             continue
         probes = int(group.get("probe_blocks", 0))
         for _ in range(int(group.get("count", 1))):
-            s = ProducerStream(traffic, seed, producer)
+            s = gen.stream(traffic, seed, producer)
             producer += 1
             if "detector_alerts" in checks:
                 # the whole run in the lower precision, the probe
@@ -61,17 +64,23 @@ def control_numbers(traffic: Dict, seed: int, n_blocks: int,
         out["alert_probe_block_gap"] = probe_diff / max(probe_points, 1)
     if scored:
         out["tad_decision_mismatch"] = mism / scored
+    for name in checks:
+        mod = extend.module("check", name)
+        if mod is not None and hasattr(mod, "control"):
+            out.update(mod.control(traffic, seed, n_blocks, precision))
     return out
 
 
 def main(argv: List[str]) -> int:
     bench = manifest.load()
+    extend.use(bench.base)
     traffic = bench.traffic(bench.cell(argv[1])["traffic"])
     n_blocks = int(argv[2])
     failed_all = True
     for seed in map(int, argv[3:]):
         nums = control_numbers(traffic, seed, n_blocks)
-        fails = {k: v > traffic["limits"][k] for k, v in nums.items()}
+        fails = {k: v > check.limit(traffic, k)
+                 for k, v in nums.items()}
         print(json.dumps({"cell": argv[1], "seed": seed,
                           "blocks_per_producer": n_blocks,
                           "control": nums, "limits": traffic["limits"],

@@ -32,6 +32,7 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from . import extend as _extend
 from .schema import FLOW_SCHEMA, host_dtype
 
 BLOCK_MAGIC = b"TBLK"
@@ -216,7 +217,14 @@ class ProducerStream:
     """Blocks of one producer, in order. `values(b)` gives what the
     reference needs of block b (no encoding); `block(b)` the payload.
     Blocks must be encoded in order 0, 1, 2, … because the cumulative
-    counters of a connection carry from one block to the next."""
+    counters of a connection carry from one block to the next.
+
+    This is the key law `slices`, and its surface is what every key
+    law has (`stream` below): `producer`, `n_conn`, `cpb`, `points`,
+    `rows`, `interval`, `start`, `conn_index(b)`, `values(b)`,
+    `block(b)`. A block carries `points` successive points of `cpb`
+    distinct connections. What the seed changes here: the values and
+    the order of the slices, never a connection's identity."""
 
     def __init__(self, traffic: Dict, seed: int, producer: int) -> None:
         g = traffic["generator"]
@@ -336,3 +344,20 @@ class ProducerStream:
                 parts.append(static[name])
         return b"".join(parts), {"rows": self.rows,
                                  "octets": int(octet.sum())}
+
+
+#: the built-in key laws; any other name is benchmarks/laws/<name>.py
+LAWS = {"slices": ProducerStream}
+
+
+def law(traffic: Dict):
+    """The class of the key law the traffic file names
+    (`generator.law`, default `slices`)."""
+    return _extend.resolve("law", traffic["generator"].get("law", "slices"))
+
+
+def stream(traffic: Dict, seed: int, producer: int):
+    """One producer's stream under the traffic file's key law. The
+    producer, the reference and the control all get theirs here, so
+    they draw the same rows."""
+    return law(traffic)(traffic, seed, producer)

@@ -10,6 +10,11 @@ rehearsal, whose output is labelled a rehearsal and carries no metric.
 Phases of a run (every run of a cell goes through the same ones, from
 the same starting state):
 
+  resolve    every check, key law, worker role, reduction and kernel
+             function the cell's files name is found, built in or as a
+             file (extend.py), and every tolerance a check declares is
+             in the traffic file's `limits`: a name nothing provides
+             ends the run here, before any child exists
   start      manager child up (compile cache at a fixed path inside the
              checkout), workers up with their inputs prepared — in
              parallel, since the manager's start is the longer
@@ -44,9 +49,12 @@ import urllib.request
 from typing import Dict, List, Optional
 
 from . import check as _check
+from . import extend as _extend
+from . import gen as _gen
 from . import manifest as _manifest
 from . import prom as _prom
 from . import reductions as _reductions
+from .extend import RunFailed        # noqa: F401  (run.py and tests catch it here)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -61,11 +69,6 @@ BANNER = re.compile(
     r"device_kind='([^']*)' devices=(\d+) native=(\S+)")
 COMPILED = re.compile(rb"Finished XLA compilation of (.*?) in ([0-9.]+) sec")
 CACHE_HIT = re.compile(rb"Persistent compilation cache hit")
-
-
-class RunFailed(Exception):
-    """The run cannot give a result (no chip, a child died, a phase
-    timed out): exit non-zero, print no result line."""
 
 
 def log(msg: str) -> None:
@@ -239,7 +242,7 @@ class Worker:
     def __init__(self, children: Children, spec: Dict, work: str,
                  cores: Optional[List[int]]) -> None:
         self.spec = spec
-        self.name = f"{spec['role']}-{spec.get('producer', 0)}"
+        self.name = f"{spec['role']}-{spec['instance']}"
         self.out = spec["out"] = os.path.join(work, self.name + ".out.json")
         path = os.path.join(work, self.name + ".spec.json")
         with open(path, "w") as f:
@@ -275,15 +278,20 @@ class Worker:
             return json.load(f)
 
 
-def worker_specs(traffic: Dict, seed: int, addr: str, scale: Dict
-                 ) -> List[Dict]:
-    """One spec per worker process from the traffic file's `workers`."""
+def worker_specs(traffic: Dict, seed: int, addr: str, scale: Dict,
+                 base: str) -> List[Dict]:
+    """One spec per worker process from the traffic file's `workers`:
+    the group's own keys (whatever a role reads), and `addr`, `seed`,
+    `traffic`, `base` (where extension files are looked for) and
+    `instance` (which of its role's workers it is)."""
     specs = []
     producer = 0
     for group in traffic["workers"]:
         for i in range(int(group.get("count", 1))):
             spec = {k: v for k, v in group.items() if k != "count"}
-            spec.update(addr=addr, seed=seed, traffic=traffic)
+            spec.update(addr=addr, seed=seed, traffic=traffic, base=base,
+                        instance=sum(s["role"] == group["role"]
+                                     for s in specs))
             if group["role"] == "producer":
                 spec["producer"] = producer
                 sched = group.get("schedule")
@@ -378,6 +386,18 @@ def fetch_trace(mgr: Manager, work: str, platform: str,
         return json.load(f)
 
 
+def resolve_all(bench: _manifest.Bench, cell_name: str, traffic: Dict
+                ) -> None:
+    """Find everything the cell's files name (see `resolve` in the
+    module's text), or raise RunFailed naming the file looked for."""
+    _extend.use(bench.base)
+    _check.resolve_all(traffic)
+    _gen.law(traffic)
+    for group in traffic["workers"]:
+        _extend.resolve("role", group["role"])
+    _reductions.resolve_all(bench, cell_name)
+
+
 def compile_stats(text: bytes) -> Dict[str, float]:
     found = COMPILED.findall(text)
     return {"compiles": len(found),
@@ -397,6 +417,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     if scale and "env" in scale:          # rehearsal: chip paths on the CPU
         config = dict(config, env={**config.get("env", {}), **scale["env"]})
     traffic = bench.traffic(cell["traffic"], scale)
+    resolve_all(bench, cell_name, traffic)
     work = os.path.join(WORK, cell_name)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
@@ -405,7 +426,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     phases: Dict[str, float] = {}
     try:
         mgr = Manager(children, config, work, platform)
-        specs = worker_specs(traffic, seed, mgr.addr, scale or {})
+        specs = worker_specs(traffic, seed, mgr.addr, scale or {},
+                             bench.base)
         cores = plan_cores(len(specs))
         if cores["manager"]:
             os.sched_setaffinity(mgr.proc.pid, cores["manager"])
@@ -484,9 +506,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
         phases["quiesce_s"] = time.monotonic() - t
         t = time.monotonic()
         probes = []
-        for w in workers:               # one producer at a time
+        for w in workers:               # one worker at a time
             n = int(w.spec.get("probe_blocks", 0))
-            if w.spec["role"] == "producer" and n:
+            if n:
                 probes.append(all_do([w], f"probe {n}", "probed")[0])
             else:
                 probes.append({"records": []})
@@ -559,6 +581,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
                "device": device}
         if trace_doc is not None:
             out["breakdown"] = trace_doc["breakdown"]
+        out["checks"] = report["numbers"]     # each beside its limit; last
         return out
     finally:
         children.stop_all()

@@ -1,7 +1,8 @@
 """Reductions from what a run recorded to the manifest's metrics.
 
 A metric's reader is a small JSON file (end_to_end/<name>.json or
-layer_metrics/<name>.json): {"reduce": <one of REDUCTIONS>, ...its
+layer_metrics/<name>.json): {"reduce": <one of REDUCTIONS, or the name
+of a file benchmarks/reduce/<name>.py with `reduce(data, p)`>, ...its
 parameters}. The reductions are general (a rate, a percentile, a
 histogram's mean over the window, a phase's seconds, a device
 operation's time in the trace); a later PR adds a metric by adding a
@@ -18,6 +19,7 @@ from __future__ import annotations
 import statistics
 from typing import Callable, Dict, List, Optional
 
+from . import extend as _extend
 from . import prom as _prom
 from . import roofline as _roofline
 from . import stats as _stats
@@ -252,11 +254,23 @@ REDUCTIONS: Dict[str, Callable[[Dict, Dict], Optional[float]]] = {
     if name.startswith("r_") and callable(fn)}
 
 
+def resolve_all(bench, cell: str) -> None:
+    """Every reduction, and every kernel's roofline function, that the
+    cell's readers of either section name: found before the run
+    starts, or the run is broken (extend.RunFailed)."""
+    for section in ("end_to_end", "per_layer"):
+        for m in bench.metrics_of(cell, section):
+            reader = bench.reader(section, m["name"])
+            _extend.resolve("reduction", reader["reduce"])
+            if "kernel" in reader:
+                _extend.resolve("kernel", reader["kernel"])
+
+
 def reduce_all(bench, cell: str, section: str, data: Dict) -> Dict:
     out = {}
     for m in bench.metrics_of(cell, section):
         reader = bench.reader(section, m["name"])
-        value = REDUCTIONS[reader["reduce"]](data, reader)
+        value = _extend.resolve("reduction", reader["reduce"])(data, reader)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out

@@ -20,7 +20,7 @@ the float32 the program states), which the comparison has to fail.
 
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -106,29 +106,24 @@ def series_of(stream, n_blocks: int) -> Tuple[np.ndarray, np.ndarray,
                                               np.ndarray]:
     """The producer's retained rows as padded per-connection series:
     values [C, L] int64, times [C, L] int64, mask [C, L], in time
-    order (blocks are generated in time order)."""
-    per_conn_vals: List[List[np.ndarray]] = [[] for _ in range(stream.n_slices)]
-    per_conn_times: List[List[np.ndarray]] = [[] for _ in range(stream.n_slices)]
-    for b in range(n_blocks):
-        v = stream.values(b)
-        s = stream.slice_of(b)
-        per_conn_vals[s].append(v["thr"])
-        per_conn_times[s].append(
-            np.broadcast_to(v["flow_end"], v["thr"].shape))
-    length = max((sum(a.shape[1] for a in vs) for vs in per_conn_vals),
-                 default=0)
+    order (blocks are generated in time order; a block's connections
+    are distinct). Whatever the key law: only `values(b)` is asked."""
+    blocks = [stream.values(b) for b in range(n_blocks)]
+    filled = np.zeros(stream.n_conn, np.int64)
+    for v in blocks:
+        filled[v["conn"]] += v["thr"].shape[1]
+    length = int(filled.max()) if blocks else 0
     vals = np.zeros((stream.n_conn, length), np.int64)
     times = np.zeros((stream.n_conn, length), np.int64)
     mask = np.zeros((stream.n_conn, length), bool)
-    for s in range(stream.n_slices):
-        if not per_conn_vals[s]:
-            continue
-        v = np.concatenate(per_conn_vals[s], axis=1)
-        t = np.concatenate(per_conn_times[s], axis=1)
-        rows = slice(s * stream.cpb, (s + 1) * stream.cpb)
-        vals[rows, :v.shape[1]] = v
-        times[rows, :v.shape[1]] = t
-        mask[rows, :v.shape[1]] = True
+    filled[:] = 0
+    for v in blocks:
+        conn = v["conn"][:, None]
+        cols = filled[conn] + np.arange(v["thr"].shape[1])
+        vals[conn, cols] = v["thr"]
+        times[conn, cols] = v["flow_end"]
+        mask[conn, cols] = True
+        filled[v["conn"]] += v["thr"].shape[1]
     return vals, times, mask
 
 

@@ -1,12 +1,21 @@
 """Bytes and operations a kernel needs for one call, from its shapes,
 and the chip's published peaks (peaks.json, keyed by `device_kind`; a
-device that is not in the table is an error, not a default)."""
+device that is not in the table is an error, not a default).
+
+A kernel's function `least(data) -> {"bytes": int, "flops": int}`
+counts what the algorithm needs if everything in between stays on the
+chip; either may be 0. Float32 arithmetic runs on the vector unit, for
+which no peak is published (peaks.json `_source`): such a kernel states
+`flops: 0` and is held to its bytes. `KERNELS` has the built-in ones;
+any other name is benchmarks/kernels/<name>.py (extend.py)."""
 
 from __future__ import annotations
 
 import json
 import os
-from typing import Dict
+from typing import Callable, Dict
+
+from . import extend as _extend
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -41,10 +50,21 @@ def series_shape(data: Dict) -> Dict[str, int]:
             "steps": visits * g["points_per_conn"]}
 
 
+def least_ewma_scores(data: Dict) -> Dict[str, int]:
+    shape = series_shape(data)
+    return {"bytes": ewma_scores_bytes(shape["series"], shape["steps"]),
+            "flops": 0}
+
+
+KERNELS: Dict[str, Callable[[Dict], Dict[str, int]]] = {
+    "ewma_scores": least_ewma_scores,
+}
+
+
 def least_seconds(kernel: str, data: Dict, device: Dict) -> float:
+    """The larger of bytes over the memory's peak and operations over
+    the matrix unit's."""
     pk = peaks(device["kind"])
-    if kernel == "ewma_scores":
-        shape = series_shape(data)
-        return (ewma_scores_bytes(shape["series"], shape["steps"])
-                / pk["hbm_bytes_per_s"])
-    raise KeyError(f"no roofline function for kernel {kernel!r}")
+    need = _extend.resolve("kernel", kernel)(data)
+    return max(need["bytes"] / pk["hbm_bytes_per_s"],
+               need["flops"] / pk["bf16_flops_per_s"])

@@ -2,8 +2,11 @@
 --trace <0|1>`: one run of one cell on the chip this machine holds.
 
 The last line of stdout is the result object and nothing else goes
-into it; a run that finds no chip, or whose children fail, exits
-non-zero and prints no such line."""
+into it; a run that finds no chip, or whose children fail, or whose
+files name a check, key law, role, reduction or kernel that nothing
+provides, exits non-zero and prints no such line. Each number the
+check compared stands beside its limit under the result's last key,
+`checks`, and on the last lines of stderr."""
 
 import time
 
@@ -29,6 +32,11 @@ def main(argv=None) -> int:
     except harness.RunFailed as e:
         print(f"run failed: {e}", file=sys.stderr)
         return 1
+    for name, c in out["checks"].items():
+        print(f"check {name}: value={c['value']!r} limit={c['limit']!r} "
+              f"{'ok' if c['value'] <= c['limit'] else 'FAILED'}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)
     return 0
 

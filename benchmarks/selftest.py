@@ -10,10 +10,12 @@
    control flow show here. It is a REHEARSAL: it prints whether each
    cell's plumbing and check hold and never a metric's value, because a
    number from the CPU backend says nothing about the chip.
-4. Data-drivenness: a cell, a configuration and a per-layer metric
-   added as files only (in a temporary overlay: the first Open question
-   of PERF.md, `parts-fused.ingest-saturate`) run without any edit of
-   the code.
+4. Data-drivenness: a deployment added as files only, in a temporary
+   overlay: a configuration, a traffic mix and a cell that name a key
+   law, a check, a worker role, a reduction and a kernel's roofline
+   function which are files too (benchmarks/extend.py). The cell runs
+   end to end with no edit of any code, `correct` true; the same check
+   file with a perturbed expectation gives `correct` false.
 """
 
 from __future__ import annotations
@@ -26,7 +28,8 @@ import sys
 import tempfile
 import time
 
-from . import harness, manifest, rehearsal, roofline, stats, tracered
+from . import (extend, harness, manifest, rehearsal, roofline, stats,
+               tracered)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -83,9 +86,11 @@ def trace_reduction() -> None:
           "breakdown lists at most ten entries each")
 
 
-def rehearse(cell: dict, bench=None, trace: bool = True) -> None:
+def rehearse(cell: dict, bench=None, trace: bool = True) -> list:
+    """One untraced run and, with `trace`, one traced: their results."""
     bench = bench or manifest.load()
     scale = rehearsal.scale_for(bench, cell)
+    outs = []
     for tr in ([False, True] if trace else [False]):
         # a traced rehearsal needs room after the profiler's export,
         # which is slow on the CPU backend (its host trace is large)
@@ -106,49 +111,216 @@ def rehearse(cell: dict, bench=None, trace: bool = True) -> None:
               f"{sorted(missing)})")
         check(out["device"]["platform"] == "cpu",
               "REHEARSAL ran on the CPU backend and says so")
+        outs.append(out)
+    return outs
 
 
-def files_only() -> None:
-    """A later PR's cell, configuration and metric: files and manifest
-    entries in an overlay, no edit of any code."""
-    tmp = tempfile.mkdtemp(prefix="selftest-overlay-")
+#: what a later PR's deployment brings, one file of each kind (step 4)
+OVERLAY_FILES = {
+    "laws/hot_slice.py": '''"""Key law `hot_slice`: of a producer's slices of connections, slice 0
+comes three times in every round of visits and each other slice once.
+The seed changes the values and the order of a round's visits; which
+connections exist, and how often a slice comes, it does not."""
+import numpy as np
+
+from benchmarks.gen import ProducerStream
+
+
+class Stream(ProducerStream):
+    def __init__(self, traffic, seed, producer):
+        super().__init__(traffic, seed, producer)
+        rng = np.random.default_rng([self.seed, producer, 0x407])
+        self.round = rng.permutation([0, 0] + list(range(self.n_slices)))
+
+    def slice_of(self, b):
+        return int(self.round[b % len(self.round)])
+''',
+    "checks/series_by_law.py": '''"""As many detector series as the key law sent distinct connections."""
+from benchmarks import check as _check
+
+limits = ("series_gap_share",)
+EXPECTED_BESIDE_THE_LAW = 0
+
+
+def check(ctx, rep):
+    series = sum(s["series"] for s in ctx["health"]["ingest"]["perShard"])
+    want = EXPECTED_BESIDE_THE_LAW
+    for stream, n, _ in _check.streams(ctx):
+        want += len(set().union(*(stream.conn_index(b).tolist()
+                                  for b in range(n))))
+    rep.compare("series_gap_share", abs(series - want) / max(want, 1),
+                _check.limit(ctx["traffic"], "series_gap_share"),
+                f"{series} series, {want} connections under the law")
+''',
+    "roles/health_probe.py": '''"""An operator's one request at a fixed offset in the window."""
+import json
+import time
+
+from benchmarks.client import Http, sleep_until
+
+
+class Role:
+    def __init__(self, spec):
+        self.spec = spec
+        self.http = Http(spec["addr"])
+
+    def ask(self, due):
+        t0 = time.monotonic()
+        status, body = self.http.request("GET", "/healthz")
+        rec = {"due": due, "send": t0, "ack": time.monotonic(),
+               "status": status}
+        if status == 200:
+            rec["flow_rows"] = json.loads(body)["store"]["flowRows"]
+        return rec
+
+    def handle(self, cmd):
+        if cmd[0] == "preload":
+            return {"event": "preloaded", "records": []}
+        if cmd[0] == "warm":
+            return {"event": "warmed",
+                    "records": [self.ask(time.monotonic())]}
+        due = float(cmd[1]) + float(self.spec["offset_s"])
+        sleep_until(due)
+        return {"event": "done", "records": [self.ask(due)]}
+''',
+    "reduce/counter_per_block.py": '''"""A counter's rise over the window per ingest block acked in it."""
+from benchmarks import prom
+
+
+def reduce(data, p):
+    before, after = data["metrics_before"], data["metrics_after"]
     try:
-        base = os.path.join(tmp, "benchmarks")
+        blocks = prom.delta(before, after, "theia_ingest_batches_total")
+        rise = prom.delta(before, after, p["series"])
+    except KeyError:
+        return None
+    return rise / blocks if blocks > 0 else None
+''',
+    "kernels/toy_step.py": '''"""A fixture, not a kernel of the program: 12 bytes and
+`flops_per_point` operations for every point of one block."""
+
+
+def least(data):
+    g = data["traffic"]["generator"]
+    points = g["conns_per_block"] * g["points_per_conn"]
+    return {"bytes": 12 * points,
+            "flops": int(g.get("flops_per_point", 0)) * points}
+''',
+}
+
+
+def write(path: str, text: str) -> None:
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as f:
+        f.write(text)
+
+
+def deployment_as_files() -> None:
+    """A later PR's deployment: a configuration, a traffic mix, a cell,
+    a check, a key law, a worker role, a reduction, a kernel's roofline
+    function and three per-layer metrics, all as files and manifest
+    entries in an overlay, with no edit of any file that is there."""
+    tmp = tempfile.mkdtemp(prefix="selftest-overlay-")
+    base = os.path.join(tmp, "benchmarks")
+    try:
         for sub in ("configs", "traffic", "end_to_end", "layer_metrics"):
             shutil.copytree(os.path.join(HERE, sub), os.path.join(base, sub))
+        for rel, text in OVERLAY_FILES.items():
+            write(os.path.join(base, rel), text)
         doc = copy.deepcopy(manifest.load().doc)
-        cfg = json.load(open(os.path.join(
-            base, "configs", "theia-parts-fused-1x1.json")))
-        cfg["name"] = "theia-parts-fused-1x2"
-        cfg["manager_args"] = cfg["manager_args"] + ["--workers", "3"]
-        with open(os.path.join(base, "configs", cfg["name"] + ".json"),
-                  "w") as f:
-            json.dump(cfg, f)
+        with open(os.path.join(base, "configs",
+                               "theia-default-1x1.json")) as f:
+            cfg = json.load(f)
+        cfg["name"] = "overlay-default-1x1"
+        write(os.path.join(base, "configs", cfg["name"] + ".json"),
+              json.dumps(cfg))
         doc["configs"].append({
             "name": cfg["name"], "source": "selftest overlay",
             "file": f"benchmarks/configs/{cfg['name']}.json",
             "reduced": [], "why": "selftest overlay"})
-        new = "parts-fused.ingest-saturate"
+        write(os.path.join(base, "traffic", "hot-ingest.json"), json.dumps({
+            "generator": {"law": "hot_slice",
+                          "connections_per_producer": 512,
+                          "conns_per_block": 64, "points_per_conn": 4,
+                          "spike_rate": 0.0, "flops_per_point": 8},
+            "workers": [
+                {"role": "producer", "count": 2, "warm_blocks": 10,
+                 "prepared_blocks": 48, "probe_blocks": 2},
+                {"role": "health_probe", "count": 1, "offset_s": 1.0}],
+            "trace_seconds": 1,
+            "checks": ["acks", "store_totals", "detector_series",
+                       "detector_alerts", "series_by_law"],
+            "limits": {"alert_count_gap": 1e-05,
+                       "alert_probe_block_gap": 5e-05,
+                       "series_gap_share": 0.0}}))
+        new = "overlay.hot-ingest"
         doc["workloads"].append({
-            "name": new, "config": cfg["name"],
-            "traffic": "ingest-saturate", "chips": 1, "why": "overlay"})
+            "name": new, "config": cfg["name"], "traffic": "hot-ingest",
+            "chips": 1, "why": "overlay"})
+        # a new cell's name is appended to every metric it reports
         for m in doc["end_to_end"] + doc["per_layer"]:
             if "default.ingest-saturate" in m.get("workloads", []):
                 m["workloads"].append(new)
-        with open(os.path.join(base, "layer_metrics",
-                               "store_parts_sealed.json"), "w") as f:
-            json.dump({"reduce": "counter_delta",
-                       "series": "theia_store_parts_sealed_total"}, f)
-        doc["per_layer"].append({
-            "name": "store_parts_sealed", "unit": "parts",
-            "better": "lower", "source": "program_counter",
-            "layer": "store", "moves": "acked_rows_per_s",
-            "workloads": [new]})
+        readers = {
+            "detector.partition_bytes_per_block": (
+                "bytes/block", "program_counter", "detector",
+                {"reduce": "counter_per_block",
+                 "series": "theia_detector_partition_bytes_total"}),
+            "healthz_at_offset_ms": (
+                "ms", "host_clock", "operator",
+                {"reduce": "latency_percentile", "role": "health_probe",
+                 "q": 50}),
+            "toy_step_roofline": (
+                "%", "device_trace", "detector",
+                {"reduce": "roofline_share", "kernel": "toy_step",
+                 "match": "module:jit_stream_update_sparse"}),
+        }
+        for name, (unit, source, layer, reader) in readers.items():
+            write(os.path.join(base, "layer_metrics", name + ".json"),
+                  json.dumps(reader))
+            doc["per_layer"].append({
+                "name": name, "unit": unit, "better": "lower",
+                "source": source, "layer": layer,
+                "moves": "acked_rows_per_s", "workloads": [new]})
         bench = manifest.Bench(doc, base=base)
-        rehearse(bench.cell(new), bench=bench, trace=True)
-        check(True, "a cell, a configuration and a per-layer metric "
-              "were added as files only")
+        cell = bench.cell(new)
+        plain, traced = rehearse(cell, bench=bench, trace=True)
+        check("series_gap_share" in traced["checks"]
+              and "alert_probe_block_gap" in traced["checks"],
+              "the file's number stands beside the built-in ones, each "
+              "with its limit, under the result's `checks`")
+        check({"detector.partition_bytes_per_block",
+               "healthz_at_offset_ms"} <= set(traced["metrics"]),
+              "the file's reduction and the file's role's records are "
+              "read into metrics")
+        data = {"traffic": bench.traffic("hot-ingest")}
+        pk = roofline.peaks("TPU v5 lite")
+        check(roofline.least_seconds("toy_step", data, {"kind": "TPU v5 lite"})
+              == 12 * 256 / pk["hbm_bytes_per_s"],
+              "the file's kernel function: bound by its bytes")
+        data["traffic"]["generator"]["flops_per_point"] = 10 ** 6
+        check(roofline.least_seconds("toy_step", data, {"kind": "TPU v5 lite"})
+              == 256e6 / pk["bf16_flops_per_s"],
+              "the file's kernel function: bound by its operations")
+        # the same check file with a perturbed expectation
+        extend.forget(base)
+        write(os.path.join(base, "checks", "series_by_law.py"),
+              OVERLAY_FILES["checks/series_by_law.py"].replace(
+                  "EXPECTED_BESIDE_THE_LAW = 0",
+                  "EXPECTED_BESIDE_THE_LAW = 1000"))
+        out = harness.run_cell(new, 7, 3.0, False, time.monotonic(),
+                               platform="cpu",
+                               scale=rehearsal.scale_for(bench, cell),
+                               bench=bench)
+        failed = [k for k, c in out["checks"].items()
+                  if c["value"] > c["limit"]]
+        check(out["correct"] is False and failed == ["series_gap_share"],
+              "REHEARSAL the file-borne check with a perturbed "
+              "expectation gives correct: false")
+        check(True, "a deployment was added as files only: "
+              + ", ".join(sorted(OVERLAY_FILES)))
     finally:
+        extend.forget(base)
         shutil.rmtree(tmp, ignore_errors=True)
 
 
@@ -159,7 +331,7 @@ def main() -> int:
     trace_reduction()
     for cell in manifest.load().doc["workloads"]:
         rehearse(cell)
-    files_only()
+    deployment_as_files()
     print("selftest: all ok (rehearsal only)")
     return 0
 
