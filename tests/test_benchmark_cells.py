@@ -1,0 +1,163 @@
+"""Every cell of BENCHMARK.json resolves without a chip, and a cell
+whose files cannot be found is refused before any child starts: the
+benchmark's own refusal and sharpness cases (benchmarks/tests/) that
+need no manager run here as tier-1 cases, so that a broken cell fails
+this suite and not the driver's check."""
+
+import os
+
+import pytest
+
+from benchmarks import check, extend, harness, manifest
+from benchmarks.tests.conftest import overlay            # noqa: F401
+from benchmarks.tests.test_extension import (            # noqa: F401
+    test_a_kernel_file_is_held_to_the_larger_bound,
+    test_a_name_nothing_provides_is_a_broken_run,
+    test_forgotten_overlay_is_read_anew,
+    test_resolver,
+)
+from benchmarks.tests.test_snapshot_prefix import (      # noqa: F401
+    test_a_perturbed_snapshot_or_log_is_not_correct,
+    test_a_refused_request_counts_as_failed,
+    test_a_sound_generation_pair_is_correct,
+    test_a_window_with_two_snapshots_or_none,
+    test_another_interval_than_the_configurations,
+)
+
+BENCH = manifest.load()
+CELLS = [w["name"] for w in BENCH.doc["workloads"]]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_resolves_without_a_chip(cell):
+    """Configuration, traffic, key law, roles, checks with their
+    limits, reductions and kernel functions: all found by name."""
+    w = BENCH.cell(cell)
+    config = BENCH.config(w["config"])
+    traffic = BENCH.traffic(w["traffic"])
+    harness.resolve_all(BENCH, cell, traffic)
+    assert w["chips"] in (1, 4)
+    assert "--checkpoint-interval" in config["manager_args"]
+    for fn in check.resolve_all(traffic):
+        assert callable(fn)
+    for section in ("end_to_end", "per_layer"):
+        metrics = BENCH.metrics_of(cell, section)
+        assert metrics, f"{cell} reports no {section} metric"
+        for m in metrics:
+            assert "reduce" in BENCH.reader(section, m["name"])
+    assert "setup_s" in {m["name"] for m in
+                         BENCH.metrics_of(cell, "end_to_end")}
+
+
+@pytest.mark.parametrize("config", [c["name"]
+                                    for c in BENCH.doc["configs"]])
+def test_configuration_file_states_what_the_manifest_says(config):
+    entry = next(c for c in BENCH.doc["configs"] if c["name"] == config)
+    doc = BENCH.config(config)
+    assert doc["name"] == config
+    assert os.path.exists(os.path.join(manifest.ROOT, entry["file"]))
+    assert any(w["config"] == config for w in BENCH.doc["workloads"])
+    # a key that is `reduced` differs from the source's or the
+    # program's own value, which the file states beside it
+    for key in entry["reduced"]:
+        assert key in doc
+    interval = doc["manager_args"][
+        doc["manager_args"].index("--checkpoint-interval") + 1]
+    assert float(interval) == doc["checkpoint_interval_s"]
+    assert ("checkpoint_interval_s" in entry["reduced"]) \
+        == (doc["checkpoint_interval_s"] != 60)
+
+
+def test_the_checkpoint_cell_is_saturate_with_the_snapshot_on():
+    """The two cells differ in the snapshot and in nothing else of
+    the producers' traffic: their gap is the snapshot's cost."""
+    sat = BENCH.traffic("ingest-saturate")
+    ckpt = BENCH.traffic("ingest-saturate-ckpt")
+    assert ckpt["generator"] == sat["generator"]
+    assert ckpt["limits"] == sat["limits"]
+    assert ckpt["trace_seconds"] == sat["trace_seconds"] == 10
+    producers, operator = ckpt["workers"]
+    assert producers == {**sat["workers"][0], "preload_blocks": 8}
+    assert operator == {"role": "operator", "count": 1, "offset_s": 3.0}
+    assert ckpt["checks"] == ["snapshot_prefix"] + sat["checks"]
+    rows = (producers["count"] * producers["preload_blocks"]
+            * ckpt["generator"]["conns_per_block"]
+            * ckpt["generator"]["points_per_conn"])
+    cfg = BENCH.config("theia-default-ckpt-1x1")
+    assert rows == cfg["retained_window_rows"] == 1024000
+    base = BENCH.config("theia-default-1x1")
+    assert [a for a in cfg["manager_args"] if a != "60"] \
+        == [a for a in base["manager_args"] if a != "0"]
+    assert (cfg["env"], cfg["expect"]) == (base["env"], base["expect"])
+    e2e = {m["name"] for m in BENCH.metrics_of(
+        "default-ckpt.ingest-saturate-ckpt", "end_to_end")}
+    assert e2e == {"acked_rows_per_s", "setup_s"}
+    layer = {m["name"] for m in BENCH.metrics_of(
+        "default-ckpt.ingest-saturate-ckpt", "per_layer")}
+    assert {m["name"] for m in BENCH.metrics_of(
+        "default.ingest-saturate", "per_layer")} < layer
+    assert len({n for n in layer if n.startswith("ckpt.")}) == 12
+
+
+def test_an_operator_no_manager_answers_ends_in_the_warm_up(tmp_path):
+    """The parent commit has no /admin/checkpoint: the role must end
+    its worker in set-up (exit 1 for the run), never hang or go on."""
+    import http.server
+    import threading
+
+    class NotFound(http.server.BaseHTTPRequestHandler):
+        def do_POST(self):                               # noqa: N802
+            self.send_response(404)
+            self.send_header("Content-Length", "0")
+            self.end_headers()
+
+        def log_message(self, *args):
+            pass
+
+    httpd = http.server.HTTPServer(("127.0.0.1", 0), NotFound)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    try:
+        extend.use(manifest.HERE)
+        role = extend.resolve("role", "operator")({
+            "addr": f"http://127.0.0.1:{httpd.server_address[1]}",
+            "offset_s": 3.0})
+        assert role.handle(["preload"]) == {"event": "preloaded",
+                                            "records": []}
+        with pytest.raises(SystemExit, match="404"):
+            role.handle(["warm"])
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+
+
+@pytest.mark.parametrize("name,data,want", [
+    ("record_field", {"p": {"role": "operator",
+                            "field": "stages_ms.hold"}}, 1500.0),
+    ("record_field", {"p": {"role": "operator", "field": "seconds",
+                            "scale": 1000.0}}, 20000.0),
+    ("record_field", {"p": {"role": "operator", "field": "absent"}},
+     None),
+    ("longest_ack_gap", {"p": {"role": "producer"}}, 2500.0),
+    ("rate_between", {"p": {"role": "producer", "field": "rows",
+                            "between": "operator"}}, 3 * 32000 / 20.0),
+])
+def test_reductions_the_cell_brings(name, data, want):
+    extend.use(manifest.HERE)
+    acks = [100.5, 101.0, 103.5, 104.0, 121.0, 140.0]
+    run = {
+        "t_open": 100.0, "seconds": 51.0, "clean": [0.0, float("inf")],
+        "specs": [{"role": "producer"}, {"role": "operator"}],
+        "results": [
+            {"records": [{"ack": t, "status": 200, "rows": 32000}
+                         for t in acks]},
+            {"records": [{"send": 103.0, "ack": 123.0, "status": 200,
+                          "seconds": 20.0,
+                          "stages_ms": {"hold": 1500.0}}]}],
+    }
+    got = extend.resolve("reduction", name)(run, data["p"])
+    if name == "longest_ack_gap":
+        # no ack between 121 and 140
+        assert got == pytest.approx(19000.0)
+        run["clean"] = [0.0, 105.0]      # a traced run: before the profiler
+        got = extend.resolve("reduction", name)(run, data["p"])
+    assert got == (want if want is None else pytest.approx(want))

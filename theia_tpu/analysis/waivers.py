@@ -218,7 +218,7 @@ WAIVERS = [
     # -- raw-clock -------------------------------------------------------
     {
         "check": "raw-clock",
-        "match": "raw-clock:theia_tpu/store/wal.py:read:"
+        "match": "raw-clock:theia_tpu/store/wal.py:acquire_read:"
                  "time.monotonic",
         "invariant": (
             "The latch's lockdep-witness wait/hold measurement: it "

@@ -126,13 +126,13 @@ def _urlopen(addr: str, req: urllib.request.Request,
 
 
 def _request(addr: str, method: str, path: str,
-             body: Optional[Dict] = None) -> Dict:
+             body: Optional[Dict] = None, timeout: float = 30) -> Dict:
     req = urllib.request.Request(
         addr + path, method=method,
         data=json.dumps(body).encode() if body is not None else None,
         headers={"Content-Type": "application/json",
                  **_auth_headers()})
-    raw = _urlopen(addr, req)
+    raw = _urlopen(addr, req, timeout=timeout)
     return json.loads(raw) if raw else {}
 
 
@@ -1758,6 +1758,31 @@ def locks_cmd(args) -> None:
                           for k, v in sorted(nesting.items())))
 
 
+def checkpoint_cmd(args) -> None:
+    """`theia checkpoint` — ask the manager for a snapshot of --db
+    now and wait until it is published (POST /admin/checkpoint; the
+    timer's own routine on the timer's own thread, and it counts as
+    the tick). Prints the log stamp the snapshot is exact at, its
+    rows, bytes, seconds and stage times."""
+    doc = _request(args.manager_addr, "POST", "/admin/checkpoint", {},
+                   timeout=args.timeout)
+    if args.json:
+        print(json.dumps(doc, indent=2))
+        return
+    if doc.get("skipped"):
+        print(f"nothing changed since snapshot {doc.get('generation')} "
+              f"(stamp {doc.get('stamp')}): not written again")
+        return
+    print(f"snapshot {doc.get('generation')}: stamp {doc.get('stamp')}, "
+          f"{doc.get('rows')} flow rows, {doc.get('bytesIn')} B in, "
+          f"{doc.get('bytes')} B written, "
+          f"{doc.get('seconds', 0.0):.2f} s")
+    stages = doc.get("stagesMs") or {}
+    if stages:
+        print("  " + "  ".join(f"{k} {v:.1f} ms"
+                               for k, v in stages.items()))
+
+
 def version(args) -> None:
     from .. import __version__
     print(f"theia version: {__version__}")
@@ -2134,6 +2159,16 @@ def build_parser() -> argparse.ArgumentParser:
     lk.add_argument("--limit", type=int, default=30,
                     help="stats rows shown (sorted by total wait)")
     lk.set_defaults(fn=locks_cmd)
+
+    ck = sub.add_parser(
+        "checkpoint",
+        help="ask the manager for a snapshot of --db now and wait "
+             "for it (POST /admin/checkpoint)")
+    ck.add_argument("--json", action="store_true",
+                    help="raw JSON answer")
+    ck.add_argument("--timeout", type=float, default=600.0,
+                    help="seconds to wait for the snapshot")
+    ck.set_defaults(fn=checkpoint_cmd)
 
     ver = sub.add_parser("version")
     ver.set_defaults(fn=version)

@@ -351,6 +351,7 @@ def main(argv=None) -> None:
                                     interval=args.checkpoint_interval,
                                     assume_current=pristine)
         checkpointer.start()
+        server.attach_checkpointer(checkpointer)
         print(f"checkpointing {args.db} every "
               f"{args.checkpoint_interval:g}s", file=sys.stderr)
 

@@ -113,6 +113,7 @@ _ACK_ROWS = b'{"rows":'
 _ACK_ALERTS = b',"alerts":'
 _ACK_KIND_HH = b',"alertsByKind":{"heavy_hitter":'
 _ACK_KIND_CONN = b',"connection_anomaly":'
+_ACK_LSN = b',"walLsn":'
 _ACK_DUP = b',"duplicate":true'
 _ACK_TRACE = b',"traceId":"'
 
@@ -129,10 +130,11 @@ def _fast_ack_bytes(doc: Dict[str, object]) -> Optional[bytes]:
     except KeyError:
         return None
     kinds = doc.get("alertsByKind")
+    lsn = doc.get("walLsn")
     dup = doc.get("duplicate")
     trace = doc.get("traceId")
-    if len(doc) != (2 + (kinds is not None) + (dup is not None)
-                    + (trace is not None)):
+    if len(doc) != (2 + (kinds is not None) + (lsn is not None)
+                    + (dup is not None) + (trace is not None)):
         return None
     if type(rows) is not int or type(alerts) is not int \
             or dup not in (None, True):
@@ -147,6 +149,10 @@ def _fast_ack_bytes(doc: Dict[str, object]) -> Optional[bytes]:
         parts += [_ACK_KIND_HH, str(kinds["heavy_hitter"]).encode(),
                   _ACK_KIND_CONN,
                   str(kinds["connection_anomaly"]).encode(), b"}"]
+    if lsn is not None:
+        if type(lsn) is not int or kinds is None or dup:
+            return None        # not where _apply_decoded puts it
+        parts += [_ACK_LSN, str(lsn).encode()]
     if dup:
         parts.append(_ACK_DUP)
     if trace is not None:
@@ -415,6 +421,7 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
     cluster = None    # ClusterNode (multi-node tier)
     history = None    # MetricsHistoryLoop (scrape-to-store series)
     rules = None      # RulesEngine (alert rules over stored series)
+    checkpointer = None   # Checkpointer (--db with an interval > 0)
     auth_token: Optional[str] = None
     quiet = True
     # Socket timeout (StreamRequestHandler honors it): a client that
@@ -887,6 +894,11 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
                 ws = None
             if ws:
                 doc["wal"] = ws
+        # Snapshots: the interval, how many were written, whether one
+        # runs now, and what the last one gave (or why it failed).
+        ck = getattr(self, "checkpointer", None)
+        if ck is not None:
+            doc["checkpoint"] = ck.status()
         # Cluster tier: role/term, peer liveness, replication lag or
         # follower staleness, router counters. A down peer or a
         # non-streaming follower degrades the node (it still serves).
@@ -1210,6 +1222,9 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
         if parts and parts[0] == "cluster":
             self._post_cluster(parts)
             return
+        if parts == ("admin", "checkpoint"):
+            self._post_checkpoint()
+            return
         if self.path.startswith(GROUP_INTELLIGENCE) and len(parts) == 4:
             kind = _RESOURCE_KIND[parts[3]]
             body = self._read_body()
@@ -1232,6 +1247,31 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
                 python_tracer=body.get("pythonTracer") is True), 201)
             return
         raise KeyError(self.path)
+
+    def _post_checkpoint(self) -> None:
+        """POST /admin/checkpoint: ask the checkpointer for a snapshot
+        now and answer when it is published (token-gated like every
+        POST). The snapshot is the timer's own: same thread, same
+        routine, and it counts as the tick. The answer's `stamp` is
+        the log position the snapshot is exact at: every acked block
+        whose `walLsn` is at or below it is in the file, none above
+        it is. 409 when no snapshot can be asked for (no --db, or
+        --checkpoint-interval 0); 500 with the reason when the write
+        failed."""
+        from ..store.checkpoint import CheckpointUnavailable
+        self._read_raw_body()
+        ck = self.checkpointer
+        if ck is None:
+            self._send_error_json(
+                409, "this manager writes no snapshots: it runs "
+                     "without --db, or with --checkpoint-interval 0")
+            return
+        try:
+            result = ck.request()
+        except CheckpointUnavailable as e:
+            self._send_error_json(409, str(e))
+            return
+        self._send_json(result, 500 if "error" in result else 200)
 
     def _post_query_partial(self) -> None:
         """Cluster-internal scatter-gather server half: execute the
@@ -1614,6 +1654,12 @@ class TheiaManagerServer:
         _obs_trace.watch_gc()
         self._thread: Optional[threading.Thread] = None
         self._serving = False
+
+    def attach_checkpointer(self, checkpointer) -> None:
+        """Hand the server the checkpointer (built after it, once the
+        store is seeded): POST /admin/checkpoint asks it for a
+        snapshot and /healthz shows its `checkpoint` block."""
+        self.httpd.RequestHandlerClass.checkpointer = checkpointer
 
     def start_background(self) -> None:
         self._serving = True

@@ -10,7 +10,9 @@ producers POST flow batches to the manager —
               or TabSeparated rows (text/tab-separated-values)
         response: {"rows": N, "alerts": K,
                    "alertsByKind": {"heavy_hitter": H,
-                                    "connection_anomaly": C}}
+                                    "connection_anomaly": C},
+                   "walLsn": L}     (with a WAL: the LSN of the flows
+                                     record that holds the rows)
 
 Every ingested batch fans out to the store (materialized views, TTL)
 AND advances the streaming detectors — the heavy-hitter / DDoS sketch
@@ -908,8 +910,9 @@ class IngestManager:
         skip_local = local_dup is not None or len(batch) == 0
         fut = None
         if not skip_local:
+            journaled: Dict[str, object] = {}
             fut = self._submit_insert(self._timed_insert, batch,
-                                      dedup_tag, wire)
+                                      dedup_tag, wire, journaled)
         # Brownout: under pressure the scoring leg degrades first —
         # sampled at a declining fraction, then fully shed — while the
         # durable leg (WAL + store) keeps acknowledging rows.
@@ -951,6 +954,12 @@ class IngestManager:
                 _M_ERRORS.labels(stage="store_insert").inc()
                 raise insert_exc
             n = fut.result()
+            if "latchWait" in journaled:
+                # timed on the pool thread, beside this thread's
+                # detector stages: a request that stalled behind a
+                # snapshot's hold says so
+                _trace.add_stage("store.latch_wait",
+                                 journaled["latchWait"])
         else:
             n = local_dup or 0
         if seq is not None and routed is not None and fut is not None:
@@ -1001,6 +1010,11 @@ class IngestManager:
             "rows": total, "alerts": n_alerts,
             "alertsByKind": {"heavy_hitter": len(alerts),
                              "connection_anomaly": n_conn}}
+        if fut is not None and "walLsn" in journaled:
+            # the LSN of the flows record that holds these rows: a
+            # snapshot stamped at or above it holds them, one stamped
+            # below it does not (store/wal.py)
+            out["walLsn"] = journaled["walLsn"]
         if remote_rows:
             # rows this node forwarded to their owner-shard peers
             # (scored and alert-ringed THERE, not here)
@@ -1013,8 +1027,16 @@ class IngestManager:
 
     def _timed_insert(self, batch: ColumnarBatch,
                       dedup: Optional[Tuple[str, int]] = None,
-                      wire: Optional[memoryview] = None) -> int:
+                      wire: Optional[memoryview] = None,
+                      journaled: Optional[Dict[str, object]] = None
+                      ) -> int:
+        """The store leg, on a pool thread. `journaled` is filled with
+        the flows record's `walLsn` and the `latchWait` seconds where
+        the store journals into one log (a sharded store's slices have
+        one LSN each: none is reported)."""
         t0 = time.perf_counter()
+        applied = getattr(self.db, "wal_last_applied", None)
+        before = applied() if callable(applied) else None
         try:
             # kwargs are passed only when set, so minimal insert_flows
             # signatures (test doubles, pre-wire stores) keep working
@@ -1023,7 +1045,12 @@ class IngestManager:
                 kwargs["dedup"] = dedup
             if wire is not None:
                 kwargs["wire"] = wire
-            return self.db.insert_flows(batch, **kwargs)
+            n = self.db.insert_flows(batch, **kwargs)
+            after = applied() if callable(applied) else None
+            if journaled is not None and after is not None \
+                    and after is not before:
+                journaled["walLsn"], journaled["latchWait"] = after
+            return n
         finally:
             _M_STAGE_STORE.observe(time.perf_counter() - t0)
 

@@ -388,12 +388,13 @@ class Stage:
     """One timed part of the work inside the thread's innermost span
     (see the module docstring). Reusable only sequentially."""
 
-    __slots__ = ("name", "hist", "_span", "_outer", "_t0", "_nested",
-                 "_ann")
+    __slots__ = ("name", "hist", "seconds", "_span", "_outer", "_t0",
+                 "_nested", "_ann")
 
     def __init__(self, name: str, hist=None) -> None:
         self.name = name
         self.hist = hist
+        self.seconds = 0.0              # own time of the last run
         self._span: Optional[Span] = None
         self._outer: Optional["Stage"] = None
         self._nested = 0.0
@@ -419,7 +420,7 @@ class Stage:
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
-        own = whole - self._nested
+        own = self.seconds = whole - self._nested
         sp = self._span
         if sp is not None:
             sp._stage = self._outer
@@ -443,6 +444,20 @@ def stage(name: str, hist=None) -> Stage:
 
     `hist` is a histogram child (or None)."""
     return Stage(name, hist)
+
+
+def add_stage(name: str, seconds: float) -> None:
+    """Put a stage that was timed on another thread (a pool worker
+    running one leg of this request) on this thread's innermost span:
+    it ran beside the span's own stages, so it is added as it was
+    measured and taken from no enclosing stage."""
+    sp = current_span()
+    if sp is None:
+        return
+    if sp.stages is None:
+        sp.stages = {name: seconds}
+    else:
+        sp.stages[name] = sp.stages.get(name, 0.0) + seconds
 
 
 class StageMarks:

@@ -15,6 +15,14 @@ table under its own lock briefly (zero-copy concat of the append log),
 so ingest keeps flowing while the checkpoint compresses and writes.
 A cheap fingerprint (row counts + byte sizes) skips writes when
 nothing changed.
+
+A snapshot can also be ASKED for (`Checkpointer.request`, reached by
+`POST /admin/checkpoint` and `theia checkpoint`): the request wakes
+the same thread, which runs the same `checkpoint()`, one at a time,
+and the run counts as the tick: the next falls one interval after it
+ends. The caller gets that run's result: the log stamp, rows, bytes,
+seconds and the stage times (`latch_wait`, `hold`, `digest`, `write`,
+`publish`; store/flow_store.py) of the `bg.checkpoint` span.
 """
 
 from __future__ import annotations
@@ -22,13 +30,35 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
+from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..utils import get_logger
 from ..utils.faults import fire as _fire_fault
+from .flow_store import checkpoint_stage
 
 logger = get_logger("checkpoint")
+
+_M_CHECKPOINTS = _metrics.counter(
+    "theia_checkpoints_total",
+    "Runs of the checkpointer, by result: written, skipped (nothing "
+    "changed since the last write) or failed",
+    labelnames=("result",))
+_M_ROWS = _metrics.counter(
+    "theia_checkpoint_rows_total",
+    "Flow rows written into snapshots")
+_M_BYTES_IN = _metrics.counter(
+    "theia_checkpoint_bytes_in_total",
+    "Bytes of the column arrays that snapshots were written from")
+_M_BYTES_WRITTEN = _metrics.counter(
+    "theia_checkpoint_bytes_written_total",
+    "Bytes of the snapshot files written (after compression)")
+
+
+class CheckpointUnavailable(Exception):
+    """No snapshot can be asked for: the checkpointer is not running
+    (no --db, interval 0, or the manager is shutting down)."""
 
 
 class Checkpointer:
@@ -50,6 +80,8 @@ class Checkpointer:
         self.checkpoints_written = 0
         self.last_checkpoint_time: float = 0.0
         self.last_error: Optional[str] = None
+        #: what the last run gave (`request` answers with it)
+        self.last_result: Optional[Dict[str, object]] = None
         self._last_fingerprint: Optional[Tuple] = (
             self._fingerprint() if assume_current else None)
         #: WAL stamp of the PREVIOUS successful snapshot — GC lags one
@@ -58,8 +90,17 @@ class Checkpointer:
         #: current stamp would orphan .prev the moment the primary
         #: corrupts)
         self._gc_stamp = None
-        self._stop = threading.Event()
+        self._last_stamp = None
+        self._last_stages: Optional[Dict[str, float]] = None
+        self._stopped = False
         self._thread: Optional[threading.Thread] = None
+        #: runs are numbered as they start; a request waits for the
+        #: first run that starts after it arrived
+        self._cond = threading.Condition()
+        self._started = 0
+        self._finished = 0
+        self._wanted = 0
+        self._results: Dict[int, Dict[str, object]] = {}
 
     # -- lifecycle --------------------------------------------------------
 
@@ -100,7 +141,9 @@ class Checkpointer:
         wedged write) — the caller's final save could then race a
         late os.replace; both writes are atomic, so the file is never
         torn, but the caller should log the condition."""
-        self._stop.set()
+        with self._cond:
+            self._stopped = True
+            self._cond.notify_all()
         if self._thread:
             self._thread.join(timeout=30)
             if self._thread.is_alive():
@@ -109,12 +152,110 @@ class Checkpointer:
         return True
 
     def _loop(self) -> None:
-        while not self._stop.wait(self.interval):
-            try:
-                self.checkpoint()
-            except Exception as e:   # keep ticking after a bad write
-                self.last_error = f"{type(e).__name__}: {e}"
-                logger.error("checkpoint failed: %s", self.last_error)
+        due = time.monotonic() + self.interval
+        while True:
+            with self._cond:
+                while not self._stopped \
+                        and self._wanted <= self._started:
+                    left = due - time.monotonic()
+                    if left <= 0:
+                        break
+                    self._cond.wait(left)
+                if self._stopped:
+                    return
+                self._started += 1
+                run = self._started
+            result = self._run_once()
+            with self._cond:
+                self._finished = run
+                self.last_result = result
+                # kept for the requests that wait for this run (they
+                # wake on the notify below), not for longer
+                self._results[run] = result
+                self._results.pop(run - 2, None)
+                self._cond.notify_all()
+            # a run, asked for or not, is the tick
+            due = time.monotonic() + self.interval
+
+    def _run_once(self) -> Dict[str, object]:
+        """One `checkpoint()` with its outcome as a document; keeps
+        ticking after a bad write."""
+        t0 = time.perf_counter()
+        try:
+            wrote = self.checkpoint()
+        except Exception as e:
+            self.last_error = f"{type(e).__name__}: {e}"
+            logger.error("checkpoint failed: %s", self.last_error)
+            _M_CHECKPOINTS.labels(result="failed").inc()
+            return {"error": self.last_error,
+                    "generation": self.checkpoints_written,
+                    "seconds": time.perf_counter() - t0}
+        self.last_error = None
+        _M_CHECKPOINTS.labels(
+            result="written" if wrote else "skipped").inc()
+        result: Dict[str, object] = {
+            "stamp": self._last_stamp, "skipped": not wrote,
+            "generation": self.checkpoints_written,
+            "seconds": time.perf_counter() - t0}
+        if wrote:
+            snap = getattr(self.db, "last_snapshot", None) or {}
+            result["rows"] = snap.get("rows")
+            result["bytes"] = snap.get("bytesWritten")
+            result["bytesIn"] = snap.get("bytesIn")
+            result["stagesMs"] = {
+                k.split(".", 1)[1]: round(v * 1e3, 4)
+                for k, v in (self._last_stages or {}).items()
+                if k.startswith("checkpoint.")}
+            _M_ROWS.inc(snap.get("rows") or 0)
+            _M_BYTES_IN.inc(snap.get("bytesIn") or 0)
+            _M_BYTES_WRITTEN.inc(snap.get("bytesWritten") or 0)
+        return result
+
+    def request(self, timeout: Optional[float] = None
+                ) -> Dict[str, object]:
+        """Ask for a snapshot now and wait for it: the result of the
+        first run that STARTS after this call (so its stamp is never
+        older than the request; a run already under way is waited
+        out first). Runs on the checkpointer's thread, one at a time.
+        Raises CheckpointUnavailable when that thread is not running,
+        TimeoutError after `timeout` seconds."""
+        deadline = None if timeout is None \
+            else time.monotonic() + timeout
+        with self._cond:
+            if self._thread is None or self._stopped:
+                raise CheckpointUnavailable(
+                    "the checkpointer is not running")
+            run = self._started + 1
+            self._wanted = max(self._wanted, run)
+            self._cond.notify_all()
+            while self._finished < run:
+                if self._stopped:
+                    raise CheckpointUnavailable(
+                        "the checkpointer stopped")
+                left = None if deadline is None \
+                    else deadline - time.monotonic()
+                if left is not None and left <= 0:
+                    raise TimeoutError(
+                        f"no snapshot within {timeout:g}s")
+                self._cond.wait(left)
+            return self._results.get(run, self.last_result)
+
+    def status(self) -> Dict[str, object]:
+        """The `checkpoint` block of /healthz."""
+        with self._cond:
+            running = self._started > self._finished
+        doc: Dict[str, object] = {
+            "intervalSeconds": self.interval,
+            "written": self.checkpoints_written,
+            "running": running,
+            "lastError": self.last_error,
+        }
+        last = self.last_result
+        if last is not None:
+            doc["last"] = {k: last.get(k) for k in
+                           ("stamp", "rows", "bytes", "seconds",
+                            "skipped", "error") if k in last}
+        return doc
 
     # -- one checkpoint ---------------------------------------------------
 
@@ -144,18 +285,22 @@ class Checkpointer:
         if fp == self._last_fingerprint:
             return False
         _fire_fault("checkpoint.save", path=self.path)
-        with _trace.background("checkpoint"):
+        with _trace.background("checkpoint") as sp:
             stamp = self.db.save(self.path, compress=self.compress)
-        self._last_fingerprint = fp
-        self.checkpoints_written += 1
-        self.last_checkpoint_time = time.time()
-        gc = getattr(self.db, "wal_gc", None)
-        if self._gc_stamp is not None and callable(gc):
-            try:
-                gc(self._gc_stamp)
-            except Exception as e:   # GC failure must not fail the tick
-                logger.error("WAL gc after checkpoint failed: %s", e)
-        self._gc_stamp = stamp
+            self._last_fingerprint = fp
+            self.checkpoints_written += 1
+            self.last_checkpoint_time = time.time()
+            self._last_stamp = stamp
+            gc = getattr(self.db, "wal_gc", None)
+            if self._gc_stamp is not None and callable(gc):
+                try:
+                    with checkpoint_stage("publish"):
+                        gc(self._gc_stamp)
+                except Exception as e:   # GC failure must not fail
+                    logger.error(        # the tick
+                        "WAL gc after checkpoint failed: %s", e)
+            self._gc_stamp = stamp
+        self._last_stages = sp.stages
         logger.v(1).info("checkpoint %d written to %s",
                          self.checkpoints_written, self.path)
         return True

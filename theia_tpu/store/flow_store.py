@@ -105,6 +105,24 @@ _M_SNAP_FALLBACK = _metrics.counter(
     "Snapshot loads that failed verification on the primary file and "
     "fell back to the previous good snapshot (<path>.prev)")
 
+_M_CKPT_STAGE = _metrics.histogram(
+    "theia_checkpoint_stage_seconds",
+    "Stages of writing one snapshot (FlowDatabase.save): latch_wait "
+    "until in-flight appends drain, hold = log stamp + table scan "
+    "with every append held, digest, write = compress + file, publish "
+    "= .prev rotation + replace + WAL GC",
+    labelnames=("stage",))
+#: the stages of one snapshot, in order; on the `bg.checkpoint` span
+#: (and the profiler's host lines) each is `checkpoint.<stage>`
+CHECKPOINT_STAGES = ("latch_wait", "hold", "digest", "write", "publish")
+_M_CKPT = {name: _M_CKPT_STAGE.labels(stage=name)
+           for name in CHECKPOINT_STAGES}
+
+
+def checkpoint_stage(name: str) -> "_trace.Stage":
+    return _trace.stage("checkpoint." + name, _M_CKPT[name])
+
+
 #: snapshot payload keys outside the table namespace
 WAL_LSNS_KEY = "__wal__/lsns"
 INTEGRITY_KEY = "__integrity__/crc32"
@@ -715,32 +733,41 @@ def payload_digest(payload: Mapping[str, np.ndarray]) -> int:
 
 def write_snapshot(path: str, payload: Dict[str, np.ndarray],
                    compress: bool = True,
-                   wal_lsns: Optional[Sequence[int]] = None) -> None:
+                   wal_lsns: Optional[Sequence[int]] = None
+                   ) -> Dict[str, int]:
     """Publish a snapshot: stamp schema version, WAL LSNs, and an
     integrity footer; write to a same-directory temp file; keep the
     previous good snapshot as `<path>.prev`; then atomically replace.
     A crash at ANY point leaves either the previous or the new
     complete snapshot reachable (possibly only as .prev — the loader
-    falls back)."""
+    falls back). Returns the bytes taken in (the payload's arrays)
+    and the bytes of the file written."""
     from .migration import CURRENT_SCHEMA_VERSION, force
-    force(payload, CURRENT_SCHEMA_VERSION)
-    if wal_lsns is not None:
-        payload[WAL_LSNS_KEY] = np.asarray(list(wal_lsns), np.int64)
-    payload[INTEGRITY_KEY] = np.asarray(payload_digest(payload),
-                                        np.int64)
+    with checkpoint_stage("digest"):
+        force(payload, CURRENT_SCHEMA_VERSION)
+        if wal_lsns is not None:
+            payload[WAL_LSNS_KEY] = np.asarray(list(wal_lsns), np.int64)
+        payload[INTEGRITY_KEY] = np.asarray(payload_digest(payload),
+                                            np.int64)
     writer = np.savez_compressed if compress else np.savez
     d = os.path.dirname(os.path.abspath(path)) or "."
     fd, tmp = tempfile.mkstemp(dir=d, prefix=".tmp-", suffix=".npz")
     os.close(fd)
     try:
-        writer(tmp, **payload)
-        if os.path.exists(path):
-            os.replace(path, path + ".prev")
-        os.replace(tmp, path)
+        with checkpoint_stage("write"):
+            writer(tmp, **payload)
+        written = os.path.getsize(tmp)
+        with checkpoint_stage("publish"):
+            if os.path.exists(path):
+                os.replace(path, path + ".prev")
+            os.replace(tmp, path)
     except BaseException:
         with contextlib.suppress(OSError):
             os.unlink(tmp)
         raise
+    return {"bytesIn": sum(np.asarray(a).nbytes
+                           for a in payload.values()),
+            "bytesWritten": written}
 
 
 def read_snapshot(path: str) -> Dict[str, np.ndarray]:
@@ -853,6 +880,8 @@ class FlowDatabase:
         self.ttl_seconds = ttl_seconds
         #: attached WriteAheadLog (None = snapshot-only durability)
         self._wal = None
+        #: rows / bytesIn / bytesWritten of the last `save`
+        self.last_snapshot: Optional[Dict[str, int]] = None
         #: per-log WAL stamps read from the loaded snapshot (empty =
         #: fresh store or pre-WAL snapshot); attach_wal replays above
         #: these
@@ -1101,6 +1130,12 @@ class FlowDatabase:
     def wal_stats(self) -> Optional[Dict[str, object]]:
         wal = self._wal
         return None if wal is None else wal.stats()
+
+    def wal_last_applied(self):
+        """(LSN, seconds waited for the snapshot latch) of the calling
+        thread's last journaled insert; None without a WAL."""
+        wal = self._wal
+        return None if wal is None else wal.last_applied()
 
     def wal_position(self) -> Optional[int]:
         """Last appended LSN (None when no WAL attached)."""
@@ -1420,27 +1455,38 @@ class FlowDatabase:
         parts_aware = (tables is None
                        and getattr(flows, "directory", None)
                        and hasattr(flows, "snapshot_parts_state"))
+        marks = _trace.StageMarks()
         if not parts_aware:
             if wal is not None and tables is None:
-                with wal.quiesce():
-                    stamp = wal.last_lsn
-                    payload = self._snapshot_payload(tables)
+                marks.mark("checkpoint.latch_wait",
+                           _M_CKPT["latch_wait"])
+                try:
+                    with wal.quiesce():
+                        marks.mark("checkpoint.hold", _M_CKPT["hold"])
+                        stamp = wal.last_lsn
+                        payload = self._snapshot_payload(tables)
+                        marks.end()
+                finally:
+                    marks.end()
             else:
                 stamp = None
                 payload = self._snapshot_payload(tables)
-            write_snapshot(
+            self._note_snapshot(payload, write_snapshot(
                 path, payload, compress=compress,
-                wal_lsns=[stamp] if stamp is not None else None)
+                wal_lsns=[stamp] if stamp is not None else None))
             return stamp
         # The ingest latch (writer side) excludes in-flight
         # insert_flows across BOTH legs (flows append + view apply);
         # the WAL quiesce additionally freezes result-table appends so
         # the stamp partitions every table's records exactly.
         with contextlib.ExitStack() as stack:
+            stack.callback(marks.end)
+            marks.mark("checkpoint.latch_wait", _M_CKPT["latch_wait"])
             if self._ingest_latch is not None:
                 stack.enter_context(self._ingest_latch.write())
             if wal is not None:
                 stack.enter_context(wal.quiesce())
+            marks.mark("checkpoint.hold", _M_CKPT["hold"])
             stamp = wal.last_lsn if wal is not None else None
             entries, payload = flows.snapshot_parts_state()
             for table in self.result_tables.values():
@@ -1463,14 +1509,24 @@ class FlowDatabase:
                 # skip this — their load rebuilds through the insert
                 # path
                 payload.update(rollups.snapshot_payload())
+            marks.end()
         gen = flows.publish_manifest(entries, stamp)
         payload["__parts__/generation"] = np.asarray(gen, np.int64)
         payload["__parts__/dir"] = np.asarray(
             os.path.abspath(flows.directory), dtype=object)
-        write_snapshot(path, payload, compress=compress,
-                       wal_lsns=[stamp] if stamp is not None else None)
+        self._note_snapshot(payload, write_snapshot(
+            path, payload, compress=compress,
+            wal_lsns=[stamp] if stamp is not None else None))
         flows.gc_part_files()
         return stamp
+
+    def _note_snapshot(self, payload: Dict[str, np.ndarray],
+                       written: Dict[str, int]) -> None:
+        """What the last `save` wrote, for the checkpointer's result:
+        the flow rows in the file and `write_snapshot`'s byte counts."""
+        rows = payload.get("flows/timeInserted")
+        self.last_snapshot = {
+            "rows": 0 if rows is None else int(len(rows)), **written}
 
     def _snapshot_payload(self, tables: Optional[Sequence[str]] = None
                           ) -> Dict[str, np.ndarray]:

@@ -148,6 +148,11 @@ _M_APPENDED = _metrics.counter(
 _M_FSYNC = _metrics.histogram(
     "theia_wal_fsync_seconds",
     "WAL fsync latency (the durability tax of the sync policy)")
+_M_LATCH_WAIT = _metrics.histogram(
+    "theia_ingest_latch_wait_seconds",
+    "Wait of one insert for the snapshot latch (wal.latch read side): "
+    "a checkpoint holds every append while it stamps the log and "
+    "scans the tables")
 _M_RECOVERED = _metrics.counter(
     "theia_wal_recovered_rows_total",
     "Rows re-applied from WAL records above the snapshot LSN at "
@@ -381,8 +386,7 @@ class _Latch:
         if self._witness:
             _lockdep.register_name(name)
 
-    @contextlib.contextmanager
-    def read(self):
+    def acquire_read(self) -> None:
         if self._witness:
             # order validation BEFORE blocking: a raise-mode
             # inversion must propagate with the latch untouched
@@ -399,15 +403,22 @@ class _Latch:
                 self, self.name, blocking=True,
                 wait=time.monotonic() - t0 if waited else 0.0,
                 contended=waited)
+
+    def release_read(self) -> None:
+        with self._cond:
+            self._readers -= 1
+            if self._readers == 0:
+                self._cond.notify_all()
+        if self._witness:
+            _lockdep.note_release(self, self.name)
+
+    @contextlib.contextmanager
+    def read(self):
+        self.acquire_read()
         try:
             yield
         finally:
-            with self._cond:
-                self._readers -= 1
-                if self._readers == 0:
-                    self._cond.notify_all()
-            if self._witness:
-                _lockdep.note_release(self, self.name)
+            self.release_read()
 
     @contextlib.contextmanager
     def write(self):
@@ -462,6 +473,9 @@ class WriteAheadLog:
         self._clock = clock
         self._io = named_lock("wal.io")
         self._latch = _Latch("wal.latch")
+        #: per thread: (LSN, latch wait seconds) of its last
+        #: `logged_apply`, for the request that ran it to report
+        self._applied = threading.local()
         self._file = None
         self._seg_path: Optional[str] = None
         self._seg_size = 0
@@ -591,10 +605,21 @@ class WriteAheadLog:
         them. `wire` (a received TBLK column section covering exactly
         these rows) is journaled verbatim instead of re-encoding the
         adopted batch."""
-        with self._latch.read():
-            self.append(table, adopted, wire=wire)
+        wait = _trace.stage("store.latch_wait", _M_LATCH_WAIT)
+        with wait:
+            self._latch.acquire_read()
+        try:
+            lsn = self.append(table, adopted, wire=wire)
             apply(adopted)
+        finally:
+            self._latch.release_read()
+        self._applied.last = (lsn, wait.seconds)
         self._policy_sync()
+
+    def last_applied(self) -> Optional[Tuple[int, float]]:
+        """(LSN, seconds waited for the snapshot latch) of the calling
+        thread's last `logged_apply`; None before its first."""
+        return getattr(self._applied, "last", None)
 
     def append(self, table: str, batch: ColumnarBatch,
                wire: Optional[memoryview] = None) -> int:
@@ -1126,6 +1151,11 @@ class WriteAheadLog:
             "policy": str(self.policy),
             "segments": len(segs),
             "bytes": size,
+            # the oldest record still on disk: a snapshot stamped at
+            # or above firstRetainedLsn - 1 plus the retained log
+            # covers every acknowledged row
+            "firstRetainedLsn": (segs[0][0] if segs
+                                 else self.last_lsn + 1),
             "lastLsn": self.last_lsn,
             "syncedLsn": self.synced_lsn,
             "lagRecords": self._dirty_records,
