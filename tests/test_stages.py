@@ -359,6 +359,63 @@ def test_tad_run_and_its_result_answer_are_timed_by_part():
                      stage=s)[1] >= 1
 
 
+def test_get_of_a_completed_job_is_timed_counted_and_makes_no_row(
+        monkeypatch):
+    """The served path: GET of a completed EWMA job by name. Each of
+    the answer's three phases is observed once, its bytes and rows are
+    counted, and no row dict is made on the way (to_rows may raise)."""
+    import urllib.request
+
+    from theia_tpu.manager import TheiaManagerServer
+    from theia_tpu.schema.columnar import ColumnarBatch
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=16, points_per_series=24, seed=5)))
+    srv = TheiaManagerServer(db, port=0, workers=1)
+    srv.start_background()
+
+    def get(path):
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}{path}", timeout=60) as r:
+            return r.status, r.headers["Content-Length"], r.read()
+
+    try:
+        rec = srv.controller.create("tad", {"jobType": "EWMA"})
+        assert srv.controller.wait_all(120)
+        assert rec.state == "COMPLETED", rec.status_dict()
+        n_rows = len(db.tadetector.scan())
+        assert n_rows
+        path = ("/apis/intelligence.theia.antrea.io/v1alpha1/"
+                "throughputanomalydetectors/" + rec.name)
+        phases = ("rows", "encode", "send")
+        n0 = {p: _hist("theia_job_result_seconds", kind="tad",
+                       phase=p)[1] for p in phases}
+        sent = metrics.REGISTRY.get(
+            "theia_job_result_bytes_total").labels(kind="tad")
+        carried = metrics.REGISTRY.get(
+            "theia_job_result_rows_total").labels(kind="tad")
+        bytes0, rows0 = sent.value(), carried.value()
+        status, length, body = get(path)
+        assert status == 200 and int(length) == len(body)
+        doc = json.loads(body)
+        assert doc["status"]["state"] == "COMPLETED"
+        assert len(doc["stats"]) == n_rows
+        for p in phases:
+            assert _hist("theia_job_result_seconds", kind="tad",
+                         phase=p)[1] == n0[p] + 1
+        assert sent.value() == bytes0 + len(body)
+        assert carried.value() == rows0 + n_rows
+        assert doc["stats"] == srv.controller.tad_stats(rec.name)
+
+        def no_rows(self, schema=None):
+            raise AssertionError("the GET path made row dicts")
+        monkeypatch.setattr(ColumnarBatch, "to_rows", no_rows)
+        assert get(path) == (200, length, body)
+        assert carried.value() == rows0 + 2 * n_rows
+    finally:
+        srv.shutdown()
+
+
 def test_run_tad_without_a_controller_still_times_stages():
     db = FlowDatabase()
     db.insert_flows(generate_flows(SynthConfig(

@@ -54,6 +54,7 @@ from ..obs import prom as _obs_prom
 from ..obs import trace as _obs_trace
 from .jobs import (
     JOB_RESULT_BYTES,
+    JOB_RESULT_ROWS,
     JOB_RESULT_SECONDS,
     KIND_DD,
     KIND_FPM,
@@ -170,8 +171,18 @@ def _fast_ack_bytes(doc: Dict[str, object]) -> Optional[bytes]:
     return b"".join(parts)
 
 
+def _carries_result_rows(record: JobRecord) -> bool:
+    """A completed job of a kind whose result is a table's rows (NPR
+    answers with its joined policy text instead)."""
+    return record.kind != KIND_NPR and record.state == STATE_COMPLETED
+
+
 def record_to_api(record: JobRecord, controller: JobController,
                   with_result: bool = False) -> Dict[str, object]:
+    """The job's API document. `with_result` attaches a completed
+    job's result: `stats` as one dict a row, its last key, for
+    in-process callers; the GET of a job by name sends the same bytes
+    without making a row (`_send_job_result`)."""
     doc: Dict[str, object] = {
         "kind": _KIND_NAMES[record.kind],
         "apiVersion": "intelligence.theia.antrea.io/v1alpha1",
@@ -179,13 +190,11 @@ def record_to_api(record: JobRecord, controller: JobController,
         "status": record.status_dict(),
     }
     doc.update(record.spec)
-    if with_result and record.state == STATE_COMPLETED:
-        if record.kind == KIND_NPR:
-            doc["status"]["recommendationOutcome"] = (  # type: ignore
-                controller.recommendation_outcome(record.name))
-        else:
-            doc["stats"] = controller.result_stats(record.kind,
-                                                   record.name)
+    if with_result and _carries_result_rows(record):
+        doc["stats"] = controller.result_stats(record.kind, record.name)
+    elif with_result and record.state == STATE_COMPLETED:
+        doc["status"]["recommendationOutcome"] = (  # type: ignore
+            controller.recommendation_outcome(record.name))
     return doc
 
 
@@ -462,17 +471,25 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
             return
         self._send_raw_json(raw)
 
-    def _send_job_result(self, doc, kind: str) -> None:
+    def _send_job_result(self, record: JobRecord) -> None:
         """The answer that carries a completed job's result rows, its
         encode and its socket write timed apart (the row scan is timed
-        where it happens, JobController._result_stats): with the
+        where it happens, JobController.result_columns): with the
         client's polling these are the whole of a job's turn-around
-        outside its run."""
+        outside its run. The bytes are json.dumps' of
+        record_to_api(with_result=True): the document without `stats`
+        encoded as ever, the rows joined from their columns and
+        spliced in as its last key."""
+        kind = record.kind
+        columns = self.controller.result_columns(kind, record.name)
         with _obs_trace.stage("job.result.encode",
                               JOB_RESULT_SECONDS.labels(
                                   kind=kind, phase="encode")):
-            raw = json.dumps(doc, default=str).encode()
+            head = json.dumps(record_to_api(record, self.controller),
+                              default=str)
+            raw = columns.json_bytes(head[:-1] + ', "stats": ', "}")
         JOB_RESULT_BYTES.labels(kind=kind).inc(len(raw))
+        JOB_RESULT_ROWS.labels(kind=kind).inc(columns.n_rows)
         with _obs_trace.stage("job.result.send",
                               JOB_RESULT_SECONDS.labels(
                                   kind=kind, phase="send")):
@@ -1036,12 +1053,14 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
             record = self.controller.get(parts[4])
             if record.kind != kind:
                 raise KeyError(parts[4])
-            doc = record_to_api(record, self.controller,
-                                with_result=True)
-            if "stats" in doc:
-                self._send_job_result(doc, kind)
+            if _carries_result_rows(record):
+                self._send_job_result(record)
             else:
-                self._send_json(doc)
+                # the state was read once, above: a job that completes
+                # meanwhile answers with its rows at the next poll
+                self._send_json(record_to_api(
+                    record, self.controller,
+                    with_result=kind == KIND_NPR))
         else:
             raise KeyError(self.path)
 

@@ -65,6 +65,7 @@ from ..utils.env import env_float, env_int
 from ..utils.faults import FaultError
 from ..utils.faults import fire as _fire_fault
 from ..analysis.lockdep import named_lock
+from .results import ResultColumns, select_job
 
 logger = get_logger("jobs")
 
@@ -82,16 +83,21 @@ _M_RETRIES = _obs_metrics.counter(
     "Transient job failures re-queued with backoff")
 # What a job's turn-around holds outside its run and that is the
 # program's: the answer that carries the result rows (manager/api.py
-# observes encode and send).
+# observes encode and send, and counts bytes and rows).
 JOB_RESULT_SECONDS = _obs_metrics.histogram(
     "theia_job_result_seconds",
     "One answer carrying a completed job's result rows, by phase: "
-    "rows (result table scan to row dicts), encode (json.dumps), "
-    "send (socket write)", labelnames=("kind", "phase"))
+    "rows (result table scan, the job's rows by id code, each "
+    "column's distinct values as strings), encode (the columns' JSON "
+    "fragments joined into the answer's bytes), send (socket write)",
+    labelnames=("kind", "phase"))
 JOB_RESULT_BYTES = _obs_metrics.counter(
     "theia_job_result_bytes_total",
     "Bytes of JSON sent in answers carrying job result rows",
     labelnames=("kind",))
+JOB_RESULT_ROWS = _obs_metrics.counter(
+    "theia_job_result_rows_total",
+    "Result rows carried in those answers", labelnames=("kind",))
 _M_DEADLINE_KILLS = _obs_metrics.counter(
     "theia_job_deadline_kills_total",
     "Runner children killed at deadlineSeconds")
@@ -341,38 +347,32 @@ class JobController:
         """Joined policy YAML for a COMPLETED NPR job (reference
         getRecommendationResult joins rows with '---\\n', rest.go:213)."""
         job_id = job_id_from_name(KIND_NPR, name)
-        data = self.db.recommendations.scan()
-        if not len(data):
-            return ""
-        rows = data.filter(data.strings("id") == job_id)
+        rows = select_job(self.db.recommendations.scan(), job_id)
         return "---\n".join(rows.strings("policy"))
 
-    def _result_stats(self, kind: str, table,
-                      name: str) -> List[Dict[str, str]]:
+    def result_columns(self, kind: str, name: str) -> ResultColumns:
         """Result rows for a job as string-typed stat entries
-        (reference getTADetectorResult, rest.go:249-310)."""
+        (reference getTADetectorResult, rest.go:249-310), by column:
+        the GET of a job joins its answer from them, the `*_stats`
+        lists below are their `rows()`."""
+        table = self.db.result_tables[_RESULT_TABLE[kind]]
         job_id = job_id_from_name(kind, name)
         with _obs_trace.stage("job.result.rows",
                               JOB_RESULT_SECONDS.labels(
                                   kind=kind, phase="rows")):
-            data = table.scan()
-            if not len(data):
-                return []
-            rows = data.filter(data.strings("id") == job_id)
-            return [{k: str(v) for k, v in row.items()}
-                    for row in rows.to_rows()]
+            return ResultColumns(select_job(table.scan(), job_id),
+                                 table.schema)
 
     def tad_stats(self, name: str) -> List[Dict[str, str]]:
-        return self._result_stats(KIND_TAD, self.db.tadetector, name)
+        return self.result_columns(KIND_TAD, name).rows()
 
     def drop_detection_stats(self, name: str) -> List[Dict[str, str]]:
-        return self._result_stats(KIND_DD, self.db.dropdetection, name)
+        return self.result_columns(KIND_DD, name).rows()
 
     def result_stats(self, kind: str, name: str) -> List[Dict[str, str]]:
         """Generic result rows for any job kind (the per-kind helpers
         above remain for the established call sites)."""
-        return self._result_stats(
-            kind, self.db.result_tables[_RESULT_TABLE[kind]], name)
+        return self.result_columns(kind, name).rows()
 
     # -- workers ---------------------------------------------------------
 
@@ -506,10 +506,7 @@ class JobController:
         result table directly (result_stats stringifies every value;
         alerts carry native types like the other alert kinds)."""
         table = self.db.result_tables[_RESULT_TABLE[KIND_SPATIAL]]
-        data = table.scan()
-        if not len(data):
-            return
-        rows = data.filter(data.strings("id") == record.job_id)
+        rows = select_job(table.scan(), record.job_id)
         # Cap the push: the alert ring is a bounded shared surface
         # (ingest.MAX_ALERTS slots) — one large batch result must not
         # evict every live streaming/heavy-hitter alert. Keep the
@@ -842,11 +839,9 @@ class JobController:
         table_name = _RESULT_TABLE[record.kind]
         src = out.result_tables[table_name]
         dst = self.db.result_tables[table_name]
-        data = src.scan()
-        if len(data):
-            rows = data.filter(data.strings("id") == record.job_id)
-            if len(rows):
-                dst.insert(rows)
+        rows = select_job(src.scan(), record.job_id)
+        if len(rows):
+            dst.insert(rows)
 
     def health(self) -> Dict[str, object]:
         """Operator health view (served by GET /healthz): queue depth
