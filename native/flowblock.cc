@@ -19,9 +19,9 @@
 //                                out_codes [n_string][max_rows];
 //                                returns rows decoded, or -1-row_index
 //                                on a malformed row
-//   fb_decode_block(h, buf, nbytes, max_rows, out_ints, out_codes)
+//   fb_decode_block2(h, buf, nbytes, max_rows, widths, out_cols)
 //                                decode one binary columnar block (the
-//                                "TFB1" format below — the analogue of
+//                                "TFB2" format below — the analogue of
 //                                ClickHouse's column-major native
 //                                protocol): header, per-string-column
 //                                dictionary delta, then raw column
@@ -239,92 +239,29 @@ int64_t fb_decode(void* h, const char* buf, int64_t nbytes,
   return row;
 }
 
-// Binary columnar block ("TFB1", little-endian):
-//   "TFB1" | n_rows:i64 | n_cols:i32
+// Binary columnar block ("TFB2", little-endian):
+//   "TFB2" | n_rows:i64 | n_cols:i32
 //   per string column (schema order): base:i32 | count:i32 |
 //       count x (len:i32 | bytes)     -- dictionary delta; `base` must
 //                                        equal the decoder's current
 //                                        dictionary size (codes are a
 //                                        shared, append-only sequence)
-//   per column (schema order): raw plane —
-//       numeric: n_rows x 8 bytes (int64 / f64 through the int plane)
-//       string:  n_rows x 4 bytes (int32 codes)
+//   per column (schema order): raw plane at the column's NATIVE width
+//       (widths[c] bytes per element: 1/2/4/8 for numerics, always 4
+//       for string codes)
+// Planes land directly in per-column output buffers (out_cols[c],
+// allocated by the caller at the column's final dtype) — no widening
+// on the wire and no re-narrowing pass after decode. String-code
+// validation runs over the copied (aligned) output plane so the
+// compiler can vectorize the min/max scan instead of per-row
+// unaligned loads.
 // Error codes: -1 malformed, -2 dictionary desync (delta base !=
 // dictionary size), -3 outputs too small, -4 string code out of
 // dictionary range, -5 delta repeats an existing or intra-delta entry.
-// The block is fully validated BEFORE any dictionary mutation or
-// output write, so a bad block leaves the decoder exactly as it was
-// (no poisoned state).
-int64_t fb_decode_block(void* h, const char* buf, int64_t nbytes,
-                        int64_t max_rows, int64_t* out_ints,
-                        int32_t* out_codes) {
-  auto* d = static_cast<Decoder*>(h);
-  const char* p = buf;
-  const char* end = buf + nbytes;
-  auto need = [&](int64_t n) { return end - p >= n; };
-
-  if (!need(4) || memcmp(p, "TFB1", 4) != 0) return -1;
-  p += 4;
-  int64_t n_rows;
-  int32_t n_cols;
-  if (!need(12)) return -1;
-  memcpy(&n_rows, p, 8); p += 8;
-  memcpy(&n_cols, p, 4); p += 4;
-  if (n_rows < 0 || n_cols != static_cast<int32_t>(d->kinds.size()))
-    return -1;
-  if (n_rows > max_rows) return -3;
-
-  // -- validation pass: walk the whole block without mutating anything.
-  const char* delta_start = p;
-  std::vector<int32_t> new_sizes(d->dicts.size());
-  if (int32_t err = validate_deltas(d, &p, end, &new_sizes)) return err;
-  const char* planes_start = p;
-  for (int32_t c = 0; c < n_cols; ++c) {
-    const int64_t width = (d->kinds[c] == kString) ? 4 : 8;
-    if (!need(n_rows * width)) return -1;
-    if (d->kinds[c] == kString) {
-      // every code must resolve against the post-delta dictionary
-      const int32_t limit = new_sizes[d->slot[c]];
-      for (int64_t r = 0; r < n_rows; ++r) {
-        int32_t code;
-        memcpy(&code, p + r * 4, 4);
-        if (code < 0 || code >= limit) return -4;
-      }
-    }
-    p += n_rows * width;
-  }
-
-  // -- commit pass: append dictionary deltas, bulk-copy planes.
-  p = delta_start;
-  commit_deltas(d, &p);
-  p = planes_start;
-  for (int32_t c = 0; c < n_cols; ++c) {
-    const int32_t slot = d->slot[c];
-    if (d->kinds[c] == kString) {
-      memcpy(&out_codes[static_cast<int64_t>(slot) * max_rows], p,
-             static_cast<size_t>(n_rows * 4));
-      p += n_rows * 4;
-    } else {
-      memcpy(&out_ints[static_cast<int64_t>(slot) * max_rows], p,
-             static_cast<size_t>(n_rows * 8));
-      p += n_rows * 8;
-    }
-  }
-  return n_rows;
-}
-
-// Binary columnar block v2 ("TFB2", little-endian) — the production
-// wire format. Identical header + dictionary-delta layout to TFB1, but
-// column planes carry each column's NATIVE width (widths[c] bytes per
-// element: 1/2/4/8 for numerics, always 4 for string codes) and land
-// directly in per-column output buffers (out_cols[c], allocated by the
-// caller at the column's final dtype) — no 8-byte widening on the wire
-// and no re-narrowing pass after decode. String-code validation runs
-// over the copied (aligned) output plane so the compiler can vectorize
-// the min/max scan instead of per-row unaligned loads.
-// Error codes match fb_decode_block. Dictionary state is only mutated
-// after every check passes; output buffers may hold partial data on
-// error (callers discard them on raise).
+// Dictionary state is only mutated after every check passes, so a bad
+// block leaves the decoder exactly as it was (no poisoned state);
+// output buffers may hold partial data on error (callers discard them
+// on raise).
 int64_t fb_decode_block2(void* h, const char* buf, int64_t nbytes,
                          int64_t max_rows, const int32_t* widths,
                          void** out_cols) {

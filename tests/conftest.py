@@ -12,7 +12,7 @@ import os
 # before theia_tpu imports — lock wrapping is decided at creation):
 # every test run doubles as a deadlock hunt. A session-scoped fixture
 # below asserts zero observed lock-order inversions at teardown.
-# THEIA_LOCKDEP=0 in the environment opts a run out (bench A/B).
+# THEIA_LOCKDEP=0 in the environment opts a run out.
 os.environ.setdefault("THEIA_LOCKDEP", "1")
 
 # Force CPU even if the ambient environment points JAX at an accelerator:

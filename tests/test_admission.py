@@ -822,12 +822,16 @@ def test_streaming_detector_injectable_clock():
     assert all(a["latency_s"] == 0.0 for a in alerts)
 
 
-def test_admission_disabled_env(monkeypatch):
+def test_admission_cannot_be_switched_off(monkeypatch):
+    """The retired THEIA_ADMISSION_DISABLED switch does nothing: the
+    manager keeps its controller and a block is admitted and acked."""
     monkeypatch.setenv("THEIA_ADMISSION_DISABLED", "1")
     im = IngestManager(FlowDatabase(), n_shards=1)
     try:
-        assert im.admission is None
+        assert isinstance(im.admission, AdmissionController)
         payload, n = _block()
-        assert im.ingest(payload)["rows"] == n   # plain path intact
+        before = im.admission.admitted
+        assert im.ingest(payload)["rows"] == n
+        assert im.admission.admitted == before + 1
     finally:
         im.close()

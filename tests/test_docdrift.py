@@ -148,14 +148,49 @@ def test_all_theia_env_knobs_in_sync():
         documented_env_knobs,
         extract_env_reads,
     )
-    referenced = set(extract_env_reads(
-        str(PACKAGE_DIR), extra=[str(REPO / "bench.py")]))
+    referenced = set(extract_env_reads(str(PACKAGE_DIR)))
     documented = set(documented_env_knobs(str(REPO / "docs")))
     undocumented = sorted(referenced - documented)
     stale = sorted(documented - referenced)
     assert not undocumented, (
-        f"THEIA_* env vars read by code (theia_tpu/ + bench.py) with "
+        f"THEIA_* env vars read by code (theia_tpu/) with "
         f"no knob-table row in any docs/*.md: {undocumented}")
     assert not stale, (
         f"docs/*.md knob tables document THEIA_* vars nothing reads "
         f"(renamed or removed?): {stale}")
+
+
+#: the count of ``THEIA_*`` names the package reads; it may fall,
+#: never rise (PR 31 left 70)
+MAX_ENV_KNOBS = 70
+
+
+def test_env_knob_count_does_not_grow():
+    from theia_tpu.analysis.lint import extract_env_reads
+    reads = extract_env_reads(str(PACKAGE_DIR))
+    assert len(reads) <= MAX_ENV_KNOBS, (
+        f"{len(reads)} THEIA_* names are read, {MAX_ENV_KNOBS} were: a "
+        f"new option needs two callers that exist (a benchmark "
+        f"configuration, a deploy manifest, chip_smoke.py: not tests) "
+        f"with different values, or it is a constant. Lower "
+        f"MAX_ENV_KNOBS when the count falls.")
+
+
+#: a backticked path under one of the repo's directories, or a
+#: root-level ``*.py``, optionally with a ``:line`` or ``::test`` tail
+_DOC_PATH = re.compile(
+    r"`((?:theia_tpu|tests|native|deploy|benchmarks)/[A-Za-z0-9_./-]+"
+    r"|[A-Za-z0-9_]+\.py)(?::[^`]*)?`")
+
+
+@pytest.mark.parametrize(
+    "doc", [REPO / "README.md", *sorted((REPO / "docs").glob("*.md"))],
+    ids=lambda p: p.name)
+def test_doc_paths_exist(doc):
+    """Every file a doc names in backticks is there: a doc cannot
+    point at a module that was deleted or moved."""
+    missing = sorted({m for m in _DOC_PATH.findall(doc.read_text())
+                      if "*" not in m and "<" not in m
+                      and not (REPO / m).exists()})
+    assert not missing, f"{doc.name} names files that do not exist: " \
+                        f"{missing}"

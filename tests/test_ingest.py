@@ -309,49 +309,33 @@ def test_block_delta_with_intra_delta_duplicate_rejected(block_wire):
         assert dec.dicts["sourceIP"].lookup("brand-new") is None
 
 
-def test_block_v1_backward_compat(block_wire):
-    """TFB1 blocks (8-byte-widened numeric planes) still decode —
-    mixed-version producers during a rolling upgrade."""
-    from theia_tpu.ingest.native import BLOCK_MAGIC_V1
-    from theia_tpu.schema import FLOW_SCHEMA as _S
-    batch, enc, _ = block_wire
-
-    # Craft a v1 block from a fresh encoder (full dictionary delta).
-    enc1 = BlockEncoder()
-    codes = {}
-    parts = [BLOCK_MAGIC_V1, np.int64(len(batch)).tobytes(),
-             np.int32(len(_S)).tobytes()]
-    for col in _S:
-        if not col.is_string:
-            continue
-        d = enc1.dicts[col.name]
-        codes[col.name] = d.encode(
-            list(batch.strings(col.name))).astype(np.int32)
-        base, delta = 1, d.entries_since(1)
-        parts.append(np.asarray([base, len(delta)], np.int32).tobytes())
-        for s in delta:
-            raw = s.encode()
-            parts.append(np.int32(len(raw)).tobytes())
-            parts.append(raw)
-    for col in _S:
-        if col.is_string:
-            parts.append(codes[col.name].tobytes())
-        else:
-            arr = np.asarray(batch[col.name])
-            if arr.dtype == np.float64:
-                parts.append(arr.tobytes())
-            else:
-                parts.append(arr.astype(np.int64).tobytes())
-    payload_v1 = b"".join(parts)
+def test_retired_tfb1_payload_is_refused(block_wire):
+    """The TFB1 block format (8-byte-widened planes) is retired: a
+    payload with its magic is an undecodable payload (ValueError, the
+    API's 400), mints nothing and leaves no stream slot behind."""
+    from theia_tpu.manager.ingest import IngestManager
+    _, _, payload = block_wire
+    tfb1 = b"TFB1" + payload[4:]
+    im = IngestManager(FlowDatabase(), n_shards=1)
+    try:
+        with pytest.raises(ValueError):
+            im.ingest(tfb1, stream="old-producer")
+        assert "old-producer" not in im._streams
+        # a stream with state: the refusal resets it like any other
+        # undecodable payload, and its dictionaries took nothing in
+        assert im.ingest(payload, stream="s")["rows"] > 0
+        dicts = im._streams["s"].decoder.dicts
+        sizes = {name: len(d) for name, d in dicts.items()}
+        with pytest.raises(ValueError):
+            im.ingest(tfb1, stream="s")
+        assert {name: len(d) for name, d in dicts.items()} == sizes
+        assert "s" not in im._streams
+        assert len(im.db.flows) == len(block_wire[0])
+    finally:
+        im.close()
 
     for force_python in (False, True):
         if not force_python and not native_available():
             continue
-        dec = TsvDecoder(force_python=force_python)
-        out = dec.decode_block(payload_v1)
-        assert len(out) == len(batch)
-        np.testing.assert_array_equal(out.strings("sourceIP"),
-                                      batch.strings("sourceIP"))
-        np.testing.assert_array_equal(
-            np.asarray(out["throughput"]),
-            np.asarray(batch["throughput"]))
+        with pytest.raises(ValueError, match="not a flow block"):
+            TsvDecoder(force_python=force_python).decode_block(tfb1)

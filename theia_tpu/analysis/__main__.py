@@ -29,8 +29,7 @@ def run_all(root: str):
     pkg = os.path.join(root, "theia_tpu")
     lg = LockGraph(pkg)
     findings = lg.run()
-    findings.extend(Lint(pkg, os.path.join(root, "docs"),
-                         extra=[os.path.join(root, "bench.py")]).run())
+    findings.extend(Lint(pkg, os.path.join(root, "docs")).run())
     return findings, lg
 
 

@@ -29,17 +29,15 @@ from __future__ import annotations
 import ast
 import os
 import re
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from .base import Finding
 
 #: docs knob-table rows: `| `THEIA_FOO` | default | meaning |`
 _ENV_ROW = re.compile(r"^\|\s*`(THEIA_[A-Z0-9_]+)`", re.MULTILINE)
 
-def _iter_py(package_dir: str, extra: Sequence[str] = ()
-             ) -> List[Tuple[str, str]]:
-    """(path, repo-relative) for every module in the package plus any
-    ``extra`` files (bench.py reads knobs too)."""
+def _iter_py(package_dir: str) -> List[Tuple[str, str]]:
+    """(path, repo-relative) for every module in the package."""
     root = os.path.dirname(os.path.abspath(package_dir))
     out = []
     for dirpath, _d, filenames in sorted(os.walk(package_dir)):
@@ -47,10 +45,6 @@ def _iter_py(package_dir: str, extra: Sequence[str] = ()
             if fn.endswith(".py"):
                 path = os.path.join(dirpath, fn)
                 out.append((path, os.path.relpath(path, root)))
-    for path in extra:
-        if os.path.exists(path):
-            out.append((path, os.path.relpath(
-                path, root)))
     return out
 
 
@@ -77,8 +71,7 @@ def _docstring_linenos(tree: ast.AST) -> Set[int]:
     return out
 
 
-def extract_env_reads(package_dir: str, extra: Sequence[str] = ()
-                      ) -> Dict[str, List[str]]:
+def extract_env_reads(package_dir: str) -> Dict[str, List[str]]:
     """Every ``THEIA_*`` name the code READS from the environment ->
     [file:line sites]. Two tiers, merged: direct reads (env access
     calls with a literal name) and indirect references (a THEIA_*
@@ -92,7 +85,7 @@ def extract_env_reads(package_dir: str, extra: Sequence[str] = ()
         if name.startswith("THEIA_"):
             reads.setdefault(name, []).append(f"{rel}:{lineno}")
 
-    for path, rel in _iter_py(package_dir, extra):
+    for path, rel in _iter_py(package_dir):
         with open(path, "r", encoding="utf-8") as f:
             try:
                 tree = ast.parse(f.read(), filename=path)
@@ -163,11 +156,9 @@ def extract_fired_sites(package_dir: str
 # -- the pass ------------------------------------------------------------
 
 class Lint:
-    def __init__(self, package_dir: str, docs_dir: str,
-                 extra: Sequence[str] = ()) -> None:
+    def __init__(self, package_dir: str, docs_dir: str) -> None:
         self.package_dir = package_dir
         self.docs_dir = docs_dir
-        self.extra = list(extra)
 
     def run(self) -> List[Finding]:
         findings: List[Finding] = []
@@ -177,7 +168,7 @@ class Lint:
         return findings
 
     def _check_env(self) -> List[Finding]:
-        reads = extract_env_reads(self.package_dir, self.extra)
+        reads = extract_env_reads(self.package_dir)
         documented = documented_env_knobs(self.docs_dir)
         findings = []
         for name in sorted(reads):

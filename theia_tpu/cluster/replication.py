@@ -51,7 +51,7 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..store.wal import WalShipGap
 from ..utils.backoff import capped_backoff
-from ..utils.env import env_float, env_int
+from ..utils.env import env_float
 from ..utils.logging import get_logger
 from .transport import PeerUnreachable
 from ..analysis.lockdep import named_condition, named_lock
@@ -60,6 +60,12 @@ logger = get_logger("cluster")
 
 #: THEIA_REPL_ACKS values, least to most durable
 ACK_POLICIES = ("leader", "quorum", "all")
+
+#: most raw frame bytes one batched ship POST carries: every frame
+#: pending when the shipper wakes rides ONE request (one connection-
+#: pool roundtrip, one follower fsync), which is what turns concurrent
+#: producers into larger ship batches instead of more roundtrips
+SHIP_BATCH_BYTES = 256 << 10
 
 #: resync stream envelope: magic, version, crc algo, reserved,
 #: header-json length
@@ -209,20 +215,8 @@ class ReplicationLeader:
         self.ack_timeout = (env_float("THEIA_REPL_ACK_TIMEOUT", 10.0)
                             if ack_timeout is None
                             else float(ack_timeout))
-        if ship_bytes is None:
-            # frames ship in batched POSTs up to this budget: every
-            # frame pending when the shipper wakes rides ONE request
-            # (one connection-pool roundtrip, one follower fsync),
-            # which is what turns concurrent producers into larger
-            # ship batches instead of more roundtrips. The old
-            # THEIA_REPL_SHIP_BYTES spelling is honored for
-            # deployments that pinned it.
-            legacy = os.environ.get("THEIA_REPL_SHIP_BYTES")
-            self.ship_bytes = (
-                int(legacy) if legacy
-                else env_int("THEIA_REPL_BATCH_BYTES", 256 << 10))
-        else:
-            self.ship_bytes = int(ship_bytes)
+        self.ship_bytes = (SHIP_BATCH_BYTES if ship_bytes is None
+                           else int(ship_bytes))
         self.idle_wait = idle_wait
         self.dedup_dump = dedup_dump
         self._clock = clock

@@ -7,7 +7,8 @@ documented at test/e2e/flowvisibility_test.go:46-90): pod-to-pod /
 pod-to-service / pod-to-external connections with per-connection throughput
 time series, plus injected anomaly spikes so the detectors have ground truth.
 
-Every benchmark and most tests sit on top of this module.
+The manager's `--synth`, `theia ingest` and most tests sit on top of this
+module (`benchmarks/` has its own generator).
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ unavailability with **503**; a producer that times out or gets shed
 must RETRY THE SAME BATCH — and the retry must not double-insert if
 the first attempt actually landed (ack lost on the wire, manager
 killed after the WAL append). This client implements that contract so
-every producer (the `theia ingest` CLI, bench.py's overload legs,
+every producer (the `theia ingest` CLI, which `chip_smoke.py` drives,
 operator scripts) gets it right once:
 
   * every batch is stamped `?stream=<id>&seq=<n>` — the manager's
@@ -86,7 +86,7 @@ def default_ingest_format() -> str:
 
 def make_block_encoder(fmt: Optional[str] = None, schema=None,
                        dicts=None):
-    """The one producer-side encoder factory (CLI, bench, tests):
+    """The one producer-side encoder factory (CLI, tests):
     returns a `TblkEncoder` or `BlockEncoder` per `fmt` (default:
     `default_ingest_format()`), both exposing `encode(batch) ->
     bytes`."""
@@ -133,7 +133,7 @@ class IngestClient:
                      if ca_cert else None)
         self.seq = 0
         self._encoder = None   # lazy, built by send_batch()
-        # producer-side ledger (the bench/CLI summary surface)
+        # producer-side ledger (the CLI's summary surface)
         self.rows_acked = 0
         self.batches_acked = 0
         self.duplicates = 0
@@ -178,7 +178,7 @@ class IngestClient:
         # a router forward running inside a sampled trace context
         # stamps the context on the wire, so the owner node's spans
         # join the originating trace; producers outside any trace (the
-        # CLI, the bench) add nothing — the wire is unchanged
+        # CLI) add nothing — the wire is unchanged
         tp = _trace.traceparent()
         if tp:
             h["traceparent"] = tp

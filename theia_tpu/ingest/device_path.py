@@ -5,12 +5,11 @@ host↔device transfer.
 The sharded detector engine (manager/ingest.py) scores each request's
 batch shard by shard under shard locks — per batch it pays N_shards ×
 (2 dispatches + 2 fetches) and allocates fresh tile/feature arrays
-every time (the transfer leg measured allocation-bound at 0.49 GB/s).
-This engine replaces that hot loop with a pipeline:
+every time. This engine replaces that hot loop with a pipeline:
 
   1. **Coalescing.** Score requests from all ingest shards land in a
      bounded queue; the scorer thread drains whatever is waiting (up
-     to THEIA_FUSED_RING_ROWS rows) and gathers the key/value columns
+     to MAX_STEP_ROWS rows) and gathers the key/value columns
      of every pending block *directly from the decode output* into
      reused staging buffers — no per-shard ColumnarBatch copies (the
      sharded path slices all ~52 columns per shard; this path touches
@@ -65,7 +64,6 @@ from ..analytics.streaming import (
 from ..obs import metrics as _metrics
 from ..ops import fused_detector as _ops
 from ..utils import get_logger
-from ..utils.env import env_float, env_int
 
 logger = get_logger("device_path")
 
@@ -100,6 +98,14 @@ _EXTRA_COLUMNS = ("flowEndSeconds", "octetDeltaCount",
 #: imports this module, not the other way round) — only the newest
 #: survive the ring, so only those are worth decoding
 _MAX_DESCRIBED_ALERTS = 1000
+
+#: score requests the bounded pipeline queue holds (backpressure)
+QUEUE_CAPACITY = 8
+#: most rows coalesced into one fused step (bounds the staging ring;
+#: a bigger single block still scores alone)
+MAX_STEP_ROWS = 131072
+#: seconds a request waits for its fused step (queue put + result)
+STEP_TIMEOUT = 120.0
 
 
 class _StagingPool:
@@ -196,10 +202,8 @@ class FusedDetectorEngine:
     score path, scored through the coalescing fused pipeline."""
 
     def __init__(self, shards: Sequence, shard_totals: np.ndarray,
-                 on_scored: Optional[Callable[[int, int], None]] = None,
-                 queue_capacity: Optional[int] = None,
-                 max_step_rows: Optional[int] = None,
-                 step_timeout: Optional[float] = None) -> None:
+                 on_scored: Optional[Callable[[int, int], None]] = None
+                 ) -> None:
         if not shards:
             raise ValueError("fused engine needs at least one shard")
         alphas = {s.streaming.alpha for s in shards}
@@ -216,15 +220,9 @@ class FusedDetectorEngine:
         self.clock = self.shards[0].streaming.clock
         self._totals = shard_totals
         self._on_scored = on_scored
-        self.queue_capacity = (queue_capacity
-                               or env_int("THEIA_FUSED_QUEUE", 8))
-        self.max_step_rows = (max_step_rows
-                              or env_int("THEIA_FUSED_RING_ROWS",
-                                         131072))
-        self.step_timeout = (step_timeout
-                             or env_float("THEIA_FUSED_STEP_TIMEOUT",
-                                          120.0))
-        self._queue: _queue.Queue = _queue.Queue(self.queue_capacity)
+        #: an attribute so that a test can lower it on one engine
+        self.max_step_rows = MAX_STEP_ROWS
+        self._queue: _queue.Queue = _queue.Queue(QUEUE_CAPACITY)
         self._staging = _StagingPool()
         self._use_pallas, self._interpret = _ops.pallas_mode()
         self.steps = 0
@@ -244,7 +242,7 @@ class FusedDetectorEngine:
         """Operator doc for /healthz ingest.engine and `theia top`."""
         return {
             "queueDepth": self.queue_depth(),
-            "queueCapacity": self.queue_capacity,
+            "queueCapacity": QUEUE_CAPACITY,
             "maxStepRows": self.max_step_rows,
             "steps": self.steps,
             "coalescedBlocks": self.coalesced_blocks,
@@ -274,14 +272,14 @@ class FusedDetectorEngine:
                                      else idx)
         item = _ScoreItem(scored, shard_rows, self.clock())
         try:
-            self._queue.put(item, timeout=self.step_timeout)
+            self._queue.put(item, timeout=STEP_TIMEOUT)
         except _queue.Full:
             raise RuntimeError(
                 f"fused scoring queue stalled (capacity "
-                f"{self.queue_capacity}, no step completed in "
-                f"{self.step_timeout:.0f}s)")
+                f"{QUEUE_CAPACITY}, no step completed in "
+                f"{STEP_TIMEOUT:.0f}s)")
         _M_QDEPTH.set(self._queue.qsize())
-        deadline = time.monotonic() + self.step_timeout
+        deadline = time.monotonic() + STEP_TIMEOUT
         while True:
             try:
                 # short poll instead of one long wait: an item that
@@ -297,7 +295,7 @@ class FusedDetectorEngine:
                 if time.monotonic() >= deadline:
                     raise RuntimeError(
                         f"fused scoring step not resolved within "
-                        f"{self.step_timeout:.0f}s")
+                        f"{STEP_TIMEOUT:.0f}s")
 
     def close(self, timeout: float = 10.0) -> None:
         """Stop the scorer (idempotent): queued work is still scored,

@@ -126,8 +126,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
         ctypes.c_int64, ctypes.POINTER(ctypes.c_int64),
         ctypes.POINTER(ctypes.c_int32)]
-    lib.fb_decode_block.restype = ctypes.c_int64
-    lib.fb_decode_block.argtypes = lib.fb_decode.argtypes
     lib.fb_decode_block2.restype = ctypes.c_int64
     lib.fb_decode_block2.argtypes = [
         ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
@@ -316,41 +314,19 @@ class TsvDecoder:
         how the reference's FlowAggregator actually inserts
         (clickhouse-go `tcp://…:9000`, pkg/util/clickhouse/clickhouse.go:125).
         """
-        if len(payload) < 16 or payload[:4] not in (BLOCK_MAGIC,
-                                                    BLOCK_MAGIC_V1):
+        if len(payload) < 16 or payload[:4] != BLOCK_MAGIC:
             raise ValueError("not a flow block payload")
-        v2 = payload[:4] == BLOCK_MAGIC
         n_rows = int(np.frombuffer(payload, np.int64, 1, 4)[0])
         # Output allocation is sized from the header, so sanity-bound it
         # against what the payload could possibly carry before trusting
         # a (possibly corrupt/hostile) row count.
-        row_bytes = sum(self._col_width) if v2 else (
-            8 * len(self._numeric_cols) + 4 * len(self._string_cols))
-        if n_rows < 0 or n_rows * row_bytes > len(payload):
+        if n_rows < 0 or n_rows * sum(self._col_width) > len(payload):
             raise ValueError(
                 f"flow block claims {n_rows} rows but carries only "
                 f"{len(payload)} bytes")
-        if self._handle is not None and v2:
-            return self._decode_block2_native(payload, n_rows)
         if self._handle is not None:
-            self._push_python_dicts()
-            ints = np.empty((len(self._numeric_cols), max(n_rows, 1)),
-                            np.int64)
-            codes = np.empty((len(self._string_cols), max(n_rows, 1)),
-                             np.int32)
-            n = self._lib.fb_decode_block(
-                self._handle, payload, len(payload), max(n_rows, 1),
-                ints.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-                codes.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
-            # The native decoder validates the whole block before
-            # mutating any state, so every error leaves the decoder
-            # (and the shared dictionaries) untouched.
-            if n < 0:
-                raise ValueError(self._BLOCK_ERRORS.get(
-                    n, f"malformed flow block ({n})"))
-            self._sync_dicts()
-            return self._planes_to_batch(ints, codes, int(n))
-        return self._decode_block_python(payload, n_rows, v2)
+            return self._decode_block2_native(payload, n_rows)
+        return self._decode_block_python(payload, n_rows)
 
     _BLOCK_ERRORS = {
         -2: "dictionary desync: block's delta base does not match the "
@@ -384,6 +360,9 @@ class TsvDecoder:
         n = self._lib.fb_decode_block2(
             self._handle, payload, len(payload), max(n_rows, 1),
             self._widths_arr, out)
+        # The native decoder validates the whole block before
+        # mutating any state, so every error leaves the decoder
+        # (and the shared dictionaries) untouched.
         if n < 0:
             raise ValueError(self._BLOCK_ERRORS.get(
                 n, f"malformed flow block ({n})"))
@@ -392,8 +371,8 @@ class TsvDecoder:
             {col.name: arr[:n] for col, arr in zip(self.schema, arrays)},
             self.dicts)
 
-    def _decode_block_python(self, payload: bytes, n_rows: int,
-                             v2: bool = True) -> ColumnarBatch:
+    def _decode_block_python(self, payload: bytes,
+                             n_rows: int) -> ColumnarBatch:
         """Mirrors the native decoder's discipline: the whole block is
         parsed and validated into locals first; the shared dictionaries
         are only touched once nothing can fail."""
@@ -444,11 +423,7 @@ class TsvDecoder:
             limits[col.name] = int(base) + len(entries)
         cols: Dict[str, np.ndarray] = {}
         for i, col in enumerate(self.schema):
-            if v2:
-                width, dtype = self._col_width[i], self._col_dtype[i]
-            else:
-                width = 4 if col.is_string else 8
-                dtype = np.int32 if col.is_string else np.int64
+            width, dtype = self._col_width[i], self._col_dtype[i]
             if off + n_rows * width > len(payload):
                 raise ValueError("malformed flow block (truncated)")
             if col.is_string:
@@ -460,15 +435,9 @@ class TsvDecoder:
                         "flow block carries string codes outside its "
                         "dictionary")
                 cols[col.name] = codes
-            elif v2:
+            else:
                 cols[col.name] = np.frombuffer(payload, dtype, n_rows,
                                                off).copy()
-            else:
-                raw = np.frombuffer(payload, np.int64, n_rows, off)
-                if col.kind == ColumnKind.F64:
-                    cols[col.name] = raw.view(np.float64).copy()
-                else:
-                    cols[col.name] = raw.astype(col.host_dtype)
             off += n_rows * width
         # -- commit: everything validated, now mint the delta entries.
         for col in self._string_cols:
@@ -525,10 +494,9 @@ class TsvDecoder:
         return ColumnarBatch(cols, self.dicts)
 
 
-# Current wire format: TFB2 (native-width column planes). TFB1 blocks
-# (8-byte-widened numeric planes) are still accepted on decode.
+# The stream-stateful block format: TFB2 (native-width column planes
+# behind a per-stream dictionary delta).
 BLOCK_MAGIC = b"TFB2"
-BLOCK_MAGIC_V1 = b"TFB1"
 
 
 class BlockEncoder:

@@ -16,8 +16,8 @@ Three tiers per detector shard:
 
   hot   the existing device slot arrays (`StreamState`), now with a
         host-side per-slot last-touched generation counter. Occupancy
-        crossing `THEIA_STATE_HOT_WATERMARK` evicts LRU-by-generation
-        victims down to `THEIA_STATE_EVICT_TO` — one jitted gather per
+        crossing `TierConfig.hot_watermark` evicts LRU-by-generation
+        victims down to `TierConfig.evict_to` — one jitted gather per
         eviction batch, never per-row Python.
   warm  evicted state blocks in DRAM, stored in the parts/WAL
         width-reduced column encoding (`store/wire.py` — the same
@@ -31,7 +31,7 @@ Three tiers per detector shard:
   cold  every spill is ALSO appended to the `detstate` result table,
         which rides the standard store planes (WAL journal, snapshot,
         replication, resync) — so spilled state survives kill -9 and
-        failover. Warm blocks idle past `THEIA_STATE_AGE_OUT_SECONDS`
+        failover. Warm blocks idle past `TierConfig.age_out_seconds`
         are dropped from DRAM; their keys fall back to a hash-indexed
         cold map resolved against the table on re-arrival.
 
@@ -132,21 +132,11 @@ def enabled() -> bool:
 
 
 class TierConfig(NamedTuple):
-    """Eviction/aging policy knobs (all THEIA_STATE_* envs)."""
+    """Eviction/aging policy; the defaults are what a manager runs
+    (tests pass their own)."""
     hot_watermark: float = 0.9    # evict when occupancy would cross
     evict_to: float = 0.7         # ...down to this occupancy
     age_out_seconds: float = 900.0  # warm block idle age; 0 = never
-
-    @classmethod
-    def from_env(cls) -> "TierConfig":
-        d = cls()
-        return cls(
-            hot_watermark=float(os.environ.get(
-                "THEIA_STATE_HOT_WATERMARK", d.hot_watermark)),
-            evict_to=float(os.environ.get(
-                "THEIA_STATE_EVICT_TO", d.evict_to)),
-            age_out_seconds=float(os.environ.get(
-                "THEIA_STATE_AGE_OUT_SECONDS", d.age_out_seconds)))
 
 
 def key_hash(resolved: Tuple) -> int:
@@ -160,7 +150,7 @@ def key_hash(resolved: Tuple) -> int:
 
 
 def default_resolver(keys: np.ndarray) -> List[Tuple]:
-    """Resolver for standalone detectors (tests, bench): the raw int64
+    """Resolver for standalone detectors (tests): the raw int64
     key codes ARE the identity — stable for the process lifetime,
     which is all an un-stored tier needs. The manager supplies a
     string-decoding resolver for restart-stable durable identity."""

@@ -117,7 +117,7 @@ def main(argv=None) -> None:
                         "table equivalent)")
     p.add_argument("--ingest-shards", type=int, default=None,
                    help="detector shards on the ingest path (default: "
-                        "THEIA_INGEST_SHARDS env, else min(8, cores)); "
+                        "min(8, cores)); "
                         "concurrent producer streams score "
                         "concurrently, one lock per shard")
     p.add_argument("--replicas", type=int, default=1,

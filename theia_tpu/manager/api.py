@@ -1141,8 +1141,9 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
     @staticmethod
     def _cache_flag(raw) -> bool:
         """`cache=0|false|no` (GET param / POST body key) bypasses the
-        result cache for one query — the bench's timed windows measure
-        execution, not cache hits."""
+        result cache for one query, so that a caller who compares or
+        times executions (the parity tests, `benchmarks/check.py`)
+        gets an execution, not a hit."""
         return str(raw).strip().lower() not in ("0", "false", "no")
 
     @staticmethod
@@ -1483,14 +1484,13 @@ class TheiaManagerServer:
         self.controller = JobController(
             db, workers=workers, dispatch=dispatch,
             alert_sink=self.ingest.push_alert)
-        if self.ingest.admission is not None:
-            # third pressure signal (the ingest manager wired the
-            # insert backlog + WAL lag itself): a deep job queue means
-            # the workers are saturated — stop piling ingest on top
-            from ..utils.env import env_int as _env_int
-            self.ingest.admission.add_signal(
-                "jobQueue", self.controller._queue.qsize,
-                _env_int("THEIA_JOB_QUEUE_HIGH", 64))
+        # third pressure signal (the ingest manager wired the
+        # insert backlog + WAL lag itself): a deep job queue means
+        # the workers are saturated — stop piling ingest on top
+        from ..utils.env import env_float, env_int
+        self.ingest.admission.add_signal(
+            "jobQueue", self.controller._queue.qsize,
+            env_int("THEIA_JOB_QUEUE_HIGH", 64))
         self.stats = StatsProvider(db, capacity_bytes=capacity_bytes)
         # Vectorized read path: filtered aggregations over the store
         # (part-native on the parts engine, reference executor on
@@ -1510,7 +1510,6 @@ class TheiaManagerServer:
         # THEIA_STORE_CAPACITY_BYTES overrides the API capacity arg as
         # the trim threshold's denominator. Constructed here (cannot
         # fail meaningfully), STARTED after the socket bind below.
-        from ..utils.env import env_float, env_int
         self.retention = None
         retention_interval = env_float("THEIA_RETENTION_INTERVAL",
                                        60.0)
@@ -1522,12 +1521,11 @@ class TheiaManagerServer:
             self.retention = RetentionLoop(monitor,
                                            interval=retention_interval)
         # Parts engine → supervised background merge loop (compacts
-        # small sealed parts; THEIA_STORE_MERGE_INTERVAL <= 0
-        # disables). Constructed here, STARTED after the socket bind.
+        # small sealed parts every store/parts.py MERGE_INTERVAL
+        # seconds). Constructed here, STARTED after the socket bind.
         self.maintenance = None
-        merge_interval = env_float("THEIA_STORE_MERGE_INTERVAL", 5.0)
         store_stats = getattr(db, "store_stats", None)
-        if merge_interval > 0 and callable(store_stats) and \
+        if callable(store_stats) and \
                 callable(getattr(db, "maintenance_tick", None)):
             try:
                 engine = store_stats().get("engine")
@@ -1542,8 +1540,7 @@ class TheiaManagerServer:
                 # file is torn/missing AT BOOT still needs the
                 # cadence that will pick up its repair
                 from ..store import PartMaintenanceLoop
-                self.maintenance = PartMaintenanceLoop(
-                    db, interval=merge_interval)
+                self.maintenance = PartMaintenanceLoop(db)
 
         # Multi-node cluster tier (theia_tpu/cluster): membership +
         # heartbeats, and per role the replication leader (WAL
@@ -1581,11 +1578,9 @@ class TheiaManagerServer:
             # at CALL time, so a follower promoted to leader later
             # starts enforcing the quorum without rewiring
             self.ingest.durability_gate = self.cluster.durability_gate
-            if self.ingest.admission is not None:
-                from ..utils.env import env_int as _env_int
-                self.ingest.admission.add_signal(
-                    "replLag", self.cluster.repl_lag,
-                    _env_int("THEIA_REPL_LAG_HIGH", 10_000))
+            self.ingest.admission.add_signal(
+                "replLag", self.cluster.repl_lag,
+                env_int("THEIA_REPL_LAG_HIGH", 10_000))
 
         # Metrics history: the scrape-to-store loop (obs/history.py)
         # snapshots the process registry into the parts-backed

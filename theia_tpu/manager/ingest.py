@@ -33,7 +33,7 @@ the wire.
 Concurrency shape (the shard-parallel, pipelined path):
 
   * Detector state is partitioned by destination into N_SHARDS
-    independent shards (THEIA_INGEST_SHARDS, default min(8, cores)),
+    independent shards (`--ingest-shards`, default min(8, cores)),
     each holding its own HeavyHitterDetector + StreamingDetector and
     its own lock — concurrent producer streams score concurrently
     instead of queueing on one global detector lock.
@@ -81,8 +81,7 @@ import numpy as np
 
 from ..analytics.heavy_hitters import HeavyHitterDetector
 from ..analytics.streaming import DETECTOR_STAGE, StreamingDetector
-from ..ingest.native import (BLOCK_MAGIC, BLOCK_MAGIC_V1, TsvDecoder,
-                             native_available)
+from ..ingest.native import BLOCK_MAGIC, TsvDecoder, native_available
 from ..store import wire as _wire
 from ..store.wal import RECORD_MAGIC
 from ..obs import metrics as _metrics
@@ -171,23 +170,24 @@ MAX_ALERTS = 1000
 
 MAX_STREAMS = 64
 
+#: spilled-series watermark of the admission ladder's `stateSpill`
+#: signal (state tier on)
+STATE_SPILL_HIGH = 1_000_000
+
 
 def default_ingest_shards() -> int:
-    """Detector shard count: THEIA_INGEST_SHARDS wins, else one shard
-    per host core up to 8 (past that the slices get too small to beat
-    the per-slice dispatch overhead)."""
-    n = env_int("THEIA_INGEST_SHARDS", 0)
-    if n <= 0:
-        n = min(8, os.cpu_count() or 1)
-    return max(1, n)
+    """Detector shard count when neither `--ingest-shards` nor the
+    `n_shards` parameter gives one: one shard per host core up to 8
+    (past that the slices get too small to beat the per-slice
+    dispatch overhead)."""
+    return min(8, os.cpu_count() or 1)
 
 
 #: selectable scoring engines (THEIA_DETECTOR_ENGINE): "sharded" is
 #: today's per-shard-lock path; "fused" is the device-resident
 #: coalescing pipeline (ingest/device_path.py) — a drop-in with the
 #: same alert semantics; "auto" resolves per backend at construction
-#: (fused on TPU/GPU, sharded on CPU-only — the PR-16 crossover
-#: measurement in docs/ingest.md)
+#: (fused on TPU/GPU, sharded on CPU: `resolve_auto_engine`)
 DETECTOR_ENGINES = ("sharded", "fused", "auto")
 
 
@@ -198,11 +198,12 @@ def default_detector_engine() -> str:
 
 def resolve_auto_engine() -> str:
     """`auto` → concrete engine for this host, from the backend JAX
-    reports: the fused single-dispatch pipeline on accelerator
-    backends, the sharded per-lock path on CPU-only hosts (448k vs
-    642k rows/s detector-leg on the 2-core reference host — the CPU
-    crossover docs/ingest.md records). A backend that fails to
-    initialize is an error here, not a reason to pick an engine."""
+    reports: the fused single-dispatch pipeline on a TPU or GPU
+    backend, the sharded per-lock path on CPU. Which of the two is
+    faster on a chip under ingest is open: no benchmark cell sends
+    ingest to the fused engine yet (ROADMAP D2, S7, R3/R4). A backend
+    that fails to initialize is an error here, not a reason to pick
+    an engine."""
     import jax
     return ("fused" if jax.default_backend() in ("tpu", "gpu")
             else "sharded")
@@ -308,7 +309,6 @@ class IngestManager:
         if detector is None and streaming is None:
             from ..ingest import state_tier as _state_tier
             if _state_tier.enabled():
-                cfg = _state_tier.TierConfig.from_env()
                 table = getattr(db, "result_tables", {}) or {}
                 table = table.get(_state_tier.DETSTATE_TABLE)
                 cold = _state_tier.SpillStore.recover_cold_indexes(
@@ -321,7 +321,6 @@ class IngestManager:
                         _state_tier.DETSTATE_TABLE)
                 _tiers = [
                     _state_tier.WorkingSetTier(
-                        cfg,
                         store=(_state_tier.SpillStore(table)
                                if table is not None else None),
                         key_resolver=self._resolve_keys,
@@ -404,37 +403,32 @@ class IngestManager:
         # watermark now drives the admission ladder to reject instead.
         self.inflight_high = env_int("THEIA_INGEST_INFLIGHT_HIGH",
                                      0) or 2 * self._insert_workers
-        if os.environ.get("THEIA_ADMISSION_DISABLED", "") == "1":
-            self.admission: Optional[AdmissionController] = None
-        else:
-            self.admission = (admission if admission is not None
-                              else AdmissionController())
-        if self.admission is not None:
-            self.admission.add_signal("insertBacklog",
-                                      self.inflight_count,
-                                      self.inflight_high)
+        self.admission: AdmissionController = (
+            admission or AdmissionController())
+        self.admission.add_signal("insertBacklog",
+                                  self.inflight_count,
+                                  self.inflight_high)
+        self.admission.add_signal(
+            "walLag", self._wal_lag,
+            env_int("THEIA_WAL_LAG_HIGH", 50_000))
+        if self._fused is not None:
+            # Fused-pipeline backlog: a slow/wedged device step
+            # fills the bounded queue; crossing the watermark (the
+            # queue's capacity) walks the brownout ladder (sampled
+            # scoring → shed detector → reject) instead of stacking
+            # requests behind an invisible device stall.
+            from ..ingest.device_path import QUEUE_CAPACITY
             self.admission.add_signal(
-                "walLag", self._wal_lag,
-                env_int("THEIA_WAL_LAG_HIGH", 50_000))
-            if self._fused is not None:
-                # Fused-pipeline backlog: a slow/wedged device step
-                # fills the bounded queue; crossing the watermark
-                # walks the brownout ladder (sampled scoring → shed
-                # detector → reject) instead of stacking requests
-                # behind an invisible device stall.
-                self.admission.add_signal(
-                    "fusedQueue", self._fused.queue_depth,
-                    env_int("THEIA_FUSED_QUEUE_HIGH", 0)
-                    or self._fused.queue_capacity)
-            if self._tiers:
-                # Spill-tier occupancy as overload pressure: a spilled
-                # series costs DRAM + a promote on re-arrival, so an
-                # unbounded working set walks the brownout ladder
-                # before it walks the host into swap.
-                self.admission.add_signal(
-                    "stateSpill",
-                    lambda: sum(t.spilled_count for t in self._tiers),
-                    env_int("THEIA_STATE_SPILL_HIGH", 1_000_000))
+                "fusedQueue", self._fused.queue_depth, QUEUE_CAPACITY)
+        if self._tiers:
+            # Spill-tier occupancy as overload pressure: a spilled
+            # series costs DRAM + a promote on re-arrival, so an
+            # unbounded working set walks the brownout ladder
+            # before it walks the host into swap.
+            self.admission.add_signal(
+                "stateSpill",
+                lambda: sum(t.spilled_count for t in self._tiers),
+                STATE_SPILL_HIGH)
         # -- cluster tier hooks (theia_tpu/cluster wires these) ------
         # Router: split decoded batches by owner node, forward remote
         # slices (role `peer` routing mesh).
@@ -646,10 +640,9 @@ class IngestManager:
                         # corrupt the stream's dictionary-delta chain
                         # — tell the producer to come back for its
                         # duplicate ack
-                        if self.admission is not None:
-                            # keep /healthz admission.rejected in
-                            # lockstep with the metric
-                            self.admission.note_rejected()
+                        # keep /healthz admission.rejected in
+                        # lockstep with the metric
+                        self.admission.note_rejected()
                         _admission._M_REJECTED.labels(
                             reason="in_flight").inc()
                         raise _admission.AdmissionRejected(
@@ -698,19 +691,17 @@ class IngestManager:
             except _wire.WireCorruption:
                 _M_ERRORS.labels(stage="decode").inc()
                 raise
-        level = LEVEL_OK
-        if self.admission is not None:
-            # raises AdmissionRejected → 429 + Retry-After (payload
-            # bytes are charged here; rows after decode — except TBLK,
-            # whose header already charged them via rows_hint). The
-            # kwarg is passed only when a hint exists, so admit()
-            # stubs/wrappers with the pre-TBLK two-arg signature keep
-            # working for non-TBLK payloads.
-            if rows_hint is None:
-                level = self.admission.admit(stream, len(payload))
-            else:
-                level = self.admission.admit(stream, len(payload),
-                                             rows_hint=rows_hint)
+        # raises AdmissionRejected → 429 + Retry-After (payload
+        # bytes are charged here; rows after decode — except TBLK,
+        # whose header already charged them via rows_hint). The
+        # kwarg is passed only when a hint exists, so admit()
+        # stubs/wrappers with the pre-TBLK two-arg signature keep
+        # working for non-TBLK payloads.
+        if rows_hint is None:
+            level = self.admission.admit(stream, len(payload))
+        else:
+            level = self.admission.admit(stream, len(payload),
+                                         rows_hint=rows_hint)
         parked = None
         if seq is not None and not is_record and not is_block:
             with self._parked_lock:
@@ -796,7 +787,7 @@ class IngestManager:
             with st.lock:
                 t_dec = time.perf_counter()
                 try:
-                    if payload[:4] in (BLOCK_MAGIC, BLOCK_MAGIC_V1):
+                    if magic == BLOCK_MAGIC:
                         batch = st.decoder.decode_block(payload)
                     else:
                         batch = st.decoder.decode(payload)
@@ -809,8 +800,7 @@ class IngestManager:
                     _M_ERRORS.labels(stage="decode").inc()
                     raise
                 _M_STAGE_DECODE.observe(time.perf_counter() - t_dec)
-        if parked is None and not is_block \
-                and self.admission is not None:
+        if parked is None and not is_block:
             # post-decode row accounting: the row bucket may go into
             # debt, which rejects FUTURE requests until it refills
             # (TBLK already charged its exact count from the header)
@@ -917,8 +907,7 @@ class IngestManager:
         # sampled at a declining fraction, then fully shed — while the
         # durable leg (WAL + store) keeps acknowledging rows.
         scored = (level == LEVEL_OK
-                  or (self.admission is not None
-                      and self.admission.should_score(level)))
+                  or self.admission.should_score(level))
         if skip_local:
             # local slice already landed (a routed retry) or every row
             # belongs to a remote owner — nothing to insert or score

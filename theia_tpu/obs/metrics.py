@@ -26,14 +26,8 @@ Metric constructors are idempotent per (name): calling
 `counter("x", ...)` twice returns the same object, so instrumented
 modules declare their handles at import with no registration dance.
 
-Env knobs:
-
-    THEIA_METRICS_STRIPES    stripe count per counter/histogram
-                             (default 16)
-    THEIA_METRICS_DISABLED   "1"/"true" → every inc/observe/set is a
-                             no-op (the bench's overhead A/B switch);
-                             also togglable at runtime via
-                             disable()/enable()
+`disable()` / `enable()` turn every inc/observe/set into a no-op and
+back at run time (tests use them; `obs/trace.py` honours them).
 
 This module deliberately imports nothing from the rest of theia_tpu
 (stdlib + numpy only, plus analysis.lockdep — itself stdlib-only, so
@@ -44,7 +38,6 @@ firings here, and utils is imported by everything.
 from __future__ import annotations
 
 import math
-import os
 import threading
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -52,25 +45,16 @@ import numpy as np
 from ..analysis.lockdep import named_lock
 
 
-def _env_int(name: str, default: int) -> int:
-    raw = os.environ.get(name, "")
-    try:
-        return int(raw) if raw else default
-    except ValueError:
-        return default
-
-
 #: owned stripes per counter/histogram (slot 0 is the locked shared
 #: slot, so the arrays are N_STRIPES + 1 wide)
-N_STRIPES = max(1, _env_int("THEIA_METRICS_STRIPES", 16))
+N_STRIPES = 16
 
 #: histogram bucket bounds: 2^k seconds for k in [EXP_MIN, EXP_MIN +
 #: N_BUCKETS) — ~1 µs to ~16 s — plus a +Inf overflow bucket
 EXP_MIN = -20
 N_BUCKETS = 25
 
-_DISABLED = os.environ.get(
-    "THEIA_METRICS_DISABLED", "").strip().lower() in ("1", "true", "yes")
+_DISABLED = False
 
 
 def disable() -> None:

@@ -90,7 +90,11 @@ from ..analysis.lockdep import named_lock
 
 
 def _ring_capacity() -> int:
-    return max(0, _metrics._env_int("THEIA_TRACE_RING", 256))
+    raw = os.environ.get("THEIA_TRACE_RING", "")
+    try:
+        return max(0, int(raw)) if raw else 256
+    except ValueError:
+        return 256
 
 
 def _sample_rate(env: Optional[str] = None) -> float:

@@ -4,13 +4,11 @@ The sharded ingest engine scores one micro-batch with (per shard) one
 jitted heavy-hitter step (CMS scatter + query + k-means) plus one
 jitted streaming step (EWMA/Welford gather-scan-scatter) — two
 dispatches and two host↔device fetch round trips per shard per batch.
-On weak hosts the per-dispatch fixed cost dominates the compute
-(ROADMAP item 3: the detector leg caps e2e at ~1.5M rows/s while
-native decode does 17.7M), so this module fuses ALL of it — EWMA
-update + Welford band + CMS heavy-hitter update + k-means shape
-outliers + alert thresholding — across EVERY shard's coalesced slice
-into ONE jitted computation: one dispatch, one fetch, per coalesced
-micro-batch.
+That fixed cost is paid 2 × shards times a block whatever its rows,
+so this module fuses ALL of it — EWMA update + Welford band + CMS
+heavy-hitter update + k-means shape outliers + alert thresholding —
+across EVERY shard's coalesced slice into ONE jitted computation: one
+dispatch, one fetch, per coalesced micro-batch.
 
 Parity contract: the per-shard math is literally the sharded engine's
 — the streaming scan applies `analytics.streaming._update` tick by

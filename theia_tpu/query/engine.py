@@ -40,11 +40,11 @@ materialized copy:
      boundaries come from one adjacent-row comparison over
      contiguous runs, bit-identical output.
   4. **Parallel per-part execution.** Live parts are striped across a
-     bounded pool (`THEIA_QUERY_WORKERS`); each worker folds its
+     bounded pool (`DEFAULT_WORKERS` threads); each worker folds its
      parts into ONE per-worker partial accumulator, and the partials
      merge exactly (count via sum, min via min, ...).
   5. **Cold tier stays cold.** A demoted part streams through a
-     bounded decode buffer (`THEIA_QUERY_COLD_BUFFER` concurrent
+     bounded decode buffer (`DEFAULT_COLD_BUFFER` concurrent
      decodes), decoding ONLY the columns the plan touches
      (column-subset part-file decode), and is never promoted back to
      RAM — the hot/cold working-set split of arXiv:1902.04143 holds
@@ -380,10 +380,9 @@ class QueryEngine:
                  cold_buffer: Optional[int] = None) -> None:
         self.db = db
         self.workers = max(1, (
-            env_int("THEIA_QUERY_WORKERS", DEFAULT_WORKERS)
-            if workers is None else int(workers)))
+            DEFAULT_WORKERS if workers is None else int(workers)))
         self.cold_buffer = max(1, (
-            env_int("THEIA_QUERY_COLD_BUFFER", DEFAULT_COLD_BUFFER)
+            DEFAULT_COLD_BUFFER
             if cold_buffer is None else int(cold_buffer)))
         self._cold_sem = threading.Semaphore(self.cold_buffer)
         self.cache = QueryCache(cache_bytes)
@@ -483,8 +482,7 @@ class QueryEngine:
         adopts a caller's trace context (this is a trace ingress);
         `use_rollup=False` (the request's `rollup=0` flag) forces the
         raw-scan path even when a declared rollup view subsumes the
-        plan — the bench's A/B lever and the parity tests' oracle
-        side."""
+        plan — the parity tests' oracle side."""
         with _trace.ingress_span("query.request",
                                  traceparent=traceparent) as sp:
             doc = self._execute_traced(plan, use_cache, explain,

@@ -15,7 +15,7 @@ query:
     POST `"explain": true`).
   * **SlowQueryLog** — any query slower than `THEIA_QUERY_SLOW_MS`
     (default 1000 ms; <= 0 disables) is captured WITH its full
-    profile into a bounded ring (`THEIA_QUERY_SLOW_RING`, default 64)
+    profile into a bounded ring (`SLOW_RING` = 64 entries)
     served at `GET /debug/slow_queries` (token-gated — plans carry
     flow identities). Because a slow query must be profiled before it
     is known to be slow, profile collection runs whenever capture is
@@ -36,7 +36,6 @@ import time
 from typing import Deque, Dict, List, Optional
 
 from ..obs import metrics as _metrics
-from ..utils.env import env_int
 from ..analysis.lockdep import named_lock
 
 _M_SLOW = _metrics.counter(
@@ -47,6 +46,8 @@ _M_SLOW = _metrics.counter(
 #: per-part detail entries kept per profile (a 10k-part scan still
 #: profiles — the list just truncates, with the drop counted)
 MAX_PROFILE_PARTS = 128
+#: slow-query capture ring capacity
+SLOW_RING = 64
 
 
 def slow_threshold_ms() -> float:
@@ -159,8 +160,7 @@ class SlowQueryLog:
     small and the rows add nothing to "why was it slow")."""
 
     def __init__(self, capacity: Optional[int] = None) -> None:
-        cap = (env_int("THEIA_QUERY_SLOW_RING", 64)
-               if capacity is None else int(capacity))
+        cap = SLOW_RING if capacity is None else int(capacity)
         self._ring: Deque[Dict[str, object]] = collections.deque(
             maxlen=max(0, cap))
         self._lock = named_lock("query.slowlog")

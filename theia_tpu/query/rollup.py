@@ -74,8 +74,8 @@ Env knobs (documented in docs/queries.md):
     THEIA_ROLLUP_DEFAULTS   1 = include the reference's three MVs as
                             built-in views (default 0)
     THEIA_ROLLUP_QUERY      0 = disable the planner rewrite (forced
-                            raw scans; the bench A/B uses the per-
-                            request `rollup=0` flag instead)
+                            raw scans; one request forces them with
+                            its `rollup=0` flag)
 """
 
 from __future__ import annotations

@@ -61,7 +61,7 @@ Sort order + indexes (PR 12, the rest of the MergeTree read design):
     un-permute on decode (byte-identical flat parity holds unchanged)
     and positional delete masks resolve through the row-id.
   * Each sorted part keeps a SPARSE PRIMARY INDEX + per-granule SKIP
-    INDEXES (`THEIA_STORE_GRANULE_ROWS`, default 8192): min/max zone
+    INDEXES (one per `DEFAULT_GRANULE_ROWS` = 8192 rows): min/max zone
     maps on every column (the sort-key prefix's zone map IS the
     binary-searchable sparse index, since the column is sorted) and
     bounded set indexes of distinct dictionary codes on string
@@ -81,17 +81,18 @@ Env knobs (all also constructor-injectable for tests):
 
     THEIA_STORE_ENGINE             parts|flat (default flat)
     THEIA_STORE_MEMTABLE_ROWS      memtable rows before a seal (65536)
-    THEIA_STORE_PART_ROWS          merge target part size (262144)
     THEIA_STORE_PARTITION_SECONDS  time partition width (3600)
     THEIA_STORE_SORT_KEY           part primary key, comma-separated
                                    columns (default timeInserted,
                                    destinationIP,sourceIP; empty
                                    disables sorting → v1 parts)
-    THEIA_STORE_GRANULE_ROWS       rows per index granule (8192)
     THEIA_STORE_COLD_DIR           part/manifest directory (manager
                                    default: <db path>.parts)
-    THEIA_STORE_MERGE_INTERVAL     background merge cadence (5s;
-                                   <=0 disables the loop)
+
+The merge target part size (DEFAULT_PART_ROWS), the rows per index
+granule (DEFAULT_GRANULE_ROWS) and the background merge cadence
+(MERGE_INTERVAL) are constants below; the first two are constructor
+parameters for tests.
 """
 
 from __future__ import annotations
@@ -112,7 +113,7 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..schema import ColumnarBatch
 from ..utils.backoff import capped_backoff
-from ..utils.env import env_float, env_int
+from ..utils.env import env_int
 from ..utils.logging import get_logger
 from . import wal as _wal
 from .flow_store import Table
@@ -137,7 +138,11 @@ MAX_PARTS_PER_SEAL = 32
 #: (string columns by dictionary code — identical values still
 #: cluster exactly)
 DEFAULT_SORT_KEY = "timeInserted,destinationIP,sourceIP"
+#: rows per index granule of a sorted part (zone maps + string set
+#: indexes; smaller = finer query pruning, more index bytes)
 DEFAULT_GRANULE_ROWS = 8192
+#: seconds between background part-merge passes
+MERGE_INTERVAL = 5.0
 #: a granule's string set index is dropped (None = "no proof") once
 #: its distinct-code count exceeds this — the ClickHouse set(N) cap
 SET_INDEX_MAX = 128
@@ -625,7 +630,7 @@ class PartTable(Table):
         self.sort_key: Tuple[str, ...] = tuple(
             c for c in key if any(col.name == c for col in schema))
         self.granule_rows = max(1, (
-            env_int("THEIA_STORE_GRANULE_ROWS", DEFAULT_GRANULE_ROWS)
+            DEFAULT_GRANULE_ROWS
             if granule_rows is None else int(granule_rows)))
         self.parts_upgraded = 0
         # Directory is EXPLICIT-ONLY at this level: the topology
@@ -639,7 +644,7 @@ class PartTable(Table):
             env_int("THEIA_STORE_MEMTABLE_ROWS", DEFAULT_MEMTABLE_ROWS)
             if memtable_rows is None else int(memtable_rows))
         self.part_rows = (
-            env_int("THEIA_STORE_PART_ROWS", DEFAULT_PART_ROWS)
+            DEFAULT_PART_ROWS
             if part_rows is None else int(part_rows))
         self.partition_seconds = max(1, (
             env_int("THEIA_STORE_PARTITION_SECONDS",
@@ -875,7 +880,7 @@ class PartTable(Table):
                 self._gc_guard.discard(os.path.basename(path))
 
     def seal(self) -> None:
-        """Force-seal the memtable (tests, bench)."""
+        """Force-seal the memtable (tests)."""
         with self._lock:
             self._seal_locked()
 
@@ -2012,12 +2017,9 @@ class PartMaintenanceLoop:
     instead of hammering a broken store; the first clean pass restores
     the cadence. Stats surface on /healthz under store.maintenance."""
 
-    def __init__(self, db, interval: Optional[float] = None,
-                 backoff_cap: float = 300.0) -> None:
+    def __init__(self, db, backoff_cap: float = 300.0) -> None:
         self.db = db
-        self.interval = (
-            env_float("THEIA_STORE_MERGE_INTERVAL", 5.0)
-            if interval is None else float(interval))
+        self.interval = MERGE_INTERVAL
         self.backoff_cap = backoff_cap
         self.rounds = 0
         self.merges = 0

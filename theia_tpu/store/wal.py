@@ -73,7 +73,7 @@ Sync policy (THEIA_WAL_SYNC, default `interval:1`):
                     path plus a background timer for quiescent periods
                     (loss bound: <secs> of acks)
     never           rely on the OS page cache (loss bound: unbounded;
-                    bench/throwaway stores only)
+                    throwaway stores only)
 
 Fault sites (utils/faults.py grammar): `wal.append`, `wal.fsync`,
 `wal.rotate`.

@@ -1,5 +1,5 @@
 """Process-level JAX set-up shared by the entry points (manager,
-runner, bench child, __graft_entry__): where the persistent compile
+runner, __graft_entry__): where the persistent compile
 cache lives, and one line saying what the process runs on.
 
 Platform selection is JAX's own: `JAX_PLATFORMS` is honoured by JAX
@@ -41,8 +41,8 @@ def enable_compile_cache() -> Optional[str]:
     bury every log tail, to save compiles that take milliseconds.
     The minimum-compile-time floor is dropped to zero: the served path
     compiles many bucketed steps that each take well under JAX's
-    default 1 s floor, and those are exactly the ones every manager,
-    runner and bench child would otherwise recompile from cold."""
+    default 1 s floor, and those are exactly the ones every manager
+    and runner would otherwise recompile from cold."""
     import jax
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
