@@ -21,6 +21,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from ..ops import arima_scores, dbscan_scores, ewma_scores
+from ..schema import TADETECTOR_SCHEMA, ColumnarBatch, StringDictionary
 from ..store import FlowDatabase
 from ..utils import get_logger
 from .series import SeriesBatch, TadQuerySpec, build_series
@@ -190,71 +191,106 @@ def run_tad(db: FlowDatabase, algo: str, spec: TadQuerySpec,
 
     if progress:
         progress.stage("score")
-    rows = detect_anomalies(batch, algo, tad_id, now=now,
-                            refit_every=spec.refit_every, mesh=mesh)
+    result = detect_anomalies(batch, algo, tad_id, now=now,
+                              refit_every=spec.refit_every, mesh=mesh)
 
     if progress:
         progress.stage("write")
-    db.tadetector.insert_rows(rows)
+    db.tadetector.insert(result)
     if progress:
+        progress.wrote(result)
         progress.done()
     return tad_id
 
 
 def detect_anomalies(batch: SeriesBatch, algo: str, tad_id: str,
                      now: Optional[int] = None, refit_every: int = 1,
-                     mesh=None):
-    """Score a series batch and materialize tadetector result rows."""
+                     mesh=None) -> ColumnarBatch:
+    """Score a series batch and build its tadetector result rows, one
+    for each anomalous point in `np.nonzero` order, as one batch made
+    from the kernel's arrays: no row is a Python object."""
     refit = effective_refit(
         algo, refit_every,
         batch.values.shape[1] if batch.n_series else 0)
-    if batch.n_series == 0:
-        return [_no_anomaly_row(batch.agg_type, algo, tad_id, now,
-                                refit)]
-
-    # Pass the resolved cadence so the emitted refitEvery and the one
-    # actually executed cannot drift (effective_refit is idempotent).
-    calc, std, anom = score_series(batch.values, batch.mask, algo,
-                                   refit_every=refit if refit else 1,
-                                   mesh=mesh)
-    sidx, tidx = np.nonzero(anom)
+    sidx = tidx = np.zeros(0, np.intp)
+    if batch.n_series:
+        # Pass the resolved cadence so the emitted refitEvery and the
+        # one actually executed cannot drift (effective_refit is
+        # idempotent).
+        calc, std, anom = score_series(
+            batch.values, batch.mask, algo,
+            refit_every=refit if refit else 1, mesh=mesh)
+        sidx, tidx = np.nonzero(anom)
     if sidx.size == 0:
-        return [_no_anomaly_row(batch.agg_type, algo, tad_id, now,
-                                refit)]
+        return _result_batch(1, _no_anomaly_row(
+            batch.agg_type, algo, tad_id, now, refit))
 
-    # stddev_samp is NULL (NaN) for 1-point series; those can't be
-    # anomalous, but guard the cast anyway.
-    std = np.nan_to_num(std, nan=0.0)
-    rows = []
-    for s, t in zip(sidx, tidx):
-        row: Dict[str, object] = {
-            "aggType": batch.agg_type,
-            "algoType": algo,
-            "flowEndSeconds": int(batch.times[s, t]),
-            "throughputStandardDeviation": float(std[s]),
-            "algoCalc": float(calc[s, t]),
-            "throughput": float(batch.values[s, t]),
-            "anomaly": "true",
-            "refitEvery": refit,
-            "id": tad_id,
-        }
-        # Series key names coincide with tadetector column names; keys
-        # not present for this agg mode default to ''/0 in the schema
-        # (the reference emits a mode-specific column subset,
-        # filter_df_with_true_anomalies :352-394).
-        for key_name in batch.key_names:
-            v = batch.keys[key_name][s]
-            row[key_name] = v.item() if isinstance(v, np.generic) else v
-        rows.append(row)
-    return rows
+    point = (sidx, tidx)
+    values: Dict[str, object] = {
+        "aggType": batch.agg_type,
+        "algoType": algo,
+        "flowEndSeconds": (batch.times, point),
+        # stddev_samp is NULL (NaN) for 1-point series; those can't be
+        # anomalous, but guard the cast anyway.
+        "throughputStandardDeviation": (
+            np.nan_to_num(std, nan=0.0), sidx),
+        "algoCalc": (calc, point),
+        "throughput": (batch.values, point),
+        "anomaly": "true",
+        "refitEvery": refit,
+        "id": tad_id,
+    }
+    # Series key names coincide with tadetector column names; keys
+    # not present for this agg mode default to ''/0 in the schema
+    # (the reference emits a mode-specific column subset,
+    # filter_df_with_true_anomalies :352-394). A key is taken from the
+    # series that have a row, each row's place among them.
+    used, place = np.unique(sidx, return_inverse=True)
+    for key_name in batch.key_names:
+        values[key_name] = (batch.keys[key_name][used], place)
+    return _result_batch(sidx.size, values)
+
+
+def _result_batch(n_rows: int,
+                  values: Dict[str, object]) -> ColumnarBatch:
+    """`n_rows` tadetector rows as one batch that carries dictionaries
+    of its own (`Table.insert` adopts them, so every store facade
+    takes it). A column of `values` is one value for every row, or
+    `(source, index)` for rows that hold `source[index]`; a column it
+    does not name holds its kind's default, 0 or ''.
+
+    A string column's `source` is encoded once an entry (a series, not
+    a point) and holds only values that some row has, so the batch's
+    dictionary lists them in sorted order: the order in which
+    `from_rows` over the same rows would hand the table's dictionary
+    its new strings. The table's codes are the same either way."""
+    cols: Dict[str, np.ndarray] = {}
+    dicts: Dict[str, StringDictionary] = {}
+    for col in TADETECTOR_SCHEMA:
+        value = values.get(col.name, "" if col.is_string else 0)
+        gathered = isinstance(value, tuple)
+        if not col.is_string:
+            cols[col.name] = (
+                np.asarray(value[0][value[1]], col.host_dtype)
+                if gathered else np.full(n_rows, value, col.host_dtype))
+            continue
+        d = dicts[col.name] = StringDictionary()
+        if not gathered:
+            cols[col.name] = np.full(n_rows, d.encode_one(value),
+                                     np.int32)
+            continue
+        source, index = value
+        cols[col.name] = d.encode(
+            [str(v) for v in source.tolist()])[index]
+    return ColumnarBatch(cols, dicts)
 
 
 def _no_anomaly_row(agg_type: str, algo: str, tad_id: str,
                     now: Optional[int],
                     refit: int = 0) -> Dict[str, object]:
-    """The reference's filler row (:401-419): string identity columns get
-    'None', flowStartSeconds gets the wall clock, anomaly gets the
-    sentinel text."""
+    """The reference's filler row (:401-419), a value a column: string
+    identity columns get 'None', flowStartSeconds gets the wall clock,
+    anomaly gets the sentinel text."""
     return {
         "sourceIP": "None",
         "sourceTransportPort": 0,

@@ -30,6 +30,13 @@ _M_STAGE = _metrics.histogram(
     "Wall time of one stage of a job run (read, tensorize, score, "
     "write, ...: the stages JobProgress announces)",
     labelnames=("kind", "stage"))
+_M_ROWS_WRITTEN = _metrics.counter(
+    "theia_job_rows_written_total",
+    "Result rows a job inserted into its result table as one batch",
+    labelnames=("kind",))
+_M_BYTES_WRITTEN = _metrics.counter(
+    "theia_job_bytes_written_total",
+    "Column bytes of those batches", labelnames=("kind",))
 
 
 class JobProgress:
@@ -62,6 +69,12 @@ class JobProgress:
                 self._completed += 1
             self._current = name
         self._flush()
+
+    def wrote(self, batch) -> None:
+        """Count the batch of result rows the `write` stage inserted."""
+        _M_ROWS_WRITTEN.labels(kind=self.kind).inc(len(batch))
+        _M_BYTES_WRITTEN.labels(kind=self.kind).inc(
+            sum(a.nbytes for a in batch.columns.values()))
 
     def done(self) -> None:
         self._marks.end()
