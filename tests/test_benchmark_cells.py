@@ -24,6 +24,18 @@ from benchmarks.tests.test_snapshot_prefix import (      # noqa: F401
     test_another_interval_than_the_configurations,
 )
 
+from benchmarks.tests.test_tad_arima import (            # noqa: F401
+    test_a_job_that_did_not_complete_and_an_answer_that_is_missing,
+    test_a_perturbed_answer_is_not_correct,
+    test_float32_in_the_programs_place_is_correct,
+    test_forecasts_are_compared_on_the_scale_they_were_modelled_on,
+    test_the_bfloat16_control_fails_a_limit_and_float64_none,
+    test_the_cadence_a_job_resolves_to,
+    test_the_estimator_as_defined_and_by_running_sums_agree,
+    test_the_kernels_bytes_and_steps_at_the_cells_shape,
+    test_the_references_own_rows_are_correct,
+)
+
 BENCH = manifest.load()
 CELLS = [w["name"] for w in BENCH.doc["workloads"]]
 
@@ -97,6 +109,53 @@ def test_the_checkpoint_cell_is_saturate_with_the_snapshot_on():
     assert {m["name"] for m in BENCH.metrics_of(
         "default.ingest-saturate", "per_layer")} < layer
     assert len({n for n in layer if n.startswith("ckpt.")}) == 12
+
+
+def test_the_arima_cell_holds_a_whole_retained_day_of_20_connections():
+    """The time axis is whole (12 h at 1 s), the cut is in connections,
+    and everything but the kernel is `parts-fused.tad-ewma`'s."""
+    cfg = BENCH.config("theia-parts-fused-12h-1x1")
+    sib = BENCH.config("theia-parts-fused-1x1")
+    assert (cfg["env"], cfg["manager_args"], cfg["expect"]) \
+        == (sib["env"], sib["manager_args"], sib["expect"])
+    entry = next(c for c in BENCH.doc["configs"]
+                 if c["name"] == cfg["name"])
+    assert entry["reduced"] == ["retained_connections",
+                                "checkpoint_interval_s"]
+    assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    t = BENCH.traffic("tad-arima")
+    ewma = BENCH.traffic("tad-ewma")
+    g = t["generator"]
+    for k in ("interval_seconds", "base_throughput", "spike_rate",
+              "spike_magnitude"):
+        assert g[k] == ewma["generator"][k]
+    producer, jobs = t["workers"]
+    points = producer["preload_blocks"] * g["points_per_conn"]
+    assert points == cfg["points_per_connection"] == 43200 == 12 * 3600
+    assert g["connections_per_producer"] == g["conns_per_block"] \
+        == cfg["retained_connections"] == 20
+    assert 20 * points == cfg["retained_window_rows"] == 864000
+    assert cfg["source_retained_connections"] * points \
+        == cfg["source_retained_window_rows"] == 172800000
+    assert producer["window"] == "idle" and producer["warm_blocks"] == 0
+    assert jobs["job"] == {**ewma["workers"][1]["job"],
+                           "spec": {"jobType": "ARIMA", "refitEvery": 0}}
+    assert t["checks"] == ["acks", "store_totals", "detector_series",
+                           "tad_arima"]
+    assert set(t["limits"]) == {"arima_decision_mismatch",
+                                "arima_forecast_gap", "arima_stddev_gap"}
+    cell = "parts-fused-12h.tad-arima"
+    assert {m["name"] for m in BENCH.metrics_of(cell, "end_to_end")} \
+        == {"job_turnaround_s", "setup_s"}
+    layer = {m["name"] for m in BENCH.metrics_of(cell, "per_layer")}
+    old = {m["name"] for m in BENCH.metrics_of("parts-fused.tad-ewma",
+                                               "per_layer")}
+    # a whole call of the kernel cannot be captured (the traffic
+    # file's `trace_note`), so no device-trace metric of it is declared
+    assert layer - old == {"job.arima_fits"}
+    assert old - layer == {"job.ewma_device_ms", "ewma_scores_roofline"}
+    assert (t["trace_seconds"], t["trace_lead_seconds"]) == (1, 0)
+    assert {"job.score_kernel_ms", "job.score_rows_ms"} <= layer & old
 
 
 def test_an_operator_no_manager_answers_ends_in_the_warm_up(tmp_path):

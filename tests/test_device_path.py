@@ -318,6 +318,34 @@ def test_pallas_interpret_matches_jnp_scan(u):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b2))
 
 
+@pytest.mark.parametrize("ticks,kernels", [(4, 1), (64, 1), (128, 0),
+                                           (2048, 0)])
+def test_a_long_tile_takes_the_scan_not_the_unrolled_kernel(ticks,
+                                                            kernels):
+    """The Pallas kernel unrolls its tick loop at trace time: a block
+    of 1,600 points a connection (a 2,048-tick tile, eight shards)
+    kept a process's first request past its 120 s. Up to
+    PALLAS_MAX_TICKS the kernel, beyond it the scan, whose alerts are
+    the same (test_pallas_interpret_matches_jnp_scan)."""
+    import jax
+    import jax.numpy as jnp
+
+    from theia_tpu.analytics.streaming import init_state
+    from theia_tpu.ops import fused_detector as fd
+
+    assert fd.PALLAS_MAX_TICKS == 64
+    u = 64
+    inp = fd.ShardInputs(
+        slots=jnp.arange(u, dtype=jnp.int32),
+        x=jnp.ones((ticks, u), jnp.float32),
+        active=jnp.ones((ticks, u), bool),
+        keys=None, vols=None, q=None, feats=None, valid=None)
+    jaxpr = jax.make_jaxpr(
+        lambda st, i: fd._stream_half(st, i, 0.5, True, True))(
+            init_state(512), inp)
+    assert str(jaxpr).count("pallas_call") == kernels
+
+
 # -- accelerator-only ----------------------------------------------------
 
 @pytest.mark.device

@@ -357,6 +357,14 @@ def test_tad_run_and_its_result_answer_are_timed_by_part():
     for s in TAD_STAGES:
         assert _hist("theia_job_stage_seconds", kind="tad",
                      stage=s)[1] >= 1
+    # the parts of `score` lie inside it and take nothing from it
+    parts = run["partsMs"]
+    assert list(parts) == ["job.score." + p
+                           for p in ("transfer", "kernel", "rows")]
+    assert sum(parts.values()) <= run["stagesMs"]["job.score"]
+    for p in ("transfer", "kernel", "rows"):
+        assert _hist("theia_job_stage_part_seconds", kind="tad",
+                     stage="score", part=p)[1] >= 1
 
 
 def test_get_of_a_completed_job_is_timed_counted_and_makes_no_row(
@@ -400,6 +408,13 @@ def test_get_of_a_completed_job_is_timed_counted_and_makes_no_row(
         doc = json.loads(body)
         assert doc["status"]["state"] == "COMPLETED"
         assert len(doc["stats"]) == n_rows
+        # `send` is observed once the socket write has returned, which
+        # a client on the same host can outrun: give it a moment
+        deadline = time.time() + 5
+        while _hist("theia_job_result_seconds", kind="tad",
+                    phase="send")[1] == n0["send"] \
+                and time.time() < deadline:
+            time.sleep(0.01)
         for p in phases:
             assert _hist("theia_job_result_seconds", kind="tad",
                          phase=p)[1] == n0[p] + 1

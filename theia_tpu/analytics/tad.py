@@ -14,10 +14,14 @@ batch (kernels in theia_tpu.ops); the reference's per-row Python UDFs
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import time
 import uuid
 from typing import Dict, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..ops import arima_scores, dbscan_scores, ewma_scores
@@ -50,19 +54,19 @@ def effective_refit(algo: str, refit_every: int, n_steps: int) -> int:
     return refit_every if refit_every else max(1, n_steps // 2048)
 
 
-def _score_series_sharded(values, mask, algo, refit_every, mesh):
-    """Score over a device mesh: data-parallel over series (plus
-    sequence-parallel over time for EWMA). The sharded kernels run the
-    same per-series computation as the single-device path, so result
-    rows are identical — this is the reference's `executorInstances`
-    scale-out applied to the production job (SURVEY §2.7 row 1)."""
+def _sharded_kernel(values, mask, algo, refit_every, mesh):
+    """(place, kernel) of one algorithm over a device mesh:
+    data-parallel over series (plus sequence-parallel over time for
+    EWMA). The sharded kernels run the same per-series computation as
+    the single-device path, so result rows are identical — this is the
+    reference's `executorInstances` scale-out applied to the
+    production job (SURVEY §2.7 row 1)."""
     from ..parallel import (cached_kernel, make_sharded_arima,
                             make_sharded_dbscan, make_sharded_ewma,
                             pad_to_multiple, shard_arrays)
     from ..parallel.mesh import SERIES_AXIS, TIME_AXIS
     from ..parallel.tad_sharded import make_series_sharded
 
-    S, T = values.shape
     values, _ = pad_to_multiple(values, mesh.shape[SERIES_AXIS], axis=0)
     mask, _ = pad_to_multiple(mask, mesh.shape[SERIES_AXIS], axis=0)
     if algo == "EWMA" and mesh.shape.get(TIME_AXIS, 1) > 1:
@@ -74,18 +78,15 @@ def _score_series_sharded(values, mask, algo, refit_every, mesh):
         mask, _ = pad_to_multiple(mask, mesh.shape[TIME_AXIS], axis=1)
         fn = cached_kernel(("ewma_time", mesh),
                            lambda: make_sharded_ewma(mesh))
-        calc, std, anom, _count = fn(*shard_arrays(mesh, values, mask))
     elif algo == "EWMA":
         fn = cached_kernel(
             ("ewma", mesh),
             lambda: make_series_sharded(mesh, ewma_scores))
-        calc, std, anom = fn(*shard_arrays(mesh, values, mask))
     elif algo == "ARIMA":
-        refit = effective_refit(algo, refit_every, T)
+        refit = effective_refit(algo, refit_every, values.shape[1])
         fn = cached_kernel(
             ("arima", mesh, refit),
             lambda: make_sharded_arima(mesh, refit_every=refit))
-        calc, std, anom = fn(*shard_arrays(mesh, values, mask))
     else:
         from ..ops.dbscan import DEFAULT_EPS, DEFAULT_MIN_SAMPLES
         fn = cached_kernel(
@@ -93,33 +94,39 @@ def _score_series_sharded(values, mask, algo, refit_every, mesh):
             lambda: make_sharded_dbscan(
                 mesh, eps=DEFAULT_EPS,
                 min_samples=DEFAULT_MIN_SAMPLES))
-        calc, std, anom = fn(*shard_arrays(mesh, values, mask))
-    return (np.asarray(calc)[:S, :T], np.asarray(std)[:S],
-            np.asarray(anom)[:S, :T])
+    return (lambda: shard_arrays(mesh, values, mask)), fn
 
 
-def score_series(values: np.ndarray, mask: np.ndarray, algo: str,
-                 refit_every: int = 1, mesh=None):
-    """Run one algorithm over a padded [S, T] batch.
+def _local_kernel(values, mask, algo, refit_every):
+    """(place, kernel) of one algorithm on the default device."""
+    if algo == "EWMA":
+        fn = ewma_scores
+    elif algo == "ARIMA":
+        refit = effective_refit(algo, refit_every, values.shape[1])
+        if refit > 1:
+            logger.info(
+                "ARIMA grouped-refit approximation active: refitting "
+                "every %d steps over T=%d (reference-exact is "
+                "refitEvery=1)", refit, values.shape[1])
+        elif values.shape[1] > 8192:
+            logger.warning(
+                "ARIMA exact refit-per-step over T=%d steps is "
+                "O(T^2) — expect a long job; pass refitEvery=0 "
+                "(auto) or k>1 for grouped refits", values.shape[1])
+        fn = functools.partial(arima_scores, refit_every=refit)
+    else:
+        fn = dbscan_scores
+    return (lambda: (jnp.asarray(values), jnp.asarray(mask))), fn
 
-    Returns (algo_calc [S,T], stddev [S], anomaly [S,T]) as numpy.
-    `refit_every` applies to ARIMA only (see `effective_refit`).
-    With `mesh` (a jax.sharding.Mesh with >1 device), scoring shards
-    over the mesh; results are identical to the local path for
-    series-sharded meshes (time_shards=1 — the job_mesh() default).
-    Time sharding engages in two cases, both bit-approximate in the
-    psum-reduced stddev (anomaly flags exactly ON the threshold can
-    differ): an explicitly time-sharded mesh, or automatically for
-    EWMA when the batch has fewer series than devices and T ≥
-    LONG_SERIES_T (sequence parallelism instead of idle devices).
-    """
+
+def _choose_kernel(values, mask, algo, refit_every, mesh):
     if algo not in ALGORITHMS:
         raise ValueError(
             f"algo must be one of {ALGORITHMS}, got {algo!r}")
     if mesh is not None and mesh.size > 1:
         if values.shape[0] >= mesh.size:
-            return _score_series_sharded(values, mask, algo,
-                                         refit_every, mesh)
+            return _sharded_kernel(values, mask, algo, refit_every,
+                                   mesh)
         if algo == "EWMA" and values.shape[1] >= LONG_SERIES_T:
             # Few series, long T: series-DP would idle most devices,
             # so re-mesh the same devices sequence-parallel and scan
@@ -137,27 +144,47 @@ def score_series(values: np.ndarray, mask: np.ndarray, algo: str,
                 "%d of them)", values.shape[0], values.shape[1],
                 tmesh.devices.size,
                 mesh.devices.size - values.shape[0])
-            return _score_series_sharded(values, mask, algo,
-                                         refit_every, tmesh)
-    if algo == "EWMA":
-        calc, std, anom = ewma_scores(values, mask)
-    elif algo == "ARIMA":
-        refit = effective_refit(algo, refit_every, values.shape[1])
-        if refit > 1:
-            logger.info(
-                "ARIMA grouped-refit approximation active: refitting "
-                "every %d steps over T=%d (reference-exact is "
-                "refitEvery=1)", refit, values.shape[1])
-        elif values.shape[1] > 8192:
-            logger.warning(
-                "ARIMA exact refit-per-step over T=%d steps is "
-                "O(T^2) — expect a long job; pass refitEvery=0 "
-                "(auto) or k>1 for grouped refits", values.shape[1])
-        calc, std, anom = arima_scores(values, mask,
-                                       refit_every=refit)
-    else:
-        calc, std, anom = dbscan_scores(values, mask)
-    return np.asarray(calc), np.asarray(std), np.asarray(anom)
+            return _sharded_kernel(values, mask, algo, refit_every,
+                                   tmesh)
+    return _local_kernel(values, mask, algo, refit_every)
+
+
+def _part(progress, name: str):
+    return progress.part(name) if progress else contextlib.nullcontext()
+
+
+def _score_on_device(values, mask, algo, refit_every, mesh, progress):
+    """The batch to the device(s) and through one algorithm's kernel,
+    each waited for, so that the two parts are the transfer's and the
+    kernel's own time (dispatch until the results are ready). Returns
+    the kernel's device arrays, still padded as the mesh needed."""
+    place, kernel = _choose_kernel(values, mask, algo, refit_every, mesh)
+    with _part(progress, "transfer"):
+        placed = jax.block_until_ready(place())
+    with _part(progress, "kernel"):
+        return jax.block_until_ready(kernel(*placed))[:3]
+
+
+def score_series(values: np.ndarray, mask: np.ndarray, algo: str,
+                 refit_every: int = 1, mesh=None):
+    """Run one algorithm over a padded [S, T] batch.
+
+    Returns (algo_calc [S,T], stddev [S], anomaly [S,T]) as numpy.
+    `refit_every` applies to ARIMA only (see `effective_refit`).
+    With `mesh` (a jax.sharding.Mesh with >1 device), scoring shards
+    over the mesh; results are identical to the local path for
+    series-sharded meshes (time_shards=1 — the job_mesh() default).
+    Time sharding engages in two cases, both bit-approximate in the
+    psum-reduced stddev (anomaly flags exactly ON the threshold can
+    differ): an explicitly time-sharded mesh, or automatically for
+    EWMA when the batch has fewer series than devices and T ≥
+    LONG_SERIES_T (sequence parallelism instead of idle devices).
+    """
+    S, T = values.shape
+    calc, std, anom = _score_on_device(values, mask, algo, refit_every,
+                                       mesh, None)
+    return (np.asarray(calc)[:S, :T], np.asarray(std)[:S],
+            np.asarray(anom)[:S, :T])
 
 
 def run_tad(db: FlowDatabase, algo: str, spec: TadQuerySpec,
@@ -192,7 +219,8 @@ def run_tad(db: FlowDatabase, algo: str, spec: TadQuerySpec,
     if progress:
         progress.stage("score")
     result = detect_anomalies(batch, algo, tad_id, now=now,
-                              refit_every=spec.refit_every, mesh=mesh)
+                              refit_every=spec.refit_every, mesh=mesh,
+                              progress=progress)
 
     if progress:
         progress.stage("write")
@@ -205,22 +233,37 @@ def run_tad(db: FlowDatabase, algo: str, spec: TadQuerySpec,
 
 def detect_anomalies(batch: SeriesBatch, algo: str, tad_id: str,
                      now: Optional[int] = None, refit_every: int = 1,
-                     mesh=None) -> ColumnarBatch:
+                     mesh=None, progress=None) -> ColumnarBatch:
     """Score a series batch and build its tadetector result rows, one
     for each anomalous point in `np.nonzero` order, as one batch made
-    from the kernel's arrays: no row is a Python object."""
-    refit = effective_refit(
-        algo, refit_every,
-        batch.values.shape[1] if batch.n_series else 0)
-    sidx = tidx = np.zeros(0, np.intp)
-    if batch.n_series:
-        # Pass the resolved cadence so the emitted refitEvery and the
-        # one actually executed cannot drift (effective_refit is
-        # idempotent).
-        calc, std, anom = score_series(
-            batch.values, batch.mask, algo,
-            refit_every=refit if refit else 1, mesh=mesh)
-        sidx, tidx = np.nonzero(anom)
+    from the kernel's arrays: no row is a Python object. `progress`
+    (the job's, in its `score` stage) times the transfer, the kernel
+    and the rows as that stage's parts and counts what was scored."""
+    n_steps = batch.values.shape[1] if batch.n_series else 0
+    refit = effective_refit(algo, refit_every, n_steps)
+    if not batch.n_series:
+        return _result_batch(1, _no_anomaly_row(
+            batch.agg_type, algo, tad_id, now, refit))
+    # Pass the resolved cadence so the emitted refitEvery and the one
+    # actually executed cannot drift (effective_refit is idempotent).
+    scores = _score_on_device(batch.values, batch.mask, algo,
+                              refit if refit else 1, mesh, progress)
+    if progress:
+        progress.scored(
+            algo, batch.n_series, int(np.count_nonzero(batch.mask)),
+            fits=batch.n_series * -(-n_steps // refit) if refit else 0)
+    with _part(progress, "rows"):
+        return _result_rows(batch, scores, algo, tad_id, now, refit)
+
+
+def _result_rows(batch: SeriesBatch, scores, algo: str, tad_id: str,
+                 now: Optional[int], refit: int) -> ColumnarBatch:
+    """Fetch the kernel's arrays and gather the anomalous points'
+    rows."""
+    S, T = batch.values.shape
+    calc, std, anom = (np.asarray(a) for a in scores)
+    calc, std, anom = calc[:S, :T], std[:S], anom[:S, :T]
+    sidx, tidx = np.nonzero(anom)
     if sidx.size == 0:
         return _result_batch(1, _no_anomaly_row(
             batch.agg_type, algo, tad_id, now, refit))

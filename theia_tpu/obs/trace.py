@@ -27,6 +27,8 @@ Three layers share one ring:
     to it, so the slowest-span exemplar of an op carries the stage
     breakdown of its slowest request. `StageMarks` is the same for
     code that already announces its boundaries (`JobProgress.stage`).
+    `part(name, hist)` (PR 34) names a piece of the stage open around
+    it and leaves that stage's own seconds whole.
     An ingress span also records `cpuMs` (`time.thread_time()` delta):
     wall − cpu − lock wait − device fetch is what the thread spent
     waiting for the interpreter or the OS. `background(task)` is a
@@ -57,7 +59,7 @@ Span records are plain dicts (JSON-ready for GET /debug/traces):
 
     {"op", "startTime", "durationMs", "parent", "thread",
      # when stages ran inside it / on an ingress span:
-     "stagesMs": {name: ms}, "cpuMs",
+     "stagesMs": {name: ms}, "partsMs": {name: ms}, "cpuMs",
      # present under a sampled trace context:
      "traceId", "spanId", "parentSpanId", "node", ...attrs}
 
@@ -270,8 +272,8 @@ class Span:
 
     __slots__ = ("op", "attrs", "_t0", "_start", "parent", "context",
                  "_parent_span_id", "_ingress", "_traceparent",
-                 "_explicit_ctx", "_sample_env", "stages", "_stage",
-                 "_cpu0", "_ann", "_hist")
+                 "_explicit_ctx", "_sample_env", "stages", "parts",
+                 "_stage", "_cpu0", "_ann", "_hist")
 
     def __init__(self, op: str, attrs: Dict[str, object],
                  ingress: bool = False,
@@ -291,6 +293,8 @@ class Span:
         self._start = 0.0
         #: stage name → summed self seconds (None until one runs)
         self.stages: Optional[Dict[str, float]] = None
+        #: part name → summed seconds, each also inside its stage's
+        self.parts: Optional[Dict[str, float]] = None
         self._stage: Optional["Stage"] = None   # innermost open stage
         self._cpu0 = 0.0
         self._ann = None
@@ -382,6 +386,9 @@ class Span:
         if self.stages:
             record["stagesMs"] = {k: round(v * 1e3, 4)
                                   for k, v in self.stages.items()}
+        if self.parts:
+            record["partsMs"] = {k: round(v * 1e3, 4)
+                                 for k, v in self.parts.items()}
         if exc_type is not None:
             record["error"] = exc_type.__name__
         record.update(self.attrs)
@@ -390,14 +397,17 @@ class Span:
 
 class Stage:
     """One timed part of the work inside the thread's innermost span
-    (see the module docstring). Reusable only sequentially."""
+    (see the module docstring). Reusable only sequentially. With
+    `within` it is a named part of the stage that is open around it:
+    that stage keeps the part's seconds as its own."""
 
-    __slots__ = ("name", "hist", "seconds", "_span", "_outer", "_t0",
-                 "_nested", "_ann")
+    __slots__ = ("name", "hist", "within", "seconds", "_span", "_outer",
+                 "_t0", "_nested", "_ann")
 
-    def __init__(self, name: str, hist=None) -> None:
+    def __init__(self, name: str, hist=None, within: bool = False) -> None:
         self.name = name
         self.hist = hist
+        self.within = within
         self.seconds = 0.0              # own time of the last run
         self._span: Optional[Span] = None
         self._outer: Optional["Stage"] = None
@@ -428,11 +438,15 @@ class Stage:
         sp = self._span
         if sp is not None:
             sp._stage = self._outer
-            if self._outer is not None:
-                self._outer._nested += whole
-            if sp.stages is None:
-                sp.stages = {self.name: own}
+            if self.within:
+                if sp.parts is None:
+                    sp.parts = {}
+                sp.parts[self.name] = sp.parts.get(self.name, 0.0) + own
             else:
+                if self._outer is not None:
+                    self._outer._nested += whole
+                if sp.stages is None:
+                    sp.stages = {}
                 sp.stages[self.name] = sp.stages.get(self.name,
                                                      0.0) + own
             self._span = self._outer = None
@@ -448,6 +462,19 @@ def stage(name: str, hist=None) -> Stage:
 
     `hist` is a histogram child (or None)."""
     return Stage(name, hist)
+
+
+def part(name: str, hist=None) -> Stage:
+    """Context manager around a named part of the stage that is open:
+
+        with part("job.score.kernel", _M_PART):
+            out = jax.block_until_ready(kernel(x, mask))
+
+    Timed and annotated like a stage, put on the span's `partsMs`
+    and taken from nothing: the enclosing stage's seconds still hold
+    it, so `stagesMs` adds up to the span as before and the parts of
+    a stage explain its time without changing it."""
+    return Stage(name, hist, within=True)
 
 
 def add_stage(name: str, seconds: float) -> None:

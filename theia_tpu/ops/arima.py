@@ -168,7 +168,8 @@ def arima_walk_forward(y: jnp.ndarray, mask: jnp.ndarray,
             # m just reads eps[m−2].
             m_fit = jnp.maximum(g * k, 3)
             w = (idx < (m_fit - 1)).astype(y_row.dtype)
-            phi, theta = _fit_prefix(d, w)
+            with jax.named_scope("fit"):
+                phi, theta = _fit_prefix(d, w)
 
             def step(eps_prev, t):
                 d_prev = jnp.where(t >= 1, d[jnp.maximum(t - 1, 0)],
@@ -177,8 +178,9 @@ def arima_walk_forward(y: jnp.ndarray, mask: jnp.ndarray,
                 eps_t = jnp.where(t == 0, 0.0, eps_t)
                 return eps_t, eps_t
 
-            _, eps = jax.lax.scan(step, jnp.array(0.0, y_row.dtype),
-                                  idx)
+            with jax.named_scope("css"):
+                _, eps = jax.lax.scan(
+                    step, jnp.array(0.0, y_row.dtype), idx)
             ms = g * k + jnp.arange(k)
             last = jnp.clip(ms - 2, 0, T - 2)
             d_hat = phi * d[last] + theta * eps[last]
@@ -226,8 +228,9 @@ def arima_scores(x: jnp.ndarray, mask: jnp.ndarray,
     gm = jnp.exp(log_gm)[..., None]
     xs = safe_x / gm
 
-    lam = boxcox_lambda(xs, mask)
-    y = boxcox_transform(xs, lam)
+    with jax.named_scope("boxcox"):
+        lam = boxcox_lambda(xs, mask)
+        y = boxcox_transform(xs, lam)
     # Auto-size the group chunk: each chunk materializes an
     # [S, chunk, T] f32 eps stack — budget it at ~256 MiB so 24h@1s
     # series fit alongside the rest of the working set.
@@ -238,6 +241,7 @@ def arima_scores(x: jnp.ndarray, mask: jnp.ndarray,
     preds = inv_boxcox(preds_bc, lam) * gm
     preds = jnp.where(ok[..., None] & mask, preds, 0.0)
 
-    std = masked_stddev_samp(x, mask)
+    with jax.named_scope("stddev"):
+        std = masked_stddev_samp(x, mask)
     anomaly = (jnp.abs(x - preds) > std[..., None]) & mask & ok[..., None]
     return preds, std, anomaly

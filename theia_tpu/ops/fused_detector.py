@@ -20,7 +20,9 @@ decisions (tests/test_device_path.py holds both engines to that).
 The T-tick scan over the [T, U] slot tile has a Pallas TPU kernel
 (`THEIA_FUSED_PALLAS=auto|1|0|interpret`): one VMEM-resident pass per
 128-lane slot block with the tick loop unrolled in-register, instead of
-the lax.scan's per-tick HLO while-loop. `auto` (the default) engages it
+the lax.scan's per-tick HLO while-loop (tiles of at most
+PALLAS_MAX_TICKS ticks; a longer one takes the scan, since unrolling
+is paid at trace time). `auto` (the default) engages it
 only on TPU backends; everywhere else — tier-1 CI included — the plain
 jnp scan keeps the semantics on CPU. `interpret` runs the Pallas kernel
 through the interpreter so its logic is testable without hardware.
@@ -43,6 +45,14 @@ from .sketch import CmsState, KMeansState, cms_query, cms_update, kmeans_step
 #: Slot tiles arrive padded to powers of two >= 64; the one 64-wide
 #: bucket is padded up to a full lane block inside the kernel wrapper.
 PALLAS_BLOCK_U = 128
+#: The kernel unrolls its tick loop, so tracing and compiling it grow
+#: with the tile's ticks (at 2,048 ticks x 8 shards a process's first
+#: block waited out the request's 120 s with every program in the
+#: compile cache; my chip runs, PR 34). A tile with more ticks than
+#: this, a block that carries hundreds of points a connection, takes
+#: the `lax.scan`, whose one traced tick serves any length; the tiles
+#: of 4-point blocks, coalesced or not, stay on the kernel.
+PALLAS_MAX_TICKS = 64
 
 
 class ShardInputs(NamedTuple):
@@ -167,7 +177,7 @@ def _stream_half(stream: StreamState, inp: ShardInputs, alpha,
     with jax.named_scope("gather"):
         sub = StreamState(*(a[inp.slots] for a in stream))
     with jax.named_scope("scan"):
-        if use_pallas:
+        if use_pallas and inp.x.shape[0] <= PALLAS_MAX_TICKS:
             sub, anomalies = _scan_tile_pallas(sub, inp.x, inp.active,
                                                alpha, interpret)
         else:

@@ -113,8 +113,10 @@ def build_parser() -> argparse.ArgumentParser:
                      help="scope to one cluster in a multicluster store")
     tad.add_argument("--refit-every", "--refit_every",
                      dest="refit_every", type=int, default=1,
-                     help="ARIMA refit cadence (1=exact per-step, "
-                          "0=auto for long series)")
+                     help="ARIMA refit cadence (1 = a fit at every "
+                          "step, T fits of up to T points; k>1 = one "
+                          "fit every k steps; 0 = auto: "
+                          "max(1, T // 2048))")
 
     npr = sub.add_parser("npr", help="network policy recommendation")
     _add_common_job_flags(npr)

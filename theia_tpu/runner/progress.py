@@ -10,7 +10,9 @@ kept in memory for in-process callers.
 Every stage is also timed, here and nowhere else, for every job kind:
 `theia_job_stage_seconds{kind,stage}`, the same seconds as `stagesMs`
 on the enclosing `job.run` span, and a profiler annotation
-`job.<stage>` while a capture runs (obs/trace.py StageMarks).
+`job.<stage>` while a capture runs (obs/trace.py StageMarks). A stage
+may name its parts (`part`: `job.score.transfer`, `.kernel`, `.rows`),
+which are timed the same way and leave the stage's seconds whole.
 """
 
 from __future__ import annotations
@@ -30,6 +32,21 @@ _M_STAGE = _metrics.histogram(
     "Wall time of one stage of a job run (read, tensorize, score, "
     "write, ...: the stages JobProgress announces)",
     labelnames=("kind", "stage"))
+_M_PART = _metrics.histogram(
+    "theia_job_stage_part_seconds",
+    "Wall time of one named part of a job's stage (score: transfer, "
+    "kernel, rows); the stage's own seconds include it",
+    labelnames=("kind", "stage", "part"))
+_M_SERIES_SCORED = _metrics.counter(
+    "theia_job_series_scored_total",
+    "Series a job's kernel scored", labelnames=("kind", "algo"))
+_M_POINTS_SCORED = _metrics.counter(
+    "theia_job_points_scored_total",
+    "Valid points of those series", labelnames=("kind", "algo"))
+_M_ARIMA_FITS = _metrics.counter(
+    "theia_job_arima_fits_total",
+    "Prefix fits of ARIMA jobs: series x refit groups, each one "
+    "Hannan-Rissanen fit and one residual recursion over the series")
 _M_ROWS_WRITTEN = _metrics.counter(
     "theia_job_rows_written_total",
     "Result rows a job inserted into its result table as one batch",
@@ -69,6 +86,23 @@ class JobProgress:
                 self._completed += 1
             self._current = name
         self._flush()
+
+    def part(self, name: str):
+        """Context manager around a named part of the stage that is
+        running: `job.<stage>.<name>` on the span and in a capture,
+        `theia_job_stage_part_seconds{kind,stage,part}`."""
+        stage = self._current
+        return _trace.part(
+            f"job.{stage}.{name}",
+            _M_PART.labels(kind=self.kind, stage=stage, part=name))
+
+    def scored(self, algo: str, series: int, points: int,
+               fits: int = 0) -> None:
+        """Count what the `score` stage's kernel was given."""
+        _M_SERIES_SCORED.labels(kind=self.kind, algo=algo).inc(series)
+        _M_POINTS_SCORED.labels(kind=self.kind, algo=algo).inc(points)
+        if fits:
+            _M_ARIMA_FITS.inc(fits)
 
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
