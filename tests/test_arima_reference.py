@@ -125,3 +125,35 @@ def test_job_rows_carry_the_references_forecast_deviation_and_cadence(
         assert r["throughputStandardDeviation"] == pytest.approx(
             std[s], rel=REL)
         assert r["refitEvery"] == k
+
+
+@pytest.mark.parametrize("refit_every,n_steps,turns", [
+    (1, 160, 160), (4, 160, 40), (0, 4200, 2100)])
+def test_a_job_counts_its_fits_and_its_loops_turns(refit_every, n_steps,
+                                                   turns):
+    """`theia_job_arima_loop_iterations_total` rises by what
+    `css_loop_iterations` says of the job's tensor and cadence, the
+    function the kernel lays its loop out with; the fits as before,
+    series x groups; an EWMA job moves neither."""
+    from theia_tpu.obs import metrics
+    from theia_tpu.ops.arima import css_loop_iterations
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+
+    def read():
+        return tuple(metrics.REGISTRY.get(name).value() for name in (
+            "theia_job_arima_fits_total",
+            "theia_job_arima_loop_iterations_total"))
+
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=2, points_per_series=n_steps, seed=11,
+        base_throughput=1e7)))
+    fits0, turns0 = read()
+    run_tad(db, "EWMA", TadQuerySpec(), progress=JobProgress(
+        "ewma", TAD_STAGES, kind="tad"))
+    assert read() == (fits0, turns0)
+    run_tad(db, "ARIMA", TadQuerySpec(refit_every=refit_every),
+            progress=JobProgress("arima", TAD_STAGES, kind="tad"))
+    k = effective_refit("ARIMA", refit_every, n_steps)
+    assert css_loop_iterations(2, n_steps, k) == turns
+    assert read() == (fits0 + 2 * -(-n_steps // k), turns0 + turns)

@@ -152,7 +152,7 @@ def test_the_arima_cell_holds_a_whole_retained_day_of_20_connections():
                                                "per_layer")}
     # a whole call of the kernel cannot be captured (the traffic
     # file's `trace_note`), so no device-trace metric of it is declared
-    assert layer - old == {"job.arima_fits"}
+    assert layer - old == {"job.arima_fits", "job.arima_loop_iterations"}
     assert old - layer == {"job.ewma_device_ms", "ewma_scores_roofline"}
     assert (t["trace_seconds"], t["trace_lead_seconds"]) == (1, 0)
     assert {"job.score_kernel_ms", "job.score_rows_ms"} <= layer & old
@@ -220,3 +220,44 @@ def test_reductions_the_cell_brings(name, data, want):
         run["clean"] = [0.0, 105.0]      # a traced run: before the profiler
         got = extend.resolve("reduction", name)(run, data["p"])
     assert got == (want if want is None else pytest.approx(want))
+
+
+def test_the_arima_cells_counters_reduce_to_a_jobs_figures():
+    """`job.arima_loop_iterations` (PR 35) and `job.arima_fits` read
+    the program's own exposition around one ARIMA job: the loop's
+    turns and the fits of that job. A manager that does not export the
+    counter (the parent of the PR that brought it) gives nothing."""
+    import time
+
+    from benchmarks import prom
+    from theia_tpu.analytics import TadQuerySpec, run_tad
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.ops.arima import css_loop_iterations
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+    from theia_tpu.store import FlowDatabase
+
+    cell = "parts-fused-12h.tad-arima"
+    harness.resolve_all(BENCH, cell, BENCH.traffic("tad-arima"))
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=3, points_per_series=48, seed=2)))
+    before = prom.parse(exposition.render())
+    run_tad(db, "ARIMA", TadQuerySpec(refit_every=4),
+            now=int(time.time()),
+            progress=JobProgress("job", TAD_STAGES, kind="tad"))
+    after = prom.parse(exposition.render())
+
+    def read(name, before, after):
+        reader = BENCH.reader("per_layer", name)
+        return extend.resolve("reduction", reader["reduce"])(
+            {"metrics_before": before, "metrics_after": after}, reader)
+
+    assert read("job.arima_fits", before, after) == 3 * 12
+    assert read("job.arima_loop_iterations", before, after) == 12 \
+        == css_loop_iterations(3, 48, 4)
+    series = BENCH.reader("per_layer",
+                          "job.arima_loop_iterations")["series"]
+    assert series in after
+    after.pop(series), before.pop(series, None)
+    assert read("job.arima_loop_iterations", before, after) is None

@@ -20,7 +20,9 @@ fitted *simultaneously*:
     with the Hannan–Rissanen two-stage regression — pure masked
     prefix-moment algebra (no iterative optimizer), vmapped over
     [series × prefix].
-  * The MA residual recursion is a `lax.scan` over time under `vmap`.
+  * The MA residual recursion runs once over time for all prefixes of
+    a slab of series, carrying eps[series, prefix] and keeping of every
+    step the one residual a forecast reads (`_css_forecasts`).
 
 Accuracy delta vs the reference (documented per SURVEY §7 hard-part b):
 Hannan–Rissanen is a consistent estimator of the same model but not the
@@ -43,6 +45,17 @@ from .masked import masked_count, masked_stddev_samp
 MIN_POINTS = 4        # reference requires len > 3  (:232)
 _RIDGE = 1e-6
 _CLIP = 0.99
+# The CSS recursion's carry, eps[series, refit groups]: how many
+# elements one slab of series may hold (16 MiB of float32; the loop
+# reads and writes it once an iteration, and on a TPU v5e the
+# recursion over 4,096 series x 2,048 groups took 182 ms as one slab
+# of 2^23 elements, 99 ms as two of 2^22, 116 ms as eight of 2^20), and
+# the most steps one loop iteration runs straight-line (an iteration
+# costs 2.2 us + 0.19 us a step there; the bound keeps the compiled
+# program small when a caller asks for a refit every thousands of
+# steps).
+CSS_CARRY_ELEMENTS = 1 << 22
+CSS_BLOCK_STEPS = 64
 
 
 def boxcox_llf(lam: jnp.ndarray, x: jnp.ndarray,
@@ -130,11 +143,111 @@ def _fit_prefix(d: jnp.ndarray, w: jnp.ndarray):
             jnp.clip(theta, -_CLIP, _CLIP))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("refit_every", "group_chunk"))
+def css_plan(n_series: int, n_steps: int, refit_every: int):
+    """How the CSS recursion of an [n_series, n_steps] batch is laid
+    out, from its static shapes alone: (slabs, series a slab, loop
+    iterations a slab, steps an iteration).
+
+    The recursion carries eps[series, groups] through time, so a slab
+    takes as many series as keep that carry within CSS_CARRY_ELEMENTS.
+    One loop iteration runs a block of steps straight-line: a group's
+    whole window of `refit_every` steps or, where that is longer than
+    CSS_BLOCK_STEPS, its largest divisor that is not (a block never
+    spans two windows, so the column it records is fixed)."""
+    k = refit_every
+    n_groups = -(-n_steps // k)
+    n_slabs = max(1, -(-n_series
+                       // max(1, CSS_CARRY_ELEMENTS // n_groups)))
+    block = max(b for b in range(1, min(k, CSS_BLOCK_STEPS) + 1)
+                if k % b == 0)
+    return (n_slabs, -(-n_series // n_slabs), n_groups * (k // block),
+            block)
+
+
+def css_loop_iterations(n_series: int, n_steps: int,
+                        refit_every: int) -> int:
+    """Trip count of the recursion's sequential loop as compiled for
+    one call, summed over its slabs: what `arima_walk_forward` itself
+    lays out (`css_plan`)."""
+    n_slabs, _, n_blocks, _ = css_plan(n_series, n_steps, refit_every)
+    return n_slabs * n_blocks
+
+
+def _fit_groups(d_row: jnp.ndarray, k: int, n_groups: int,
+                group_chunk: int):
+    """(phi, theta), each [n_groups], of one series' differences
+    d_row [T-1]: group g is fitted on the prefix available at its
+    first step, max(g·k, 3) points. Groups evaluate in
+    `group_chunk`-sized chunks via lax.map, because each fit's masked
+    sums run over the whole row."""
+    idx = jnp.arange(d_row.shape[0])
+
+    def fit_group(g):
+        with jax.named_scope("fit"):
+            m_fit = jnp.maximum(g * k, 3)
+            w = (idx < (m_fit - 1)).astype(d_row.dtype)
+            return _fit_prefix(d_row, w)
+
+    gs = jnp.arange(n_groups)
+    if n_groups <= group_chunk:
+        return jax.vmap(fit_group)(gs)
+    pad = (-n_groups) % group_chunk
+    gs = jnp.concatenate([gs, jnp.zeros(pad, gs.dtype)])
+    phi, theta = jax.lax.map(jax.vmap(fit_group),
+                             gs.reshape(-1, group_chunk))
+    return phi.reshape(-1)[:n_groups], theta.reshape(-1)[:n_groups]
+
+
+def _css_forecasts(d_cur, d_prev, phi, theta, block: int):
+    """The forecast differences of one slab of series, [S, G·k]:
+    column m is φ d_(m-2) + θ eps_(m-2) under the (φ, θ) [S, G] of
+    group m // k, eps by the CSS recursion
+    eps_t = d_t − φ d_(t-1) − θ eps_(t-1), eps_t = 0 for t ≤ 0.
+
+    One pass over time for all groups. Group g reads eps only at
+    t = g·k + j − 2, j < k, so the one column of the carry eps[S, G]
+    that anybody reads at step t is (t + 2) // k. Loop iteration b
+    runs the `block` steps from t = b·block − 2 straight-line on the
+    carry, and the same steps on that column alone, whose forecasts it
+    keeps. `d_cur` and `d_prev` [S, G·k] hold d_t and d_(t-1) in
+    column t + 2 and 0 where t ≤ 0, so that no step needs a mask.
+
+    Inside, series are the minor axis (the carry is [G, S], a step's
+    differences a row), so that no array has `block` for its minor
+    axis, which the device would pad to a whole tile."""
+    S, G = phi.shape
+    n_blocks = d_cur.shape[1] // block
+    phi, theta = phi.T, theta.T
+
+    def steps(eps, cur, prev, phi, theta):
+        seen = []
+        for j in range(block):
+            eps = cur[j:j + 1] - phi * prev[j:j + 1] - theta * eps
+            seen.append(eps)
+        return seen
+
+    def run_block(eps, xs):
+        b, cur, prev = xs                               # [block, S]
+        own = functools.partial(jax.lax.dynamic_slice_in_dim,
+                                start_index=b // (n_blocks // G),
+                                slice_size=1, axis=0)
+        phi_g, theta_g = own(phi), own(theta)
+        read = jnp.concatenate(steps(own(eps), cur, prev, phi_g, theta_g))
+        return (steps(eps, cur, prev, phi, theta)[-1],
+                phi_g * cur + theta_g * read)
+
+    def by_block(a):                      # [S, G·k] → [n_blocks, block, S]
+        return a.T.reshape(n_blocks, block, S)
+
+    _, d_hat = jax.lax.scan(
+        run_block, jnp.zeros((G, S), phi.dtype),
+        (jnp.arange(n_blocks), by_block(d_cur), by_block(d_prev)))
+    return d_hat.reshape(-1, S).T
+
+
+@functools.partial(jax.jit, static_argnames=("refit_every",))
 def arima_walk_forward(y: jnp.ndarray, mask: jnp.ndarray,
-                       refit_every: int = 1,
-                       group_chunk: int = 512) -> jnp.ndarray:
+                       refit_every: int = 1) -> jnp.ndarray:
     """Walk-forward one-step forecasts for a padded [S, T] Box-Cox batch.
 
     pred[:, :3] = y[:, :3] (the reference's train prefix is passed
@@ -142,63 +255,48 @@ def arima_walk_forward(y: jnp.ndarray, mask: jnp.ndarray,
     prefix of y.
 
     `refit_every=k` groups prefixes: the fit for steps [g·k, (g+1)·k)
-    uses the prefix of length max(g·k, 3), and one CSS residual
-    recursion per group serves all its steps — k=1 is the reference's
+    uses the prefix of length max(g·k, 3) — k=1 is the reference's
     exact refit-per-step semantics; k>1 trades refit freshness for a
     k× compute cut on long series (the 24h@1s scale where per-step
-    refits are infeasible for any implementation). Groups evaluate in
-    `group_chunk`-sized chunks via lax.map, so peak memory is
-    O(S · group_chunk · T) instead of the O(S · T²) a full vmap over
-    prefixes would materialize.
-    """
+    refits are infeasible for any implementation). With G = ⌈T / k⌉
+    groups: first every group's fit, [S, G] (`_fit_groups`, chunked
+    over groups); then one CSS residual recursion over time for all
+    groups at once, which keeps of each step the one residual a
+    forecast reads and turns it into that forecast (`_css_forecasts`;
+    eps_t for t < m−1 doesn't depend on the prefix cutoff, so step m
+    just reads its group's eps_(m−2)), over slabs of series
+    (`css_plan`)."""
     S, T = y.shape
     k = refit_every
     n_groups = -(-T // k)
     y0 = jnp.where(mask, y, 0.0)
+    d = y0[:, 1:] - y0[:, :-1]                            # [S, T-1]
 
-    def per_series(y_row):
-        d = y_row[1:] - y_row[:-1]            # [T-1]
-        idx = jnp.arange(T - 1)
+    # Each chunk of fits holds [S, chunk, T-1] masked products: budget
+    # them at ~256 MiB of f32 so 24h@1s series fit alongside the rest
+    # of the working set.
+    chunk = max(1, min(512, (256 << 20) // max(1, 4 * S * T)))
+    phi, theta = jax.vmap(
+        lambda d_row: _fit_groups(d_row, k, n_groups, chunk))(d)
 
-        def group_preds(g):
-            # Fit on the prefix available at the group's first step;
-            # CSS recursion eps_t = d_t − φ d_{t-1} − θ eps_{t-1}
-            # (eps_0 = 0) runs once with the group's params — eps_t for
-            # t < m−1 doesn't depend on the prefix cutoff, so each step
-            # m just reads eps[m−2].
-            m_fit = jnp.maximum(g * k, 3)
-            w = (idx < (m_fit - 1)).astype(y_row.dtype)
-            with jax.named_scope("fit"):
-                phi, theta = _fit_prefix(d, w)
+    def from_step_minus_2(a):      # d[:, 1:] or d[:, :-1] → [S, G·k]
+        return jnp.pad(a, ((0, 0), (3, k)))[:, :n_groups * k]
 
-            def step(eps_prev, t):
-                d_prev = jnp.where(t >= 1, d[jnp.maximum(t - 1, 0)],
-                                   0.0)
-                eps_t = d[t] - phi * d_prev - theta * eps_prev
-                eps_t = jnp.where(t == 0, 0.0, eps_t)
-                return eps_t, eps_t
+    d_cur = from_step_minus_2(d[:, 1:])
+    d_prev = from_step_minus_2(d[:, :-1])
+    n_slabs, slab, _, block = css_plan(S, T, k)
 
-            with jax.named_scope("css"):
-                _, eps = jax.lax.scan(
-                    step, jnp.array(0.0, y_row.dtype), idx)
-            ms = g * k + jnp.arange(k)
-            last = jnp.clip(ms - 2, 0, T - 2)
-            d_hat = phi * d[last] + theta * eps[last]
-            return y_row[jnp.clip(ms - 1, 0, T - 1)] + d_hat
+    def slabs(a):                      # [S, n] → [n_slabs, slab, n]
+        return jnp.pad(a, ((0, n_slabs * slab - S), (0, 0))).reshape(
+            n_slabs, slab, -1)
 
-        gs = jnp.arange(n_groups)
-        if n_groups <= group_chunk:
-            preds = jax.vmap(group_preds)(gs).reshape(-1)[:T]
-        else:
-            pad = (-n_groups) % group_chunk
-            gs = jnp.concatenate([gs, jnp.zeros(pad, gs.dtype)])
-            preds = jax.lax.map(
-                jax.vmap(group_preds),
-                gs.reshape(-1, group_chunk)).reshape(-1)[:T]
-        ms_all = jnp.arange(T)
-        return jnp.where(ms_all < 3, y_row, preds)
-
-    return jax.vmap(per_series)(y0)
+    with jax.named_scope("css"):
+        d_hat = jax.lax.map(
+            lambda args: _css_forecasts(*args, block=block),
+            tuple(slabs(a) for a in (d_cur, d_prev, phi, theta)))
+    d_hat = d_hat.reshape(n_slabs * slab, -1)[:S, :T]
+    preds = jnp.pad(y0, ((0, 0), (1, 0)))[:, :T] + d_hat
+    return jnp.where(jnp.arange(T) < 3, y0, preds)
 
 
 @functools.partial(jax.jit, static_argnames=("refit_every",))
@@ -231,13 +329,7 @@ def arima_scores(x: jnp.ndarray, mask: jnp.ndarray,
     with jax.named_scope("boxcox"):
         lam = boxcox_lambda(xs, mask)
         y = boxcox_transform(xs, lam)
-    # Auto-size the group chunk: each chunk materializes an
-    # [S, chunk, T] f32 eps stack — budget it at ~256 MiB so 24h@1s
-    # series fit alongside the rest of the working set.
-    S, T = x.shape
-    chunk = max(1, min(512, (256 << 20) // max(1, 4 * S * T)))
-    preds_bc = arima_walk_forward(y, mask, refit_every=refit_every,
-                                  group_chunk=chunk)
+    preds_bc = arima_walk_forward(y, mask, refit_every=refit_every)
     preds = inv_boxcox(preds_bc, lam) * gm
     preds = jnp.where(ok[..., None] & mask, preds, 0.0)
 

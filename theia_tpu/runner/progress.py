@@ -46,7 +46,13 @@ _M_POINTS_SCORED = _metrics.counter(
 _M_ARIMA_FITS = _metrics.counter(
     "theia_job_arima_fits_total",
     "Prefix fits of ARIMA jobs: series x refit groups, each one "
-    "Hannan-Rissanen fit and one residual recursion over the series")
+    "Hannan-Rissanen fit and one column of the residual recursion's "
+    "carry")
+_M_ARIMA_LOOP_ITERATIONS = _metrics.counter(
+    "theia_job_arima_loop_iterations_total",
+    "Trip count of the sequential loop of ARIMA jobs' residual "
+    "recursion as compiled for the job's shape, summed over its slabs "
+    "of series (ops.arima.css_loop_iterations)")
 _M_ROWS_WRITTEN = _metrics.counter(
     "theia_job_rows_written_total",
     "Result rows a job inserted into its result table as one batch",
@@ -97,12 +103,13 @@ class JobProgress:
             _M_PART.labels(kind=self.kind, stage=stage, part=name))
 
     def scored(self, algo: str, series: int, points: int,
-               fits: int = 0) -> None:
+               fits: int = 0, loop_iterations: int = 0) -> None:
         """Count what the `score` stage's kernel was given."""
         _M_SERIES_SCORED.labels(kind=self.kind, algo=algo).inc(series)
         _M_POINTS_SCORED.labels(kind=self.kind, algo=algo).inc(points)
         if fits:
             _M_ARIMA_FITS.inc(fits)
+            _M_ARIMA_LOOP_ITERATIONS.inc(loop_iterations)
 
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
