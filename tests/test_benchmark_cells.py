@@ -35,6 +35,21 @@ from benchmarks.tests.test_tad_arima import (            # noqa: F401
     test_the_kernels_bytes_and_steps_at_the_cells_shape,
     test_the_references_own_rows_are_correct,
 )
+from benchmarks.tests.test_tad_dbscan import (           # noqa: F401
+    a_pair_at_eps_exactly,
+    test_a_job_that_did_not_complete_and_an_answer_that_is_missing
+    as test_a_dbscan_job_that_did_not_complete,
+    test_a_job_that_found_nothing_where_the_reference_did,
+    test_a_perturbed_answer_is_not_correct
+    as test_a_perturbed_dbscan_answer_is_not_correct,
+    test_float32_in_the_programs_place_is_correct
+    as test_float32_in_the_dbscan_kernels_place_is_correct,
+    test_less_than_for_at_most_loses_the_pair_at_eps,
+    test_the_bfloat16_control_fails_a_limit_and_float64_none
+    as test_the_bfloat16_dbscan_control_fails_a_limit,
+    test_the_kernels_bytes_and_pair_tests_at_the_cells_shape,
+    test_the_references_own_rows_are_correct_and_hold_every_class,
+)
 
 BENCH = manifest.load()
 CELLS = [w["name"] for w in BENCH.doc["workloads"]]
@@ -158,6 +173,78 @@ def test_the_arima_cell_holds_a_whole_retained_day_of_20_connections():
     assert {"job.score_kernel_ms", "job.score_rows_ms"} <= layer & old
 
 
+def test_the_dbscan_cell_holds_a_whole_retained_day_of_80_connections():
+    """The time axis is whole (12 h at 1 s), the cut is in connections,
+    the spikes are spread, and everything but the kernel, the law and
+    the check is `parts-fused-12h.tad-arima`'s."""
+    cfg = BENCH.config("theia-parts-fused-12h-ns-1x1")
+    sib = BENCH.config("theia-parts-fused-12h-1x1")
+    assert (cfg["env"], cfg["manager_args"], cfg["expect"]) \
+        == (sib["env"], sib["manager_args"], sib["expect"])
+    entry = next(c for c in BENCH.doc["configs"]
+                 if c["name"] == cfg["name"])
+    assert entry["reduced"] == ["retained_connections",
+                                "checkpoint_interval_s"]
+    assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    assert "reference's decisions" in cfg["guarantees"]["job_result"]
+    t = BENCH.traffic("tad-dbscan")
+    arima = BENCH.traffic("tad-arima")
+    assert t["generator"] == {
+        "law": "spread_spikes", "connections_per_producer": 80,
+        "conns_per_block": 80, "points_per_conn": 400,
+        "interval_seconds": 1, "base_throughput": 1e7,
+        "spike_rate": 2e-4, "spike_magnitude_low": 5,
+        "spike_magnitude_high": 200}
+    producer, jobs = t["workers"]
+    assert producer == {**arima["workers"][0], "preload_blocks": 108,
+                        "prepared_blocks": 108}
+    points = producer["preload_blocks"] * t["generator"]["points_per_conn"]
+    assert points == cfg["points_per_connection"] == 43200 == 12 * 3600
+    assert cfg["retained_connections"] == 80
+    assert 80 * points == cfg["retained_window_rows"] == 3456000
+    assert 80 * t["generator"]["points_per_conn"] == 32000  # a block
+    assert cfg["source_retained_connections"] * points \
+        == cfg["source_retained_window_rows"] == 172800000
+    assert jobs["job"] == {**arima["workers"][1]["job"],
+                           "spec": {"jobType": "DBSCAN"}}
+    assert t["checks"] == ["acks", "store_totals", "detector_series",
+                           "tad_dbscan"]
+    check_file = extend.module("check", "tad_dbscan")
+    assert set(t["limits"]) == set(check_file.limits) == {
+        "dbscan_decision_mismatch", "dbscan_stddev_gap"}
+    assert set(t["limits_why"]) == set(t["limits"]) | {"exact"}
+    assert (t["trace_seconds"], t["trace_lead_seconds"]) == (8, 4)
+    cell = "parts-fused-12h-ns.tad-dbscan"
+    assert BENCH.cell(cell)["chips"] == 1
+    assert {m["name"] for m in BENCH.metrics_of(cell, "end_to_end")} \
+        == {"job_turnaround_s", "setup_s"}
+    layer = {m["name"] for m in BENCH.metrics_of(cell, "per_layer")}
+    old = {m["name"] for m in BENCH.metrics_of(
+        "parts-fused-12h.tad-arima", "per_layer")}
+    assert layer - old == {"job.dbscan_pair_tests", "job.dbscan_device_ms",
+                           "dbscan_noise_roofline"}
+    assert old - layer == {"job.arima_fits", "job.arima_loop_iterations"}
+    for name in layer - old:
+        m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+        assert (m["layer"], m["moves"], m["workloads"]) == (
+            "DBSCAN kernel", "job_turnaround_s", [cell])
+    assert len(BENCH.doc["workloads"]) == 6
+
+
+def test_the_dbscan_cell_is_rehearsed_on_the_cpu_backend():
+    """`benchmarks/selftest.py`'s rehearsal of the cell at a tiny size:
+    manager child, preload, warm-up job, window, checks; plumbing only,
+    no number of it is a result."""
+    from benchmarks import selftest
+
+    (out,) = selftest.rehearse(
+        BENCH.cell("parts-fused-12h-ns.tad-dbscan"), trace=False)
+    assert out["correct"] and out["failed"] == 0
+    assert set(out["metrics"]) == {"job_turnaround_s", "setup_s"}
+    assert {"dbscan_decision_mismatch", "dbscan_stddev_gap",
+            "dbscan_calc_gap", "jobs_not_completed"} <= set(out["checks"])
+
+
 def test_an_operator_no_manager_answers_ends_in_the_warm_up(tmp_path):
     """The parent commit has no /admin/checkpoint: the role must end
     its worker in set-up (exit 1 for the run), never hang or go on."""
@@ -261,3 +348,60 @@ def test_the_arima_cells_counters_reduce_to_a_jobs_figures():
     assert series in after
     after.pop(series), before.pop(series, None)
     assert read("job.arima_loop_iterations", before, after) is None
+
+
+def test_the_dbscan_cells_metrics_reduce_to_a_jobs_figures():
+    """`job.dbscan_pair_tests` reads the program's own exposition
+    around one DBSCAN job: the sum over series of (valid points)^2; a
+    manager without the counter gives nothing. The two device metrics
+    read a trace's `module:jit_dbscan_noise` line: ms a call, and the
+    kernel file's bytes at the memory's peak over it."""
+    import time
+
+    from benchmarks import prom
+    from benchmarks.kernels import dbscan_noise as kernel
+    from theia_tpu.analytics import TadQuerySpec, run_tad
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.ops.dbscan import dbscan_noise
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+    from theia_tpu.store import FlowDatabase
+
+    cell = "parts-fused-12h-ns.tad-dbscan"
+    traffic = BENCH.traffic("tad-dbscan")
+    harness.resolve_all(BENCH, cell, traffic)
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=3, points_per_series=48, seed=2)))
+    before = prom.parse(exposition.render())
+    run_tad(db, "DBSCAN", TadQuerySpec(), now=int(time.time()),
+            progress=JobProgress("job", TAD_STAGES, kind="tad"))
+    after = prom.parse(exposition.render())
+
+    def read(name, data):
+        reader = BENCH.reader("per_layer", name)
+        return extend.resolve("reduction", reader["reduce"])(data, reader)
+
+    counters = {"metrics_before": before, "metrics_after": after}
+    assert read("job.dbscan_pair_tests", counters) == 3 * 48 * 48 \
+        == kernel.pair_tests(3, 48)
+    series = BENCH.reader("per_layer", "job.dbscan_pair_tests")["series"]
+    assert series in after
+    after.pop(series), before.pop(series, None)
+    assert read("job.dbscan_pair_tests", counters) is None
+
+    # the jitted program's name is what the trace's line carries
+    assert dbscan_noise.__name__ == "dbscan_noise"
+    traced = {
+        "traffic": traffic, "device": {"kind": "TPU v5 lite"},
+        "specs": [{"role": "producer", "preload_blocks": 108},
+                  {"role": "jobs"}],
+        "trace": {"ops": [("module:jit_dbscan_noise(123)", 3.0, 2),
+                          ("module:jit_masked_stddev", 0.001, 2)],
+                  "busy_s": 3.1, "window_s": 8.0}}
+    assert read("job.dbscan_device_ms", traced) == pytest.approx(1500.0)
+    assert read("dbscan_noise_roofline", traced) == pytest.approx(
+        100 * (20736320 / 819e9) / 1.5)
+    traced["trace"]["ops"] = traced["trace"]["ops"][1:]
+    assert read("job.dbscan_device_ms", traced) is None
+    assert read("dbscan_noise_roofline", traced) is None

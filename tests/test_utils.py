@@ -128,3 +128,44 @@ def test_support_bundle_includes_manager_logs():
     finally:
         controller.shutdown()
         clear_logs()
+
+
+def test_retain_freed_memory_sets_the_allocators_thresholds():
+    """On glibc the three `mallopt` calls are taken, a second time
+    too; the pad is over one 64 MiB heap, which is what keeps a
+    thread's heaps mapped, and the threshold is glibc's largest."""
+    import ctypes
+
+    from theia_tpu.utils import alloc
+
+    if not hasattr(ctypes.CDLL(None), "mallopt"):
+        pytest.skip("the C library has no mallopt")
+    assert alloc.retain_freed_memory() is True
+    assert alloc.retain_freed_memory() is True
+    assert alloc.TOP_PAD > 64 << 20
+    assert alloc.MMAP_THRESHOLD == 32 << 20
+
+
+@pytest.mark.parametrize("library", ["missing", "no_mallopt", "refuses"])
+def test_retain_freed_memory_says_when_it_could_not(monkeypatch, library):
+    """No C library to open, one without `mallopt`, or one that
+    refuses a value: False, and nothing raised."""
+    from theia_tpu.utils import alloc
+
+    class Refuses:
+        def __init__(self):
+            self.argtypes = self.restype = None
+
+        def __call__(self, param, value):
+            return 0
+
+    def cdll(name):
+        if library == "missing":
+            raise OSError("no such library")
+        lib = type("Lib", (), {})()
+        if library == "refuses":
+            lib.mallopt = Refuses()
+        return lib
+
+    monkeypatch.setattr(alloc.ctypes, "CDLL", cdll)
+    assert alloc.retain_freed_memory() is False

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..ops import arima_scores, dbscan_scores, ewma_scores
 from ..ops.arima import css_loop_iterations
+from ..ops.dbscan import pair_tests
 from ..schema import TADETECTOR_SCHEMA, ColumnarBatch, StringDictionary
 from ..store import FlowDatabase
 from ..utils import get_logger
@@ -254,7 +255,8 @@ def detect_anomalies(batch: SeriesBatch, algo: str, tad_id: str,
             algo, batch.n_series, int(np.count_nonzero(batch.mask)),
             fits=batch.n_series * -(-n_steps // refit) if refit else 0,
             loop_iterations=css_loop_iterations(
-                batch.n_series, n_steps, refit) if refit else 0)
+                batch.n_series, n_steps, refit) if refit else 0,
+            pair_tests=pair_tests(batch.mask) if algo == "DBSCAN" else 0)
     with _part(progress, "rows"):
         return _result_rows(batch, scores, algo, tad_id, now, refit)
 

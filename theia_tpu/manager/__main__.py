@@ -200,6 +200,13 @@ def main(argv=None) -> None:
     if args.auth_token is None:
         args.auth_token = os.environ.get("THEIA_AUTH_TOKEN") or None
 
+    # a server asks for the same memory again with every job's scan
+    # and every block's decode (utils/alloc.py)
+    from ..utils.alloc import retain_freed_memory
+    if not retain_freed_memory():
+        log.v(1).info("the C library has no mallopt: freed memory goes "
+                      "back to the system as it chooses")
+
     from ..utils import env_int
     ttl = args.ttl_seconds
     if ttl is None:

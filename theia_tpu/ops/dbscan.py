@@ -24,6 +24,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .masked import masked_stddev_samp
 
@@ -39,10 +40,21 @@ def dbscan_noise(x: jnp.ndarray, mask: jnp.ndarray,
     within = (jnp.abs(x[..., :, None] - x[..., None, :]) <= eps)
     pair_valid = mask[..., :, None] & mask[..., None, :]
     within &= pair_valid
-    neighbor_counts = jnp.sum(within, axis=-1)
+    with jax.named_scope("counts"):
+        neighbor_counts = jnp.sum(within, axis=-1)
     core = (neighbor_counts >= min_samples) & mask
-    reachable = jnp.any(within & core[..., None, :], axis=-1)
+    with jax.named_scope("reach"):
+        reachable = jnp.any(within & core[..., None, :], axis=-1)
     return mask & ~core & ~reachable
+
+
+def pair_tests(mask) -> int:
+    """Pairs one pass of the definition tests over a padded [S, T]
+    batch: the sum over series of (valid points)^2. `dbscan_noise`
+    makes two such passes (`counts`, `reach`), whichever formulation
+    runs them."""
+    n = np.count_nonzero(np.asarray(mask), axis=-1).astype(np.int64)
+    return int(np.sum(n * n))
 
 
 def _interpret() -> bool:
@@ -96,7 +108,8 @@ def dbscan_scores(x: jnp.ndarray, mask: jnp.ndarray,
         anomaly = dbscan_noise(x, mask, eps=eps,
                                min_samples=min_samples)
     calc = jnp.zeros_like(x)
-    std = masked_stddev_samp(x, mask)
+    with jax.named_scope("stddev"):
+        std = masked_stddev_samp(x, mask)
     return calc, std, anomaly
 
 

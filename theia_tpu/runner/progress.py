@@ -53,6 +53,11 @@ _M_ARIMA_LOOP_ITERATIONS = _metrics.counter(
     "Trip count of the sequential loop of ARIMA jobs' residual "
     "recursion as compiled for the job's shape, summed over its slabs "
     "of series (ops.arima.css_loop_iterations)")
+_M_DBSCAN_PAIR_TESTS = _metrics.counter(
+    "theia_job_dbscan_pair_tests_total",
+    "Pairs of points one pass of DBSCAN jobs' definition tests: the "
+    "sum over series of (valid points)^2 (ops.dbscan.pair_tests); the "
+    "kernel makes two passes, neighbour counts and reachability")
 _M_ROWS_WRITTEN = _metrics.counter(
     "theia_job_rows_written_total",
     "Result rows a job inserted into its result table as one batch",
@@ -103,13 +108,16 @@ class JobProgress:
             _M_PART.labels(kind=self.kind, stage=stage, part=name))
 
     def scored(self, algo: str, series: int, points: int,
-               fits: int = 0, loop_iterations: int = 0) -> None:
+               fits: int = 0, loop_iterations: int = 0,
+               pair_tests: int = 0) -> None:
         """Count what the `score` stage's kernel was given."""
         _M_SERIES_SCORED.labels(kind=self.kind, algo=algo).inc(series)
         _M_POINTS_SCORED.labels(kind=self.kind, algo=algo).inc(points)
         if fits:
             _M_ARIMA_FITS.inc(fits)
             _M_ARIMA_LOOP_ITERATIONS.inc(loop_iterations)
+        if pair_tests:
+            _M_DBSCAN_PAIR_TESTS.inc(pair_tests)
 
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
