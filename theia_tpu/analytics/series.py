@@ -86,6 +86,54 @@ class SeriesBatch:
         return self.values.shape[0]
 
 
+CONNECTION_KEY = ("sourceIP", "sourceTransportPort", "destinationIP",
+                  "destinationTransportPort", "protocolIdentifier",
+                  "flowStartSeconds")
+NS_COLUMNS = ("sourcePodNamespace", "destinationPodNamespace")
+
+
+def _pod_sides(by_name: bool):
+    """(direction, namespace column, name-or-labels column) of the two
+    sides the pod mode unions."""
+    return (("inbound", "destinationPodNamespace",
+             "destinationPodName" if by_name else "destinationPodLabels"),
+            ("outbound", "sourcePodNamespace",
+             "sourcePodName" if by_name else "sourcePodLabels"))
+
+
+def _group_key(spec: TadQuerySpec) -> Tuple[Tuple[str, ...], str]:
+    """Key columns and throughput reduction of the non-pod modes."""
+    if spec.agg_flow == "external":
+        return ("destinationIP",), "sum"
+    if spec.agg_flow == "svc":
+        return ("destinationServicePortName",), "sum"
+    return CONNECTION_KEY, "max"
+
+
+def read_columns(spec: TadQuerySpec) -> Tuple[str, ...]:
+    """The flow columns `build_series` reads for `spec`, a function of
+    the spec alone: the mode's group key, the time axis, the value,
+    and what the filters that are set touch. A job hands this to the
+    store's `select(columns=...)`; `build_series` takes its names
+    from the same `_group_key` / `_pod_sides` / `NS_COLUMNS`, and a
+    batch that lacks one raises KeyError."""
+    if spec.agg_flow == "pod":
+        names = [c for _, ns_col, id_col in _pod_sides(
+            bool(spec.pod_name)) for c in (ns_col, id_col)]
+    else:
+        names = list(_group_key(spec)[0])
+        if spec.agg_flow == "external":
+            names.append("flowType")
+        if spec.start_time is not None:
+            names.append("flowStartSeconds")
+    names += ["flowEndSeconds", "throughput"]
+    if spec.ns_ignore_list:
+        names += NS_COLUMNS
+    if spec.cluster_uuid:
+        names.append("clusterUUID")
+    return tuple(dict.fromkeys(names))
+
+
 def _codes_for_strings(batch: ColumnarBatch, name: str,
                        values: Sequence[str]) -> List[int]:
     """Codes of `values` in the batch's dictionary (missing → -1 which
@@ -105,7 +153,7 @@ def _ns_ignore_mask(batch: ColumnarBatch,
     mask = np.ones(len(batch), dtype=bool)
     if not ns_ignore_list:
         return mask
-    for col in ("sourcePodNamespace", "destinationPodNamespace"):
+    for col in NS_COLUMNS:
         codes = np.asarray(
             _codes_for_strings(batch, col, ns_ignore_list), np.int64)
         mask &= ~np.isin(np.asarray(batch[col], np.int64), codes)
@@ -203,14 +251,13 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
     if spec.end_time is not None:
         base &= np.asarray(flows["flowEndSeconds"]) < spec.end_time
 
+    key_names, op = _group_key(spec)
     if spec.agg_flow == "external":
         base &= np.asarray(flows["flowType"]) == 3
         if spec.external_ip:
             code = flows.dicts["destinationIP"].lookup(spec.external_ip)
             base &= (np.asarray(flows["destinationIP"])
                      == (-1 if code is None else code))
-        key_names: Tuple[str, ...] = ("destinationIP",)
-        op = "sum"
     elif spec.agg_flow == "svc":
         if spec.svc_port_name:
             code = flows.dicts["destinationServicePortName"].lookup(
@@ -219,13 +266,6 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
                      == (-1 if code is None else code))
         else:
             base &= np.asarray(flows["destinationServicePortName"]) != 0
-        key_names = ("destinationServicePortName",)
-        op = "sum"
-    else:
-        key_names = ("sourceIP", "sourceTransportPort", "destinationIP",
-                     "destinationTransportPort", "protocolIdentifier",
-                     "flowStartSeconds")
-        op = "max"
 
     # Materialize only the columns this query touches (masking all 52
     # through ColumnarBatch.filter costs more than the grouping itself
@@ -244,11 +284,7 @@ def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
     """Inbound ∪ outbound pod aggregation (reference :511-565)."""
     by_name = bool(spec.pod_name)
     parts = []  # (keys [n,2], time, thr, direction_id)
-    for direction, ns_col, id_col in (
-            ("inbound", "destinationPodNamespace",
-             "destinationPodName" if by_name else "destinationPodLabels"),
-            ("outbound", "sourcePodNamespace",
-             "sourcePodName" if by_name else "sourcePodLabels")):
+    for direction, ns_col, id_col in _pod_sides(by_name):
         m = base.copy()
         if by_name:
             code = flows.dicts[id_col].lookup(spec.pod_name)
@@ -282,15 +318,12 @@ def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
     key_mat, values, times, mask = _group_and_pad(
         all_keys, all_t, all_v, "sum", dtype)
 
-    ns_dict = flows.dicts["destinationPodNamespace"]
-    id_dict = flows.dicts[
-        ("destinationPodName" if by_name else "destinationPodLabels")]
     # Source- and destination-side columns share string values but not
     # dictionaries; decode via the side each row came from is impossible
     # after the union, so decode against a merged lookup.
-    src_ns = flows.dicts["sourcePodNamespace"]
-    src_id = flows.dicts[
-        "sourcePodName" if by_name else "sourcePodLabels"]
+    (_, ns_in, id_in), (_, ns_out, id_out) = _pod_sides(by_name)
+    ns_dict, id_dict = flows.dicts[ns_in], flows.dicts[id_in]
+    src_ns, src_id = flows.dicts[ns_out], flows.dicts[id_out]
 
     def dual_decode(codes, primary, secondary, is_outbound):
         out = np.empty(len(codes), dtype=object)

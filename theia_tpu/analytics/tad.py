@@ -30,7 +30,8 @@ from ..ops.dbscan import pair_tests
 from ..schema import TADETECTOR_SCHEMA, ColumnarBatch, StringDictionary
 from ..store import FlowDatabase
 from ..utils import get_logger
-from .series import SeriesBatch, TadQuerySpec, build_series
+from .series import (SeriesBatch, TadQuerySpec, build_series,
+                     read_columns)
 
 logger = get_logger("tad")
 
@@ -212,9 +213,10 @@ def run_tad(db: FlowDatabase, algo: str, spec: TadQuerySpec,
 
     if progress:
         progress.stage("read")
-    flows = db.flows.scan()
+    flows = db.flows.select(columns=read_columns(spec))
 
     if progress:
+        progress.read(flows)
         progress.stage("tensorize")
     batch = build_series(flows, spec)
 

@@ -58,6 +58,17 @@ _M_DBSCAN_PAIR_TESTS = _metrics.counter(
     "Pairs of points one pass of DBSCAN jobs' definition tests: the "
     "sum over series of (valid points)^2 (ops.dbscan.pair_tests); the "
     "kernel makes two passes, neighbour counts and reachability")
+_M_READ_ROWS = _metrics.counter(
+    "theia_job_read_rows_total",
+    "Rows of the batch a job's read stage handed on",
+    labelnames=("kind",))
+_M_READ_COLUMNS = _metrics.counter(
+    "theia_job_read_columns_total",
+    "Columns of those batches: what the job's query names, not the "
+    "table's 52", labelnames=("kind",))
+_M_READ_BYTES = _metrics.counter(
+    "theia_job_read_bytes_total",
+    "Column bytes of those batches", labelnames=("kind",))
 _M_ROWS_WRITTEN = _metrics.counter(
     "theia_job_rows_written_total",
     "Result rows a job inserted into its result table as one batch",
@@ -65,6 +76,10 @@ _M_ROWS_WRITTEN = _metrics.counter(
 _M_BYTES_WRITTEN = _metrics.counter(
     "theia_job_bytes_written_total",
     "Column bytes of those batches", labelnames=("kind",))
+
+
+def _column_bytes(batch) -> int:
+    return sum(a.nbytes for a in batch.columns.values())
 
 
 class JobProgress:
@@ -107,6 +122,12 @@ class JobProgress:
             f"job.{stage}.{name}",
             _M_PART.labels(kind=self.kind, stage=stage, part=name))
 
+    def read(self, batch) -> None:
+        """Count the batch the `read` stage hands on."""
+        _M_READ_ROWS.labels(kind=self.kind).inc(len(batch))
+        _M_READ_COLUMNS.labels(kind=self.kind).inc(len(batch.columns))
+        _M_READ_BYTES.labels(kind=self.kind).inc(_column_bytes(batch))
+
     def scored(self, algo: str, series: int, points: int,
                fits: int = 0, loop_iterations: int = 0,
                pair_tests: int = 0) -> None:
@@ -122,8 +143,7 @@ class JobProgress:
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
         _M_ROWS_WRITTEN.labels(kind=self.kind).inc(len(batch))
-        _M_BYTES_WRITTEN.labels(kind=self.kind).inc(
-            sum(a.nbytes for a in batch.columns.values()))
+        _M_BYTES_WRITTEN.labels(kind=self.kind).inc(_column_bytes(batch))
 
     def done(self) -> None:
         self._marks.end()
