@@ -24,6 +24,12 @@ from benchmarks.tests.test_snapshot_prefix import (      # noqa: F401
     test_another_interval_than_the_configurations,
 )
 
+from benchmarks.tests.test_stage_usage_metrics import (  # noqa: F401
+    test_counter_rise_per_sums_a_bare_name_and_scales,
+    test_reader_gives_a_number_here_and_nothing_at_the_parent,
+    test_tensorize_parts_cover_the_stage_in_the_recorded_pair,
+    test_the_new_readers_are_the_ones_the_issue_lists,
+)
 from benchmarks.tests.test_tad_arima import (            # noqa: F401
     test_a_job_that_did_not_complete_and_an_answer_that_is_missing,
     test_a_perturbed_answer_is_not_correct,
@@ -123,7 +129,7 @@ def test_the_checkpoint_cell_is_saturate_with_the_snapshot_on():
         "default-ckpt.ingest-saturate-ckpt", "per_layer")}
     assert {m["name"] for m in BENCH.metrics_of(
         "default.ingest-saturate", "per_layer")} < layer
-    assert len({n for n in layer if n.startswith("ckpt.")}) == 12
+    assert len({n for n in layer if n.startswith("ckpt.")}) == 13
 
 
 def test_the_arima_cell_holds_a_whole_retained_day_of_20_connections():

@@ -28,8 +28,12 @@ from theia_tpu.store import wire as _wire
 
 
 @pytest.fixture(autouse=True)
-def _clean_ring():
+def _clean_ring(monkeypatch):
     trace.reset()
+    # every span's stages are read, however short it is (the budget
+    # has its own test below)
+    monkeypatch.setattr(trace, "READING_BUDGET", 1.0)
+    trace._local.read_after = 0.0
     yield
     trace.set_annotation_factory(None)
     trace.reset()
@@ -155,6 +159,276 @@ def test_background_span_feeds_histogram_and_ring():
     assert rec["rows"] == 3 and rec["durationMs"] >= 5.0
 
 
+# -- what a stage's thread did with its wall ------------------------------
+
+def _spin(seconds):
+    """Burn `seconds` of this thread's CPU time (however long the
+    machine takes to grant them)."""
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
+        pass
+
+
+def _touch(buf):
+    """Write one byte of every 4 KiB page of `buf`."""
+    memoryview(buf)[::4096] = b"\1" * (len(buf) // 4096)
+
+
+#: a stage's CPU comes from the thread's rusage, which the kernel
+#: brings up to date at its scheduler tick: one run is good to a tick
+#: (1-4 ms on a plain kernel, 10 ms under a sandboxed one)
+TICK_MS = 12.0
+
+needs_rusage = pytest.mark.skipif(
+    not trace.HAS_THREAD_RUSAGE,
+    reason="no getrusage(RUSAGE_THREAD) on this platform")
+
+
+def test_a_stage_that_spins_and_one_that_sleeps_report_their_cpu():
+    series = trace.StageSeries("t_usage_seconds", "test", ("stage",))
+    spin, sleep = series.labels(stage="spin"), series.labels(stage="sleep")
+    with trace.span("usage"):
+        with trace.stage("u.spin", spin) as st_spin:
+            _spin(0.1)
+        with trace.stage("u.sleep", sleep) as st_sleep:
+            time.sleep(0.1)
+    rec = _span("usage")
+    wall, cpu = rec["stagesMs"], rec["stagesCpuMs"]
+    assert set(cpu) == set(wall) == {"u.spin", "u.sleep"}
+    assert 100.0 - TICK_MS <= cpu["u.spin"] <= wall["u.spin"] + TICK_MS
+    assert cpu["u.sleep"] <= TICK_MS < 0.2 * wall["u.sleep"]
+    # the same numbers on the stage, the series and the span
+    assert st_spin.cpu_seconds * 1e3 == pytest.approx(cpu["u.spin"],
+                                                      abs=0.01)
+    assert st_sleep.seconds >= 0.1
+    assert spin.cpu.sum() == pytest.approx(st_spin.cpu_seconds)
+    assert spin.wall.count() == spin.cpu.count() == 1
+    assert sleep.cpu.sum() < 0.2 * sleep.wall.sum()
+    reg = metrics.REGISTRY
+    assert reg.get("t_usage_cpu_seconds").labelnames == ("stage",)
+    if trace.HAS_THREAD_RUSAGE:
+        assert set(rec["stagesSysMs"]) == set(rec["stagesFaults"]) \
+            == set(wall)
+        assert rec["stagesSysMs"]["u.spin"] <= cpu["u.spin"]
+        assert reg.get("t_usage_minor_faults_total").kind == "counter"
+    else:       # absent, never 0
+        assert "stagesFaults" not in rec and "stagesSysMs" not in rec
+        assert spin.faults is None and st_spin.faults is None
+    assert "stagesGcMs" not in rec      # nothing collected: left out
+
+
+@needs_rusage
+def test_a_fresh_mapping_faults_many_times_what_a_touched_one_does():
+    import mmap
+    series = trace.StageSeries("t_fault_seconds", "test", ("stage",))
+    fresh, again = (series.labels(stage=s) for s in ("fresh", "again"))
+    buf = mmap.mmap(-1, 64 << 20)
+    try:
+        with trace.span("faults"):
+            with trace.stage("f.fresh", fresh):
+                _touch(buf)
+            with trace.stage("f.again", again):
+                _touch(buf)
+    finally:
+        buf.close()
+    flt = _span("faults")["stagesFaults"]
+    # a ratio: pages are 4 KiB here and 2 MiB under transparent huge
+    # pages, and the second pass faults next to nothing either way
+    assert flt["f.fresh"] >= 16 and flt["f.fresh"] >= 8 * (
+        flt["f.again"] + 1)
+    assert fresh.faults.value() == flt["f.fresh"]
+    assert again.faults.value() == flt["f.again"]
+
+
+def test_nested_stages_account_cpu_and_faults_to_themselves():
+    import mmap
+    buf = mmap.mmap(-1, 16 << 20)
+    try:
+        with trace.span("nest"):
+            with trace.stage("n.outer") as outer:
+                _spin(0.05)
+                with trace.stage("n.inner") as inner:
+                    _spin(0.1)
+                    _touch(buf)
+    finally:
+        buf.close()
+    rec = _span("nest")
+    cpu = rec["stagesCpuMs"]
+    assert 100.0 - TICK_MS <= cpu["n.inner"] \
+        <= rec["stagesMs"]["n.inner"] + TICK_MS
+    # the outer stage keeps its own 50 ms, not the inner's 100
+    assert 50.0 - TICK_MS <= cpu["n.outer"] <= 50.0 + 2 * TICK_MS
+    assert cpu["n.outer"] + cpu["n.inner"] <= rec["durationMs"] + TICK_MS
+    assert outer.cpu_seconds * 1e3 == pytest.approx(cpu["n.outer"],
+                                                    abs=0.01)
+    if trace.HAS_THREAD_RUSAGE:
+        flt = rec["stagesFaults"]
+        assert flt["n.inner"] >= 8 * (flt["n.outer"] + 1)
+        assert inner.faults == flt["n.inner"]
+
+
+def test_a_part_is_whole_and_leaves_its_stages_numbers_whole():
+    series = trace.StageSeries("t_part_seconds", "test", ("part",))
+    child = series.labels(part="p")
+    with trace.span("parts"):
+        with trace.stage("s.stage") as st:
+            _spin(0.03)
+            with trace.part("s.stage.p", child) as pt:
+                _spin(0.06)
+                with trace.stage("s.nested"):
+                    _spin(0.03)
+    rec = _span("parts")
+    # the part holds the stage nested in it; the stage that encloses
+    # the part loses the nested stage and keeps the part
+    assert rec["partsMs"]["s.stage.p"] >= 90.0 - TICK_MS
+    assert rec["partsCpuMs"]["s.stage.p"] >= 90.0 - TICK_MS
+    assert rec["stagesMs"]["s.stage"] >= 90.0 - TICK_MS
+    assert 90.0 - TICK_MS <= rec["stagesCpuMs"]["s.stage"]
+    assert 30.0 - TICK_MS <= rec["stagesCpuMs"]["s.nested"] \
+        <= 30.0 + 2 * TICK_MS
+    assert sum(rec["stagesMs"].values()) <= rec["durationMs"]
+    assert pt.cpu_seconds <= pt.seconds + TICK_MS / 1e3
+    assert st.cpu_seconds * 1e3 == pytest.approx(
+        rec["stagesCpuMs"]["s.stage"], abs=0.01)
+    assert child.cpu.count() == 1
+    if trace.HAS_THREAD_RUSAGE:
+        assert set(rec["partsFaults"]) == {"s.stage.p"}
+
+
+def test_neighbouring_edges_share_one_reading(monkeypatch):
+    """A stage's exit and the next one's enter, a few microseconds
+    apart on one thread, are one system call; edges further apart
+    than the window are not."""
+    calls = []
+    real = trace._read_usage
+
+    def counted():
+        calls.append(threading.get_ident())
+        return real()
+
+    monkeypatch.setattr(trace, "_read_usage", counted)
+    monkeypatch.setattr(trace, "SHARED_READING_SECONDS", 1e-3)
+
+    def two(gap):
+        time.sleep(0.005)               # past any earlier reading
+        del calls[:]
+        with trace.stage("near.a") as a:
+            _spin(0.005)
+        time.sleep(gap)
+        with trace.stage("near.b") as b:
+            _spin(0.005)
+        return len(calls), a, b
+
+    n, a, b = two(0.0)
+    assert n == 3                       # a's enter, the shared edge, b's exit
+    assert a.cpu_seconds + b.cpu_seconds >= 0.010 - TICK_MS / 1e3
+    assert two(0.005)[0] == 4
+    # another thread has its own reading: nothing is shared across
+    t = threading.Thread(target=two, args=(0.0,))
+    del calls[:]
+    t.start()
+    t.join(10)
+    assert not t.is_alive() and len(set(calls)) == 1
+
+
+def test_the_reading_budget_skips_spans_and_the_next_stands_for_them(
+        monkeypatch):
+    """A thread whose readings took more than READING_BUDGET of the
+    time since its last read span began times the next spans by the
+    wall alone; the first one read after that counts its CPU once for
+    each of them, so the series' sums and counts stay whole."""
+    series = trace.StageSeries("t_budget_seconds", "test", ("stage",))
+    child = series.labels(stage="b")
+    monkeypatch.setattr(trace, "_read_usage",
+                        lambda real=trace._read_usage: (
+                            time.sleep(0.01), real())[1])
+    monkeypatch.setattr(trace, "READING_BUDGET", 0.05)
+
+    def request():
+        with trace.span("budget.req"):
+            with trace.stage("b.stage", child) as st:
+                _spin(0.005)
+        return st
+
+    first = request()                   # read: two readings, 20 ms
+    assert first.cpu_seconds is not None
+    # 20 ms at 5 % are paid off 400 ms after it began
+    skipped = [request(), request()]
+    assert [st.cpu_seconds for st in skipped] == [None, None]
+    assert all(st.seconds >= 0.005 for st in skipped)
+    time.sleep(0.5)
+    last = request()
+    assert last.cpu_seconds >= 0.0
+    recs = [s for s in trace.recent(100) if s["op"] == "budget.req"]
+    assert [r.get("usageWeight") for r in recs] == [3, None, None, 1]
+    assert ["stagesCpuMs" in r for r in recs] == [True, False, False,
+                                                  True]
+    assert all("b.stage" in r["stagesMs"] for r in recs)
+    assert child.wall.count() == 4      # every run, by the wall
+    assert child.cpu.count() == 1 + 3   # the last one stands for three
+    assert child.cpu.sum() == pytest.approx(
+        first.cpu_seconds + 3 * last.cpu_seconds)
+    # the exemplar is the slowest span that says what it did
+    assert "usageWeight" in trace.slowest()["budget.req"]
+
+
+def test_add_stage_reports_the_pool_threads_numbers():
+    done = []
+
+    def leg():
+        with trace.stage("pool.leg") as st:     # no span on this thread
+            _spin(0.06)
+        done.append(st)
+
+    t = threading.Thread(target=leg)
+    with trace.span("request"):
+        t.start()
+        time.sleep(0.1)                         # this thread: no CPU
+        t.join(10)
+        assert not t.is_alive()
+        trace.add_stage(done[0])
+        trace.add_stage(done[0])                # a second leg adds up
+    rec = _span("request")
+    assert rec["stagesMs"]["pool.leg"] >= 120.0 - 2 * TICK_MS
+    assert rec["stagesCpuMs"]["pool.leg"] >= 120.0 - 2 * TICK_MS
+    assert rec["stagesCpuMs"]["pool.leg"] == pytest.approx(
+        2e3 * done[0].cpu_seconds, abs=0.01)
+    if trace.HAS_THREAD_RUSAGE:
+        assert rec["stagesFaults"]["pool.leg"] == 2 * done[0].faults
+    trace.add_stage(done[0])                    # outside a span: nothing
+
+
+def test_a_young_collection_lands_on_the_open_stage_and_the_counters():
+    def counters(gen):
+        return tuple(metrics.REGISTRY.get(name).labels(
+            generation=str(gen)).value() for name in (
+            "theia_gc_pause_seconds_total", "theia_gc_collections_total"))
+
+    trace.watch_gc()
+    try:
+        before = [counters(g) for g in range(3)]
+        with trace.span("collected"):
+            with trace.stage("gc.victim") as st:
+                with trace.part("gc.victim.part"):
+                    gc.collect(0)
+            with trace.stage("gc.bystander"):
+                pass
+        after = [counters(g) for g in range(3)]
+    finally:
+        trace.unwatch_gc()
+    rec = _span("collected")
+    assert list(rec["stagesGcMs"]) == ["gc.victim"]
+    assert 0 < rec["stagesGcMs"]["gc.victim"] <= rec["stagesMs"]["gc.victim"]
+    assert st.gc_seconds * 1e3 == pytest.approx(
+        rec["stagesGcMs"]["gc.victim"], abs=0.01)
+    assert after[0][1] >= before[0][1] + 1
+    assert after[0][0] - before[0][0] >= st.gc_seconds
+    assert after[2] == before[2]                # no full collection
+    assert not [s for s in trace.recent(100) if s["op"] == "bg.gc"]
+    text = __import__("theia_tpu.obs.prom", fromlist=["render"]).render()
+    assert 'theia_gc_collections_total{generation="0"}' in text
+
+
 def test_full_collections_are_reported_as_bg_gc():
     _, before = _hist("theia_background_seconds", task="gc")
     trace.watch_gc()
@@ -176,7 +450,7 @@ def test_full_collections_are_reported_as_bg_gc():
 
 def _detector_sums():
     return {key[0]: (child.sum(), child.count())
-            for key, child in DETECTOR_STAGE.children()}
+            for key, child in DETECTOR_STAGE.wall.children()}
 
 
 def _block(seed=3, n_series=64, points=4):
@@ -193,12 +467,12 @@ def test_detector_stages_sum_to_the_leg_over_four_shards():
         mgr.ingest(payload, stream="warm")       # compiles
         trace.reset()
         before = _detector_sums()
-        leg0 = ingest_mod._M_STAGE_DET.sum()
-        cpu0 = ingest_mod._M_STAGE_CPU_DET.count()
+        leg0 = ingest_mod._M_LEG_DET.wall.sum()
+        cpu0 = ingest_mod._M_LEG_DET.cpu.count()
         out = mgr.ingest(_block(seed=4)[1], stream="s")
     finally:
         mgr.close()
-    leg = ingest_mod._M_STAGE_DET.sum() - leg0
+    leg = ingest_mod._M_LEG_DET.wall.sum() - leg0
     after = _detector_sums()
     delta = {k: after[k][0] - before.get(k, (0.0, 0))[0] for k in after}
     count = {k: after[k][1] - before.get(k, (0.0, 0))[1] for k in after}
@@ -211,7 +485,7 @@ def test_detector_stages_sum_to_the_leg_over_four_shards():
     assert count["heavy_hitters"] == 4
     total = sum(delta.values())
     assert 0.8 * leg <= total <= leg * 1.001, (delta, leg)
-    assert ingest_mod._M_STAGE_CPU_DET.count() == cpu0 + 1
+    assert ingest_mod._M_LEG_DET.cpu.count() == cpu0 + 1
     # the same seconds ride on the request's span, so the slowest
     # exemplar carries the breakdown
     rec = _span("ingest.request")
@@ -357,14 +631,18 @@ def test_tad_run_and_its_result_answer_are_timed_by_part():
     for s in TAD_STAGES:
         assert _hist("theia_job_stage_seconds", kind="tad",
                      stage=s)[1] >= 1
-    # the parts of `score` lie inside it and take nothing from it
+    # the parts of a stage lie inside it and take nothing from it
     parts = run["partsMs"]
-    assert list(parts) == ["job.score." + p
-                           for p in ("transfer", "kernel", "rows")]
-    assert sum(parts.values()) <= run["stagesMs"]["job.score"]
-    for p in ("transfer", "kernel", "rows"):
-        assert _hist("theia_job_stage_part_seconds", kind="tad",
-                     stage="score", part=p)[1] >= 1
+    named = {"tensorize": ("keys", "group", "decode"),
+             "score": ("transfer", "kernel", "rows")}
+    assert list(parts) == [f"job.{s}.{p}" for s in named
+                           for p in named[s]]
+    for s, ps in named.items():
+        assert sum(parts[f"job.{s}.{p}"] for p in ps) \
+            <= run["stagesMs"]["job." + s]
+        for p in ps:
+            assert _hist("theia_job_stage_part_seconds", kind="tad",
+                         stage=s, part=p)[1] >= 1
 
 
 def test_get_of_a_completed_job_is_timed_counted_and_makes_no_row(
@@ -441,6 +719,121 @@ def test_run_tad_without_a_controller_still_times_stages():
             progress=JobProgress("lib-1", TAD_STAGES))
     assert _hist("theia_job_stage_seconds", kind="",
                  stage="score")[1] == n0 + 1
+
+
+@pytest.fixture(scope="module")
+def wide_db():
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=2048, points_per_series=24, seed=9)))
+    return db
+
+
+@pytest.mark.parametrize("mode", ["", "pod"])
+def test_tensorize_is_covered_by_its_three_parts(wide_db, mode):
+    """keys + group + decode are the stage but for its bookkeeping,
+    each observed once a job with its CPU beside it."""
+    kind = "parts-" + (mode or "connection")
+    with trace.span("job.run"):
+        run_tad(wide_db, "EWMA", TadQuerySpec(agg_flow=mode),
+                progress=JobProgress("p-" + mode, TAD_STAGES, kind=kind))
+    rec = _span("job.run")
+    names = ["job.tensorize." + p for p in ("keys", "group", "decode")]
+    assert [k for k in rec["partsMs"] if "tensorize" in k] == names
+    stage = rec["stagesMs"]["job.tensorize"]
+    covered = sum(rec["partsMs"][n] for n in names)
+    assert 0.85 * stage <= covered <= stage, rec["partsMs"]
+    assert sum(rec["partsCpuMs"][n] for n in names) \
+        <= rec["stagesCpuMs"]["job.tensorize"] + 1e-3
+    for p in ("keys", "group", "decode"):
+        labels = dict(kind=kind, stage="tensorize", part=p)
+        assert _hist("theia_job_stage_part_seconds", **labels)[1] == 1
+        assert _hist("theia_job_stage_part_cpu_seconds", **labels)[1] == 1
+    assert _hist("theia_job_stage_cpu_seconds", kind=kind,
+                 stage="tensorize")[1] == 1
+
+
+#: every series this file's mechanism adds, by what makes it appear
+STAGE_USAGE_SERIES = [
+    f"theia_{family}_{kind}" for family in (
+        "detector_stage", "ingest_stage", "job_stage", "job_stage_part",
+        "job_result", "dashboard_stage", "checkpoint_stage")
+    for kind in ("cpu_seconds_count", "minor_faults_total")
+] + ["theia_gc_pause_seconds_total", "theia_gc_collections_total"]
+
+
+def test_every_usage_series_is_served_after_one_of_each_request(
+        tmp_path):
+    """One ingest request, one job with its result fetched, one panel
+    and one snapshot: /metrics then has a sample of every CPU
+    histogram and fault counter beside its wall histogram, and the
+    slowest spans carry the fields."""
+    import urllib.request
+
+    from theia_tpu.manager import TheiaManagerServer
+    from theia_tpu.store import Checkpointer
+    db = FlowDatabase()
+    db.attach_wal(str(tmp_path / "wal"))
+    srv = TheiaManagerServer(db, port=0, ingest_shards=2, workers=1)
+    ck = Checkpointer(db, str(tmp_path / "db.npz"), interval=3600)
+    ck.start()
+    srv.attach_checkpointer(ck)
+    srv.start_background()
+
+    def call(path, data=None):
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.port}{path}", data=data,
+            method="GET" if data is None else "POST")
+        with urllib.request.urlopen(req, timeout=120) as r:
+            return r.read()
+
+    try:
+        call("/ingest?stream=s&seq=1", _block(n_series=48)[1])
+        rec = srv.controller.create("tad", {"jobType": "EWMA"})
+        assert srv.controller.wait_all(120)
+        assert rec.state == "COMPLETED", rec.status_dict()
+        call("/apis/intelligence.theia.antrea.io/v1alpha1/"
+             "throughputanomalydetectors/" + rec.name)
+        call("/dashboards/api/pod_to_pod")
+        assert json.loads(call("/admin/checkpoint", b""))["rows"]
+        text = call("/metrics").decode()
+        slowest = json.loads(call("/debug/traces"))["slowest"]
+    finally:
+        ck.stop()
+        srv.shutdown()
+        db.close_wal()
+    present = {line.split("{")[0].split(" ")[0]
+               for line in text.splitlines() if line[:1] != "#"}
+    wanted = [s for s in STAGE_USAGE_SERIES if trace.HAS_THREAD_RUSAGE
+              or not s.endswith("_minor_faults_total")]
+    assert not [s for s in wanted if s not in present]
+    for family in ("detector_stage", "job_stage", "job_stage_part",
+                   "job_result", "dashboard_stage", "checkpoint_stage"):
+        wall = f"theia_{family}_seconds_count{{"
+        cpu = f"theia_{family}_cpu_seconds_count{{"
+        # the same label sets on both, so a reader changes one name
+        assert sorted(ln.split("}")[0][len(cpu):]
+                      for ln in text.splitlines() if ln.startswith(cpu)) \
+            == sorted(ln.split("}")[0][len(wall):]
+                      for ln in text.splitlines() if ln.startswith(wall))
+    leg = 'theia_ingest_stage_cpu_seconds_count{stage="detector"} '
+    assert [int(ln[len(leg):]) >= 1 for ln in text.splitlines()
+            if ln.startswith(leg)] == [True]
+    for op, name in (("ingest.request", "detector.plan"),
+                     ("job.run", "job.tensorize"),
+                     ("dashboard.panel", "dash.scan"),
+                     ("bg.checkpoint", "checkpoint.hold")):
+        span = slowest[op]
+        assert name in span["stagesCpuMs"], (op, span)
+        assert span["stagesCpuMs"][name] <= \
+            span["stagesMs"][name] + TICK_MS
+        if trace.HAS_THREAD_RUSAGE:
+            assert name in span["stagesFaults"]
+            assert name in span["stagesSysMs"]
+    request = slowest["ingest.request"]
+    assert "ingest.detector" in request["partsCpuMs"]
+    assert "store.latch_wait" in request["stagesCpuMs"]
+    assert "job.tensorize.keys" in slowest["job.run"]["partsCpuMs"]
 
 
 # -- panels --------------------------------------------------------------

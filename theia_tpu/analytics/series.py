@@ -23,6 +23,7 @@ semantically meaningful order for the time-series detectors.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -235,53 +236,91 @@ def remove_meaningless_labels(labels_json: str) -> str:
         sort_keys=True)
 
 
-def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
-                 dtype=np.float64) -> SeriesBatch:
-    """Build the padded series batch for one TAD query."""
+def job_part(progress, name: str):
+    """A named part of the stage `progress` (a job's, or None) is in:
+    the context manager to do that part's work under."""
+    return progress.part(name) if progress else contextlib.nullcontext()
+
+
+def _base_mask(flows: ColumnarBatch, spec: TadQuerySpec) -> np.ndarray:
+    """The filters every mode applies: ignored namespaces, cluster."""
     base = _ns_ignore_mask(flows, spec.ns_ignore_list)
     if spec.cluster_uuid:
         code = flows.dicts["clusterUUID"].lookup(spec.cluster_uuid)
         base &= (np.asarray(flows["clusterUUID"])
                  == (-1 if code is None else code))
+    return base
+
+
+def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
+                 dtype=np.float64, progress=None) -> SeriesBatch:
+    """Build the padded series batch for one TAD query. `progress`
+    (the job's, in its `tensorize` stage) times the stage's three
+    parts, named by what each produces: `keys` (the filter masks, the
+    row selection and the key matrix), `group` (the value and time
+    columns through `_group_and_pad` into the padded tensors) and
+    `decode` (the series' keys as the result rows show them)."""
     if spec.agg_flow == "pod":
-        return _build_pod_series(flows, spec, base, dtype)
+        return _build_pod_series(flows, spec, dtype, progress)
 
-    if spec.start_time is not None:
-        base &= np.asarray(flows["flowStartSeconds"]) >= spec.start_time
-    if spec.end_time is not None:
-        base &= np.asarray(flows["flowEndSeconds"]) < spec.end_time
+    with job_part(progress, "keys"):
+        base = _base_mask(flows, spec)
+        if spec.start_time is not None:
+            base &= (np.asarray(flows["flowStartSeconds"])
+                     >= spec.start_time)
+        if spec.end_time is not None:
+            base &= np.asarray(flows["flowEndSeconds"]) < spec.end_time
 
-    key_names, op = _group_key(spec)
-    if spec.agg_flow == "external":
-        base &= np.asarray(flows["flowType"]) == 3
-        if spec.external_ip:
-            code = flows.dicts["destinationIP"].lookup(spec.external_ip)
-            base &= (np.asarray(flows["destinationIP"])
-                     == (-1 if code is None else code))
-    elif spec.agg_flow == "svc":
-        if spec.svc_port_name:
-            code = flows.dicts["destinationServicePortName"].lookup(
-                spec.svc_port_name)
-            base &= (np.asarray(flows["destinationServicePortName"])
-                     == (-1 if code is None else code))
-        else:
-            base &= np.asarray(flows["destinationServicePortName"]) != 0
+        key_names, op = _group_key(spec)
+        if spec.agg_flow == "external":
+            base &= np.asarray(flows["flowType"]) == 3
+            if spec.external_ip:
+                code = flows.dicts["destinationIP"].lookup(
+                    spec.external_ip)
+                base &= (np.asarray(flows["destinationIP"])
+                         == (-1 if code is None else code))
+        elif spec.agg_flow == "svc":
+            if spec.svc_port_name:
+                code = flows.dicts["destinationServicePortName"].lookup(
+                    spec.svc_port_name)
+                base &= (np.asarray(flows["destinationServicePortName"])
+                         == (-1 if code is None else code))
+            else:
+                base &= np.asarray(
+                    flows["destinationServicePortName"]) != 0
 
-    # Materialize only the columns this query touches (masking all 52
-    # through ColumnarBatch.filter costs more than the grouping itself
-    # on the tensorize hot path).
-    col = flows.column_selector(base)
+        # Materialize only the columns this query touches (masking all
+        # 52 through ColumnarBatch.filter costs more than the grouping
+        # itself on the tensorize hot path).
+        col = flows.column_selector(base)
 
-    key_cols = np.stack([col(c) for c in key_names], axis=1)
-    key_mat, values, times, mask = _group_and_pad(
-        key_cols, col("flowEndSeconds"), col("throughput"), op, dtype)
-    keys = _decode_keys(flows, key_names, key_mat)
+        key_cols = np.stack([col(c) for c in key_names], axis=1)
+    with job_part(progress, "group"):
+        key_mat, values, times, mask = _group_and_pad(
+            key_cols, col("flowEndSeconds"), col("throughput"), op,
+            dtype)
+    with job_part(progress, "decode"):
+        keys = _decode_keys(flows, key_names, key_mat)
     return SeriesBatch(key_names, keys, values, times, mask, spec.agg_type)
 
 
 def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
-                      base: np.ndarray, dtype) -> SeriesBatch:
+                      dtype, progress=None) -> SeriesBatch:
     """Inbound ∪ outbound pod aggregation (reference :511-565)."""
+    with job_part(progress, "keys"):
+        all_keys, all_t, all_v = _pod_rows(flows, spec)
+    with job_part(progress, "group"):
+        key_mat, values, times, mask = _group_and_pad(
+            all_keys, all_t, all_v, "sum", dtype)
+    with job_part(progress, "decode"):
+        key_names, keys = _decode_pod_keys(flows, spec, key_mat)
+    return SeriesBatch(key_names, keys, values, times, mask, "pod")
+
+
+def _pod_rows(flows: ColumnarBatch, spec: TadQuerySpec):
+    """The rows of both sides as (keys [n, 3], time, throughput): a
+    side's namespace and name-or-labels codes and its direction."""
+    base = _base_mask(flows, spec)
     by_name = bool(spec.pod_name)
     parts = []  # (keys [n,2], time, thr, direction_id)
     for direction, ns_col, id_col in _pod_sides(by_name):
@@ -305,8 +344,6 @@ def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
         parts.append((keys, col("flowEndSeconds"), col("throughput"),
                       direction))
 
-    id_name = "podName" if by_name else "podLabels"
-    key_names = ("podNamespace", id_name, "direction")
     dir_code = {"inbound": 0, "outbound": 1}
     all_keys = np.concatenate(
         [np.concatenate(
@@ -314,10 +351,15 @@ def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
          for k, _, _, d in parts], axis=0)
     all_t = np.concatenate([t for _, t, _, _ in parts])
     all_v = np.concatenate([v for _, _, v, _ in parts])
+    return all_keys, all_t, all_v
 
-    key_mat, values, times, mask = _group_and_pad(
-        all_keys, all_t, all_v, "sum", dtype)
 
+def _decode_pod_keys(flows: ColumnarBatch, spec: TadQuerySpec,
+                     key_mat: np.ndarray):
+    """(key names, the series' keys as strings) of the pod mode."""
+    by_name = bool(spec.pod_name)
+    id_name = "podName" if by_name else "podLabels"
+    key_names = ("podNamespace", id_name, "direction")
     # Source- and destination-side columns share string values but not
     # dictionaries; decode via the side each row came from is impossible
     # after the union, so decode against a merged lookup.
@@ -347,7 +389,7 @@ def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
         "direction": np.where(is_outbound, "outbound", "inbound").astype(
             object),
     }
-    return SeriesBatch(key_names, keys, values, times, mask, "pod")
+    return key_names, keys
 
 
 def _decode_keys(flows: ColumnarBatch, key_names, key_mat) -> Dict[

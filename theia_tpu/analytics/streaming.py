@@ -66,7 +66,7 @@ _M_DROPPED = _metrics.counter(
 # a block and _count{stage="dispatch"} / blocks is dispatches a block).
 # Declared here, the lowest module of the leg; manager/ingest.py takes
 # the children of its own stages from it.
-DETECTOR_STAGE = _metrics.histogram(
+DETECTOR_STAGE = _trace.StageSeries(
     "theia_detector_stage_seconds",
     "Host time of the detector leg by stage: remap (dictionary remap "
     "incl. the wait for its lock), partition, lock_wait (blocked on a "

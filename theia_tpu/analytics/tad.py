@@ -14,7 +14,6 @@ batch (kernels in theia_tpu.ops); the reference's per-row Python UDFs
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 import uuid
@@ -30,7 +29,7 @@ from ..ops.dbscan import pair_tests
 from ..schema import TADETECTOR_SCHEMA, ColumnarBatch, StringDictionary
 from ..store import FlowDatabase
 from ..utils import get_logger
-from .series import (SeriesBatch, TadQuerySpec, build_series,
+from .series import (SeriesBatch, TadQuerySpec, build_series, job_part,
                      read_columns)
 
 logger = get_logger("tad")
@@ -152,19 +151,15 @@ def _choose_kernel(values, mask, algo, refit_every, mesh):
     return _local_kernel(values, mask, algo, refit_every)
 
 
-def _part(progress, name: str):
-    return progress.part(name) if progress else contextlib.nullcontext()
-
-
 def _score_on_device(values, mask, algo, refit_every, mesh, progress):
     """The batch to the device(s) and through one algorithm's kernel,
     each waited for, so that the two parts are the transfer's and the
     kernel's own time (dispatch until the results are ready). Returns
     the kernel's device arrays, still padded as the mesh needed."""
     place, kernel = _choose_kernel(values, mask, algo, refit_every, mesh)
-    with _part(progress, "transfer"):
+    with job_part(progress, "transfer"):
         placed = jax.block_until_ready(place())
-    with _part(progress, "kernel"):
+    with job_part(progress, "kernel"):
         return jax.block_until_ready(kernel(*placed))[:3]
 
 
@@ -218,7 +213,7 @@ def run_tad(db: FlowDatabase, algo: str, spec: TadQuerySpec,
     if progress:
         progress.read(flows)
         progress.stage("tensorize")
-    batch = build_series(flows, spec)
+    batch = build_series(flows, spec, progress=progress)
 
     if progress:
         progress.stage("score")
@@ -259,7 +254,7 @@ def detect_anomalies(batch: SeriesBatch, algo: str, tad_id: str,
             loop_iterations=css_loop_iterations(
                 batch.n_series, n_steps, refit) if refit else 0,
             pair_tests=pair_tests(batch.mask) if algo == "DBSCAN" else 0)
-    with _part(progress, "rows"):
+    with job_part(progress, "rows"):
         return _result_rows(batch, scores, algo, tad_id, now, refit)
 
 

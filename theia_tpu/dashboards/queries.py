@@ -37,7 +37,7 @@ _M_PANEL = _metrics.histogram(
     "theia_dashboard_panel_seconds",
     "One /dashboards/api/<panel> request, server side: scan, "
     "aggregation and JSON encode", labelnames=("panel",))
-_M_STAGE = _metrics.histogram(
+_M_STAGE = _trace.StageSeries(
     "theia_dashboard_stage_seconds",
     "A dashboard request by stage (self time): scan (table or view "
     "to a column batch), aggregate (the panel's body), encode "

@@ -102,24 +102,22 @@ logger = get_logger("ingest")
 # Per-stage latency of the pipelined ingest path. The three stages of
 # one request overlap (store-insert ∥ detector), so their histograms
 # are independent distributions, not a partition of request time.
-_M_STAGE = _metrics.histogram(
+_M_STAGE = _trace.StageSeries(
     "theia_ingest_stage_seconds",
     "Per-stage ingest latency (decode under the stream lock; "
     "store_insert and detector run overlapped)",
     labelnames=("stage",))
-_M_STAGE_DECODE = _M_STAGE.labels(stage="decode")
-_M_STAGE_STORE = _M_STAGE.labels(stage="store_insert")
-_M_STAGE_DET = _M_STAGE.labels(stage="detector")
+_M_STAGE_DECODE = _M_STAGE.wall.labels(stage="decode")
+_M_STAGE_STORE = _M_STAGE.wall.labels(stage="store_insert")
+# The detector leg is timed whole on the request thread, as the part
+# `ingest.detector` of its request (obs/trace.py): wall, the thread's
+# CPU time and its page faults. Wall - CPU - lock wait - device fetch
+# is what the thread spent waiting for the interpreter (four request
+# threads share one) or the OS; the leg's stages say where.
+_M_LEG_DET = _M_STAGE.labels(stage="detector")
 _M_REQUEST = _metrics.histogram(
     "theia_ingest_request_seconds",
     "Whole POST /ingest request latency (decode + max(legs))")
-# Thread CPU time beside the wall times above: wall - cpu - lock wait
-# - device fetch is what the request thread spent waiting for the
-# interpreter (four request threads share one) or the OS.
-_M_STAGE_CPU_DET = _metrics.histogram(
-    "theia_ingest_stage_cpu_seconds",
-    "Thread CPU time of an ingest stage on the request thread",
-    labelnames=("stage",)).labels(stage="detector")
 _M_REQUEST_CPU = _metrics.histogram(
     "theia_ingest_request_cpu_seconds",
     "Thread CPU time of one acked POST /ingest on its request thread "
@@ -914,11 +912,9 @@ class IngestManager:
             alerts, conn_alerts, n_conn = [], [], 0
         elif scored:
             try:
-                t_det = time.perf_counter()
-                c_det = time.thread_time()
-                alerts, conn_alerts, n_conn = self.score_batch(batch)
-                _M_STAGE_DET.observe(time.perf_counter() - t_det)
-                _M_STAGE_CPU_DET.observe(time.thread_time() - c_det)
+                with _trace.part("ingest.detector", _M_LEG_DET):
+                    alerts, conn_alerts, n_conn = \
+                        self.score_batch(batch)
             except Exception:
                 _M_ERRORS.labels(stage="detector").inc()
                 # await the insert leg even when scoring raised: an
@@ -947,8 +943,7 @@ class IngestManager:
                 # timed on the pool thread, beside this thread's
                 # detector stages: a request that stalled behind a
                 # snapshot's hold says so
-                _trace.add_stage("store.latch_wait",
-                                 journaled["latchWait"])
+                _trace.add_stage(journaled["latchWait"])
         else:
             n = local_dup or 0
         if seq is not None and routed is not None and fut is not None:
@@ -1020,7 +1015,7 @@ class IngestManager:
                       journaled: Optional[Dict[str, object]] = None
                       ) -> int:
         """The store leg, on a pool thread. `journaled` is filled with
-        the flows record's `walLsn` and the `latchWait` seconds where
+        the flows record's `walLsn` and the `latchWait` stage where
         the store journals into one log (a sharded store's slices have
         one LSN each: none is reported)."""
         t0 = time.perf_counter()

@@ -84,7 +84,7 @@ _M_RETRIES = _obs_metrics.counter(
 # What a job's turn-around holds outside its run and that is the
 # program's: the answer that carries the result rows (manager/api.py
 # observes encode and send, and counts bytes and rows).
-JOB_RESULT_SECONDS = _obs_metrics.histogram(
+JOB_RESULT_SECONDS = _obs_trace.StageSeries(
     "theia_job_result_seconds",
     "One answer carrying a completed job's result rows, by phase: "
     "rows (result table scan, the job's rows by id code, each "

@@ -6,18 +6,20 @@ operational telemetry; the in-process equivalent must cost ~nothing on
 the path it observes, so the primitives are designed around who owns
 which lock *already*:
 
-  * Counters are STRIPED: each instance carries N_STRIPES + 1
-    float64 slots. A caller that already owns a stripe (an ingest
-    detector shard incrementing under its own shard lock) writes its
-    slot with NO additional lock — only that caller ever touches it.
-    Callers without an owned stripe go through a per-counter lock into
-    slot 0. Reads merge the stripes (`sum()`), so totals are exact as
-    soon as every writer's increment has retired.
-  * Histograms use POWER-OF-TWO buckets backed by fixed numpy arrays
-    (one [stripes, buckets] int64 grid + per-stripe sum/count):
-    `observe()` is a frexp + three array adds, no allocation, no
-    per-bucket search. Bucket bounds are 2^k seconds, so `le` values
-    are exact in both float and decimal text exposition.
+  * Counters are STRIPED: each instance carries N_STRIPES float64
+    slots and one shared slot. A caller that already owns a stripe (an
+    ingest detector shard incrementing under its own shard lock)
+    writes its slot with NO additional lock — only that caller ever
+    touches it. Callers without an owned stripe go through a
+    per-counter lock into the shared slot (a Python float: cheaper to
+    update than a numpy item). Reads merge them, so totals are exact
+    as soon as every writer's increment has retired.
+  * Histograms use POWER-OF-TWO buckets: a fixed [stripes, buckets]
+    int64 grid + per-stripe sum/count for stripe owners, a Python
+    list + float behind the lock for everyone else. `observe()` is a
+    frexp + a few adds, no allocation, no per-bucket search. Bucket
+    bounds are 2^k seconds, so `le` values are exact in both float
+    and decimal text exposition.
   * Gauges are cold-path (lock per set); a gauge child can instead be
     bound to a callback evaluated at collect time, for values that are
     cheaper to read on scrape than to maintain on write.
@@ -45,8 +47,8 @@ import numpy as np
 from ..analysis.lockdep import named_lock
 
 
-#: owned stripes per counter/histogram (slot 0 is the locked shared
-#: slot, so the arrays are N_STRIPES + 1 wide)
+#: owned stripes per counter/histogram (the locked shared slot is
+#: beside them)
 N_STRIPES = 16
 
 #: histogram bucket bounds: 2^k seconds for k in [EXP_MIN, EXP_MIN +
@@ -121,11 +123,15 @@ class _Metric:
 
 
 class _CounterChild:
-    __slots__ = ("_stripes", "_lock")
+    __slots__ = ("_stripes", "_shared", "_lock", "_callback")
 
     def __init__(self) -> None:
-        self._stripes = np.zeros(N_STRIPES + 1, np.float64)
+        # the owned stripes; the locked shared slot is a Python float
+        # (a numpy item update costs several times the lock itself)
+        self._stripes = np.zeros(N_STRIPES, np.float64)
+        self._shared = 0.0
         self._lock = named_lock("metrics.counter")
+        self._callback: Optional[Callable[[], float]] = None
 
     def inc(self, amount: float = 1.0,
             stripe: Optional[int] = None) -> None:
@@ -139,16 +145,25 @@ class _CounterChild:
             return
         if stripe is None or not 0 <= stripe < N_STRIPES:
             with self._lock:
-                self._stripes[0] += amount
+                self._shared += amount
         else:
-            self._stripes[1 + stripe] += amount
+            self._stripes[stripe] += amount
+
+    def set_callback(self, fn: Optional[Callable[[], float]]) -> None:
+        """Read a total that something else keeps (it only rises) at
+        collect time: for a writer that may take no lock, such as the
+        collector's callback in obs/trace.py."""
+        self._callback = fn
 
     def value(self) -> float:
-        return float(self._stripes.sum())
+        if self._callback is not None:
+            return float(self._callback())
+        return float(self._stripes.sum()) + self._shared
 
     def _zero(self) -> None:
         with self._lock:
             self._stripes[:] = 0.0
+            self._shared = 0.0
 
 
 class Counter(_Metric):
@@ -248,19 +263,26 @@ def bucket_index(value: float) -> int:
 
 
 class _HistogramChild:
-    __slots__ = ("_counts", "_sums", "_ns", "_lock")
+    __slots__ = ("_counts", "_sums", "_ns", "_shared", "_shared_sum",
+                 "_lock")
 
     def __init__(self) -> None:
-        # rows: stripe slots (0 = locked shared slot); cols: buckets
-        # (+Inf last). Fixed allocation — observe() never grows it.
-        self._counts = np.zeros((N_STRIPES + 1, N_BUCKETS + 1),
-                                np.int64)
-        self._sums = np.zeros(N_STRIPES + 1, np.float64)
-        self._ns = np.zeros(N_STRIPES + 1, np.int64)
+        # rows: the owned stripes; cols: buckets (+Inf last). Fixed
+        # allocation — observe() never grows it. The locked shared
+        # slot is a Python list and a float: every stage of a request
+        # observes through it, and three numpy item updates cost
+        # several times the lock itself.
+        self._counts = np.zeros((N_STRIPES, N_BUCKETS + 1), np.int64)
+        self._sums = np.zeros(N_STRIPES, np.float64)
+        self._ns = np.zeros(N_STRIPES, np.int64)
+        self._shared = [0] * (N_BUCKETS + 1)
+        self._shared_sum = 0.0
         self._lock = named_lock("metrics.histogram")
 
-    def observe(self, value: float,
-                stripe: Optional[int] = None) -> None:
+    def observe(self, value: float, stripe: Optional[int] = None,
+                times: int = 1) -> None:
+        """`times`: the observation counts for that many (one that
+        stands for others like it which were not measured)."""
         if _DISABLED:
             return
         b = bucket_index(value)
@@ -268,33 +290,35 @@ class _HistogramChild:
             # out-of-range stripes take the locked path — aliasing two
             # owners onto one lock-free row would lose observations
             with self._lock:
-                self._counts[0, b] += 1
-                self._sums[0] += value
-                self._ns[0] += 1
+                self._shared[b] += times
+                self._shared_sum += value * times
         else:
-            row = 1 + stripe
-            self._counts[row, b] += 1
-            self._sums[row] += value
-            self._ns[row] += 1
+            self._counts[stripe, b] += times
+            self._sums[stripe] += value * times
+            self._ns[stripe] += times
 
     def snapshot(self) -> Tuple[np.ndarray, float, int]:
         """(cumulative bucket counts incl. +Inf, sum, count) — the
         Prometheus exposition triple."""
-        merged = self._counts.sum(axis=0)
+        shared = np.asarray(self._shared, np.int64)
+        merged = self._counts.sum(axis=0) + shared
         return (np.cumsum(merged),
-                float(self._sums.sum()), int(self._ns.sum()))
+                float(self._sums.sum()) + self._shared_sum,
+                int(self._ns.sum()) + int(shared.sum()))
 
     def count(self) -> int:
-        return int(self._ns.sum())
+        return int(self._ns.sum()) + sum(self._shared)
 
     def sum(self) -> float:
-        return float(self._sums.sum())
+        return float(self._sums.sum()) + self._shared_sum
 
     def _zero(self) -> None:
         with self._lock:
             self._counts[:] = 0
             self._sums[:] = 0.0
             self._ns[:] = 0
+            self._shared[:] = [0] * (N_BUCKETS + 1)
+            self._shared_sum = 0.0
 
 
 class Histogram(_Metric):

@@ -19,24 +19,40 @@ Three layers share one ring:
     hold the pieces of one cross-node tree — `GET
     /debug/traces?trace=<id>` stitches them (manager/api.py).
 
-  * **Stages** (PR 26): `stage(name, hist)` brackets one part of the
-    work INSIDE a span with a single `perf_counter` pair. Its self
-    time (its own duration minus the stages nested in it) is summed
-    into the enclosing span's `stagesMs[name]` (a stage that repeats,
-    once per shard say, adds up) and observed on the histogram handed
-    to it, so the slowest-span exemplar of an op carries the stage
-    breakdown of its slowest request. `StageMarks` is the same for
-    code that already announces its boundaries (`JobProgress.stage`).
-    `part(name, hist)` (PR 34) names a piece of the stage open around
-    it and leaves that stage's own seconds whole.
-    An ingress span also records `cpuMs` (`time.thread_time()` delta):
-    wall − cpu − lock wait − device fetch is what the thread spent
-    waiting for the interpreter or the OS. `background(task)` is a
-    span `bg.<task>` that also feeds `theia_background_seconds`, for
-    the housekeeping that competes with requests (metrics-history
-    tick, retention round, WAL segment roll, checkpoint, parts
-    seal/merge) and `watch_gc()` reports generation-2 collections
-    the same way.
+  * **Stages** (PR 26): `stage(name, series)` brackets one part of
+    the work INSIDE a span. At its two edges it reads `perf_counter`
+    and the thread's `getrusage(RUSAGE_THREAD)` (CPU time = user +
+    system, system time, minor page faults: one system call, which
+    neighbouring edges share, `SHARED_READING_SECONDS`, and which a
+    thread makes for at most `READING_BUDGET` of its time: a request
+    in between is timed by the wall alone and the next one read
+    stands for it), so a stage says what its thread did with its
+    wall:
+    wall − CPU is time OFF the CPU (waiting for the interpreter, a
+    lock, the device, I/O); system time and faults are time ON the
+    CPU but in the kernel (a fresh mapping paged in). Its self
+    numbers (its own minus the stages nested in it) are summed into
+    the enclosing span's `stagesMs[name]`, `stagesCpuMs`,
+    `stagesSysMs`, `stagesFaults` (a stage that repeats, once per
+    shard say, adds up) and observed on the series handed to it (a
+    `StageSeries(...)` child: a wall histogram, a CPU histogram and
+    a fault counter declared together; a plain histogram child takes
+    the wall alone), so the slowest-span exemplar of an op carries
+    the breakdown of its slowest request. `StageMarks` is the same
+    for code that already announces its boundaries
+    (`JobProgress.stage`). `part(name, series)` (PR 34) names a piece
+    of the stage open around it, is reported whole (`partsMs`,
+    `partsCpuMs`, `partsFaults`) and leaves that stage's own numbers
+    whole. An ingress span also records `cpuMs` (the same clock over
+    the request). `background(task)` is a span `bg.<task>` that also
+    feeds `theia_background_seconds`, for the housekeeping that
+    competes with requests (metrics-history tick, retention round,
+    WAL segment roll, checkpoint, parts seal/merge). `watch_gc()`
+    times the collector's pauses of every generation
+    (`theia_gc_pause_seconds_total{generation}`,
+    `theia_gc_collections_total{generation}`, and `stagesGcMs` on
+    the stage open on the collecting thread) and reports generation
+    2 as `bg.gc` spans as well.
 
 While a profiler capture runs (manager/profiling.py hands this module
 an annotation factory with `set_annotation_factory`), every span and
@@ -60,6 +76,9 @@ Span records are plain dicts (JSON-ready for GET /debug/traces):
     {"op", "startTime", "durationMs", "parent", "thread",
      # when stages ran inside it / on an ingress span:
      "stagesMs": {name: ms}, "partsMs": {name: ms}, "cpuMs",
+     "stagesCpuMs", "stagesSysMs", "stagesFaults", "stagesGcMs",
+     "partsCpuMs", "partsSysMs", "partsFaults",   # keys as in *Ms
+     "usageWeight",     # the spans of the op this one was read for
      # present under a sampled trace context:
      "traceId", "spanId", "parentSpanId", "node", ...attrs}
 
@@ -85,10 +104,64 @@ import os
 import random
 import threading
 import time
-from typing import Callable, Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 from . import metrics as _metrics
 from ..analysis.lockdep import named_lock
+
+try:
+    import resource as _resource
+    _RUSAGE_THREAD: Optional[int] = _resource.RUSAGE_THREAD
+    _resource.getrusage(_RUSAGE_THREAD)
+except (ImportError, AttributeError, ValueError, OSError):
+    _RUSAGE_THREAD = None
+#: whether a stage can read its thread's system time and page faults
+#: (Linux); without it those numbers are absent from spans and series
+HAS_THREAD_RUSAGE = _RUSAGE_THREAD is not None
+_perf_counter = time.perf_counter
+#: a thread's reading is shared by the edges of its stages that fall
+#: within this many seconds of it (one stage's exit and the next one's
+#: enter, a stage that opens at once inside another): a reading is a
+#: system call, 0.5 us on a plain Linux host and 6-25 us under a
+#: sandboxed kernel, and about half of a request's stage edges are
+#: such pairs. At most this much CPU moves between two neighbours.
+SHARED_READING_SECONDS = 25e-6
+#: the share of a thread's time its readings may take. A span that no
+#: other encloses (a request, a job, a snapshot) has its stages read
+#: if the readings of the last one that was read are paid off by the
+#: time gone since it began; a span in between is timed by the wall
+#: alone and the next one read stands for it too (its CPU and faults
+#: are observed once for each). Where a reading is 0.5 us every span
+#: that lasts a few milliseconds is read; under a sandboxed kernel
+#: (20-50 us a reading, 46 a 435 ms ingest request) about one
+#: request in ten is, one job in five to two in three, a snapshot
+#: always.
+READING_BUDGET = 5e-4
+
+if HAS_THREAD_RUSAGE:
+    def _read_usage() -> Tuple[float, Optional[float], Optional[int]]:
+        """The calling thread's (CPU s, of it system s, minor faults)
+        in one call. The kernel brings a running thread's times up to
+        date at its scheduler tick, so one stage's CPU is good to a
+        tick (1-10 ms) and a family's sum to much less."""
+        ru = _resource.getrusage(_RUSAGE_THREAD)
+        return ru[0] + ru[1], ru[1], ru[6]
+else:
+    def _read_usage() -> Tuple[float, Optional[float], Optional[int]]:
+        return time.thread_time(), None, None
+
+
+def _usage_at(now: float) -> Tuple[float, Optional[float], Optional[int]]:
+    """The thread's reading for an edge at `now` (perf_counter): its
+    last one if that is young enough, else a new one."""
+    last = getattr(_local, "usage", None)
+    if last is not None and now - last[0] < SHARED_READING_SECONDS:
+        return last[1]
+    used = _read_usage()
+    _local.usage = (now, used)
+    _local.read_cost = (getattr(_local, "read_cost", 0.0)
+                        + _perf_counter() - now)
+    return used
 
 
 def _ring_capacity() -> int:
@@ -273,7 +346,8 @@ class Span:
     __slots__ = ("op", "attrs", "_t0", "_start", "parent", "context",
                  "_parent_span_id", "_ingress", "_traceparent",
                  "_explicit_ctx", "_sample_env", "stages", "parts",
-                 "_stage", "_cpu0", "_ann", "_hist")
+                 "stage_usage", "part_usage", "usage_weight", "_stage",
+                 "_cpu0", "_ann", "_hist")
 
     def __init__(self, op: str, attrs: Dict[str, object],
                  ingress: bool = False,
@@ -295,6 +369,17 @@ class Span:
         self.stages: Optional[Dict[str, float]] = None
         #: part name → summed seconds, each also inside its stage's
         self.parts: Optional[Dict[str, float]] = None
+        #: stage name → [CPU s, system s, minor faults, collector s],
+        #: self numbers like `stages`; system and faults None without
+        #: RUSAGE_THREAD. A stage added without them has no entry.
+        self.stage_usage: Optional[Dict[str, List]] = None
+        #: part name → the same (no collection is put on a part)
+        self.part_usage: Optional[Dict[str, List]] = None
+        #: how many spans of this op on this thread the usage of this
+        #: one's stages stands for (itself and those not read since
+        #: the last that was, READING_BUDGET); 0 = its stages are
+        #: timed by the wall alone
+        self.usage_weight = 1
         self._stage: Optional["Stage"] = None   # innermost open stage
         self._cpu0 = 0.0
         self._ann = None
@@ -351,7 +436,17 @@ class Span:
         self._start = time.time()
         if self._ingress:
             self._cpu0 = time.thread_time()
-        self._t0 = time.perf_counter()
+        t0 = self._t0 = time.perf_counter()
+        if enclosing is not None:
+            self.usage_weight = enclosing.usage_weight
+        else:
+            skipped = _local.__dict__.setdefault("skipped", {})
+            if t0 >= getattr(_local, "read_after", 0.0):
+                self.usage_weight = 1 + skipped.pop(self.op, 0)
+                _local.read_cost = 0.0
+            else:
+                self.usage_weight = 0
+                skipped[self.op] = skipped.get(self.op, 0) + 1
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -362,6 +457,9 @@ class Span:
         stack = getattr(_local, "stack", None)
         if stack:
             stack.pop()
+            if not stack and self.usage_weight:
+                _local.read_after = self._t0 + getattr(
+                    _local, "read_cost", 0.0) / READING_BUDGET
         if self._hist is not None:
             self._hist.observe(duration)
         if not _metrics.enabled():
@@ -384,74 +482,223 @@ class Span:
         if self._ingress:
             record["cpuMs"] = round(self.cpu_seconds() * 1e3, 4)
         if self.stages:
-            record["stagesMs"] = {k: round(v * 1e3, 4)
-                                  for k, v in self.stages.items()}
+            record["stagesMs"] = _ms(self.stages)
+            _usage_fields(record, "stages", self.stage_usage)
         if self.parts:
-            record["partsMs"] = {k: round(v * 1e3, 4)
-                                 for k, v in self.parts.items()}
+            record["partsMs"] = _ms(self.parts)
+            _usage_fields(record, "parts", self.part_usage)
+        if self.usage_weight and (self.stages or self.parts):
+            # its stages were read, for this many spans of the op
+            record["usageWeight"] = self.usage_weight
         if exc_type is not None:
             record["error"] = exc_type.__name__
         record.update(self.attrs)
         _publish(record)
 
 
+def _ms(seconds: Dict[str, float]) -> Dict[str, float]:
+    return {k: round(v * 1e3, 4) for k, v in seconds.items()}
+
+
+def _usage_fields(record: Dict[str, object], prefix: str,
+                  usage: Optional[Dict[str, List]]) -> None:
+    """`<prefix>CpuMs`, `SysMs`, `Faults` and, for stages, `GcMs`
+    (only the stages a collection paused) beside `<prefix>Ms`."""
+    if not usage:
+        return
+    record[prefix + "CpuMs"] = _ms({k: u[0] for k, u in usage.items()})
+    if HAS_THREAD_RUSAGE:
+        record[prefix + "SysMs"] = _ms(
+            {k: u[1] for k, u in usage.items()})
+        record[prefix + "Faults"] = {k: u[2] for k, u in usage.items()}
+    paused = {k: u[3] for k, u in usage.items() if u[3]}
+    if paused:
+        record[prefix + "GcMs"] = _ms(paused)
+
+
+class StageSeries:
+    """The series of one family of stages, declared together: the
+    wall histogram `<stem>_seconds`, the thread's CPU time of the same
+    runs `<stem>_cpu_seconds` and its minor page faults
+    `<stem>_minor_faults_total`, with the same labels. `labels(...)`
+    gives what `stage()` / `part()` take. The family has labels (an
+    unlabelled histogram is handed to `stage()` as it is and takes
+    the wall alone)."""
+
+    def __init__(self, name: str, help_text: str,
+                 labelnames: Tuple[str, ...]) -> None:
+        if not name.endswith("_seconds") or not labelnames:
+            raise ValueError(f"{name}: a stage family is a labelled "
+                             f"`<stem>_seconds`")
+        stem = name[:-len("_seconds")]
+        labelnames = tuple(labelnames)
+        self.wall = _metrics.histogram(name, help_text, labelnames)
+        self.cpu = _metrics.histogram(
+            stem + "_cpu_seconds",
+            f"Thread CPU time (user + system) of the runs {name} "
+            f"times, self time like it: wall - CPU is time off the "
+            f"CPU (interpreter, lock, device, I/O)", labelnames)
+        self.faults = _metrics.counter(
+            stem + "_minor_faults_total",
+            f"Minor page faults of the thread in the runs {name} "
+            f"times (RUSAGE_THREAD ru_minflt; no sample where the "
+            f"platform lacks it)", labelnames)
+
+    def labels(self, **labels) -> "StageUsage":
+        return StageUsage(
+            self.wall.labels(**labels), self.cpu.labels(**labels),
+            self.faults.labels(**labels) if HAS_THREAD_RUSAGE else None)
+
+
+class StageUsage:
+    """One child of a `StageSeries`: where a stage's wall seconds, CPU
+    seconds and faults go (`Stage.__exit__` observes the three;
+    `faults` is None without RUSAGE_THREAD)."""
+
+    __slots__ = ("wall", "cpu", "faults")
+
+    def __init__(self, wall, cpu, faults) -> None:
+        self.wall, self.cpu, self.faults = wall, cpu, faults
+
+
 class Stage:
     """One timed part of the work inside the thread's innermost span
     (see the module docstring). Reusable only sequentially. With
     `within` it is a named part of the stage that is open around it:
-    that stage keeps the part's seconds as its own."""
+    reported whole, and that stage keeps the part's numbers as its
+    own. After a run `seconds`, `cpu_seconds`, `sys_seconds`, `faults`
+    and `gc_seconds` hold its own numbers: system time and faults
+    None without RUSAGE_THREAD, all three None in a span whose stages
+    are not read (READING_BUDGET)."""
 
-    __slots__ = ("name", "hist", "within", "seconds", "_span", "_outer",
-                 "_t0", "_nested", "_ann")
+    __slots__ = ("name", "hist", "within", "seconds", "cpu_seconds",
+                 "sys_seconds", "faults", "gc_seconds", "_span",
+                 "_outer", "_t0", "_u0", "_weight", "_n_wall", "_n_cpu",
+                 "_n_sys", "_n_flt", "_ann")
 
     def __init__(self, name: str, hist=None, within: bool = False) -> None:
         self.name = name
-        self.hist = hist
+        self.hist = hist                # StageUsage or a histogram child
         self.within = within
-        self.seconds = 0.0              # own time of the last run
+        self.seconds = 0.0              # own numbers of the last run
+        self.cpu_seconds: Optional[float] = None
+        self.sys_seconds: Optional[float] = None
+        self.faults: Optional[int] = None
+        self.gc_seconds = 0.0           # collector pauses while innermost
         self._span: Optional[Span] = None
         self._outer: Optional["Stage"] = None
-        self._nested = 0.0
+        # wall, CPU, system seconds and faults of the stages nested in
+        # this run, which are theirs and not this stage's own
+        self._n_wall = self._n_cpu = self._n_sys = 0.0
+        self._n_flt = 0
+        self._weight = 1                # of the span it runs in
         self._ann = None
-        self._t0 = 0.0
 
     def __enter__(self) -> "Stage":
         stack = getattr(_local, "stack", None)
-        sp = self._span = stack[-1] if stack else None
-        if sp is not None:
+        if stack:
+            sp = self._span = stack[-1]
             self._outer = sp._stage
             sp._stage = self
-        self._nested = 0.0
+            self.gc_seconds = 0.0
+            self._weight = sp.usage_weight
+        else:
+            self._weight = 1            # outside any span: always read
         factory = _annotate
         if factory is not None:
             self._ann = factory(self.name)
             self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        # the thread's usage is read inside the wall clock's pair at
+        # both edges, so a stage's wall holds what the reads cost
+        t0 = self._t0 = _perf_counter()
+        self._u0 = _usage_at(t0) if self._weight else None
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        whole = time.perf_counter() - self._t0
+        if self._u0 is not None:
+            cpu0, sys0, flt0 = self._u0
+            cpu, sys_s, flt = _usage_at(_perf_counter())
+            cpu -= cpu0
+            if sys0 is not None:
+                sys_s -= sys0
+                flt -= flt0
+        else:
+            cpu = sys_s = sys0 = flt = None
+        own = _perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
-        own = self.seconds = whole - self._nested
+        within = self.within
+        if not within:                  # a part is reported whole
+            # the enclosing stage that is not a part loses this run
+            outer = _not_a_part(self._outer)
+            if outer is not None:
+                outer._n_wall += own
+                if cpu is not None:
+                    outer._n_cpu += cpu
+                if sys0 is not None:
+                    outer._n_sys += sys_s
+                    outer._n_flt += flt
+            if self._n_wall:            # stages ran nested in this one
+                own -= self._n_wall
+                if cpu is not None:
+                    cpu -= self._n_cpu
+                if sys0 is not None:
+                    sys_s -= self._n_sys
+                    flt -= self._n_flt
+                self._n_wall = self._n_cpu = self._n_sys = 0.0
+                self._n_flt = 0
+        self.seconds, self.cpu_seconds = own, cpu
+        self.sys_seconds, self.faults = sys_s, flt
         sp = self._span
         if sp is not None:
             sp._stage = self._outer
-            if self.within:
-                if sp.parts is None:
-                    sp.parts = {}
-                sp.parts[self.name] = sp.parts.get(self.name, 0.0) + own
-            else:
-                if self._outer is not None:
-                    self._outer._nested += whole
-                if sp.stages is None:
-                    sp.stages = {}
-                sp.stages[self.name] = sp.stages.get(self.name,
-                                                     0.0) + own
             self._span = self._outer = None
-        if self.hist is not None:
-            self.hist.observe(own)
+            if within:
+                if sp.parts is None:
+                    sp.parts, sp.part_usage = {}, {}
+                seconds, usage = sp.parts, sp.part_usage
+            else:
+                if sp.stages is None:
+                    sp.stages, sp.stage_usage = {}, {}
+                seconds, usage = sp.stages, sp.stage_usage
+            _sum_into(seconds, usage, self)
+        hist = self.hist
+        if hist is not None:
+            if type(hist) is StageUsage:
+                hist.wall.observe(own)
+                if cpu is not None:     # for the runs not read as well
+                    hist.cpu.observe(cpu, times=self._weight)
+                    if flt:
+                        hist.faults.inc(flt * self._weight)
+            else:
+                hist.observe(own)
+
+
+def _not_a_part(st: Optional[Stage]) -> Optional[Stage]:
+    """`st`, or the nearest open stage around it that is no part."""
+    while st is not None and st.within:
+        st = st._outer
+    return st
+
+
+def _sum_into(seconds: Dict[str, float], usage: Dict[str, List],
+              st: Stage) -> None:
+    """Add a finished stage's own numbers to a span's totals."""
+    name = st.name
+    seconds[name] = seconds.get(name, 0.0) + st.seconds
+    if st.cpu_seconds is None:
+        return
+    have = usage.get(name)
+    if have is None:
+        usage[name] = [st.cpu_seconds, st.sys_seconds, st.faults,
+                       st.gc_seconds]
+        return
+    have[0] += st.cpu_seconds
+    if st.sys_seconds is not None:
+        have[1] += st.sys_seconds
+        have[2] += st.faults
+    have[3] += st.gc_seconds
 
 
 def stage(name: str, hist=None) -> Stage:
@@ -460,7 +707,8 @@ def stage(name: str, hist=None) -> Stage:
         with stage("detector.plan", _M_PLAN):
             plan = self.build_plan(keys, values)
 
-    `hist` is a histogram child (or None)."""
+    `hist` is a `StageSeries(...)` child (wall, CPU and faults), a
+    histogram child (wall alone) or None."""
     return Stage(name, hist)
 
 
@@ -470,25 +718,26 @@ def part(name: str, hist=None) -> Stage:
         with part("job.score.kernel", _M_PART):
             out = jax.block_until_ready(kernel(x, mask))
 
-    Timed and annotated like a stage, put on the span's `partsMs`
-    and taken from nothing: the enclosing stage's seconds still hold
-    it, so `stagesMs` adds up to the span as before and the parts of
-    a stage explain its time without changing it."""
+    Timed and annotated like a stage, put whole on the span's
+    `partsMs` (`partsCpuMs`, `partsFaults`) and taken from nothing:
+    the enclosing stage's numbers still hold it, so `stagesMs` adds
+    up to the span as before and the parts of a stage explain its
+    time without changing it."""
     return Stage(name, hist, within=True)
 
 
-def add_stage(name: str, seconds: float) -> None:
+def add_stage(st: Stage) -> None:
     """Put a stage that was timed on another thread (a pool worker
-    running one leg of this request) on this thread's innermost span:
-    it ran beside the span's own stages, so it is added as it was
-    measured and taken from no enclosing stage."""
+    running one leg of this request) on this thread's innermost span,
+    with that thread's CPU and faults: it ran beside the span's own
+    stages, so it is added as it was measured and taken from no
+    enclosing stage."""
     sp = current_span()
     if sp is None:
         return
     if sp.stages is None:
-        sp.stages = {name: seconds}
-    else:
-        sp.stages[name] = sp.stages.get(name, 0.0) + seconds
+        sp.stages, sp.stage_usage = {}, {}
+    _sum_into(sp.stages, sp.stage_usage, st)
 
 
 class StageMarks:
@@ -518,6 +767,13 @@ def _publish(record: Dict[str, object]) -> None:
     _retain(record)
 
 
+def _exemplar_rank(record: Dict[str, object]) -> Tuple[bool, float]:
+    """An op's exemplar is its slowest span whose stages were read,
+    and its slowest span while none was (READING_BUDGET): the
+    exemplar is there to say what the slow instance did."""
+    return "usageWeight" in record, record["durationMs"]
+
+
 def _retain(record: Dict[str, object]) -> None:
     # THEIA_TRACE_RING=0 promises NO span retention — exemplars are
     # retained state too (attrs carry stream ids and job names), so
@@ -531,7 +787,7 @@ def _retain(record: Dict[str, object]) -> None:
         if best is None:
             if len(_slowest) < MAX_EXEMPLAR_OPS:
                 _slowest[op] = record
-        elif record["durationMs"] > best["durationMs"]:
+        elif _exemplar_rank(record) > _exemplar_rank(best):
             _slowest[op] = record
 
 
@@ -577,32 +833,67 @@ def background(task: str, **attrs: object) -> Span:
     return sp
 
 
-# -- generation-2 garbage collections ---------------------------------------
-# A full collection stops whichever thread tripped it, mid-request and
+# -- garbage collections ------------------------------------------------------
+# A collection stops whichever thread tripped it, mid-request and
 # possibly while that thread holds this module's ring lock or a
 # histogram's. So the callback takes no lock and allocates next to
-# nothing: it notes the collection on a deque, and the next span to
-# publish (or reader of the ring) turns the notes into `bg.gc` spans
-# and theia_background_seconds{task="gc"} observations.
-_gc_open: Optional[tuple] = None
+# nothing: it adds the pause to two module-level lists (read by the
+# two counters when they are collected) and to the stage open on the
+# collecting thread, and notes a full collection on a deque, which the
+# next span to publish (or reader of the ring) turns into a `bg.gc`
+# span and a theia_background_seconds{task="gc"} observation.
+_gc_t0: Optional[float] = None         # perf_counter at "start"
+_gc_open: Optional[tuple] = None       # a full collection's (wall, ann)
 _gc_done: Deque[tuple] = collections.deque(maxlen=64)
+#: seconds paused and collections, by generation (one collection runs
+#: at a time, so the callback is their only writer)
+_gc_pause = [0.0, 0.0, 0.0]
+_gc_count = [0, 0, 0]
+
+_M_GC_PAUSE = _metrics.counter(
+    "theia_gc_pause_seconds_total",
+    "Seconds the cyclic collector stopped the thread that tripped "
+    "it, by generation (timed once watch_gc() is on, as in the "
+    "manager); the pause is also on the stage open on that thread "
+    "(stagesGcMs)", labelnames=("generation",))
+_M_GC_COLLECTIONS = _metrics.counter(
+    "theia_gc_collections_total",
+    "Collections of the cyclic collector, by generation",
+    labelnames=("generation",))
+for _gen in range(3):
+    _M_GC_PAUSE.labels(generation=str(_gen)).set_callback(
+        lambda g=_gen: _gc_pause[g])
+    _M_GC_COLLECTIONS.labels(generation=str(_gen)).set_callback(
+        lambda g=_gen: _gc_count[g])
 
 
 def _on_gc(phase: str, info: Dict[str, int]) -> None:
-    global _gc_open
-    if info.get("generation") != 2:
-        return
+    global _gc_t0, _gc_open
+    gen = info.get("generation", 0)
     if phase == "start":
-        ann = None
-        factory = _annotate
-        if factory is not None:
-            ann = factory("bg.gc")
-            ann.__enter__()
-        _gc_open = (time.time(), time.perf_counter(), ann)
-    elif _gc_open is not None:
-        start, t0, ann = _gc_open
+        if gen == 2:
+            ann = None
+            factory = _annotate
+            if factory is not None:
+                ann = factory("bg.gc")
+                ann.__enter__()
+            _gc_open = (time.time(), ann)
+        _gc_t0 = time.perf_counter()
+        return
+    if _gc_t0 is None:                  # watched from mid-collection
+        return
+    duration = time.perf_counter() - _gc_t0
+    _gc_t0 = None
+    _gc_pause[gen] += duration
+    _gc_count[gen] += 1
+    stack = getattr(_local, "stack", None)
+    if stack:
+        st = _not_a_part(stack[-1]._stage)
+        if st is not None:
+            st.gc_seconds += duration
+    if gen == 2 and _gc_open is not None:
+        start, ann = _gc_open
         _gc_open = None
-        duration = time.perf_counter() - t0
         if ann is not None:
             ann.__exit__(None, None, None)
         _gc_done.append((start, duration, info.get("collected", 0),
@@ -627,8 +918,9 @@ def _flush_gc() -> None:
 
 
 def watch_gc() -> None:
-    """Report every generation-2 collection as a `bg.gc` span (the
-    manager calls this once at start; idempotent)."""
+    """Time every collection's pause and report the full ones as
+    `bg.gc` spans (the manager calls this once at start;
+    idempotent)."""
     if _on_gc not in gc.callbacks:
         gc.callbacks.append(_on_gc)
 

@@ -27,12 +27,12 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..utils import atomic_write
 
-_M_STAGE = _metrics.histogram(
+_M_STAGE = _trace.StageSeries(
     "theia_job_stage_seconds",
     "Wall time of one stage of a job run (read, tensorize, score, "
     "write, ...: the stages JobProgress announces)",
     labelnames=("kind", "stage"))
-_M_PART = _metrics.histogram(
+_M_PART = _trace.StageSeries(
     "theia_job_stage_part_seconds",
     "Wall time of one named part of a job's stage (score: transfer, "
     "kernel, rows); the stage's own seconds include it",

@@ -105,7 +105,7 @@ _M_SNAP_FALLBACK = _metrics.counter(
     "Snapshot loads that failed verification on the primary file and "
     "fell back to the previous good snapshot (<path>.prev)")
 
-_M_CKPT_STAGE = _metrics.histogram(
+_M_CKPT_STAGE = _trace.StageSeries(
     "theia_checkpoint_stage_seconds",
     "Stages of writing one snapshot (FlowDatabase.save): latch_wait "
     "until in-flight appends drain, hold = log stamp + table scan "
@@ -1132,7 +1132,7 @@ class FlowDatabase:
         return None if wal is None else wal.stats()
 
     def wal_last_applied(self):
-        """(LSN, seconds waited for the snapshot latch) of the calling
+        """(LSN, the finished `store.latch_wait` stage) of the calling
         thread's last journaled insert; None without a WAL."""
         wal = self._wal
         return None if wal is None else wal.last_applied()

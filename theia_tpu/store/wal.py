@@ -613,11 +613,12 @@ class WriteAheadLog:
             apply(adopted)
         finally:
             self._latch.release_read()
-        self._applied.last = (lsn, wait.seconds)
+        self._applied.last = (lsn, wait)
         self._policy_sync()
 
-    def last_applied(self) -> Optional[Tuple[int, float]]:
-        """(LSN, seconds waited for the snapshot latch) of the calling
+    def last_applied(self) -> Optional[Tuple[int, "_trace.Stage"]]:
+        """(LSN, the finished `store.latch_wait` stage: the wait for
+        the snapshot latch as the calling thread spent it) of that
         thread's last `logged_apply`; None before its first."""
         return getattr(self._applied, "last", None)
 
