@@ -228,7 +228,8 @@ def test_the_dbscan_cell_holds_a_whole_retained_day_of_80_connections():
     old = {m["name"] for m in BENCH.metrics_of(
         "parts-fused-12h.tad-arima", "per_layer")}
     assert layer - old == {"job.dbscan_pair_tests", "job.dbscan_device_ms",
-                           "dbscan_noise_roofline"}
+                           "dbscan_noise_roofline",
+                           "job.dbscan_sorted_points"}
     assert old - layer == {"job.arima_fits", "job.arima_loop_iterations"}
     for name in layer - old:
         m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
@@ -359,7 +360,9 @@ def test_the_arima_cells_counters_reduce_to_a_jobs_figures():
 def test_the_dbscan_cells_metrics_reduce_to_a_jobs_figures():
     """`job.dbscan_pair_tests` reads the program's own exposition
     around one DBSCAN job: the sum over series of (valid points)^2; a
-    manager without the counter gives nothing. The two device metrics
+    manager without the counter gives nothing. `job.dbscan_sorted_points`
+    (PR 40) likewise: the valid points the job sorted, nothing from a
+    manager without that counter (the parent). The two device metrics
     read a trace's `module:jit_dbscan_noise` line: ms a call, and the
     kernel file's bytes at the memory's peak over it."""
     import time
@@ -395,6 +398,11 @@ def test_the_dbscan_cells_metrics_reduce_to_a_jobs_figures():
     assert series in after
     after.pop(series), before.pop(series, None)
     assert read("job.dbscan_pair_tests", counters) is None
+    assert read("job.dbscan_sorted_points", counters) == 3 * 48
+    series = BENCH.reader("per_layer", "job.dbscan_sorted_points")["series"]
+    assert series == "theia_job_dbscan_sorted_points_total" in after
+    after.pop(series), before.pop(series, None)
+    assert read("job.dbscan_sorted_points", counters) is None
 
     # the jitted program's name is what the trace's line carries
     assert dbscan_noise.__name__ == "dbscan_noise"

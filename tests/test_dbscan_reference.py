@@ -5,7 +5,12 @@ check reads as benchmarks/references/dbscan.py).
 
 The suite runs in float64 over integer throughputs, where x + eps is
 exact: program and reference decide every point alike, so decisions
-are equal exactly and the deviations agree to REL = 1e-12 relative."""
+are equal exactly and the deviations agree to REL = 1e-12 relative.
+
+`dbscan_noise` sorts too (PR 40), but tests the rounded difference of
+two values where the reference tests x + eps: it is held, bit for bit
+in float32 and in float64, to the definition over all pairs
+(`ref.noise_by_pairs`), which is what the program evaluated before."""
 
 import json
 import pathlib
@@ -18,7 +23,8 @@ import pytest
 from tests import dbscan_reference as ref
 from theia_tpu.analytics import TadQuerySpec, build_series, run_tad
 from theia_tpu.data.synth import SynthConfig, generate_flows
-from theia_tpu.ops.dbscan import dbscan_noise, dbscan_scores, pair_tests
+from theia_tpu.ops.dbscan import (dbscan_noise, dbscan_scores, pair_tests,
+                                  sorted_points)
 from theia_tpu.schema import ColumnarBatch
 from theia_tpu.store import FlowDatabase
 
@@ -59,6 +65,16 @@ def classes(x, mask, spike):
     return noise, border, core
 
 
+def holes(shape):
+    """The points a store keeps: all, but at 130 steps series 1 keeps
+    two thirds of its points and series 2 three."""
+    keep = np.ones(shape, bool)
+    if shape[1] == 130:
+        keep[1, 130 * 2 // 3:] = False
+        keep[2, 3:] = False
+    return keep
+
+
 def database(n_series, n_steps, spike_rate, seed=23):
     """A store whose connections carry `throughputs`, and how many of
     their spikes are noise, border and core points; at 130 steps series
@@ -66,10 +82,7 @@ def database(n_series, n_steps, spike_rate, seed=23):
     flows = generate_flows(SynthConfig(
         n_series=n_series, points_per_series=n_steps, seed=seed))
     x, spike = throughputs(n_series, n_steps, spike_rate, seed)
-    keep = np.ones(x.shape, bool)
-    if n_steps == 130:
-        keep[1, n_steps * 2 // 3:] = False
-        keep[2, 3:] = False
+    keep = holes(x.shape)
     cols = dict(flows.columns)
     cols["throughput"] = x.ravel().astype(cols["throughput"].dtype)
     rows = np.flatnonzero(keep.ravel())
@@ -152,6 +165,132 @@ def test_scores_are_the_references(n_series, n_steps, spike_rate):
         np.asarray(dbscan_noise(x32, mask)))
 
 
+def _law(n_series, n_steps, spike_rate):
+    """The cell's law with the holes `database()` makes."""
+    x, _ = throughputs(n_series, n_steps, spike_rate, 23)
+    return x, holes(x.shape)
+
+
+def _equal_runs():
+    """Duplicates and runs of equal values, some of them long enough to
+    be core on their own, at distances around eps from each other."""
+    rng = np.random.default_rng(40)
+    levels = np.array([1e7, 2e8, 2.6e8, 5.1e8, 7.6e8, 1.2e9, 3e9])
+    x = rng.choice(levels, (5, 90), p=[.3, .2, .2, .1, .1, .05, .05])
+    x[0] = 1e7                                  # one value, 90 times
+    x[1, :3] = 3e9                              # a run of three far out
+    return x, rng.random(x.shape) > 0.1
+
+
+def _at_eps():
+    """A quarter of every series' points moved to a distance of eps
+    exactly (as exactly as the dtype holds it) from another point,
+    below it or above it; the first three series get a point with one
+    on both sides."""
+    x, _ = throughputs(6, 160, 0.1, 41)
+    rng = np.random.default_rng(41)
+    for s in range(x.shape[0]):
+        moved = rng.choice(160, 40, replace=False)
+        x[s, moved] = (x[s, rng.integers(0, 160, 40)]
+                       + ref.EPS * rng.choice([-1, 1], 40))
+    x[:3, 1] = x[:3, 0] - ref.EPS
+    x[:3, 2] = x[:3, 0] + ref.EPS
+    return x, np.ones(x.shape, bool)
+
+
+def _few_points():
+    """Series of 0 to 7 valid points (all masked, one, fewer than
+    `min_samples`, just enough), close together and apart."""
+    x = np.tile(np.array([1e7, 1.1e7, 1.2e7, 9e8, 1.3e7, 1.4e7, 1.5e7,
+                          1.6e7]), (9, 1))
+    mask = np.arange(8)[None, :] < np.arange(9)[:, None]
+    mask[8] = [False, True] * 4                 # every other point
+    return x, mask
+
+
+def _mask_holes():
+    """Holes at the start, in the middle and at the end, over values
+    that the holes would make neighbours of if they counted."""
+    x, _ = throughputs(4, 96, 0.08, 42)
+    mask = np.ones(x.shape, bool)
+    mask[0, :30] = False
+    mask[1, 20:70] = False
+    mask[2, ::2] = False
+    mask[3, 60:] = False
+    x = np.where(mask, x, x[:, :1])         # garbage that is in reach
+    return x, mask
+
+
+def _leading_batch():
+    """A leading batch shape [2, 3, T]."""
+    x, _ = throughputs(6, 75, 0.1, 43)
+    mask = np.random.default_rng(43).random(x.shape) > 0.15
+    return x.reshape(2, 3, 75), mask.reshape(2, 3, 75)
+
+
+NOISE_CASES = {
+    "law-6x700": lambda: _law(*SHAPES[0]),
+    "law-3x2304": lambda: _law(*SHAPES[1]),
+    "law-4x130-holes": lambda: _law(*SHAPES[2]),
+    "equal-runs": _equal_runs,
+    "at-eps-exactly": _at_eps,
+    "few-points": _few_points,
+    "mask-holes": _mask_holes,
+    "leading-batch": _leading_batch,
+}
+
+
+@pytest.mark.parametrize("min_samples", [1, 2, 4, 7])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("case", sorted(NOISE_CASES))
+def test_noise_is_the_pairwise_definitions_bit_for_bit(
+        case, dtype, min_samples):
+    """`dbscan_noise` decides every point of every series as the
+    definition over all pairs does in the same precision, whatever the
+    ties, the pairs at eps exactly, the holes and `min_samples`; and
+    answers in the shape and dtype it is given."""
+    x, mask = NOISE_CASES[case]()
+    x = x.astype(dtype)
+    got = dbscan_noise(x, mask, min_samples=min_samples)
+    assert got.shape == x.shape and got.dtype == bool
+    got = np.asarray(got).reshape(-1, x.shape[-1])
+    rows = zip(x.reshape(got.shape), mask.reshape(got.shape))
+    want = np.zeros(got.shape, bool)
+    for s, (v, m) in enumerate(rows):
+        want[s, m] = ref.noise_by_pairs(v[m], min_samples=min_samples)
+    np.testing.assert_array_equal(got, want)
+    if case == "at-eps-exactly":
+        # not vacuous: hundreds of pairs lie at eps as the dtype holds
+        # it, and with 2 or 4 samples some decision hangs on the `<=`
+        flat = x.reshape(got.shape)
+        gaps = np.abs(flat[:, :, None] - flat[:, None, :])
+        assert (gaps == dtype(ref.EPS)).sum() > 300
+        under = np.nextafter(dtype(ref.EPS), dtype(0))
+        strict = np.array([ref.noise_by_pairs(v, under, min_samples)
+                           for v in flat])
+        assert (strict != want).any() == (min_samples in (2, 4))
+
+
+def test_the_traced_program_holds_nothing_quadratic():
+    """No intermediate of `dbscan_noise` at T = 4,096 has more than a
+    small multiple of S x T elements: the pairwise form's [S, T, T]
+    (4,096 times S x T) fails this."""
+    import jax
+
+    def sizes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield from (v.aval.size for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from sizes(sub)
+
+    n_series, n_steps = 2, 4096
+    x = np.zeros((n_series, n_steps), np.float32)
+    closed = jax.make_jaxpr(dbscan_noise)(x, x > 0)
+    found = list(sizes(closed.jaxpr))
+    assert len(found) > 20                      # the walk saw the body
+    assert max(found) <= 2 * n_series * n_steps
+
+
 def _rows_of(db, tad_id):
     return [r for r in db.tadetector.scan().to_rows() if r["id"] == tad_id]
 
@@ -208,6 +347,39 @@ def test_job_rows_are_the_references_decisions_with_its_deviation(
         assert r["algoCalc"] == 0.0 and r["refitEvery"] == 0
         assert r["throughputStandardDeviation"] == pytest.approx(
             std[s], rel=REL)
+
+
+def test_a_job_counts_the_points_it_sorts(monkeypatch):
+    """A DBSCAN job through `run_tad` raises
+    `theia_job_dbscan_sorted_points_total` by its valid points, an EWMA
+    job by nothing, and a DBSCAN job whose batch the Pallas kernel
+    takes (forced here, interpreted) by nothing either, while the pair
+    tests of the definition are counted all the same."""
+    from theia_tpu.obs import metrics
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+
+    def job(algo):
+        run_tad(db, algo, TadQuerySpec(), now=int(time.time()),
+                progress=JobProgress(algo, TAD_STAGES, kind="tad"))
+
+    monkeypatch.delenv("THEIA_TPU_PALLAS", raising=False)
+    db, _ = database(*SHAPES[2])
+    points = metrics.REGISTRY.get("theia_job_dbscan_sorted_points_total")
+    pairs = metrics.REGISTRY.get("theia_job_dbscan_pair_tests_total")
+    valid = 130 + 130 + 86 + 3
+    before = points.value()
+    job("EWMA")
+    assert points.value() == before
+    job("DBSCAN")
+    assert points.value() - before == valid
+    series = build_series(db.flows.scan(), TadQuerySpec())
+    assert sorted_points(series.mask) == valid
+    monkeypatch.setenv("THEIA_TPU_PALLAS", "1")
+    assert sorted_points(series.mask) == 0
+    before, pairs_before = points.value(), pairs.value()
+    job("DBSCAN")
+    assert points.value() == before
+    assert pairs.value() - pairs_before == pair_tests(series.mask)
 
 
 def test_the_rest_path_answers_with_the_references_decisions():

@@ -25,7 +25,7 @@ import numpy as np
 
 from ..ops import arima_scores, dbscan_scores, ewma_scores
 from ..ops.arima import css_loop_iterations
-from ..ops.dbscan import pair_tests
+from ..ops.dbscan import pair_tests, sorted_points
 from ..schema import TADETECTOR_SCHEMA, ColumnarBatch, StringDictionary
 from ..store import FlowDatabase
 from ..utils import get_logger
@@ -248,12 +248,14 @@ def detect_anomalies(batch: SeriesBatch, algo: str, tad_id: str,
     scores = _score_on_device(batch.values, batch.mask, algo,
                               refit if refit else 1, mesh, progress)
     if progress:
+        dbscan = algo == "DBSCAN"
         progress.scored(
             algo, batch.n_series, int(np.count_nonzero(batch.mask)),
             fits=batch.n_series * -(-n_steps // refit) if refit else 0,
             loop_iterations=css_loop_iterations(
                 batch.n_series, n_steps, refit) if refit else 0,
-            pair_tests=pair_tests(batch.mask) if algo == "DBSCAN" else 0)
+            pair_tests=pair_tests(batch.mask) if dbscan else 0,
+            sorted_points=sorted_points(batch.mask) if dbscan else 0)
     with job_part(progress, "rows"):
         return _result_rows(batch, scores, algo, tad_id, now, refit)
 

@@ -1,4 +1,4 @@
-"""DBSCAN outlier scoring as dense pairwise-distance matrix ops.
+"""DBSCAN outlier scoring: one sort a series, then neighbours in order.
 
 Reference semantics (plugins/anomaly-detection/anomaly_detection.py:325-349):
 sklearn DBSCAN(min_samples=4, eps=2.5e8) over the 1-D throughput values of
@@ -12,9 +12,15 @@ control flow, but *noise detection* — all the job needs — is closed-form:
     noise_i  = ¬core_i ∧ ¬∃j (core_j ∧ |x_i − x_j| ≤ eps)
 
 i.e. a point is noise iff it is neither a core point nor within eps of
-one. That is exactly sklearn's label==-1 set, computed as one [T,T]
-masked distance matrix per series — batched matmul-shaped work instead of
-sequential region growing.
+one. That is exactly sklearn's label==-1 set. The values are
+one-dimensional, so nothing of size [T, T] is needed to decide it: in
+sorted order the points within eps of x_i are a contiguous run, and a
+core point within eps of x_i, if there is one, is the nearest core
+value below it or the nearest above (`dbscan_noise`). The definition
+over all pairs stays, in numpy, as `noise_by_pairs` of
+tests/dbscan_reference.py, which the tests hold this form to bit for
+bit. The Pallas kernel (`ops/dbscan_pallas.py`, short series on a TPU)
+still tests pairs.
 """
 
 from __future__ import annotations
@@ -32,29 +38,94 @@ DEFAULT_EPS = 2.5e8
 DEFAULT_MIN_SAMPLES = 4
 
 
+def _ordered(bits: jnp.ndarray) -> jnp.ndarray:
+    """Between a float's bits, as the signed integer of its width, and
+    the integer whose order is the float's: the magnitude bits of a
+    negative number flip, so the function is its own inverse. Sorting
+    these integers is sorting the floats, NaN last, without the
+    comparator that jax builds for float keys (on a v5e, PR 40: twice
+    the sort's compile time and a third of its run time)."""
+    info = jnp.iinfo(bits.dtype)
+    return bits ^ ((bits >> (info.bits - 1)) & info.max)
+
+
 @functools.partial(jax.jit, static_argnames=("eps", "min_samples"))
 def dbscan_noise(x: jnp.ndarray, mask: jnp.ndarray,
                  eps: float = DEFAULT_EPS,
                  min_samples: int = DEFAULT_MIN_SAMPLES) -> jnp.ndarray:
-    """Noise (= anomaly) flags for a padded [S, T] series batch."""
-    within = (jnp.abs(x[..., :, None] - x[..., None, :]) <= eps)
-    pair_valid = mask[..., :, None] & mask[..., None, :]
-    within &= pair_valid
+    """Noise (= anomaly) flags for a padded [..., T] series batch, in
+    O(T log T) a series and in the dtype it is given.
+
+    Every decision is the rounded difference of two values of the
+    series compared with eps, as the definition's |x_i - x_j| <= eps
+    is, and rounding is monotone: between two sorted points within eps
+    of x_i every point is within eps of x_i. So the flags are the
+    pairwise definition's bit for bit in every precision. A point that
+    is not there (masked, or beyond the row's ends) is NaN, and a
+    difference with NaN is within nothing."""
+    k = int(min_samples)
+    dtype = jnp.result_type(x, float)
+    bits = jnp.dtype(f"int{8 * dtype.itemsize}")
+    nan = jnp.array(jnp.nan, dtype)
+    inf = jnp.array(jnp.inf, dtype)
+    axis = x.ndim - 1
+    t = x.shape[axis]
+    with jax.named_scope("sort"):
+        key = jnp.where(
+            mask, _ordered(jax.lax.bitcast_convert_type(
+                x.astype(dtype), bits)),
+            jnp.iinfo(bits).max)                  # a NaN, and the last
+        pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+        # ties may fall either way: equal values are decided alike
+        key, pos = jax.lax.sort((key, pos), dimension=axis, num_keys=1,
+                                is_stable=False)
+        xs = jax.lax.bitcast_convert_type(_ordered(key), dtype)
     with jax.named_scope("counts"):
-        neighbor_counts = jnp.sum(within, axis=-1)
-    core = (neighbor_counts >= min_samples) & mask
+        # k points within eps of xs[i], itself included, iff some window
+        # of k consecutive sorted points that holds i has both its ends
+        # within eps of it: below[d] is "xs[i-d] is", above[d] "xs[i+d]".
+        edge = jnp.full(x.shape[:-1] + (k - 1,), nan, dtype)
+        row = jnp.concatenate([edge, xs, edge], axis=axis)
+
+        def shifted(d):
+            return jax.lax.slice_in_dim(row, k - 1 + d, k - 1 + d + t,
+                                        axis=axis)
+
+        below = [xs - shifted(-d) <= eps for d in range(k)]
+        above = [shifted(d) - xs <= eps for d in range(k)]
+        core = functools.reduce(
+            jnp.logical_or, (below[a] & above[k - 1 - a] for a in range(k)))
     with jax.named_scope("reach"):
-        reachable = jnp.any(within & core[..., None, :], axis=-1)
-    return mask & ~core & ~reachable
+        nearest_below = jax.lax.cummax(jnp.where(core, xs, -inf), axis=axis)
+        nearest_above = jax.lax.cummin(jnp.where(core, xs, inf), axis=axis,
+                                       reverse=True)
+        reachable = ((xs - nearest_below <= eps)
+                     | (nearest_above - xs <= eps))
+    with jax.named_scope("unsort"):
+        # the positions of a row are 0 .. T-1 once each: sorted with the
+        # flag as their lowest bit they are the series' order again
+        noise = ~core & ~reachable
+        back = jax.lax.sort(2 * pos + noise.astype(jnp.int32),
+                            dimension=axis, is_stable=False)
+    return (back & 1).astype(bool) & mask
 
 
 def pair_tests(mask) -> int:
-    """Pairs one pass of the definition tests over a padded [S, T]
-    batch: the sum over series of (valid points)^2. `dbscan_noise`
-    makes two such passes (`counts`, `reach`), whichever formulation
-    runs them."""
+    """Pairs the definition tests over a padded [S, T] batch, once
+    for the neighbour counts: the sum over series of (valid points)^2.
+    It says what the job's answer is worth in pair tests, not what the
+    program does: `dbscan_noise` sorts and tests none of them
+    (`sorted_points`); the Pallas kernel makes two such passes."""
     n = np.count_nonzero(np.asarray(mask), axis=-1).astype(np.int64)
     return int(np.sum(n * n))
+
+
+def sorted_points(mask) -> int:
+    """Valid points of a padded [S, T] batch that `dbscan_scores`
+    sends to `dbscan_noise`, which sorts them: all of them, or none
+    where `_use_pallas` gives the length to the Pallas kernel."""
+    mask = np.asarray(mask)
+    return 0 if _use_pallas(mask.shape[-1]) else int(np.count_nonzero(mask))
 
 
 def _interpret() -> bool:
@@ -68,8 +139,9 @@ def _use_pallas(t: int) -> bool:
     from what the process can observe — never from a caught compile
     error: the Pallas kernel on a TPU backend for series that pad to
     at most `PALLAS_MAX_T` steps (the length its blocks fit VMEM
-    for), the XLA formulation everywhere else. THEIA_TPU_PALLAS=1/0
-    forces either side (1 off-TPU runs the interpreter)."""
+    for), the sorting form `dbscan_noise` everywhere else.
+    THEIA_TPU_PALLAS=1/0 forces either side (1 off-TPU runs the
+    interpreter)."""
     from .dbscan_pallas import PALLAS_MAX_T, padded_length
 
     flag = os.environ.get("THEIA_TPU_PALLAS", "auto").lower()
@@ -91,8 +163,8 @@ def dbscan_scores(x: jnp.ndarray, mask: jnp.ndarray,
     reference computes it in the groupby regardless of algorithm).
 
     use_pallas=None auto-selects per `_use_pallas`: the tiled Pallas
-    kernel on TPU (no [S,T,T] HBM round-trip), the fused XLA
-    formulation elsewhere and for series too long for the kernel.
+    kernel on TPU (pair tests in VMEM tiles), the sorting form
+    `dbscan_noise` elsewhere and for series too long for the kernel.
     """
     if use_pallas is None:
         use_pallas = _use_pallas(x.shape[-1])

@@ -1,12 +1,12 @@
 """Pallas TPU kernel for DBSCAN noise detection.
 
-Same math as ops/dbscan.py (reference semantics:
+Same decisions as ops/dbscan.py (reference semantics:
 plugins/anomaly-detection/anomaly_detection.py:325-349 — sklearn
-DBSCAN(eps, min_samples) noise labels over 1-D throughput values), but
-tiled explicitly: the XLA formulation materializes the [S, T, T]
-pairwise-distance tensor through HBM, while this kernel streams series
-blocks through VMEM and never writes the pairwise tensor back. HBM
-traffic drops from O(S·T²) to O(S·T).
+DBSCAN(eps, min_samples) noise labels over 1-D throughput values), by
+the definition itself: every pair of a series' points is tested, in
+tiles that stream series blocks through VMEM and never write a
+pairwise tensor back, so HBM traffic is O(S·T) where the work is
+O(S·T²). `ops.dbscan.dbscan_noise` sorts instead and tests no pair.
 
 Layout (what Mosaic accepts — the first version built a [BS, T, T]
 cube with a minor-dim insert and sized BS from a VMEM budget, which

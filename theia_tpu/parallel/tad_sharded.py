@@ -145,10 +145,10 @@ def make_sharded_arima(mesh: Mesh, refit_every: int = 1):
 def make_sharded_dbscan(mesh: Mesh, eps: float, min_samples: int):
     """Sharded per-series DBSCAN noise scoring over the series axis.
 
-    Each series' [T, T] distance test is independent, so series shards
-    run the single-device formulation locally (the Pallas kernel on
-    real TPU shards, the fused XLA formulation elsewhere — same
-    auto-selection as `ops.dbscan.dbscan_scores`).
+    Each series is decided on its own (a sort along its time axis, or
+    the Pallas kernel's pair tests), so series shards run the
+    single-device formulation locally — same auto-selection as
+    `ops.dbscan.dbscan_scores`.
     """
     from ..ops.dbscan import dbscan_scores
 

@@ -56,8 +56,14 @@ _M_ARIMA_LOOP_ITERATIONS = _metrics.counter(
 _M_DBSCAN_PAIR_TESTS = _metrics.counter(
     "theia_job_dbscan_pair_tests_total",
     "Pairs of points one pass of DBSCAN jobs' definition tests: the "
-    "sum over series of (valid points)^2 (ops.dbscan.pair_tests); the "
-    "kernel makes two passes, neighbour counts and reachability")
+    "sum over series of (valid points)^2 (ops.dbscan.pair_tests), "
+    "from the mask; what the answer is worth, not what the program "
+    "does, which sorts (theia_job_dbscan_sorted_points_total)")
+_M_DBSCAN_SORTED_POINTS = _metrics.counter(
+    "theia_job_dbscan_sorted_points_total",
+    "Valid points of the batches DBSCAN jobs gave to the sorting "
+    "kernel (ops.dbscan.sorted_points): it decides each from its "
+    "neighbours in sorted order; 0 for a batch the Pallas kernel took")
 _M_READ_ROWS = _metrics.counter(
     "theia_job_read_rows_total",
     "Rows of the batch a job's read stage handed on",
@@ -130,7 +136,7 @@ class JobProgress:
 
     def scored(self, algo: str, series: int, points: int,
                fits: int = 0, loop_iterations: int = 0,
-               pair_tests: int = 0) -> None:
+               pair_tests: int = 0, sorted_points: int = 0) -> None:
         """Count what the `score` stage's kernel was given."""
         _M_SERIES_SCORED.labels(kind=self.kind, algo=algo).inc(series)
         _M_POINTS_SCORED.labels(kind=self.kind, algo=algo).inc(points)
@@ -139,6 +145,8 @@ class JobProgress:
             _M_ARIMA_LOOP_ITERATIONS.inc(loop_iterations)
         if pair_tests:
             _M_DBSCAN_PAIR_TESTS.inc(pair_tests)
+        if sorted_points:
+            _M_DBSCAN_SORTED_POINTS.inc(sorted_points)
 
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
