@@ -56,6 +56,16 @@ from benchmarks.tests.test_tad_dbscan import (           # noqa: F401
     test_the_kernels_bytes_and_pair_tests_at_the_cells_shape,
     test_the_references_own_rows_are_correct_and_hold_every_class,
 )
+from benchmarks.tests.test_npr_policies import (         # noqa: F401
+    test_a_job_that_did_not_complete_and_an_answer_that_is_missing
+    as test_an_npr_job_that_did_not_complete,
+    test_a_perturbed_answer_is_not_correct
+    as test_a_perturbed_npr_answer_is_not_correct,
+    test_a_program_that_counts_other_rows_or_flows_is_not_correct,
+    test_a_request_the_reference_cannot_stand_for_is_a_broken_run,
+    test_the_kernels_bytes_at_the_cells_shape_and_here,
+    test_the_references_own_documents_are_correct,
+)
 
 BENCH = manifest.load()
 CELLS = [w["name"] for w in BENCH.doc["workloads"]]
@@ -235,7 +245,6 @@ def test_the_dbscan_cell_holds_a_whole_retained_day_of_80_connections():
         m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
         assert (m["layer"], m["moves"], m["workloads"]) == (
             "DBSCAN kernel", "job_turnaround_s", [cell])
-    assert len(BENCH.doc["workloads"]) == 6
 
 
 def test_the_dbscan_cell_is_rehearsed_on_the_cpu_backend():
@@ -250,6 +259,114 @@ def test_the_dbscan_cell_is_rehearsed_on_the_cpu_backend():
     assert set(out["metrics"]) == {"job_turnaround_s", "setup_s"}
     assert {"dbscan_decision_mismatch", "dbscan_stddev_gap",
             "dbscan_calc_gap", "jobs_not_completed"} <= set(out["checks"])
+
+
+def test_the_npr_cell_holds_the_documented_clusters_connections():
+    """Connections are the job's shape and are whole (4,000, every one
+    in every block, a block one 8 s commit); the cut is in time; the
+    engines and so the job path are the TAD cells'."""
+    cfg = BENCH.config("theia-parts-fused-npr-1x1")
+    sib = BENCH.config("theia-parts-fused-1x1")
+    assert (cfg["env"], cfg["manager_args"], cfg["expect"]) \
+        == (sib["env"], sib["manager_args"], sib["expect"])
+    entry = next(c for c in BENCH.doc["configs"]
+                 if c["name"] == cfg["name"])
+    assert entry["reduced"] == ["retained_window_rows",
+                                "checkpoint_interval_s"]
+    assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    assert "reference's" in cfg["guarantees"]["job_result"]
+    assert set(sib["guarantees"]) < set(cfg["guarantees"])
+    assert "exclude_labels" in cfg["assumed"]
+    t = BENCH.traffic("npr-initial")
+    dbscan = BENCH.traffic("tad-dbscan")
+    g = t["generator"]
+    assert g == {"law": "slices", "connections_per_producer": 4000,
+                 "conns_per_block": 4000, "points_per_conn": 8,
+                 "interval_seconds": 1}
+    producer, jobs = t["workers"]
+    assert producer == dbscan["workers"][0]
+    assert g["conns_per_block"] * g["points_per_conn"] == 32000  # a block
+    rows = producer["preload_blocks"] * 32000
+    assert rows == cfg["retained_window_rows"] == 3456000
+    assert cfg["retained_connections"] \
+        == cfg["source_retained_connections"] == 4000
+    assert cfg["source_retained_window_rows"] == 4000 * 43200
+    assert jobs["job"] == {
+        "resource": "networkpolicyrecommendations",
+        "spec": {"jobType": "initial", "policyType": "anp-deny-applied",
+                 "excludeLabels": False},
+        "poll_interval_s": 0.05}
+    assert t["checks"] == ["acks", "store_totals", "detector_series",
+                           "npr_policies"]
+    assert t["limits"] == {} and set(t["limits_why"]) == {"exact"}
+    assert not getattr(extend.module("check", "npr_policies"), "limits", ())
+    cell = "parts-fused-npr.npr-initial"
+    assert BENCH.cell(cell)["chips"] == 1
+    assert {m["name"] for m in BENCH.metrics_of(cell, "end_to_end")} \
+        == {"job_turnaround_s", "setup_s"}
+    layer = {m["name"] for m in BENCH.metrics_of(cell, "per_layer")}
+    old = {m["name"] for m in BENCH.metrics_of(
+        "parts-fused-12h-ns.tad-dbscan", "per_layer")}
+    new = layer - old
+    assert new == {
+        "npr.read_ms", "npr.recommend_ms", "npr.write_ms", "npr.scan_ms",
+        "npr.keys_ms", "npr.distinct_ms", "npr.decode_ms",
+        "npr.aggregate_ms", "npr.emit_ms", "npr.rows_sorted",
+        "npr.distinct_flows", "npr.policies", "npr.read_columns",
+        "npr.read_bytes", "npr.distinct_device_ms",
+        "npr_distinct_roofline"}
+    # of the TAD cells' readers the generic ones, none that names
+    # kind="tad" or a TAD kernel
+    assert layer & old == {
+        "job.run_s", "job.outside_run_s", "job.device_idle_share",
+        "job.window_compiles", "manager_start_s", "preload_s",
+        "warmup_s", "compile_s", "setup_cache_hits"}
+    kernel = {"npr.distinct_ms", "npr.rows_sorted", "npr.distinct_flows",
+              "npr.distinct_device_ms", "npr_distinct_roofline"}
+    for name in new:
+        m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+        reader = BENCH.reader("per_layer", name)
+        layer_name = "distinct kernel" if name in kernel else "NPR job"
+        assert (m["layer"], m["moves"], m["workloads"]) == (
+            layer_name, "job_turnaround_s", [cell])
+        assert (reader["layer"], reader["moves"], reader["source"]) == (
+            m["layer"], m["moves"], m["source"])
+        assert 'kind="tad"' not in str(reader)
+    assert len(BENCH.doc["workloads"]) == 7
+
+
+def test_the_npr_cell_is_rehearsed_on_the_cpu_backend():
+    """`benchmarks/selftest.py`'s rehearsal of the cell at a tiny size,
+    with no edit to it: manager child, preload, warm-up job, window,
+    checks, and every host-side reader of a traced run; plumbing only,
+    no number of it is a result. 64 connections x 4 points x 32 blocks
+    is under `_AUTO_THRESHOLD`: the counters count what went into
+    `device_distinct` whichever path it took."""
+    from benchmarks import selftest
+
+    cell = BENCH.cell("parts-fused-npr.npr-initial")
+    plain, traced = selftest.rehearse(cell, trace=True)
+    for out in (plain, traced):
+        assert out["correct"] and out["failed"] == 0
+        assert {"npr_policies_missing", "npr_policies_unexpected",
+                "npr_policy_kind_gap", "npr_distinct_flows_gap",
+                "npr_rows_sorted_gap", "jobs_not_completed"} \
+            <= set(out["checks"])
+    assert set(plain["metrics"]) == {"job_turnaround_s", "setup_s"}
+    got = {k: v["value"] for k, v in traced["metrics"].items()}
+    device_only = {"npr.distinct_device_ms", "npr_distinct_roofline"}
+    assert set(got) == {m["name"] for m in BENCH.metrics_of(
+        cell["name"], "per_layer")} - device_only
+    # the client finds state, startTime and endTime before the YAML
+    assert got["job.run_s"] > 0
+    assert got["npr.read_columns"] == 52
+    assert got["npr.rows_sorted"] % 4 == 0 and got["npr.rows_sorted"] > 0
+    # an ANP and a reject ACNP a group, and the allow list's three
+    assert got["npr.policies"] % 2 == 1 and got["npr.policies"] > 3
+    assert got["npr.read_ms"] >= got["npr.scan_ms"] + got["npr.keys_ms"] \
+        + got["npr.distinct_ms"] + got["npr.decode_ms"] - 1e-6
+    assert got["npr.recommend_ms"] >= got["npr.aggregate_ms"] \
+        + got["npr.emit_ms"] - 1e-6
 
 
 def test_an_operator_no_manager_answers_ends_in_the_warm_up(tmp_path):

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import numpy as np
+import pytest
 
 from theia_tpu.analytics.npr_device import (
     device_distinct,
@@ -111,3 +112,140 @@ def test_npr_job_unchanged_with_device_distinct(monkeypatch):
                           rows.strings("policy")))
 
     assert policies("1") == policies("0")
+
+
+# -- packed keys, bucketed rows (PR 42) -----------------------------------
+
+def _codes(rng, n, maxima):
+    """[n, K] codes, column c uniform in [0, maxima[c]] with the
+    maximum present, and duplicates of whole rows."""
+    keys = np.stack([rng.integers(0, m + 1, size=n) for m in maxima],
+                    axis=1).astype(np.int64)
+    keys[n // 2:] = keys[:n - n // 2]          # every row twice or so
+    keys[0] = maxima                           # every column's maximum
+    return keys[rng.permutation(n)]
+
+
+#: column maxima by the words their bits need, the job's nine among
+#: them (73 bits); in "crosses" the second column lies across words
+LAYOUTS = {
+    "1-word": (15, 1023, 6, 3),
+    "2-words": (65535, 65535, 1023, 255),
+    "crosses": (1023, (1 << 31) - 1, 7),
+    "3-words-the-jobs": (15, 1023, 1245, 16, 1024, 256, 5209, 6, 3),
+    "4-words": ((1 << 31) - 1,) * 4,
+    "a-column-of-zeros": (0, 5, 0, 9),
+    "whole-words-of-ones": (65535, 65535),
+}
+
+
+@pytest.mark.parametrize("maxima", LAYOUTS.values(), ids=LAYOUTS.keys())
+@pytest.mark.parametrize("n", [1, 7, 640, 641, 3000])
+def test_packed_distinct_is_group_reduce_bit_for_bit(maxima, n):
+    """640 is a bucket's edge (5 x 128), 641 one over it; every size
+    is under `_AUTO_THRESHOLD` and forced onto the device."""
+    from theia_tpu.analytics import npr_device
+
+    assert n < npr_device._AUTO_THRESHOLD
+    rng = np.random.default_rng(n)
+    keys = _codes(rng, n, maxima)
+    layout = npr_device.KeyLayout.of(keys)
+    assert layout.bits == sum(int(m).bit_length() for m in maxima)
+    assert layout.words == max(-(-layout.bits // 32), 1)
+    u, c = device_distinct(keys, use_device="1")
+    ref_u, ref_c = _numpy_distinct(keys)
+    assert u.dtype == ref_u.dtype == c.dtype == np.int64
+    np.testing.assert_array_equal(u, ref_u)
+    np.testing.assert_array_equal(c, ref_c)
+
+
+@pytest.mark.parametrize("keys", [
+    np.full((700, 3), 9, np.int64),
+    np.arange(2100, dtype=np.int64).reshape(700, 3),
+    # rows that pack to all-ones words, as the padding does
+    np.full((700, 2), 65535, np.int64),
+], ids=["all-equal", "all-distinct", "all-ones"])
+def test_packed_distinct_of_degenerate_tables(keys):
+    u, c = device_distinct(keys, use_device="1")
+    ref_u, ref_c = _numpy_distinct(keys)
+    np.testing.assert_array_equal(u, ref_u)
+    np.testing.assert_array_equal(c, ref_c)
+
+
+def test_a_layout_packs_in_the_columns_order_and_back():
+    from theia_tpu.analytics.npr_device import KeyLayout
+
+    rng = np.random.default_rng(3)
+    for maxima in LAYOUTS.values():
+        keys = _codes(rng, 500, maxima)
+        layout = KeyLayout.of(keys)
+        words = layout.pack(keys, 512)
+        assert words.shape == (layout.words, 512)
+        assert words.dtype == np.uint32
+        assert (words[:, 500:] == 0xFFFFFFFF).all()
+        np.testing.assert_array_equal(layout.unpack(words[:, :500].T), keys)
+        # the words' lexicographic order is the columns'
+        by_words = np.lexsort(words[::-1, :500])
+        by_columns = np.lexsort(keys.T[::-1])
+        np.testing.assert_array_equal(keys[by_words], keys[by_columns])
+    with pytest.raises(ValueError, match="codes"):
+        KeyLayout.of(np.array([[1, -1]]))
+    with pytest.raises(ValueError, match="codes"):
+        KeyLayout.of(np.array([[1, 1 << 31]]))
+
+
+def test_the_bucket_rule():
+    from theia_tpu.analytics.npr_device import bucket_rows
+
+    assert bucket_rows(3_119_904) == 3_145_728      # the cell's rows
+    assert [bucket_rows(n) for n in (1, 2, 5, 8, 9, 640, 641)] \
+        == [1, 2, 5, 8, 10, 640, 768]
+    grown, n = set(), 65_536
+    while n <= 172_800_000:
+        b = bucket_rows(n)
+        assert n <= b < 1.25 * n + 1
+        grown.add(b)
+        n = b + 1
+    assert len(grown) == 47 and max(grown) == 201_326_592
+
+
+def test_two_store_sizes_of_one_bucket_run_one_program(monkeypatch):
+    """What the job pays for at a store size it has not seen: nothing,
+    while the bucket and the number of key words stay. And the
+    program sorts the packed words, three operands and not the nine
+    columns."""
+    import jax
+    from theia_tpu.analytics import npr_device
+
+    rng = np.random.default_rng(8)
+    maxima = LAYOUTS["3-words-the-jobs"]
+    traced = []
+    jitted = npr_device.distinct_rows
+
+    def counting(words, n_valid):
+        traced.append((words.shape, jitted._cache_size()))
+        return jitted(words, n_valid)
+
+    monkeypatch.setattr(npr_device, "distinct_rows", counting)
+    for n in (5200, 5500, 6144):                 # one bucket: 6,144
+        keys = _codes(rng, n, maxima)
+        u, c = device_distinct(keys, use_device="1")
+        np.testing.assert_array_equal(u, _numpy_distinct(keys)[0])
+    # another layout of three words, in the same bucket
+    device_distinct(_codes(rng, 5999, LAYOUTS["crosses"] + (3, 1 << 20)),
+                    use_device="1")
+    device_distinct(_codes(rng, 6145, maxima), use_device="1")
+    shapes = [s for s, _ in traced]
+    assert shapes == [(6144, 3)] * 4 + [(7168, 3)]
+    sizes = [k for _, k in traced] + [jitted._cache_size()]
+    # the first call compiled one program, the next three none, the
+    # next bucket one more
+    assert [b - a for a, b in zip(sizes, sizes[1:])] == [1, 0, 0, 0, 1]
+
+    text = jitted.lower(
+        jax.ShapeDtypeStruct((6144, 3), np.uint32),
+        jax.ShapeDtypeStruct((), np.int32)).as_text()
+    sorts = [ln.split("stablehlo.sort", 1)[1].split(")", 1)[0].count("%")
+             for ln in text.splitlines() if "stablehlo.sort" in ln]
+    assert sorts == [3, 1]            # the rows' words, compact's starts
+    assert "is_stable = false" in text and "is_stable = true" not in text

@@ -22,6 +22,7 @@ is host-side string/YAML work, as in the reference.
 
 from __future__ import annotations
 
+import collections
 import datetime
 import uuid
 from typing import Dict, List, Optional, Sequence
@@ -31,7 +32,7 @@ import numpy as np
 from ..schema import ColumnarBatch
 from ..store import FlowDatabase
 from . import policy_gen
-from .npr_device import device_distinct
+from .npr_device import plan_distinct
 from .policy_gen import (
     KIND_ACG,
     KIND_ACNP,
@@ -39,7 +40,7 @@ from .policy_gen import (
     KIND_KNP,
     ROW_DELIMITER,
 )
-from .series import remove_meaningless_labels
+from .series import job_part, remove_meaningless_labels
 
 NAMESPACE_ALLOW_LIST = ["kube-system", "flow-aggregator", "flow-visibility"]
 
@@ -73,61 +74,73 @@ def read_distinct_flows(flows: ColumnarBatch,
                         unprotected: bool = True,
                         rm_labels: bool = True,
                         mesh=None,
-                        use_device=None) -> List[Dict[str, object]]:
+                        use_device=None,
+                        progress=None) -> List[Dict[str, object]]:
     """SELECT DISTINCT 9 columns with the job's WHERE clause
     (generate_sql_query :785-802). The distinct runs vectorized over
-    dictionary codes; decode happens only for the surviving rows."""
-    mask = np.ones(len(flows), dtype=bool)
-    if unprotected:
-        # '' is always dictionary code 0.
-        mask &= np.asarray(flows["ingressNetworkPolicyName"]) == 0
-        mask &= np.asarray(flows["egressNetworkPolicyName"]) == 0
-    else:
-        mask &= np.asarray(flows["trusted"]) == 1
-    if start_time is not None:
-        mask &= np.asarray(flows["flowStartSeconds"]) >= start_time
-    if end_time is not None:
-        mask &= np.asarray(flows["flowEndSeconds"]) < end_time
-    # Materialize only the 9 queried columns (same narrow-column rule
-    # as the series tensorize: filtering all 52 costs more than the
-    # distinct kernel it feeds).
-    col = flows.column_selector(mask)
-    keys = np.stack([col(c) for c in FLOW_TABLE_COLUMNS], axis=1)
-    uniq, _counts = device_distinct(keys, use_device=use_device,
-                                    mesh=mesh)
+    dictionary codes; decode happens only for the surviving rows.
+    `progress` (a job's, or None) times the parts `keys`, `distinct`
+    and `decode` of its `read` stage and counts the rows that went
+    into the distinct and those that came out."""
+    with job_part(progress, "keys"):
+        mask = np.ones(len(flows), dtype=bool)
+        if unprotected:
+            # '' is always dictionary code 0.
+            mask &= np.asarray(flows["ingressNetworkPolicyName"]) == 0
+            mask &= np.asarray(flows["egressNetworkPolicyName"]) == 0
+        else:
+            mask &= np.asarray(flows["trusted"]) == 1
+        if start_time is not None:
+            mask &= np.asarray(flows["flowStartSeconds"]) >= start_time
+        if end_time is not None:
+            mask &= np.asarray(flows["flowEndSeconds"]) < end_time
+        # Materialize only the 9 queried columns (same narrow-column
+        # rule as the series tensorize: filtering all 52 costs more
+        # than the distinct kernel it feeds).
+        col = flows.column_selector(mask)
+        keys = np.stack([col(c) for c in FLOW_TABLE_COLUMNS], axis=1)
+        distinct = plan_distinct(keys, use_device=use_device, mesh=mesh)
+    with job_part(progress, "distinct"):
+        uniq, _counts = distinct()
+    if progress:
+        progress.distinct(rows_sorted=len(keys), flows=len(uniq))
 
-    rows: List[Dict[str, object]] = []
-    for r in uniq:
-        row: Dict[str, object] = {}
-        for i, c in enumerate(FLOW_TABLE_COLUMNS):
-            if c in flows.dicts:
-                row[c] = flows.dicts[c].decode_one(int(r[i]))
-            else:
-                row[c] = int(r[i])
-        rows.append(row)
+    with job_part(progress, "decode"):
+        rows: List[Dict[str, object]] = []
+        for r in uniq:
+            row: Dict[str, object] = {}
+            for i, c in enumerate(FLOW_TABLE_COLUMNS):
+                if c in flows.dicts:
+                    row[c] = flows.dicts[c].decode_one(int(r[i]))
+                else:
+                    row[c] = int(r[i])
+            rows.append(row)
 
-    if rm_labels:
-        # The reference rewrites labels then dropDuplicates on the two
-        # label columns ONLY (read_flow_df :815-830) — a quirk we keep.
-        seen = set()
-        deduped = []
+        if rm_labels:
+            # The reference rewrites labels then dropDuplicates on the
+            # two label columns ONLY (read_flow_df :815-830) — a quirk
+            # we keep.
+            seen = set()
+            deduped = []
+            for row in rows:
+                row["sourcePodLabels"] = remove_meaningless_labels(
+                    str(row["sourcePodLabels"]))
+                row["destinationPodLabels"] = remove_meaningless_labels(
+                    str(row["destinationPodLabels"]))
+                key = (row["sourcePodLabels"],
+                       row["destinationPodLabels"])
+                if key not in seen:
+                    seen.add(key)
+                    deduped.append(row)
+            rows = deduped
+
         for row in rows:
-            row["sourcePodLabels"] = remove_meaningless_labels(
-                str(row["sourcePodLabels"]))
-            row["destinationPodLabels"] = remove_meaningless_labels(
+            row["flowType"] = get_flow_type(
+                int(row["flowType"]),
+                str(row["destinationServicePortName"]),
                 str(row["destinationPodLabels"]))
-            key = (row["sourcePodLabels"], row["destinationPodLabels"])
-            if key not in seen:
-                seen.add(key)
-                deduped.append(row)
-        rows = deduped
-
-    for row in rows:
-        row["flowType"] = get_flow_type(
-            int(row["flowType"]), str(row["destinationServicePortName"]),
-            str(row["destinationPodLabels"]))
-    if limit:
-        rows = rows[:limit]
+        if limit:
+            rows = rows[:limit]
     return rows
 
 
@@ -208,25 +221,41 @@ def _allowed(applied_to: str, ns_allow_list: Sequence[str]) -> bool:
     return ns in ns_allow_list
 
 
-def recommend_k8s_policies(flows, ns_allow_list) -> Dict[str, List[str]]:
-    peers, _ = aggregate_peers(flows, k8s=True, to_services=True)
+def recommend_k8s_policies(flows, ns_allow_list, progress=None
+                           ) -> Dict[str, List[str]]:
+    with job_part(progress, "aggregate"):
+        peers, _ = aggregate_peers(flows, k8s=True, to_services=True)
     knps = []
-    for applied_to, io in sorted(peers.items()):
-        if _allowed(applied_to, ns_allow_list):
-            continue
-        p = policy_gen.generate_k8s_np(
-            applied_to, io["ingress"], io["egress"])
-        if p:
-            knps.append(p)
+    with job_part(progress, "emit"):
+        for applied_to, io in sorted(peers.items()):
+            if _allowed(applied_to, ns_allow_list):
+                continue
+            p = policy_gen.generate_k8s_np(
+                applied_to, io["ingress"], io["egress"])
+            if p:
+                knps.append(p)
     return {KIND_KNP: knps}
 
 
 def recommend_antrea_policies(flows, ns_allow_list, option: int = 1,
                               deny_rules: bool = True,
-                              to_services: bool = True
-                              ) -> Dict[str, List[str]]:
-    peers, svc_egress = aggregate_peers(flows, k8s=False,
-                                        to_services=to_services)
+                              to_services: bool = True,
+                              progress=None) -> Dict[str, List[str]]:
+    """`progress` (a job's, or None) times the parts of its
+    `recommend` stage: `aggregate`, the peers per appliedTo group, and
+    `emit`, the policy documents and their YAML."""
+    with job_part(progress, "aggregate"):
+        peers, svc_egress = aggregate_peers(flows, k8s=False,
+                                            to_services=to_services)
+    with job_part(progress, "emit"):
+        return _emit_antrea_policies(flows, peers, svc_egress,
+                                     ns_allow_list, option, deny_rules,
+                                     to_services)
+
+
+def _emit_antrea_policies(flows, peers, svc_egress, ns_allow_list,
+                          option: int, deny_rules: bool,
+                          to_services: bool) -> Dict[str, List[str]]:
     anps, cgs, acnps = [], [], []
     for applied_to, io in sorted(peers.items()):
         if _allowed(applied_to, ns_allow_list):
@@ -268,14 +297,14 @@ def recommend_antrea_policies(flows, ns_allow_list, option: int = 1,
 
 def recommend_policies_for_unprotected_flows(
         flows, ns_allow_list, option: int = 1,
-        to_services: bool = True) -> Dict[str, List[str]]:
+        to_services: bool = True, progress=None) -> Dict[str, List[str]]:
     if option not in (1, 2, 3):
         raise ValueError(f"option must be 1, 2 or 3, got {option}")
     if option == 3:
-        return recommend_k8s_policies(flows, ns_allow_list)
+        return recommend_k8s_policies(flows, ns_allow_list, progress)
     return recommend_antrea_policies(
         flows, ns_allow_list, option, deny_rules=True,
-        to_services=to_services)
+        to_services=to_services, progress=progress)
 
 
 def recommend_policies_for_ns_allow_list(ns_allow_list
@@ -336,10 +365,14 @@ def run_npr(db: FlowDatabase,
 
     if progress:
         progress.stage("read")
-    flows = db.flows.scan()
+    with job_part(progress, "scan"):
+        flows = db.flows.scan()
+    if progress:
+        progress.read(flows)
     unprotected = read_distinct_flows(
         flows, limit, start_time, end_time, unprotected=True,
-        rm_labels=rm_labels, mesh=mesh, use_device=use_device)
+        rm_labels=rm_labels, mesh=mesh, use_device=use_device,
+        progress=progress)
 
     if progress:
         progress.stage("recommend")
@@ -347,19 +380,21 @@ def run_npr(db: FlowDatabase,
         result = merge_policy_dict(
             recommend_policies_for_ns_allow_list(ns_allow_list),
             recommend_policies_for_unprotected_flows(
-                unprotected, ns_allow_list, option, to_services))
+                unprotected, ns_allow_list, option, to_services,
+                progress))
     else:
         result = recommend_policies_for_unprotected_flows(
-            unprotected, ns_allow_list, option, to_services)
+            unprotected, ns_allow_list, option, to_services, progress)
         if option in (1, 2):
             trusted = read_distinct_flows(
                 flows, limit, start_time, end_time, unprotected=False,
-                rm_labels=rm_labels, mesh=mesh, use_device=use_device)
+                rm_labels=rm_labels, mesh=mesh, use_device=use_device,
+                progress=progress)
             result = merge_policy_dict(
                 result,
                 recommend_antrea_policies(
                     trusted, ns_allow_list, option, deny_rules=False,
-                    to_services=to_services))
+                    to_services=to_services, progress=progress))
 
     if progress:
         progress.stage("write")
@@ -373,5 +408,6 @@ def run_npr(db: FlowDatabase,
     } for kind, policies in result.items() for policy in policies if policy]
     db.recommendations.insert_rows(rows)
     if progress:
+        progress.recommended(collections.Counter(r["kind"] for r in rows))
         progress.done()
     return recommendation_id

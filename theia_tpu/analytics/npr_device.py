@@ -3,28 +3,43 @@
 The reference's NPR compute is a Spark `SELECT DISTINCT` over the flow
 9-tuple followed by RDD reduceByKey shuffles
 (policy_recommendation_job.py:785-802,621-712). Here the same kernel is
-expressed TPU-natively:
+expressed TPU-natively, and so that a store which grew by a block runs
+the program it ran before:
 
-  * single chip — `lax.sort` over the key columns (XLA's lexicographic
-    multi-operand sort), boundary detection, and segment scatter/add to
-    produce the unique rows and their multiplicities ("support counts")
-    in one jitted computation with static shapes;
+  * packed keys — the host knows every column's largest code, so it
+    packs the K columns, most significant first, into W unsigned
+    32-bit words whose lexicographic order is the columns' (`KeyLayout`:
+    a function of the column maxima alone, never part of a compiled
+    program; the job's nine columns need 73 bits, W = 3);
+  * bucketed rows — N is padded up to `bucket_rows(N)` with all-ones
+    rows that sort last and weigh nothing, so a program is compiled
+    once a bucket and once a number of words, not once a store size;
+  * single chip — `distinct_rows`: one `lax.sort` of the W words as W
+    key operands (XLA's lexicographic multi-operand sort, unstable:
+    equal rows are alike), boundary detection, and the segment starts
+    sorted to the front to produce the unique rows and their
+    multiplicities ("support counts") in one jitted computation with
+    static shapes. XLA's TPU sort pays its compile time by key and by
+    operand, hardly by row (v5e host, 3.1 M rows: one key of one
+    operand 4 s, these three keys 35-39 s, the nine stable keys of the
+    form this replaces 260-296 s; a radix sort of one-operand passes
+    compiles in 7.5 s and runs 477 ms where this runs 74, and its
+    passes grow with the rows' position bits: PERF.md §6, PR 42);
   * multi chip — `shard_map` over a row-sharded mesh: each device
     dedupes its block locally, the padded local distincts ride one
     `all_gather` over ICI, and a second sort + segment-sum merges them
     into a replicated global distinct — the collective pattern that
     replaces the reference's executor shuffle (SURVEY §2.7).
 
-Outputs are padded to the input length with a validity mask (static
-shapes for XLA); hosts slice by `n_unique`. Dictionary codes are int32
-(dictionaries are far smaller than 2^31; INT32_MAX is reserved as the
-cross-shard padding sentinel).
+Outputs are padded to the bucket (static shapes for XLA); the host
+fetches a power-of-two prefix that holds `n_unique` rows and unpacks
+it. Dictionary codes are below 2^31.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Tuple
+from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +47,9 @@ import numpy as np
 
 from ..parallel.mesh import ROWS_AXIS
 
-_SENTINEL = np.iinfo(np.int32).max
+_U32 = jnp.uint32
+_WORD = 32
+_ALL_ONES = 0xFFFFFFFF
 
 # Host-side switch: "auto" uses the device path for large inputs only
 # (the host numpy lexsort wins under ~64k rows once transfer overhead is
@@ -40,99 +57,267 @@ _SENTINEL = np.iinfo(np.int32).max
 _AUTO_THRESHOLD = 65536
 
 
-def _boundaries(sk: jnp.ndarray) -> jnp.ndarray:
-    """is_new[i] = row i differs from row i-1 (sorted input)."""
-    head = jnp.ones((1,), bool)
-    return jnp.concatenate(
-        [head, jnp.any(sk[1:] != sk[:-1], axis=1)]) if sk.shape[0] > 1 \
-        else jnp.ones((sk.shape[0],), bool)
+def bucket_rows(n: int) -> int:
+    """The row count a program is compiled for: n rounded up to the
+    next of four steps an octave (5, 6, 7, 8 x 2^e), so the padding is
+    under a quarter of n (0.8 % at the documented store's 3,119,904
+    unprotected rows: 3,145,728) where powers of two would sort up to
+    twice the rows. A store growing from 65,536 to 172.8 M rows (12 h
+    at 4,000 records/s) compiles 47 programs a number of key words,
+    each once: the persistent compile cache keeps them."""
+    step = 1 << max((n - 1).bit_length() - 3, 0)
+    return -(-n // step) * step
 
 
-def _dedupe_sorted(sk: jnp.ndarray, weights: jnp.ndarray):
-    """Segment-reduce a sorted key matrix: unique rows scattered to the
-    front, weights summed per segment. Returns (uniq, counts, n_unique)
-    padded to len(sk)."""
-    n = sk.shape[0]
-    is_new = _boundaries(sk)
-    seg = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    n_unique = seg[-1] + 1
-    counts = jnp.zeros((n,), weights.dtype).at[seg].add(weights)
-    uniq = jnp.zeros_like(sk).at[seg].set(sk)
-    return uniq, counts, n_unique
+class KeyLayout(NamedTuple):
+    """How K columns of codes lie in W 32-bit words: `widths[c]` bits
+    for column c (its largest code's bit length), most significant
+    column first, the whole right-aligned in the W words."""
+    widths: Tuple[int, ...]
+
+    @classmethod
+    def of(cls, keys: np.ndarray) -> "KeyLayout":
+        largest = keys.max(axis=0)
+        if keys.min() < 0 or largest.max() >= 1 << 31:
+            raise ValueError("dictionary codes lie in [0, 2^31)")
+        return cls(tuple(int(m).bit_length() for m in largest))
+
+    @property
+    def bits(self) -> int:
+        return sum(self.widths)
+
+    @property
+    def words(self) -> int:
+        return max(-(-self.bits // _WORD), 1)
+
+    def pack(self, keys: np.ndarray, n_rows: int) -> np.ndarray:
+        """[W, n_rows] uint32: the rows of `keys` [N, K] packed, then
+        all-ones padding. Columns go in from the least significant
+        end through a 64-bit accumulator that is emptied a word at a
+        time, so a column may cross a word."""
+        n = keys.shape[0]
+        out = np.empty((self.words, n_rows), np.uint32)
+        out[:, n:] = _ALL_ONES
+        acc = np.zeros(n, np.int64)
+        fill, word = 0, self.words - 1
+        for c in reversed(range(len(self.widths))):
+            if not self.widths[c]:
+                continue
+            acc |= keys[:, c].astype(np.int64) << fill
+            fill += self.widths[c]
+            if fill >= _WORD:
+                out[word, :n] = acc & _ALL_ONES
+                acc >>= _WORD
+                fill -= _WORD
+                word -= 1
+        if fill:
+            out[word, :n] = acc
+            word -= 1
+        out[:word + 1, :n] = 0          # no column reaches these
+        return out
+
+    def unpack(self, words: np.ndarray) -> np.ndarray:
+        """[U, K] int64 codes of packed rows [U, W]."""
+        u = words.shape[0]
+        out = np.zeros((u, len(self.widths)), np.int64)
+        wide = words.astype(np.int64)
+        lo = 0
+        for c in reversed(range(len(self.widths))):
+            b = self.widths[c]
+            if not b:
+                continue
+            word, off = self.words - 1 - lo // _WORD, lo % _WORD
+            v = wide[:, word] >> off
+            if off + b > _WORD:
+                v |= wide[:, word - 1] << (_WORD - off)
+            out[:, c] = v & ((1 << b) - 1)
+            lo += b
+        return out
+
+
+def _distinct(words_t: jnp.ndarray, n_valid=None, weights=None):
+    """Sort, segment and compact `words_t` [W, N]: (uniq_t [W, N],
+    counts [N] int32, n_unique []), the distinct rows in order at the
+    front with their weights summed. A row weighs `weights[i]`, or
+    without them 1 for the first `n_valid` rows and 0 for the rest
+    (all-ones padding). A last segment that weighs nothing is padding
+    and is not counted."""
+    w, n = words_t.shape
+    with jax.named_scope("sort"):
+        # equal rows are alike in every word: ties may fall either way
+        # (a stable sort carries an iota through every exchange and
+        # takes twice as long to compile)
+        operands = tuple(words_t) + (() if weights is None else (weights,))
+        ordered = jax.lax.sort(operands, num_keys=w, is_stable=False)
+        rows_t = jnp.stack(ordered[:w])
+    with jax.named_scope("segments"):
+        is_new = jnp.concatenate([
+            jnp.ones((1,), bool),
+            jnp.any(rows_t[:, 1:] != rows_t[:, :-1], axis=0)])
+        n_unique = jnp.sum(is_new.astype(jnp.int32))
+        if weights is None:
+            # padding sorts last, so the rows before a sorted position
+            # that count are those below n_valid
+            total = jnp.int32(n_valid)
+
+            def before(position):
+                return jnp.minimum(position, total)
+        else:
+            running = jnp.cumsum(ordered[w])
+            total = running[-1]
+
+            def before(position):
+                return jnp.take(running - ordered[w], position)
+    with jax.named_scope("compact"):
+        # a segment's first position, the segments' in order at the
+        # front: sorted as one operand of unique keys, where a scatter
+        # of N single elements is serial address work on a TPU (v5e,
+        # 3.1 M rows: 74 ms a call against 162)
+        position = jax.lax.iota(_U32, n)
+        starts = jax.lax.sort(
+            jnp.where(is_new, position, position + _U32(n)),
+            is_stable=False)
+        held = jax.lax.iota(jnp.int32, n) < n_unique
+        starts = jnp.where(held, starts, _U32(0)).astype(jnp.int32)
+        uniq_t = jnp.where(held[None, :],
+                           jnp.take(rows_t, starts, axis=1), _U32(0))
+        upto = jnp.where(held, before(starts), total)
+        counts = jnp.where(
+            held, jnp.concatenate([upto[1:], total[None]]) - upto, 0)
+        last = jnp.maximum(n_unique - 1, 0)
+        n_unique = jnp.where(counts[last] == 0, last, n_unique)
+    return uniq_t, counts, n_unique
 
 
 @jax.jit
-def distinct_rows(keys: jnp.ndarray
+def distinct_rows(keys: jnp.ndarray, n_valid=None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """DISTINCT over [N, K] int32 rows with multiplicities.
+    """DISTINCT over [N, W] rows of 32-bit words with multiplicities.
 
-    Returns (uniq [N, K], counts [N] int32, n_unique []): the first
-    n_unique rows of `uniq` are the distinct key rows in lexicographic
-    order; `counts[i]` is how many input rows equal `uniq[i]`.
+    Returns (uniq [N, W], counts [N] int32, n_unique []): the first
+    n_unique rows of `uniq` are the distinct rows in lexicographic
+    order (the words compared as unsigned); `counts[i]` is how many of
+    the first `n_valid` input rows equal `uniq[i]`. Rows from `n_valid`
+    on are padding, all-ones (they sort last, and a segment of nothing
+    but padding is not counted). `n_valid` is traced: it compiles no
+    program. Default: every row.
     """
-    n, k = keys.shape
-    ops = tuple(keys[:, i] for i in range(k))
-    sorted_cols = jax.lax.sort(ops, num_keys=k)
-    sk = jnp.stack(sorted_cols, axis=1)
+    n = keys.shape[0]
     # int32 counts: a single padded block never exceeds 2^31 rows
     # (hosts widen to int64); avoids the x64-disabled truncation
     # warning on TPU.
-    return _dedupe_sorted(sk, jnp.ones((n,), jnp.int32))
+    uniq_t, counts, n_unique = _distinct(
+        keys.astype(_U32).T, n if n_valid is None else n_valid)
+    return uniq_t.T.astype(keys.dtype), counts, n_unique
 
 
-def _sharded_distinct_step(keys: jnp.ndarray):
+def _sharded_distinct_step(keys: jnp.ndarray, n_valid):
     """Per-shard body: local dedupe → all_gather → global dedupe.
 
-    keys: the local [N_loc, K] block. Output is replicated (identical
-    on every shard): (uniq [N, K], counts [N], n_unique) with
-    N = N_loc * n_shards (the shard count is implicit in the
+    keys: the local [N_loc, W] block of the padded rows, of which the
+    first `n_valid` overall are genuine. Output is replicated
+    (identical on every shard): (uniq [N, W], counts [N], n_unique)
+    with N = N_loc * n_shards (the shard count is implicit in the
     all_gather output shape).
     """
-    n_loc, k = keys.shape
-    uniq, counts, n_unique = distinct_rows(keys)
+    n_loc = keys.shape[0]
+    mine = jnp.clip(n_valid - jax.lax.axis_index(ROWS_AXIS) * n_loc,
+                    0, n_loc)
+    uniq, counts, n_unique = distinct_rows(keys, mine)
     valid = jnp.arange(n_loc) < n_unique
-    # Pad invalid slots with the sentinel so they sort to the end and
-    # carry zero weight through the merge.
-    uniq = jnp.where(valid[:, None], uniq, _SENTINEL)
+    # Slots past the local distincts become padding: all-ones rows
+    # that sort to the end and carry zero weight through the merge.
+    uniq = jnp.where(valid[:, None], uniq.astype(_U32), _U32(_ALL_ONES))
     counts = jnp.where(valid, counts, 0)
 
-    uniq_all = jax.lax.all_gather(uniq, ROWS_AXIS)       # [S, N_loc, K]
+    uniq_all = jax.lax.all_gather(uniq, ROWS_AXIS)       # [S, N_loc, W]
     counts_all = jax.lax.all_gather(counts, ROWS_AXIS)   # [S, N_loc]
-    flat_keys = uniq_all.reshape(-1, k)
-    flat_counts = counts_all.reshape(-1)
-
-    ops = tuple(flat_keys[:, i] for i in range(k)) + (flat_counts,)
-    sorted_ = jax.lax.sort(ops, num_keys=k)
-    sk = jnp.stack(sorted_[:k], axis=1)
-    merged, total, n_uniq = _dedupe_sorted(sk, sorted_[k])
-    # Drop the sentinel segment (present iff any shard had padding):
-    # padding rows are _SENTINEL in EVERY column, so a genuine row can
-    # only be misidentified if all K of its codes equal INT32_MAX —
-    # excluded by the module precondition (codes < INT32_MAX).
-    has_pad = jnp.all(merged[jnp.maximum(n_uniq - 1, 0)] == _SENTINEL)
-    n_uniq = jnp.where(has_pad, n_uniq - 1, n_uniq)
-    return merged, total, n_uniq
+    merged_t, total, n_uniq = _distinct(
+        uniq_all.reshape(-1, keys.shape[1]).T,
+        weights=counts_all.reshape(-1))
+    return merged_t.T.astype(keys.dtype), total, n_uniq
 
 
 def make_sharded_distinct(mesh: jax.sharding.Mesh):
     """Jitted multi-chip DISTINCT over a mesh with a `rows` axis.
 
-    fn(keys [N, K]) with N divisible by the axis size; returns
-    replicated (uniq, counts, n_unique) padded to N.
+    fn(keys [N, W], n_valid=None) with N divisible by the axis size and
+    `distinct_rows`' arguments; returns replicated (uniq, counts,
+    n_unique) padded to N.
 
-    Preconditions: key codes < INT32_MAX (the padding sentinel), and
-    no single distinct key's GLOBAL multiplicity reaches 2^31 (counts
-    merge in int32 because x64 is disabled on TPU; callers needing
-    exact counts beyond that must sum per-shard results host-side).
+    Precondition: no single distinct key's GLOBAL multiplicity reaches
+    2^31 (counts merge in int32 because x64 is disabled on TPU; callers
+    needing exact counts beyond that must sum per-shard results
+    host-side).
     """
     from jax.sharding import PartitionSpec as P
 
     mapped = jax.shard_map(
         _sharded_distinct_step, mesh=mesh,
-        in_specs=(P(ROWS_AXIS, None),),
+        in_specs=(P(ROWS_AXIS, None), P()),
         out_specs=(P(), P(), P()),
         check_vma=False)
-    return jax.jit(mapped)
+
+    @jax.jit
+    def sharded_distinct(keys, n_valid=None):
+        return mapped(keys, jnp.int32(
+            keys.shape[0] if n_valid is None else n_valid))
+
+    return sharded_distinct
+
+
+def _wants_device(n: int, use_device) -> bool:
+    if use_device is None:
+        use_device = os.environ.get("THEIA_NPR_DEVICE", "auto")
+    if use_device in ("0", False, "off", "false"):
+        return False
+    if use_device in ("1", True, "on", "true"):
+        return True
+    return n >= _AUTO_THRESHOLD
+
+
+def plan_distinct(keys: np.ndarray,
+                  use_device: str | bool | None = None,
+                  mesh: jax.sharding.Mesh | None = None
+                  ) -> Callable[[], Tuple[np.ndarray, np.ndarray]]:
+    """The host's half of `device_distinct`, done now: choose the
+    path and, for the device, lay the key columns out and pack them
+    into a bucket's padded words. Returns the other half: call it for
+    (uniq, counts) — the transfer, the jitted call until ready, the
+    fetch of the distinct rows and their unpacking."""
+    n = keys.shape[0]
+    if n == 0:
+        return lambda: (keys.astype(np.int64), np.zeros((0,), np.int64))
+    if not _wants_device(n, use_device):
+        def on_host():
+            from ..store.views import group_reduce
+
+            uniq, counts = group_reduce(
+                keys.astype(np.int64), np.ones((n, 1), np.int64))
+            return uniq, counts[:, 0]
+        return on_host
+
+    layout = KeyLayout.of(keys)
+    shards = mesh.size if mesh is not None and mesh.size > 1 \
+        and n >= mesh.size else 1
+    words = layout.pack(keys, bucket_rows(-(-n // shards)) * shards).T
+    if shards > 1:
+        from ..parallel import cached_kernel
+
+        fn = cached_kernel(("npr_distinct", mesh),
+                           lambda: make_sharded_distinct(mesh))
+    else:
+        fn = distinct_rows
+
+    def on_device():
+        uniq, counts, n_unique = fn(words, np.int32(n))
+        u = int(n_unique)
+        # a prefix that holds them, of few lengths: a slice is a
+        # program too
+        head = min(max(1 << (u - 1).bit_length(), 1024), words.shape[0])
+        return (layout.unpack(np.asarray(uniq[:head])[:u]),
+                np.asarray(counts[:head])[:u].astype(np.int64))
+    return on_device
 
 
 def device_distinct(keys: np.ndarray,
@@ -149,42 +334,4 @@ def device_distinct(keys: np.ndarray,
     the all_gather + segment-sum collective (production scale-out of
     the Spark shuffle, SURVEY §2.7).
     """
-    n = keys.shape[0]
-    if n == 0:
-        return (keys.astype(np.int64),
-                np.zeros((0,), np.int64))
-    if use_device is None:
-        use_device = os.environ.get("THEIA_NPR_DEVICE", "auto")
-    if use_device in ("0", False, "off", "false"):
-        on_device = False
-    elif use_device in ("1", True, "on", "true"):
-        on_device = True
-    else:
-        on_device = n >= _AUTO_THRESHOLD
-    if not on_device:
-        from ..store.views import group_reduce
-
-        uniq, counts = group_reduce(
-            keys.astype(np.int64),
-            np.ones((n, 1), np.int64))
-        return uniq, counts[:, 0]
-
-    if keys.max(initial=0) >= _SENTINEL:
-        raise ValueError("dictionary code collides with the sentinel")
-    if mesh is not None and mesh.size > 1 and n >= mesh.size:
-        from ..parallel import cached_kernel
-        from ..parallel.mesh import pad_to_multiple
-
-        # Pad rows to the shard multiple with the sentinel; padding
-        # rows sort to the end of the merge and the step drops the
-        # trailing all-sentinel segment.
-        padded, _ = pad_to_multiple(keys.astype(np.int32), mesh.size,
-                                    axis=0, fill=_SENTINEL)
-        fn = cached_kernel(("npr_distinct", mesh),
-                           lambda: make_sharded_distinct(mesh))
-        uniq, counts, n_unique = fn(padded)
-    else:
-        uniq, counts, n_unique = distinct_rows(keys.astype(np.int32))
-    u = int(n_unique)
-    return (np.asarray(uniq[:u]).astype(np.int64),
-            np.asarray(counts[:u]).astype(np.int64))
+    return plan_distinct(keys, use_device, mesh)()

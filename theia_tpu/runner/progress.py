@@ -64,6 +64,18 @@ _M_DBSCAN_SORTED_POINTS = _metrics.counter(
     "Valid points of the batches DBSCAN jobs gave to the sorting "
     "kernel (ops.dbscan.sorted_points): it decides each from its "
     "neighbours in sorted order; 0 for a batch the Pallas kernel took")
+_M_NPR_ROWS_SORTED = _metrics.counter(
+    "theia_job_npr_rows_sorted_total",
+    "Rows that passed a policy-recommendation job's WHERE clause and "
+    "went into its DISTINCT (analytics.npr_device.device_distinct, "
+    "whichever path it took)")
+_M_NPR_DISTINCT_FLOWS = _metrics.counter(
+    "theia_job_npr_distinct_flows_total",
+    "Distinct flow 9-tuples that came out of it")
+_M_NPR_POLICIES = _metrics.counter(
+    "theia_job_npr_policies_total",
+    "Policy documents a policy-recommendation job wrote, by the "
+    "result table's kind (anp, acnp, acg, knp)", labelnames=("kind",))
 _M_READ_ROWS = _metrics.counter(
     "theia_job_read_rows_total",
     "Rows of the batch a job's read stage handed on",
@@ -147,6 +159,17 @@ class JobProgress:
             _M_DBSCAN_PAIR_TESTS.inc(pair_tests)
         if sorted_points:
             _M_DBSCAN_SORTED_POINTS.inc(sorted_points)
+
+    def distinct(self, rows_sorted: int, flows: int) -> None:
+        """Count what a policy-recommendation job's DISTINCT was given
+        and what it kept."""
+        _M_NPR_ROWS_SORTED.inc(rows_sorted)
+        _M_NPR_DISTINCT_FLOWS.inc(flows)
+
+    def recommended(self, policies_by_kind) -> None:
+        """Count the policy documents the job wrote, {kind: number}."""
+        for kind, n in policies_by_kind.items():
+            _M_NPR_POLICIES.labels(kind=kind).inc(n)
 
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
