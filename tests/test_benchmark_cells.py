@@ -314,7 +314,7 @@ def test_the_npr_cell_holds_the_documented_clusters_connections():
         "npr.aggregate_ms", "npr.emit_ms", "npr.rows_sorted",
         "npr.distinct_flows", "npr.policies", "npr.read_columns",
         "npr.read_bytes", "npr.distinct_device_ms",
-        "npr_distinct_roofline"}
+        "npr_distinct_roofline", "npr.documents_direct"}
     # of the TAD cells' readers the generic ones, none that names
     # kind="tad" or a TAD kernel
     assert layer & old == {
@@ -363,10 +363,49 @@ def test_the_npr_cell_is_rehearsed_on_the_cpu_backend():
     assert got["npr.rows_sorted"] % 4 == 0 and got["npr.rows_sorted"] > 0
     # an ANP and a reject ACNP a group, and the allow list's three
     assert got["npr.policies"] % 2 == 1 and got["npr.policies"] > 3
+    # every scalar of the generator's population is plain
+    assert got["npr.documents_direct"] == got["npr.policies"]
     assert got["npr.read_ms"] >= got["npr.scan_ms"] + got["npr.keys_ms"] \
         + got["npr.distinct_ms"] + got["npr.decode_ms"] - 1e-6
     assert got["npr.recommend_ms"] >= got["npr.aggregate_ms"] \
         + got["npr.emit_ms"] - 1e-6
+
+
+def test_the_npr_cells_direct_documents_reduce_to_a_jobs_figures():
+    """`npr.documents_direct` reads the program's own exposition around
+    one NPR job: the documents `dump_yaml` wrote itself, a job, which
+    over the synthetic population's plain names are all that
+    `npr.policies` counts; a manager without the counter (the parent)
+    gives nothing."""
+    from benchmarks import prom
+    from theia_tpu.analytics import run_npr
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.runner.progress import NPR_STAGES, JobProgress
+    from theia_tpu.store import FlowDatabase
+
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=24, points_per_series=5, seed=2)))
+    before = prom.parse(exposition.render())
+    for job in ("one", "two"):
+        run_npr(db, "initial", recommendation_id=job,
+                progress=JobProgress(job, NPR_STAGES, kind="npr"))
+    after = prom.parse(exposition.render())
+    counters = {"metrics_before": before, "metrics_after": after}
+
+    def read(name):
+        reader = BENCH.reader("per_layer", name)
+        return extend.resolve("reduction", reader["reduce"])(
+            counters, reader)
+
+    rows = db.recommendations.scan().to_rows()
+    assert read("npr.documents_direct") == read("npr.policies") \
+        == len(rows) / 2 > 3
+    series = BENCH.reader("per_layer", "npr.documents_direct")["series"]
+    assert series == "theia_job_npr_documents_direct_total" in after
+    after.pop(series), before.pop(series, None)
+    assert read("npr.documents_direct") is None
 
 
 def test_an_operator_no_manager_answers_ends_in_the_warm_up(tmp_path):

@@ -376,25 +376,26 @@ def run_npr(db: FlowDatabase,
 
     if progress:
         progress.stage("recommend")
-    if recommendation_type == "initial":
-        result = merge_policy_dict(
-            recommend_policies_for_ns_allow_list(ns_allow_list),
-            recommend_policies_for_unprotected_flows(
-                unprotected, ns_allow_list, option, to_services,
-                progress))
-    else:
-        result = recommend_policies_for_unprotected_flows(
-            unprotected, ns_allow_list, option, to_services, progress)
-        if option in (1, 2):
-            trusted = read_distinct_flows(
-                flows, limit, start_time, end_time, unprotected=False,
-                rm_labels=rm_labels, mesh=mesh, use_device=use_device,
-                progress=progress)
+    with policy_gen.count_direct() as direct:
+        if recommendation_type == "initial":
             result = merge_policy_dict(
-                result,
-                recommend_antrea_policies(
-                    trusted, ns_allow_list, option, deny_rules=False,
-                    to_services=to_services, progress=progress))
+                recommend_policies_for_ns_allow_list(ns_allow_list),
+                recommend_policies_for_unprotected_flows(
+                    unprotected, ns_allow_list, option, to_services,
+                    progress))
+        else:
+            result = recommend_policies_for_unprotected_flows(
+                unprotected, ns_allow_list, option, to_services, progress)
+            if option in (1, 2):
+                trusted = read_distinct_flows(
+                    flows, limit, start_time, end_time, unprotected=False,
+                    rm_labels=rm_labels, mesh=mesh, use_device=use_device,
+                    progress=progress)
+                result = merge_policy_dict(
+                    result,
+                    recommend_antrea_policies(
+                        trusted, ns_allow_list, option, deny_rules=False,
+                        to_services=to_services, progress=progress))
 
     if progress:
         progress.stage("write")
@@ -408,6 +409,7 @@ def run_npr(db: FlowDatabase,
     } for kind, policies in result.items() for policy in policies if policy]
     db.recommendations.insert_rows(rows)
     if progress:
-        progress.recommended(collections.Counter(r["kind"] for r in rows))
+        progress.recommended(
+            collections.Counter(r["kind"] for r in rows), direct[0])
         progress.done()
     return recommendation_id

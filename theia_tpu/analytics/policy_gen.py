@@ -4,9 +4,22 @@ Builds the same policy documents the reference emits via kubernetes-client
 / antrea_crd dataclasses + camelCase conversion (reference:
 plugins/policy-recommendation/policy_recommendation_job.py:188-618 and
 policy_recommendation_utils.py camel_dict/dict_to_yaml). Here the dicts
-are written in camelCase directly — no dataclass detour — and dumped with
-pyyaml. Policy kinds match the reference's result-table values
-(antrea_crd.py:789-793: anp/knp/acnp/acg).
+are written in camelCase directly — no dataclass detour. Policy kinds
+match the reference's result-table values (antrea_crd.py:789-793:
+anp/knp/acnp/acg).
+
+`dump_yaml` is the one exit of every generator and returns the text
+`yaml.dump(doc)` returns, byte for byte. A document of `dict` (`str`
+keys), `list`, `str` and `int` whose every string PyYAML would leave
+plain (`_PLAIN`: a letter first, then letters, digits, `_ . / -`, no
+YAML 1.1 boolean or null word; or an IPv4 CIDR) it writes itself, as
+block text with no quoting logic, at a small fraction of what PyYAML's
+Python emitter takes (`PERF.md` §6, PR 43). Any other node (an empty
+or numeric-looking label value, a space, `:`, `#`, non-ASCII, an IPv6
+CIDR, a `bool`, `float`, `None`, a non-`str` key, a container that
+occurs twice and so would get an anchor) makes it decline the whole
+document and call `yaml.dump(doc)`. `count_direct` tells a caller how
+many documents took the first exit.
 
 Name suffixes: the reference appends 5 random lowercase/digit chars
 (generate_policy_name :244-250); we derive a deterministic 5-char hash of
@@ -16,10 +29,14 @@ don't need to stub the RNG.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import functools
 import hashlib
 import ipaddress
 import json
-from typing import Dict, List, Optional
+import re
+from typing import Dict, Iterator, List, Optional
 
 import yaml
 
@@ -43,8 +60,119 @@ def _cidr(ip: str) -> str:
     return f"{ip}/32" if version == 4 else f"{ip}/128"
 
 
+# -- the documents' text -------------------------------------------------
+
+# Strings for which PyYAML's emitter chooses the plain style and its
+# resolver sees a string, by a pattern much narrower than its analysis:
+# nothing here can start a number, a timestamp, `<<`, `=` or an
+# indicator, hold a space, `:` or `#`, or reach the 128 characters
+# (tag included) at which a key takes the `? ` form. The words are
+# YAML 1.1's booleans and null, in any case.
+_PLAIN = re.compile(
+    r"(?!(?:y|n|yes|no|true|false|on|off|null)\Z)"
+    r"[A-Za-z][A-Za-z0-9_./-]{0,99}"
+    r"|[0-9]{1,3}(?:\.[0-9]{1,3}){3}/[0-9]{1,3}",
+    re.ASCII | re.IGNORECASE)
+
+
+@functools.lru_cache(maxsize=4096)
+def _plain(scalar) -> bool:
+    """Most of a job's strings are the documents' own keys and words,
+    seen thousands of times: the answer is kept."""
+    return type(scalar) is str and _PLAIN.fullmatch(scalar) is not None
+
+
+class _Declined(Exception):
+    """The direct writer met a node it does not promise PyYAML's text
+    for."""
+
+
+def _block_text(doc: Dict) -> str:
+    """Block YAML as `yaml.dump` lays it out: keys sorted, a child
+    mapping two spaces in, a child sequence's `- ` at its key's own
+    indent, a collection inside a sequence starting on the `- ` line,
+    `{}` and `[]` for the empty ones. Raises `_Declined`."""
+    if type(doc) is not dict or not doc:
+        raise _Declined     # a root that is no block mapping
+    out: List[str] = []
+    add = out.append
+    seen = {id(doc)}    # containers: PyYAML anchors a second visit
+
+    def mapping(node: Dict, pad: str, at_pad: bool) -> None:
+        # keys at `pad`; `at_pad`: the cursor stands there, behind `- `
+        if not all(map(_plain, node)):
+            raise _Declined
+        for key in sorted(node):
+            add(f"{key}:" if at_pad else f"{pad}{key}:")
+            at_pad = False
+            value(node[key], pad, True)
+
+    def sequence(node: List, pad: str, at_pad: bool) -> None:
+        for item in node:
+            add("- " if at_pad else f"{pad}- ")
+            at_pad = False
+            value(item, pad, False)
+
+    def value(node, pad: str, after_key: bool) -> None:
+        # what follows `key:` or `- ` written at `pad`
+        kind = type(node)
+        if kind is str:
+            if not _plain(node):
+                raise _Declined
+            text = node
+        elif kind is int:
+            text = str(node)
+        elif kind is dict or kind is list:
+            if id(node) in seen:
+                raise _Declined
+            seen.add(id(node))
+            if not node:
+                text = "{}" if kind is dict else "[]"
+            else:
+                if after_key:
+                    add("\n")
+                if kind is dict:
+                    mapping(node, pad + "  ", not after_key)
+                else:
+                    sequence(node, pad if after_key else pad + "  ",
+                             not after_key)
+                return
+        else:
+            raise _Declined
+        add(f" {text}\n" if after_key else f"{text}\n")
+
+    mapping(doc, "", False)
+    return "".join(out)
+
+
+_direct: contextvars.ContextVar = contextvars.ContextVar(
+    "policy_gen_direct", default=None)
+
+
+@contextlib.contextmanager
+def count_direct() -> Iterator[List[int]]:
+    """A one-element tally of the documents `dump_yaml` writes itself
+    inside the block, in this thread (a context variable: a job in the
+    controller's other worker has its own)."""
+    tally = [0]
+    token = _direct.set(tally)
+    try:
+        yield tally
+    finally:
+        _direct.reset(token)
+
+
 def dump_yaml(doc: Dict) -> str:
-    return yaml.dump(doc)
+    """The text `yaml.dump(doc)` gives: written here where every node
+    is one `_block_text` promises that text for, by PyYAML where not."""
+    try:
+        text = _block_text(doc)
+    except _Declined:
+        return yaml.dump(doc)
+    tally = _direct.get()
+    if tally is not None:
+        tally[0] += 1
+    return text
 
 
 # -- K8s NetworkPolicy (option 3; reference generate_k8s_np :253-296) ----

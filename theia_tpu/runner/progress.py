@@ -76,6 +76,10 @@ _M_NPR_POLICIES = _metrics.counter(
     "theia_job_npr_policies_total",
     "Policy documents a policy-recommendation job wrote, by the "
     "result table's kind (anp, acnp, acg, knp)", labelnames=("kind",))
+_M_NPR_DOCUMENTS_DIRECT = _metrics.counter(
+    "theia_job_npr_documents_direct_total",
+    "Those of them whose YAML analytics.policy_gen.dump_yaml wrote "
+    "itself; the rest held a scalar it leaves to PyYAML")
 _M_READ_ROWS = _metrics.counter(
     "theia_job_read_rows_total",
     "Rows of the batch a job's read stage handed on",
@@ -166,10 +170,12 @@ class JobProgress:
         _M_NPR_ROWS_SORTED.inc(rows_sorted)
         _M_NPR_DISTINCT_FLOWS.inc(flows)
 
-    def recommended(self, policies_by_kind) -> None:
-        """Count the policy documents the job wrote, {kind: number}."""
+    def recommended(self, policies_by_kind, direct: int) -> None:
+        """Count the policy documents the job wrote, {kind: number},
+        and how many of them `dump_yaml` wrote without PyYAML."""
         for kind, n in policies_by_kind.items():
             _M_NPR_POLICIES.labels(kind=kind).inc(n)
+        _M_NPR_DOCUMENTS_DIRECT.inc(direct)
 
     def wrote(self, batch) -> None:
         """Count the batch of result rows the `write` stage inserted."""
