@@ -16,6 +16,13 @@ from benchmarks.tests.test_extension import (            # noqa: F401
     test_forgotten_overlay_is_read_anew,
     test_resolver,
 )
+from benchmarks.tests.test_retention_trim import (       # noqa: F401
+    test_a_refused_request_counts_as_failed_and_no_answer_is_a_gap,
+    test_a_round_that_does_something_else_is_not_correct,
+    test_a_sound_round_is_correct,
+    test_only_the_faults_own_numbers_move,
+    test_the_references_views_are_the_programs_group_by,
+)
 from benchmarks.tests.test_snapshot_prefix import (      # noqa: F401
     test_a_perturbed_snapshot_or_log_is_not_correct,
     test_a_refused_request_counts_as_failed,
@@ -140,6 +147,175 @@ def test_the_checkpoint_cell_is_saturate_with_the_snapshot_on():
     assert {m["name"] for m in BENCH.metrics_of(
         "default.ingest-saturate", "per_layer")} < layer
     assert len({n for n in layer if n.startswith("ckpt.")}) == 13
+
+
+TRIM_CELL = "default-trim.ingest-saturate-trim"
+TRIM_METRICS = {
+    "trim.total_ms", "trim.boundary_ms", "trim.delete_flows_ms",
+    "trim.delete_views_ms", "trim.rows_before", "trim.rows_deleted",
+    "trim.bytes_freed", "trim.append_wait_ms_per_block",
+    "trim.longest_ack_gap_ms", "trim.acked_rows_per_s_during"}
+
+
+def test_the_trim_cell_is_saturate_with_the_trim_and_nothing_else():
+    """The two cells differ in the store's volume, the preload that
+    brings it to 99 % of the trigger, and the one round: their gap on
+    one commit is the trim."""
+    sat = BENCH.traffic("ingest-saturate")
+    trim = BENCH.traffic("ingest-saturate-trim")
+    assert trim["generator"] == sat["generator"]
+    assert trim["limits"] == sat["limits"]
+    assert trim["trace_seconds"] == sat["trace_seconds"] == 10
+    producers, trimmer = trim["workers"]
+    assert producers == {**sat["workers"][0], "preload_blocks": 109,
+                         "prepared_blocks": 237}
+    # a producer encodes from block 0 on: the window's supply is
+    # saturate's 128 behind the 109 preloaded
+    assert producers["prepared_blocks"] \
+        == sat["workers"][0]["prepared_blocks"] + 109
+    assert trimmer == {"role": "trimmer", "count": 1, "offset_s": 3.0}
+    # store_totals would hold the store to rows the deployment deleted
+    assert trim["checks"] == ["retention_trim"] + [
+        c for c in sat["checks"] if c != "store_totals"]
+    assert not getattr(extend.module("check", "retention_trim"),
+                       "limits", ())
+    cfg = BENCH.config("theia-default-trim-1x1")
+    base = BENCH.config("theia-default-1x1")
+    entry = next(c for c in BENCH.doc["configs"]
+                 if c["name"] == cfg["name"])
+    assert entry["reduced"] == ["checkpoint_interval_s"]
+    assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    # the sibling's one deviation removed: the chart's volume, which is
+    # the program's default
+    assert cfg["manager_args"] == [
+        "8589934592" if a == "68719476736" else a
+        for a in base["manager_args"]]
+    assert cfg["capacity_bytes"] == cfg["source_capacity_bytes"] \
+        == 8 << 30
+    assert (cfg["env"], cfg["expect"]) == (base["env"], base["expect"])
+    assert "capacity_bytes" not in cfg["assumed"]
+    assert cfg["monitor"]["threshold"] == 0.5 \
+        and cfg["monitor"]["delete_percentage"] == 0.5 \
+        and cfg["monitor"]["skip_rounds"] == 3 \
+        and cfg["monitor"]["interval_s"] == 60
+    assert set(base["guarantees"]) < set(cfg["guarantees"])
+    assert "not promised" in cfg["guarantees"]["restart"]
+    # 99.0 % of the trigger when the window opens, at 284 B a row
+    rows = (producers["count"] * (producers["preload_blocks"]
+                                  + producers["warm_blocks"]) * 32000)
+    trigger = cfg["capacity_bytes"] * cfg["monitor"]["threshold"]
+    assert rows == 14976000
+    assert 0.985 < rows * 284 / trigger < 0.995
+    assert cfg["retained_window_rows"] == int(trigger // 284) == 15123124
+    cell = BENCH.cell(TRIM_CELL)
+    assert cell["chips"] == 1 and len(cell["why"]) <= 200
+    assert {m["name"] for m in BENCH.metrics_of(TRIM_CELL, "end_to_end")} \
+        == {"acked_rows_per_s", "setup_s"}
+    layer = {m["name"] for m in BENCH.metrics_of(TRIM_CELL, "per_layer")}
+    old = {m["name"] for m in BENCH.metrics_of("default.ingest-saturate",
+                                               "per_layer")}
+    assert layer - old == TRIM_METRICS and old < layer
+    for name in TRIM_METRICS:
+        m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+        reader = BENCH.reader("per_layer", name)
+        assert (m["layer"], m["moves"], m["workloads"]) == (
+            "retention", "acked_rows_per_s", [TRIM_CELL])
+        assert (reader["layer"], reader["moves"], reader["source"]) == (
+            m["layer"], m["moves"], m["source"])
+        # PR 39's readers are counted by the series they name
+        assert "_cpu_seconds" not in str(reader)
+        assert "_minor_faults_total" not in str(reader)
+
+
+def test_the_trim_cell_is_rehearsed_on_the_cpu_backend():
+    """The cell at a tiny size with no edit to it: manager child, the
+    preload, the warm-up's idle round, the window's round, the checks,
+    and every host-side reader of a traced run; plumbing only, no
+    number of it is a result. The rehearsal's store (4 x 32 blocks of
+    256 rows) gets a volume of its own size and stops just under its
+    trigger, as the cell's store does under the chart's (99.9 % here
+    where the cell has 99.0 %: the window's first block crosses it,
+    however slowly a loaded machine acks the second)."""
+    from benchmarks import rehearsal, selftest
+
+    cell = BENCH.cell(TRIM_CELL)
+    scale = rehearsal.scale_for(BENCH, cell)
+    rows = 4 * scale["preload_blocks"] * 64 * 4
+    scale["env"]["THEIA_STORE_CAPACITY_BYTES"] = str(
+        int(2 * rows * 284 / 0.999))
+    real = rehearsal.scale_for
+    rehearsal.scale_for = lambda bench, c: scale
+    try:
+        plain, traced = selftest.rehearse(cell, trace=True)
+    finally:
+        rehearsal.scale_for = real
+    for out in (plain, traced):
+        assert out["correct"] and out["failed"] == 0
+        assert {"trim_rounds_gap", "trim_boundary_gap",
+                "trim_rows_deleted_gap", "trim_store_rows_gap",
+                "trim_store_octets_gap", "trim_oldest_row_gap",
+                "trim_view_rows_gap", "trim_detector_series_gap",
+                "trim_ambiguous_blocks", "acks_not_whole",
+                "detector_series_gap"} <= set(out["checks"])
+        assert "store_rows_gap" not in out["checks"]
+    assert set(plain["metrics"]) == {"acked_rows_per_s", "setup_s"}
+    got = {k: v["value"] for k, v in traced["metrics"].items()}
+    assert TRIM_METRICS <= set(got)
+    assert got["trim.rows_before"] > rows
+    assert got["trim.rows_deleted"] * 2 <= got["trim.rows_before"]
+    assert got["trim.bytes_freed"] == got["trim.rows_deleted"] * 284
+    assert got["trim.total_ms"] >= got["trim.boundary_ms"] \
+        + got["trim.delete_flows_ms"] + got["trim.delete_views_ms"] - 1e-6
+
+
+def test_a_trimmer_no_manager_answers_ends_in_the_warm_up():
+    """The parent commit has no /admin/retention (404), a manager with
+    the loop off answers 409, one over its threshold already would
+    trim in the warm-up: each must end the worker in set-up (exit 1
+    for the run), never hang or go on."""
+    import http.server
+    import json
+    import threading
+
+    answers = []
+
+    class Canned(http.server.BaseHTTPRequestHandler):
+        def do_POST(self):                               # noqa: N802
+            status, doc = answers.pop(0)
+            body = json.dumps(doc).encode()
+            self.send_response(status)
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
+
+        def log_message(self, *args):
+            pass
+
+    httpd = http.server.HTTPServer(("127.0.0.1", 0), Canned)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    try:
+        extend.use(manifest.HERE)
+        role = extend.resolve("role", "trimmer")({
+            "addr": f"http://127.0.0.1:{httpd.server_address[1]}",
+            "offset_s": 3.0})
+        assert role.handle(["preload"]) == {"event": "preloaded",
+                                            "records": []}
+        answers[:] = [(404, {}), (409, {"message": "no loop"}),
+                      (200, {"result": "trimmed", "usageBefore": 0.51}),
+                      (200, {"result": "idle", "usageBefore": 0.49,
+                             "seconds": 0.001, "stagesMs": {"usage": 0.2}})]
+        with pytest.raises(SystemExit, match="404"):
+            role.handle(["warm"])
+        with pytest.raises(SystemExit, match="409"):
+            role.handle(["warm"])
+        with pytest.raises(SystemExit, match="'trimmed' at usage 0.51"):
+            role.handle(["warm"])
+        (rec,) = role.handle(["warm"])["records"]
+        assert (rec["result"], rec["usage_before"], rec["stages_ms"]) \
+            == ("idle", 0.49, {"usage": 0.2})
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
 
 
 def test_the_arima_cell_holds_a_whole_retained_day_of_20_connections():
@@ -332,7 +508,7 @@ def test_the_npr_cell_holds_the_documented_clusters_connections():
         assert (reader["layer"], reader["moves"], reader["source"]) == (
             m["layer"], m["moves"], m["source"])
         assert 'kind="tad"' not in str(reader)
-    assert len(BENCH.doc["workloads"]) == 7
+    assert len(BENCH.doc["workloads"]) == 8
 
 
 def test_the_npr_cell_is_rehearsed_on_the_cpu_backend():
@@ -449,19 +625,31 @@ def test_an_operator_no_manager_answers_ends_in_the_warm_up(tmp_path):
     ("longest_ack_gap", {"p": {"role": "producer"}}, 2500.0),
     ("rate_between", {"p": {"role": "producer", "field": "rows",
                             "between": "operator"}}, 3 * 32000 / 20.0),
+    # the trim cell's readers, over the same run with a trimmer's record
+    ("record_field", {"role": "trimmer",
+                      "p": {"role": "trimmer",
+                            "field": "stages_ms.delete_flows"}}, 1500.0),
+    ("record_field", {"role": "trimmer",
+                      "p": {"role": "trimmer", "field": "seconds",
+                            "scale": 1000.0}}, 20000.0),
+    ("rate_between", {"role": "trimmer",
+                      "p": {"role": "producer", "field": "rows",
+                            "between": "trimmer"}}, 3 * 32000 / 20.0),
 ])
 def test_reductions_the_cell_brings(name, data, want):
     extend.use(manifest.HERE)
     acks = [100.5, 101.0, 103.5, 104.0, 121.0, 140.0]
     run = {
         "t_open": 100.0, "seconds": 51.0, "clean": [0.0, float("inf")],
-        "specs": [{"role": "producer"}, {"role": "operator"}],
+        "specs": [{"role": "producer"},
+                  {"role": data.get("role", "operator")}],
         "results": [
             {"records": [{"ack": t, "status": 200, "rows": 32000}
                          for t in acks]},
             {"records": [{"send": 103.0, "ack": 123.0, "status": 200,
                           "seconds": 20.0,
-                          "stages_ms": {"hold": 1500.0}}]}],
+                          "stages_ms": {"hold": 1500.0,
+                                        "delete_flows": 1500.0}}]}],
     }
     got = extend.resolve("reduction", name)(run, data["p"])
     if name == "longest_ack_gap":

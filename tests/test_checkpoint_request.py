@@ -223,18 +223,18 @@ def test_a_requested_snapshot_counts_as_the_tick(tmp_path):
     ck.start()
     try:
         time.sleep(0.5)
-        assert ck._started == 0
+        assert ck._rounds.started == 0
         assert ck.request(timeout=10)["generation"] == 1
         t_end = time.monotonic()
         # the timer's own tick would have come 1.0 s after the start
         time.sleep(max(0.0, t_end + 0.75 - time.monotonic()))
-        assert ck._started == 1
+        assert ck._rounds.started == 1
         deadline = t_end + 3.0
-        while ck._started < 2 and time.monotonic() < deadline:
+        while ck._rounds.started < 2 and time.monotonic() < deadline:
             time.sleep(0.02)
         # one interval after the requested one ENDED, and skipped:
         # nothing changed
-        assert ck._started == 2 and time.monotonic() >= t_end + 0.95
+        assert ck._rounds.started == 2 and time.monotonic() >= t_end + 0.95
         while ck.last_result.get("skipped") is not True \
                 and time.monotonic() < deadline:
             time.sleep(0.02)

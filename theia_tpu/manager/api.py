@@ -734,6 +734,24 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
             from ..analysis import lockdep as _lockdep
             self._send_json(_lockdep.stats_doc())
             return
+        if parts == ("debug", "retention"):
+            # The retention monitor at inspection depth: the loop's
+            # /healthz block and, for each materialized view a round
+            # trims beside `flows`, what does not depend on how its
+            # parts lie: sum(octetDeltaCount) and the oldest
+            # timeInserted. Two columns of every view are walked
+            # (nothing is merged, as every other read of a view
+            # does), so it is asked for, not polled; time ranges
+            # narrate traffic shape — token-gated like the other
+            # /debug surfaces.
+            self._require_auth()
+            doc = dict(self.retention.stats()) \
+                if self.retention is not None else {}
+            totals = getattr(self.controller.db, "view_totals", None)
+            if callable(totals):
+                doc["views"] = totals()
+            self._send_json(doc)
+            return
         if parts == ("debug", "views"):
             # Declared rollup views at inspection depth (`theia
             # views`): definitions, tiers, per-store part/row counts,
@@ -1245,6 +1263,9 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
         if parts == ("admin", "checkpoint"):
             self._post_checkpoint()
             return
+        if parts == ("admin", "retention"):
+            self._post_retention()
+            return
         if self.path.startswith(GROUP_INTELLIGENCE) and len(parts) == 4:
             kind = _RESOURCE_KIND[parts[3]]
             body = self._read_body()
@@ -1292,6 +1313,35 @@ class ManagerAPIHandler(BaseHTTPRequestHandler):
             self._send_error_json(409, str(e))
             return
         self._send_json(result, 500 if "error" in result else 200)
+
+    def _post_retention(self) -> None:
+        """POST /admin/retention: ask the retention loop for one round
+        now and answer when it has run (token-gated like every POST).
+        The round is the timer's own: same thread, same routine, same
+        counters and back-off, and it counts as the tick: the next
+        falls one interval after it ends. The answer is the round's
+        record (`result` idle / trimmed / skipped / error, and for a
+        round that went on to delete `usageBefore`, `rowsBefore`,
+        `deleteN`, `boundary`, `rowsDeleted`, `viewRowsDeleted`,
+        `bytesFreed`, `rowsAfter`; always `seconds`, `stagesMs`),
+        which /healthz keeps as `retention.lastRound`. 409 when no
+        round can be asked for (THEIA_RETENTION_INTERVAL <= 0); 500
+        with the reason when the round failed."""
+        from ..store.flow_store import RetentionUnavailable
+        self._read_raw_body()
+        loop = self.retention
+        if loop is None:
+            self._send_error_json(
+                409, "this manager runs no retention loop: "
+                     "THEIA_RETENTION_INTERVAL is 0 or less")
+            return
+        try:
+            result = loop.request()
+        except RetentionUnavailable as e:
+            self._send_error_json(409, str(e))
+            return
+        self._send_json(result,
+                        500 if result.get("result") == "error" else 200)
 
     def _post_query_partial(self) -> None:
         """Cluster-internal scatter-gather server half: execute the

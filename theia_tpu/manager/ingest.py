@@ -944,6 +944,10 @@ class IngestManager:
                 # detector stages: a request that stalled behind a
                 # snapshot's hold says so
                 _trace.add_stage(journaled["latchWait"])
+            if "tableLockWait" in journaled:
+                # likewise: a request whose append stood behind a
+                # retention round's hold of the table says so
+                _trace.add_stage(journaled["tableLockWait"])
         else:
             n = local_dup or 0
         if seq is not None and routed is not None and fut is not None:
@@ -1017,10 +1021,13 @@ class IngestManager:
         """The store leg, on a pool thread. `journaled` is filled with
         the flows record's `walLsn` and the `latchWait` stage where
         the store journals into one log (a sharded store's slices have
-        one LSN each: none is reported)."""
+        one LSN each: none is reported), and with `tableLockWait`, the
+        append's wait for the flat table's lock."""
         t0 = time.perf_counter()
         applied = getattr(self.db, "wal_last_applied", None)
         before = applied() if callable(applied) else None
+        lock_wait = getattr(self.db, "table_lock_wait", None)
+        waited = lock_wait() if callable(lock_wait) else None
         try:
             # kwargs are passed only when set, so minimal insert_flows
             # signatures (test doubles, pre-wire stores) keep working
@@ -1034,6 +1041,10 @@ class IngestManager:
             if journaled is not None and after is not None \
                     and after is not before:
                 journaled["walLsn"], journaled["latchWait"] = after
+            if journaled is not None and callable(lock_wait):
+                wait = lock_wait()
+                if wait is not None and wait is not waited:
+                    journaled["tableLockWait"] = wait
             return n
         finally:
             _M_STAGE_STORE.observe(time.perf_counter() - t0)

@@ -36,6 +36,7 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..utils import get_logger
 from ..utils.faults import fire as _fire_fault
+from ..utils.rounds import AskedRounds
 from .flow_store import checkpoint_stage
 
 logger = get_logger("checkpoint")
@@ -80,8 +81,6 @@ class Checkpointer:
         self.checkpoints_written = 0
         self.last_checkpoint_time: float = 0.0
         self.last_error: Optional[str] = None
-        #: what the last run gave (`request` answers with it)
-        self.last_result: Optional[Dict[str, object]] = None
         self._last_fingerprint: Optional[Tuple] = (
             self._fingerprint() if assume_current else None)
         #: WAL stamp of the PREVIOUS successful snapshot — GC lags one
@@ -92,15 +91,15 @@ class Checkpointer:
         self._gc_stamp = None
         self._last_stamp = None
         self._last_stages: Optional[Dict[str, float]] = None
-        self._stopped = False
         self._thread: Optional[threading.Thread] = None
-        #: runs are numbered as they start; a request waits for the
-        #: first run that starts after it arrived
-        self._cond = threading.Condition()
-        self._started = 0
-        self._finished = 0
-        self._wanted = 0
-        self._results: Dict[int, Dict[str, object]] = {}
+        #: the timer's runs and the runs asked for (`request`)
+        self._rounds = AskedRounds("checkpointer", "snapshot",
+                                   CheckpointUnavailable)
+
+    @property
+    def last_result(self) -> Optional[Dict[str, object]]:
+        """What the last run gave (`request` answers with it)."""
+        return self._rounds.last
 
     # -- lifecycle --------------------------------------------------------
 
@@ -108,6 +107,7 @@ class Checkpointer:
         self._gc_stale_tmp()
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="theia-checkpointer")
+        self._rounds.open()
         self._thread.start()
 
     def _gc_stale_tmp(self) -> None:
@@ -141,9 +141,7 @@ class Checkpointer:
         wedged write) — the caller's final save could then race a
         late os.replace; both writes are atomic, so the file is never
         torn, but the caller should log the condition."""
-        with self._cond:
-            self._stopped = True
-            self._cond.notify_all()
+        self._rounds.stop()
         if self._thread:
             self._thread.join(timeout=30)
             if self._thread.is_alive():
@@ -153,29 +151,11 @@ class Checkpointer:
 
     def _loop(self) -> None:
         due = time.monotonic() + self.interval
-        while True:
-            with self._cond:
-                while not self._stopped \
-                        and self._wanted <= self._started:
-                    left = due - time.monotonic()
-                    if left <= 0:
-                        break
-                    self._cond.wait(left)
-                if self._stopped:
-                    return
-                self._started += 1
-                run = self._started
+        while (run := self._rounds.next(due)) is not None:
             result = self._run_once()
-            with self._cond:
-                self._finished = run
-                self.last_result = result
-                # kept for the requests that wait for this run (they
-                # wake on the notify below), not for longer
-                self._results[run] = result
-                self._results.pop(run - 2, None)
-                self._cond.notify_all()
             # a run, asked for or not, is the tick
             due = time.monotonic() + self.interval
+            self._rounds.done(run, result)
 
     def _run_once(self) -> Dict[str, object]:
         """One `checkpoint()` with its outcome as a document; keeps
@@ -219,35 +199,14 @@ class Checkpointer:
         out first). Runs on the checkpointer's thread, one at a time.
         Raises CheckpointUnavailable when that thread is not running,
         TimeoutError after `timeout` seconds."""
-        deadline = None if timeout is None \
-            else time.monotonic() + timeout
-        with self._cond:
-            if self._thread is None or self._stopped:
-                raise CheckpointUnavailable(
-                    "the checkpointer is not running")
-            run = self._started + 1
-            self._wanted = max(self._wanted, run)
-            self._cond.notify_all()
-            while self._finished < run:
-                if self._stopped:
-                    raise CheckpointUnavailable(
-                        "the checkpointer stopped")
-                left = None if deadline is None \
-                    else deadline - time.monotonic()
-                if left is not None and left <= 0:
-                    raise TimeoutError(
-                        f"no snapshot within {timeout:g}s")
-                self._cond.wait(left)
-            return self._results.get(run, self.last_result)
+        return self._rounds.ask(timeout)
 
     def status(self) -> Dict[str, object]:
         """The `checkpoint` block of /healthz."""
-        with self._cond:
-            running = self._started > self._finished
         doc: Dict[str, object] = {
             "intervalSeconds": self.interval,
             "written": self.checkpoints_written,
-            "running": running,
+            "running": self._rounds.running,
             "lastError": self.last_error,
         }
         last = self.last_result

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import concurrent.futures
 import contextlib
+import functools
 import os
 import tempfile
 import threading
@@ -69,6 +70,7 @@ from ..utils.env import env_float
 from ..utils.faults import fire as _fire_fault
 from ..utils.logging import get_logger
 from ..utils.pool import get_pool
+from ..utils.rounds import AskedRounds
 from .views import MATERIALIZED_VIEWS, ViewTable
 from ..analysis.lockdep import named_lock
 
@@ -100,6 +102,20 @@ _M_RET_DEMOTED = _metrics.counter(
     "theia_retention_bytes_demoted_total",
     "Resident bytes freed by demoting parts to the cold tier instead "
     "of deleting rows (parts engine tiered retention)")
+_M_RET_FREED = _metrics.counter(
+    "theia_retention_bytes_freed_total",
+    "Resident column bytes of the flow rows that capacity-based "
+    "retention rounds trimmed")
+_M_RET_VIEW_DELETED = _metrics.counter(
+    "theia_retention_view_rows_deleted_total",
+    "Rows trimmed from a materialized view by capacity-based "
+    "retention rounds, as the view's parts held them (per insert "
+    "block; a read merges equal keys across blocks)",
+    labelnames=("view",))
+_M_TABLE_LOCK_WAIT = _metrics.histogram(
+    "theia_ingest_table_lock_wait_seconds",
+    "Wait of one flows append for the table's lock (flat engine): a "
+    "retention round's delete holds it while it copies the table")
 _M_SNAP_FALLBACK = _metrics.counter(
     "theia_snapshot_fallbacks_total",
     "Snapshot loads that failed verification on the primary file and "
@@ -121,6 +137,26 @@ _M_CKPT = {name: _M_CKPT_STAGE.labels(stage=name)
 
 def checkpoint_stage(name: str) -> "_trace.Stage":
     return _trace.stage("checkpoint." + name, _M_CKPT[name])
+
+
+_M_RET_STAGE = _trace.StageSeries(
+    "theia_retention_stage_seconds",
+    "Stages of one retention round that trims (RetentionMonitor.tick): "
+    "usage = flows.nbytes, boundary = the metadata walk and the "
+    "partition over the candidate batches, delete_flows = the table's "
+    "lock taken, the batches concatenated, the mask, the kept rows "
+    "filtered out, delete_views = the same boundary on the three "
+    "materialized views",
+    labelnames=("stage",))
+#: the stages of one round, in order; on the `bg.retention` span (and
+#: the profiler's host lines) each is `retention.<stage>`
+RETENTION_STAGES = ("usage", "boundary", "delete_flows", "delete_views")
+_M_RET = {name: _M_RET_STAGE.labels(stage=name)
+          for name in RETENTION_STAGES}
+
+
+def retention_stage(name: str) -> "_trace.Stage":
+    return _trace.stage("retention." + name, _M_RET[name])
 
 
 #: snapshot payload keys outside the table namespace
@@ -146,7 +182,7 @@ class Table:
     predicates compile to integer comparisons.
     """
 
-    def __init__(self, name: str, schema) -> None:
+    def __init__(self, name: str, schema, lock_wait_hist=None) -> None:
         self.name = name
         self.schema = schema
         self.dicts: Dict[str, StringDictionary] = {
@@ -163,6 +199,19 @@ class Table:
         # retention trims (deletes used to mask real throughput).
         self.rows_inserted_total = 0
         self.bytes_inserted_total = 0
+        # Resident bytes of the rows that `delete_older_than` deleted
+        # (TTL, retention rounds), counted under the same lock: a
+        # round reads its bytes freed off the rise, exact whatever is
+        # appended meanwhile.
+        self.bytes_trimmed_total = 0
+        # On the table that was given a series for it (the flat
+        # `flows`) an append's wait for `_lock` is a stage,
+        # `store.table_lock_wait`, kept per thread for the caller
+        # (`last_lock_wait`); on every other table it is not timed.
+        self._lock_wait = contextlib.nullcontext \
+            if lock_wait_hist is None else functools.partial(
+                _trace.stage, "store.table_lock_wait", lock_wait_hist)
+        self._appended = threading.local()
         # Cached source-dict → table-dict code mappings: a producer
         # streaming blocks with its own dictionaries pays string
         # re-encode only for NEW entries, not per block.
@@ -270,14 +319,31 @@ class Table:
     def _append_adopted(self, adopted: ColumnarBatch) -> None:
         """Make an already-adopted batch visible (the memory apply)."""
         nbytes = sum(a.nbytes for a in adopted.columns.values())
-        with self._lock:
-            self._batches.append(adopted)
-            if self._time_column is not None:
-                a = adopted[self._time_column]
-                self._batch_meta.append((int(a.min()), int(a.max())))
-            self.generation += 1
-            self.rows_inserted_total += len(adopted)
-            self.bytes_inserted_total += nbytes
+        with self._lock_wait() as wait:
+            self._lock.acquire()
+        try:
+            self._append_locked(adopted, nbytes)
+        finally:
+            self._lock.release()
+        self._appended.wait = wait
+
+    def _append_locked(self, adopted: ColumnarBatch,
+                       nbytes: int) -> None:
+        """Body of _append_adopted; caller holds self._lock."""
+        self._batches.append(adopted)
+        if self._time_column is not None:
+            a = adopted[self._time_column]
+            self._batch_meta.append((int(a.min()), int(a.max())))
+        self.generation += 1
+        self.rows_inserted_total += len(adopted)
+        self.bytes_inserted_total += nbytes
+
+    def last_lock_wait(self) -> Optional["_trace.Stage"]:
+        """The finished `store.table_lock_wait` stage of the calling
+        thread's last append (the wait as that thread spent it); None
+        before its first, on a table whose appends are not timed, and
+        on the parts engine, whose memtable append is its own."""
+        return getattr(self._appended, "wait", None)
 
     def _row_count_locked(self) -> int:
         """Row count; caller holds self._lock (the sharded facade
@@ -433,6 +499,9 @@ class Table:
             self._batches = [kept] if len(kept) else []
             self._refresh_meta_locked()
             self.generation += 1
+            self.bytes_trimmed_total += (
+                sum(v.nbytes for v in data.columns.values())
+                - sum(v.nbytes for v in kept.columns.values()))
         return int(mask.sum())
 
     #: columns whose (min, max) the cluster heartbeat piggybacks so a
@@ -542,6 +611,13 @@ class RetentionMonitor:
     `delete_percentage` of rows fall, delete rows older than the boundary
     from the flows table and all materialized views, then skip
     `skip_rounds` rounds after a successful deletion.
+
+    `last_round` is what the last `tick()` did, as GET /healthz and
+    POST /admin/retention report it: `result` (`idle` under the
+    threshold, `trimmed`, `skipped` inside `skip_rounds`),
+    `usageBefore`, and for a round that went on to delete `rowsBefore`,
+    `deleteN`, `boundary`, `rowsDeleted`, `viewRowsDeleted` by view,
+    `bytesFreed`, `rowsAfter` (`bytesDemoted` where parts went cold).
     """
 
     def __init__(self, db: "FlowDatabase", capacity_bytes: int,
@@ -556,6 +632,7 @@ class RetentionMonitor:
         #: cumulative resident bytes freed by demoting parts to the
         #: cold tier instead of deleting rows (parts engine only)
         self.bytes_demoted = 0
+        self.last_round: Optional[Dict[str, object]] = None
 
     def usage(self) -> float:
         return self.db.flows.nbytes / float(self.capacity_bytes)
@@ -570,10 +647,17 @@ class RetentionMonitor:
         or everything already cold). The boundary for the delete comes
         from part/batch min-max metadata (retention_boundary — O(parts)),
         not a full-column sort."""
+        rec: Dict[str, object] = {"result": "idle"}
+        self.last_round = rec
         if self._remaining_skip > 0:
             self._remaining_skip -= 1
+            rec.update(result="skipped",
+                       roundsToSkip=self._remaining_skip)
             return 0
-        if self.usage() <= self.threshold:
+        with retention_stage("usage"):
+            usage = self.usage()
+        rec["usageBefore"] = usage
+        if usage <= self.threshold:
             return 0
         demote = getattr(self.db, "demote_cold", None)
         if callable(demote):
@@ -582,32 +666,45 @@ class RetentionMonitor:
             if freed:
                 self.bytes_demoted += freed
                 _M_RET_DEMOTED.inc(freed)
+                rec["bytesDemoted"] = freed
                 if self.usage() <= self.threshold:
                     self._remaining_skip = self.skip_rounds
                     return 0
         flows = self.db.flows
         n = len(flows)
-        if n == 0:
-            return 0
         delete_n = int(n * self.delete_percentage)
+        rec.update(rowsBefore=n, deleteN=delete_n)
         if delete_n == 0:
             return 0
         # timeInserted of the latest row to delete (LIMIT 1 OFFSET n-1,
         # main.go:301-318); delete strictly-older rows like the
         # reference's `timeInserted < boundary`.
-        boundary = None
-        rb = getattr(flows, "retention_boundary", None)
-        if callable(rb):
-            boundary = rb(delete_n)
-        if boundary is None:
-            t = np.asarray(flows.scan()["timeInserted"])
-            boundary = int(np.partition(t, delete_n - 1)[delete_n - 1])
-        deleted = self.db.delete_flows_older_than(int(boundary))
+        with retention_stage("boundary"):
+            boundary = None
+            rb = getattr(flows, "retention_boundary", None)
+            if callable(rb):
+                boundary = rb(delete_n)
+            if boundary is None:
+                t = np.asarray(flows.scan()["timeInserted"])
+                boundary = int(
+                    np.partition(t, delete_n - 1)[delete_n - 1])
+        rec["boundary"] = int(boundary)
+        deleted = self.db.delete_flows_older_than(int(boundary),
+                                                  detail=rec)
+        rec.update(rowsDeleted=deleted, rowsAfter=len(flows))
         if deleted:
+            rec["result"] = "trimmed"
             self._remaining_skip = self.skip_rounds
             _M_RET_DELETED.inc(deleted)
             _M_DEL_ROWS.labels(reason="retention").inc(deleted)
+            _M_RET_FREED.inc(int(rec.get("bytesFreed") or 0))
+            for view, rows in (rec.get("viewRowsDeleted") or {}).items():
+                _M_RET_VIEW_DELETED.labels(view=view).inc(rows)
         return deleted
+
+
+class RetentionUnavailable(Exception):
+    """No round can be asked for: the loop's thread is not running."""
 
 
 class RetentionLoop:
@@ -619,18 +716,27 @@ class RetentionLoop:
     schedule, and the failure policy:
 
       * one `tick()` per THEIA_RETENTION_INTERVAL seconds (injectable
-        for tests via `interval`/`run_once()` — no sleeping tests);
+        for tests via `interval`/`clock`/`run_once()` — no sleeping
+        tests);
+      * a round can also be ASKED for (`request`, reached by `POST
+        /admin/retention`): the request wakes the same thread, which
+        runs the same `run_once()`, one at a time, and the round
+        counts as the tick: the next falls one interval after it ends
+        (`next_due`, on the loop's clock). The caller gets that
+        round's record;
       * a FAILED round (e.g. every replica down mid-trim) backs off
         with the shared `capped_backoff` schedule instead of hammering
         a broken store every interval; the first clean round resets
         the cadence;
       * rounds / rows-deleted / failures are counted here (and as
-        metrics), surfaced through `stats()` on GET /healthz.
+        metrics), surfaced with the last round's record through
+        `stats()` on GET /healthz.
     """
 
     def __init__(self, monitor: RetentionMonitor,
                  interval: Optional[float] = None,
-                 backoff_cap: float = 300.0) -> None:
+                 backoff_cap: float = 300.0,
+                 clock: Callable[[], float] = time.monotonic) -> None:
         self.monitor = monitor
         self.interval = (env_float("THEIA_RETENTION_INTERVAL", 60.0)
                          if interval is None else float(interval))
@@ -640,28 +746,51 @@ class RetentionLoop:
         self.failures = 0
         self.consecutive_failures = 0
         self.current_delay = self.interval
-        self._stop = threading.Event()
+        #: what the last round did (the monitor's record, with
+        #: `seconds` and `stagesMs` of its `bg.retention` span)
+        self.last_round: Optional[Dict[str, object]] = None
+        #: when the next tick falls, on `clock`
+        self.next_due: Optional[float] = None
+        self._clock = clock
         self._thread: Optional[threading.Thread] = None
+        #: the timer's rounds and the rounds asked for (`request`)
+        self._rounds = AskedRounds("retention loop", "retention round",
+                                   RetentionUnavailable, clock)
 
     def start(self) -> None:
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="theia-retention")
+        self._rounds.open()
         self._thread.start()
 
     def stop(self) -> None:
-        self._stop.set()
+        self._rounds.stop()
         if self._thread:
             self._thread.join(timeout=15)
 
     def _loop(self) -> None:
-        while not self._stop.wait(self.current_delay):
+        self.next_due = self._clock() + self.current_delay
+        while (run := self._rounds.next(self.next_due)) is not None:
             self.run_once()
+            # a round, asked for or not, is the tick
+            self.next_due = self._clock() + self.current_delay
+            self._rounds.done(run, self.last_round)
+
+    def request(self, timeout: Optional[float] = None
+                ) -> Dict[str, object]:
+        """Ask for a round now and wait for it: the record of the
+        first round that STARTS after this call (one already under way
+        is waited out first). Runs on the loop's thread, as a tick
+        does. Raises RetentionUnavailable when that thread is not
+        running, TimeoutError after `timeout` seconds."""
+        return self._rounds.ask(timeout)
 
     def run_once(self) -> int:
         """One supervised round; returns rows deleted (0 on a failed
         round). Public so tests drive the schedule synchronously."""
+        t0 = time.perf_counter()
         try:
-            with _trace.background("retention"):
+            with _trace.background("retention") as sp:
                 deleted = self.monitor.tick()
         except Exception as e:   # a bad round must not kill the loop
             self.failures += 1
@@ -674,6 +803,9 @@ class RetentionLoop:
                 "retention round failed (%d consecutive): %s; "
                 "backing off %.1fs", self.consecutive_failures, e,
                 self.current_delay)
+            self.last_round = {
+                "result": "error", "error": f"{type(e).__name__}: {e}",
+                "seconds": time.perf_counter() - t0}
             return 0
         if self.consecutive_failures:
             _logger.info("retention recovered after %d failed rounds",
@@ -682,8 +814,15 @@ class RetentionLoop:
         self.current_delay = self.interval
         self.rounds += 1
         self.rows_deleted += deleted
-        _M_RET_ROUNDS.labels(
-            result="trimmed" if deleted else "idle").inc()
+        rec = dict(getattr(self.monitor, "last_round", None)
+                   or {"result": "trimmed" if deleted else "idle"})
+        rec["seconds"] = time.perf_counter() - t0
+        rec["stagesMs"] = {
+            k.split(".", 1)[1]: round(v * 1e3, 4)
+            for k, v in (sp.stages or {}).items()
+            if k.startswith("retention.")}
+        self.last_round = rec
+        _M_RET_ROUNDS.labels(result=rec["result"]).inc()
         if deleted:
             _logger.info("retention trimmed %d rows (usage %.1f%%)",
                          deleted, self.monitor.usage() * 100)
@@ -695,7 +834,7 @@ class RetentionLoop:
             usage = self.monitor.usage()
         except Exception:
             usage = float("nan")
-        return {
+        doc: Dict[str, object] = {
             "rounds": self.rounds,
             "rowsDeleted": self.rows_deleted,
             "bytesDemoted": getattr(self.monitor, "bytes_demoted", 0),
@@ -704,6 +843,9 @@ class RetentionLoop:
             "capacityBytes": self.monitor.capacity_bytes,
             "usagePercent": round(usage * 100, 2),
         }
+        if self.last_round is not None:
+            doc["lastRound"] = self.last_round
+        return doc
 
 
 def payload_digest(payload: Mapping[str, np.ndarray]) -> int:
@@ -853,7 +995,10 @@ class FlowDatabase:
             self._ingest_latch: Optional[object] = _Latch(
                 "store.ingest_latch")
         else:
-            self.flows = Table("flows", FLOW_SCHEMA)
+            # an append's wait for the table's lock goes on a series:
+            # a retention round's delete holds it for its whole copy
+            self.flows = Table("flows", FLOW_SCHEMA,
+                               lock_wait_hist=_M_TABLE_LOCK_WAIT)
             self._ingest_latch = None
         self.result_tables: Dict[str, Table] = {
             name: (self._make_metrics_table()
@@ -1402,12 +1547,27 @@ class FlowDatabase:
             _M_DEL_ROWS.labels(reason="ttl").inc(deleted)
         return deleted
 
-    def delete_flows_older_than(self, boundary: int) -> int:
+    def delete_flows_older_than(self, boundary: int,
+                                detail: Optional[Dict[str, object]] = None
+                                ) -> int:
         """timeInserted < boundary, applied to flows and every view
-        (monitor main.go:284-293 deletes from table + MVs)."""
-        deleted = self.flows.delete_older_than(boundary)
-        for view in self.views.values():
-            view.delete_older_than(boundary)
+        (monitor main.go:284-293 deletes from table + MVs). With
+        `detail` (a retention round's record) the two deletes are
+        stages of the enclosing span (`retention.delete_flows`,
+        `retention.delete_views`) and the record gains `bytesFreed`
+        (resident bytes of the deleted flow rows) and
+        `viewRowsDeleted` by view; both add up over the shards of a
+        sharded store."""
+        staged = retention_stage if detail is not None \
+            else (lambda name: contextlib.nullcontext())
+        flows = self.flows
+        before = flows.bytes_trimmed_total
+        with staged("delete_flows"):
+            deleted = flows.delete_older_than(boundary)
+        freed = flows.bytes_trimmed_total - before
+        with staged("delete_views"):
+            dropped = {name: view.delete_older_than(boundary)
+                       for name, view in self.views.items()}
         rollups = getattr(self, "rollups", None)
         if rollups is not None and rollups.active:
             # whole buckets below the trim drop with their parts;
@@ -1415,7 +1575,27 @@ class FlowDatabase:
             # SURVIVING raw rows so rollup answers track the trim
             # exactly
             rollups.apply_delete(boundary)
+        if detail is not None:
+            detail["bytesFreed"] = detail.get("bytesFreed", 0) + int(freed)
+            views = detail.setdefault("viewRowsDeleted", {})
+            for name, rows in dropped.items():
+                views[name] = views.get(name, 0) + rows
         return deleted
+
+    def view_totals(self) -> Dict[str, Dict[str, int]]:
+        """Per materialized view what does not depend on how its parts
+        lie (GET /debug/retention): sum(`octetDeltaCount`) and the
+        oldest `timeInserted`. Two columns of every part are walked
+        and nothing is merged or copied, where every other read of a
+        view (`scan()`: a panel, the stats API's tableInfo) first
+        re-groups the whole view into one part."""
+        return {name: view.totals() for name, view in self.views.items()}
+
+    def table_lock_wait(self) -> Optional["_trace.Stage"]:
+        """The finished `store.table_lock_wait` stage of the calling
+        thread's last flows append; None on the parts engine."""
+        fn = getattr(self.flows, "last_lock_wait", None)
+        return fn() if callable(fn) else None
 
     def monitor(self, capacity_bytes: int, **kw) -> RetentionMonitor:
         return RetentionMonitor(self, capacity_bytes, **kw)

@@ -731,6 +731,10 @@ class PartTable(Table):
         with self._lock:
             parts = list(self._parts)
             mem = list(self._batches)
+        return self._resident_bytes(parts, mem)
+
+    @staticmethod
+    def _resident_bytes(parts, mem) -> int:
         return (sum(p.nbytes for p in parts)
                 + sum(v.nbytes for b in mem
                       for v in b.columns.values()))
@@ -1255,6 +1259,7 @@ class PartTable(Table):
         only boundary-straddling parts pay a decode + rewrite."""
         deleted = 0
         with self._lock:
+            resident = self._resident_bytes(self._parts, self._batches)
             kept_parts: List[Part] = []
             for part in self._parts:
                 mm = part.minmax.get(column)
@@ -1282,6 +1287,11 @@ class PartTable(Table):
                 lambda b: np.asarray(b[column]) < boundary)
             if deleted:
                 self.generation += 1
+                # the fall of the resident bytes under the lock (a
+                # cold part's rows free none)
+                self.bytes_trimmed_total += max(
+                    resident - self._resident_bytes(self._parts,
+                                                    self._batches), 0)
         return deleted
 
     def delete_ids(self, ids, column: str = "id",

@@ -417,10 +417,26 @@ class ReplicatedFlowDatabase:
     def evict_ttl(self, now: int) -> int:
         return self._fanout(lambda r: r.evict_ttl(now), "evict_ttl")
 
-    def delete_flows_older_than(self, boundary: int) -> int:
-        return self._fanout(
-            lambda r: r.delete_flows_older_than(boundary),
-            "delete_flows_older_than")
+    def delete_flows_older_than(self, boundary: int,
+                                detail: Optional[Dict[str, object]] = None
+                                ) -> int:
+        # like the count, a round's record (`detail`) is the last
+        # successful replica's: the replicas hold the same rows
+        seen: Dict[str, object] = {}
+
+        def one(r):
+            mine: Optional[Dict[str, object]] = \
+                None if detail is None else {}
+            n = r.delete_flows_older_than(boundary, detail=mine)
+            if mine is not None:
+                seen.clear()
+                seen.update(mine)
+            return n
+
+        n = self._fanout(one, "delete_flows_older_than")
+        if detail is not None:
+            detail.update(seen)
+        return n
 
     # -- write-ahead log ---------------------------------------------------
 

@@ -445,8 +445,11 @@ class ShardedFlowDatabase:
     def evict_ttl(self, now: int) -> int:
         return sum(s.evict_ttl(now) for s in self.shards)
 
-    def delete_flows_older_than(self, boundary: int) -> int:
-        return sum(s.delete_flows_older_than(boundary)
+    def delete_flows_older_than(self, boundary: int,
+                                detail: Optional[Dict[str, object]] = None
+                                ) -> int:
+        # a round's record (`detail`) adds up over the shards
+        return sum(s.delete_flows_older_than(boundary, detail=detail)
                    for s in self.shards)
 
     def monitor(self, capacity_bytes: int, **kw) -> RetentionMonitor:

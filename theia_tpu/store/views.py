@@ -263,6 +263,21 @@ class ViewTable:
             self._parts = new_parts
         return dropped
 
+    def totals(self) -> Dict[str, int]:
+        """sum(`octetDeltaCount`) and `oldestTimeInserted` over the
+        parts as they lie; equal whether or not they were merged
+        (FlowDatabase.view_totals)."""
+        ti = self.spec.key_columns.index("timeInserted")
+        oc = self.spec.sum_columns.index("octetDeltaCount")
+        with self._lock:
+            parts = [(k, v) for k, v, _ in self._parts if len(k)]
+        doc = {"octetDeltaCount": sum(int(v[:, oc].sum())
+                                      for _, v in parts)}
+        if parts:
+            doc["oldestTimeInserted"] = min(int(k[:, ti].min())
+                                            for k, _ in parts)
+        return doc
+
     def truncate(self) -> None:
         with self._lock:
             self._parts = []
