@@ -535,7 +535,9 @@ def test_the_npr_cell_is_rehearsed_on_the_cpu_backend():
         cell["name"], "per_layer")} - device_only
     # the client finds state, startTime and endTime before the YAML
     assert got["job.run_s"] > 0
-    assert got["npr.read_columns"] == 52
+    # the query's columns (PR 45), every one int32: 44 B a row
+    assert got["npr.read_columns"] == 11
+    assert got["npr.read_bytes"] == 44 * 64 * 4 * 32
     assert got["npr.rows_sorted"] % 4 == 0 and got["npr.rows_sorted"] > 0
     # an ANP and a reject ACNP a group, and the allow list's three
     assert got["npr.policies"] % 2 == 1 and got["npr.policies"] > 3

@@ -5,13 +5,16 @@ hand-built flows, policy_recommendation_job_test.py) plus end-to-end runs
 over the synthetic store.
 """
 
+import pytest
 import yaml
 
 from theia_tpu.analytics.npr import (
+    FLOW_TABLE_COLUMNS,
     aggregate_peers,
     get_flow_type,
     map_flow_to_egress,
     map_flow_to_ingress,
+    read_columns,
     read_distinct_flows,
     recommend_policies_for_unprotected_flows,
     run_npr,
@@ -196,3 +199,126 @@ def test_npr_end_to_end_initial_and_subsequent():
     assert all(r["type"] == "subsequent" for r in rows2)
     # subsequent jobs never include the ns-allow-list platform policies
     assert not any("tier: Platform" in r["policy"] for r in rows2)
+
+
+# -- the read stays columnar (PR 45) --------------------------------------
+
+LATER = 1_700_000_000
+
+
+def _two_populations(engine, tmp_path):
+    """A store of two populations, the second joining at LATER (a
+    window can cut it whole): a fifth under a policy, a third of the
+    rest trusted; the parts engine sealed into several parts."""
+    db = FlowDatabase(engine=engine, parts_dir=str(tmp_path / "parts"),
+                      parts_config={"memtable_rows": 128})
+    for seed, start in ((4, None), (5, LATER)):
+        kw = {} if start is None else {"start_time": start}
+        batch = generate_flows(SynthConfig(
+            n_series=60, points_per_series=4, protected_fraction=0.2,
+            external_fraction=0.2, service_fraction=0.3, n_namespaces=4,
+            pods_per_namespace=5, seed=seed, **kw))
+        batch.columns["trusted"][::3] = 1
+        db.insert_flows(batch)
+    return db
+
+
+def _job_rows(db, job_id):
+    rows = db.recommendations.scan().to_rows()
+    return [(r["type"], r["kind"], r["policy"]) for r in rows
+            if r["id"] == job_id]
+
+
+def test_read_columns_names_what_the_query_names():
+    base = set(FLOW_TABLE_COLUMNS) | {
+        "ingressNetworkPolicyName", "egressNetworkPolicyName"}
+    assert len(read_columns()) == len(base) == 11
+    assert set(read_columns("initial", 3)) == base
+    for option in (1, 2):
+        assert set(read_columns("subsequent", option)) == base | {"trusted"}
+    # k8s-np never reads the trusted flows, nor does an initial job
+    assert set(read_columns("subsequent", 3)) == base
+    assert set(read_columns("initial", 1, start_time=5)) \
+        == base | {"flowStartSeconds"}
+    assert set(read_columns("initial", 1, end_time=9)) \
+        == base | {"flowEndSeconds"}
+    assert len(read_columns("initial", 2, 5, 9)) == 13
+    assert len(read_columns("subsequent", 2, 5, 9)) == 14
+    for names in (read_columns(), read_columns("subsequent", 1, 5, 9)):
+        assert len(set(names)) == len(names)
+        assert names[:9] == FLOW_TABLE_COLUMNS
+
+
+@pytest.mark.parametrize("engine", ["flat", "parts"])
+@pytest.mark.parametrize("window", [{}, {"start_time": LATER - 100,
+                                         "end_time": LATER + 3}],
+                         ids=["no-window", "window"])
+@pytest.mark.parametrize("option", [1, 2, 3])
+@pytest.mark.parametrize("kind", ["initial", "subsequent"])
+def test_the_projected_read_recommends_what_the_scan_did(
+        kind, option, window, engine, tmp_path, monkeypatch):
+    """`run_npr` over `select(columns=read_columns(...))` leaves the
+    rows that `read_distinct_flows` leaves when fed all 52 columns of
+    `scan()`, the same strings in the same order."""
+    db = _two_populations(engine, tmp_path)
+    asked = []
+    select = db.flows.select
+
+    def projected(columns):
+        asked.append(tuple(columns))
+        return select(columns=columns)
+
+    monkeypatch.setattr(db.flows, "select", projected)
+    run_npr(db, kind, option=option, recommendation_id="projected",
+            **window)
+    assert asked == [read_columns(kind, option, **window)]
+    monkeypatch.setattr(db.flows, "select",
+                        lambda columns: db.flows.scan())
+    run_npr(db, kind, option=option, recommendation_id="scanned",
+            **window)
+    got, want = _job_rows(db, "projected"), _job_rows(db, "scanned")
+    assert got == want and len(got) > 3
+    if kind == "subsequent" and option != 3:
+        # the second read found trusted flows: the answer holds more
+        # than an initial job's without its three namespace policies
+        run_npr(db, "initial", option=option, recommendation_id="first",
+                **window)
+        assert len(got) > len(_job_rows(db, "first")) - 3
+
+
+def test_the_read_is_counted_by_its_eleven_columns():
+    from theia_tpu.obs import metrics
+    from theia_tpu.runner.progress import JobProgress
+
+    def counted(what):
+        return metrics.REGISTRY.get(
+            f"theia_job_read_{what}_total").labels(kind="npr").value()
+
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=16, points_per_series=4, seed=9)))
+    before = {w: counted(w) for w in ("rows", "columns", "bytes")}
+    run_npr(db, progress=JobProgress(
+        "npr-45", ["read", "recommend", "write"], kind="npr"))
+    assert counted("rows") - before["rows"] == 64
+    assert counted("columns") - before["columns"] == 11
+    # every one an int32 column: 44 B a row where scan() hands on 284
+    assert counted("bytes") - before["bytes"] == 64 * 44
+
+
+def test_a_batch_without_a_queried_column_is_a_key_error():
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=8, points_per_series=2, seed=1)))
+    names = read_columns("subsequent", 1, 5, 9)
+    full = db.flows.select(columns=names)
+    assert read_distinct_flows(full, start_time=5, end_time=2 ** 40)
+    for missing, kw in (
+            ("egressNetworkPolicyName", {}),
+            ("destinationServicePortName", {}),
+            ("trusted", {"unprotected": False}),
+            ("flowStartSeconds", {"start_time": 5}),
+            ("flowEndSeconds", {"end_time": 9})):
+        batch = full.select([n for n in names if n != missing])
+        with pytest.raises(KeyError, match=missing):
+            read_distinct_flows(batch, **kw)

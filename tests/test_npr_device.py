@@ -249,3 +249,146 @@ def test_two_store_sizes_of_one_bucket_run_one_program(monkeypatch):
              for ln in text.splitlines() if "stablehlo.sort" in ln]
     assert sorts == [3, 1]            # the rows' words, compact's starts
     assert "is_stable = false" in text and "is_stable = true" not in text
+
+
+# -- the packer takes columns and a mask (PR 45) --------------------------
+
+def _matrix_layout(keys):
+    """`KeyLayout.of` as it stood before PR 45, over an [n, K] int64
+    matrix of the rows that count: the packer's plain reference."""
+    if not len(keys):
+        return (0,) * keys.shape[1]
+    largest = keys.max(axis=0)
+    if keys.min() < 0 or largest.max() >= 1 << 31:
+        raise ValueError("dictionary codes lie in [0, 2^31)")
+    return tuple(int(m).bit_length() for m in largest)
+
+
+def _matrix_pack(widths, keys, n_rows):
+    """`KeyLayout.pack` as it stood before PR 45."""
+    n_words = max(-(-sum(widths) // 32), 1)
+    n = keys.shape[0]
+    out = np.empty((n_words, n_rows), np.uint32)
+    out[:, n:] = 0xFFFFFFFF
+    acc = np.zeros(n, np.int64)
+    fill, word = 0, n_words - 1
+    for c in reversed(range(len(widths))):
+        if not widths[c]:
+            continue
+        acc |= keys[:, c].astype(np.int64) << fill
+        fill += widths[c]
+        if fill >= 32:
+            out[word, :n] = acc & 0xFFFFFFFF
+            acc >>= 32
+            fill -= 32
+            word -= 1
+    if fill:
+        out[word, :n] = acc
+        word -= 1
+    out[:word + 1, :n] = 0
+    return out
+
+
+def _masked_columns(maxima, dtype, mask_kind, n=500):
+    """K columns of `dtype` and a mask (None: every row counts). A row
+    outside the mask holds what no row inside may: a negative code, or
+    one wider than its column."""
+    rng = np.random.default_rng(len(maxima) + n)
+    keys = _codes(rng, n, maxima)
+    mask = {"all": None, "all-true": np.ones(n, bool),
+            "partial": rng.random(n) < 0.7,
+            "none": np.zeros(n, bool)}[mask_kind]
+    if mask is not None and not mask.all():
+        keys[~mask] = np.where(rng.random((n, 1)) < 0.5, -7,
+                               np.iinfo(dtype).max)[~mask]
+    if mask_kind == "partial":
+        keys[np.flatnonzero(mask)[0]] = maxima     # the widths' witness
+    return [keys[:, c].astype(dtype) for c in range(len(maxima))], mask
+
+
+PACKED = {
+    "31-bits-in-one-word": ((1 << 31) - 1,),
+    "73-bits-in-three-words": LAYOUTS["3-words-the-jobs"],
+    "a-column-across-words": LAYOUTS["crosses"],
+    "a-column-of-width-0": LAYOUTS["a-column-of-zeros"],
+    "no-bits-at-all": (0, 0),
+}
+
+
+@pytest.mark.parametrize("block", [97, None], ids=["blocks-of-97", "one-block"])
+@pytest.mark.parametrize("mask_kind", ["all", "all-true", "partial", "none"])
+@pytest.mark.parametrize("dtype", [np.int32, np.int64],
+                         ids=["int32", "int64"])
+@pytest.mark.parametrize("maxima", PACKED.values(), ids=PACKED.keys())
+def test_the_column_packer_is_the_matrix_packer_bit_for_bit(
+        maxima, dtype, mask_kind, block, monkeypatch):
+    from theia_tpu.analytics import npr_device
+    from theia_tpu.analytics.npr_device import KeyLayout
+
+    if block:
+        monkeypatch.setattr(npr_device, "_PACK_ROWS", block)
+    columns, mask = _masked_columns(maxima, dtype, mask_kind)
+    counted = np.stack(
+        [np.asarray(c if mask is None else c[mask], np.int64)
+         for c in columns], axis=1)
+    widths = _matrix_layout(counted)
+    if mask_kind != "none":
+        assert widths == tuple(int(m).bit_length() for m in maxima)
+    layout = KeyLayout.of(columns, mask)
+    assert layout.widths == widths
+    for n_rows in (len(counted), 512):
+        want = _matrix_pack(widths, counted, n_rows)
+        got = layout.pack(columns, n_rows, mask)
+        assert got.dtype == np.uint32 and got.flags.c_contiguous
+        np.testing.assert_array_equal(got, want)
+    # the matrix entry points hand their strided columns to that code
+    assert KeyLayout.of(counted) == layout
+    np.testing.assert_array_equal(layout.pack(counted, 512), want)
+    np.testing.assert_array_equal(
+        layout.unpack(want[:, :len(counted)].T), counted)
+
+
+@pytest.mark.parametrize("dtype, code", [
+    (np.int32, -1), (np.int64, -1), (np.int64, 1 << 31),
+    (np.int64, 1 << 40)], ids=["int32-negative", "int64-negative",
+                               "2^31", "2^40"])
+def test_a_code_out_of_range_under_the_mask_is_a_value_error(dtype, code):
+    from theia_tpu.analytics.npr_device import KeyLayout
+
+    columns = [np.arange(300, dtype=dtype), np.full(300, 5, dtype)]
+    mask = np.arange(300) % 3 > 0
+    columns[1][200] = code                       # 200 % 3 == 2: counted
+    for m in (mask, None):
+        with pytest.raises(ValueError, match="codes"):
+            KeyLayout.of(columns, m)
+        with pytest.raises(ValueError, match="codes"):
+            device_distinct(columns, use_device="1", mask=m)
+    columns[1][[200, 201]] = 5, code             # 201 % 3 == 0: not
+    assert KeyLayout.of(columns, mask).widths == (9, 3)
+    with pytest.raises(ValueError, match="codes"):
+        KeyLayout.of(columns)
+
+
+@pytest.mark.parametrize("use_device", ["1", "0", "8 shards"],
+                         ids=["device", "host", "sharded"])
+@pytest.mark.parametrize("mask_kind", ["all", "all-true", "partial", "none"])
+@pytest.mark.parametrize("dtype", [np.int32, np.int64],
+                         ids=["int32", "int64"])
+def test_distinct_of_columns_under_a_mask_is_group_reduce_of_the_rows(
+        dtype, mask_kind, use_device):
+    mesh = None
+    if use_device == "8 shards":
+        use_device, mesh = True, make_rows_mesh(8)
+    columns, mask = _masked_columns(
+        LAYOUTS["3-words-the-jobs"], dtype, mask_kind, n=3000)
+    counted = np.stack(
+        [np.asarray(c if mask is None else c[mask], np.int64)
+         for c in columns], axis=1)
+    u, c = device_distinct(columns, use_device=use_device, mesh=mesh,
+                           mask=mask)
+    ref_u, ref_c = _numpy_distinct(counted)
+    assert u.dtype == c.dtype == np.int64
+    assert u.shape == ref_u.shape == (len(ref_u), 9)
+    np.testing.assert_array_equal(u, ref_u)
+    np.testing.assert_array_equal(c, ref_c)
+    assert int(c.sum()) == len(counted)

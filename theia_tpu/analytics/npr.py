@@ -18,6 +18,11 @@ TPU-first note: the numeric kernel here is the DISTINCT over the 9-tuple
 with an all_gather + segment-sum, the collective replacing the Spark
 shuffle); everything after operates on the (small) deduplicated set and
 is host-side string/YAML work, as in the reference.
+
+The read stays columnar: the job asks the store for the columns its
+query names (`read_columns`: 11 of the 52 for an initial job), the
+WHERE clause becomes one row mask, and the nine key columns go to the
+packer as they lie in the batch, with that mask.
 """
 
 from __future__ import annotations
@@ -25,7 +30,7 @@ from __future__ import annotations
 import collections
 import datetime
 import uuid
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -50,6 +55,27 @@ FLOW_TABLE_COLUMNS = (
     "destinationServicePortName", "destinationTransportPort",
     "protocolIdentifier", "flowType",
 )
+# '' in both is the WHERE clause's "unprotected"
+POLICY_NAME_COLUMNS = ("ingressNetworkPolicyName", "egressNetworkPolicyName")
+
+
+def read_columns(recommendation_type: str = "initial", option: int = 1,
+                 start_time: Optional[int] = None,
+                 end_time: Optional[int] = None) -> Tuple[str, ...]:
+    """The flow columns a job with these arguments reads, a function
+    of the arguments alone (generate_sql_query :785-802): the nine it
+    selects and what its WHERE clauses touch. `run_npr` hands this to
+    the store's `select(columns=...)`; `read_distinct_flows` takes its
+    names from the same tuples, and a batch that lacks one raises
+    KeyError."""
+    names = FLOW_TABLE_COLUMNS + POLICY_NAME_COLUMNS
+    if recommendation_type == "subsequent" and option in (1, 2):
+        names += ("trusted",)       # the second read, of one batch
+    if start_time is not None:
+        names += ("flowStartSeconds",)
+    if end_time is not None:
+        names += ("flowEndSeconds",)
+    return names
 
 
 def get_protocol_string(protocol: int) -> str:
@@ -86,24 +112,25 @@ def read_distinct_flows(flows: ColumnarBatch,
         mask = np.ones(len(flows), dtype=bool)
         if unprotected:
             # '' is always dictionary code 0.
-            mask &= np.asarray(flows["ingressNetworkPolicyName"]) == 0
-            mask &= np.asarray(flows["egressNetworkPolicyName"]) == 0
+            for name in POLICY_NAME_COLUMNS:
+                mask &= np.asarray(flows[name]) == 0
         else:
             mask &= np.asarray(flows["trusted"]) == 1
         if start_time is not None:
             mask &= np.asarray(flows["flowStartSeconds"]) >= start_time
         if end_time is not None:
             mask &= np.asarray(flows["flowEndSeconds"]) < end_time
-        # Materialize only the 9 queried columns (same narrow-column
-        # rule as the series tensorize: filtering all 52 costs more
-        # than the distinct kernel it feeds).
-        col = flows.column_selector(mask)
-        keys = np.stack([col(c) for c in FLOW_TABLE_COLUMNS], axis=1)
-        distinct = plan_distinct(keys, use_device=use_device, mesh=mesh)
+        # The nine queried columns as they lie in the batch: the packer
+        # applies the mask to its finished words, so no column is
+        # copied or widened whole and no [n, 9] matrix is built.
+        distinct = plan_distinct(
+            [np.asarray(flows[c]) for c in FLOW_TABLE_COLUMNS],
+            use_device=use_device, mesh=mesh, mask=mask)
     with job_part(progress, "distinct"):
         uniq, _counts = distinct()
     if progress:
-        progress.distinct(rows_sorted=len(keys), flows=len(uniq))
+        progress.distinct(rows_sorted=int(np.count_nonzero(mask)),
+                          flows=len(uniq))
 
     with job_part(progress, "decode"):
         rows: List[Dict[str, object]] = []
@@ -366,7 +393,8 @@ def run_npr(db: FlowDatabase,
     if progress:
         progress.stage("read")
     with job_part(progress, "scan"):
-        flows = db.flows.scan()
+        flows = db.flows.select(columns=read_columns(
+            recommendation_type, option, start_time, end_time))
     if progress:
         progress.read(flows)
     unprotected = read_distinct_flows(

@@ -10,7 +10,12 @@ the program it ran before:
     packs the K columns, most significant first, into W unsigned
     32-bit words whose lexicographic order is the columns' (`KeyLayout`:
     a function of the column maxima alone, never part of a compiled
-    program; the job's nine columns need 73 bits, W = 3);
+    program; the job's nine columns need 73 bits, W = 3). The packer
+    takes the K columns as they lie in the store's batch, int32 each,
+    and a mask of the rows that count (an [N, K] matrix is its K
+    strided columns): `_PACK_ROWS` rows at a time through one 64-bit
+    accumulator, each finished word written under the mask, so no
+    column is copied whole and no [N, K] matrix is ever built;
   * bucketed rows — N is padded up to `bucket_rows(N)` with all-ones
     rows that sort last and weigh nothing, so a program is compiled
     once a bucket and once a number of words, not once a store size;
@@ -39,7 +44,8 @@ it. Dictionary codes are below 2^31.
 from __future__ import annotations
 
 import os
-from typing import Callable, NamedTuple, Tuple
+from typing import (Callable, Iterator, NamedTuple, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +61,24 @@ _ALL_ONES = 0xFFFFFFFF
 # (the host numpy lexsort wins under ~64k rows once transfer overhead is
 # counted), "1"/"0" force it on/off.
 _AUTO_THRESHOLD = 65536
+
+# Rows the packer handles at a time: its accumulator and the shifted
+# column stay in the host's cache (1 MiB together) while the columns
+# stream through once.
+_PACK_ROWS = 1 << 16
+
+#: K key columns: an [N, K] matrix of codes, or K arrays of N codes
+Keys = Union[np.ndarray, Sequence[np.ndarray]]
+
+
+def _columns(keys) -> Tuple[np.ndarray, ...]:
+    """The K key columns: those of an [N, K] matrix (strided views),
+    or the sequence of K equal-length arrays itself."""
+    return tuple(keys.T if isinstance(keys, np.ndarray) else keys)
+
+
+def _blocks(n: int) -> Iterator[slice]:
+    return (slice(lo, lo + _PACK_ROWS) for lo in range(0, n, _PACK_ROWS))
 
 
 def bucket_rows(n: int) -> int:
@@ -76,11 +100,23 @@ class KeyLayout(NamedTuple):
     widths: Tuple[int, ...]
 
     @classmethod
-    def of(cls, keys: np.ndarray) -> "KeyLayout":
-        largest = keys.max(axis=0)
-        if keys.min() < 0 or largest.max() >= 1 << 31:
+    def of(cls, keys: Keys, mask: np.ndarray | None = None
+           ) -> "KeyLayout":
+        """The layout of the rows of `keys` under `mask` (all of them
+        without one): rows outside it neither widen a column nor fail
+        the range check."""
+        columns = _columns(keys)
+        smallest, largest = 0, [0] * len(columns)
+        for rows in _blocks(len(columns[0])):
+            for c, column in enumerate(columns):
+                # a row outside the mask counts as code 0
+                part = column[rows] if mask is None \
+                    else column[rows] * mask[rows]
+                smallest = min(smallest, int(part.min()))
+                largest[c] = max(largest[c], int(part.max()))
+        if smallest < 0 or max(largest) >= 1 << 31:
             raise ValueError("dictionary codes lie in [0, 2^31)")
-        return cls(tuple(int(m).bit_length() for m in largest))
+        return cls(tuple(m.bit_length() for m in largest))
 
     @property
     def bits(self) -> int:
@@ -90,30 +126,52 @@ class KeyLayout(NamedTuple):
     def words(self) -> int:
         return max(-(-self.bits // _WORD), 1)
 
-    def pack(self, keys: np.ndarray, n_rows: int) -> np.ndarray:
-        """[W, n_rows] uint32: the rows of `keys` [N, K] packed, then
-        all-ones padding. Columns go in from the least significant
-        end through a 64-bit accumulator that is emptied a word at a
-        time, so a column may cross a word."""
-        n = keys.shape[0]
+    def pack(self, keys: Keys, n_rows: int,
+             mask: np.ndarray | None = None) -> np.ndarray:
+        """[W, n_rows] uint32: the rows of `keys` under `mask` (all of
+        them without one) packed in their order, then all-ones
+        padding. `_PACK_ROWS` rows at a time, the columns go in from
+        the least significant end through a 64-bit accumulator that
+        is emptied a word at a time, so a column may cross a word;
+        what a row outside the mask leaves in the accumulator is never
+        written."""
+        columns = _columns(keys)
+        total = len(columns[0])
+        n = total if mask is None else int(np.count_nonzero(mask))
         out = np.empty((self.words, n_rows), np.uint32)
         out[:, n:] = _ALL_ONES
-        acc = np.zeros(n, np.int64)
-        fill, word = 0, self.words - 1
-        for c in reversed(range(len(self.widths))):
-            if not self.widths[c]:
-                continue
-            acc |= keys[:, c].astype(np.int64) << fill
-            fill += self.widths[c]
-            if fill >= _WORD:
-                out[word, :n] = acc & _ALL_ONES
-                acc >>= _WORD
-                fill -= _WORD
-                word -= 1
-        if fill:
-            out[word, :n] = acc
-            word -= 1
-        out[:word + 1, :n] = 0          # no column reaches these
+        if not self.bits:
+            out[:, :n] = 0              # no column reaches the one word
+        acc = np.empty(min(total, _PACK_ROWS), np.int64)
+        shifted = np.empty_like(acc)
+
+        def put(word, a, keep, at):
+            low = a.astype(np.uint32)           # the low 32 bits
+            out[word, at] = low if keep is None else low[keep]
+
+        done = 0
+        for rows in _blocks(total):
+            keep = None if mask is None else mask[rows]
+            size = len(columns[0][rows])
+            a, s = acc[:size], shifted[:size]
+            a[:] = 0
+            kept = size if keep is None else int(np.count_nonzero(keep))
+            at = slice(done, done + kept)
+            fill, word = 0, self.words - 1
+            for c in reversed(range(len(self.widths))):
+                if not self.widths[c]:
+                    continue
+                np.left_shift(columns[c][rows], fill, out=s, dtype=np.int64)
+                a |= s
+                fill += self.widths[c]
+                if fill >= _WORD:
+                    put(word, a, keep, at)
+                    a >>= _WORD
+                    fill -= _WORD
+                    word -= 1
+            if fill:
+                put(word, a, keep, at)
+            done += kept
         return out
 
     def unpack(self, words: np.ndarray) -> np.ndarray:
@@ -276,31 +334,42 @@ def _wants_device(n: int, use_device) -> bool:
     return n >= _AUTO_THRESHOLD
 
 
-def plan_distinct(keys: np.ndarray,
+def plan_distinct(keys: Keys,
                   use_device: str | bool | None = None,
-                  mesh: jax.sharding.Mesh | None = None
+                  mesh: jax.sharding.Mesh | None = None,
+                  mask: np.ndarray | None = None
                   ) -> Callable[[], Tuple[np.ndarray, np.ndarray]]:
     """The host's half of `device_distinct`, done now: choose the
-    path and, for the device, lay the key columns out and pack them
-    into a bucket's padded words. Returns the other half: call it for
-    (uniq, counts) — the transfer, the jitted call until ready, the
-    fetch of the distinct rows and their unpacking."""
-    n = keys.shape[0]
+    path by the rows under `mask` and, for the device, lay the key
+    columns out and pack them into a bucket's padded words. Returns
+    the other half: call it for (uniq, counts) — the transfer, the
+    jitted call until ready, the fetch of the distinct rows and their
+    unpacking."""
+    columns = _columns(keys)
+    n = len(columns[0])
+    if mask is not None:
+        kept = int(np.count_nonzero(mask))
+        if kept == n:
+            mask = None
+        n = kept
     if n == 0:
-        return lambda: (keys.astype(np.int64), np.zeros((0,), np.int64))
+        return lambda: (np.zeros((0, len(columns)), np.int64),
+                        np.zeros((0,), np.int64))
     if not _wants_device(n, use_device):
         def on_host():
             from ..store.views import group_reduce
 
-            uniq, counts = group_reduce(
-                keys.astype(np.int64), np.ones((n, 1), np.int64))
+            rows = np.stack([np.asarray(c if mask is None else c[mask],
+                                        np.int64) for c in columns], axis=1)
+            uniq, counts = group_reduce(rows, np.ones((n, 1), np.int64))
             return uniq, counts[:, 0]
         return on_host
 
-    layout = KeyLayout.of(keys)
+    layout = KeyLayout.of(columns, mask)
     shards = mesh.size if mesh is not None and mesh.size > 1 \
         and n >= mesh.size else 1
-    words = layout.pack(keys, bucket_rows(-(-n // shards)) * shards).T
+    words = layout.pack(
+        columns, bucket_rows(-(-n // shards)) * shards, mask).T
     if shards > 1:
         from ..parallel import cached_kernel
 
@@ -320,11 +389,14 @@ def plan_distinct(keys: np.ndarray,
     return on_device
 
 
-def device_distinct(keys: np.ndarray,
+def device_distinct(keys: Keys,
                     use_device: str | bool | None = None,
-                    mesh: jax.sharding.Mesh | None = None
+                    mesh: jax.sharding.Mesh | None = None,
+                    mask: np.ndarray | None = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
-    """Host wrapper: DISTINCT + counts for an [N, K] int code matrix.
+    """Host wrapper: DISTINCT + counts over K columns of int codes (an
+    [N, K] matrix or K arrays), of the rows under `mask` if one is
+    given.
 
     Returns (uniq [U, K] int64, counts [U] int64) in lexicographic row
     order — bit-identical to the numpy group_reduce path. `use_device`
@@ -334,4 +406,4 @@ def device_distinct(keys: np.ndarray,
     the all_gather + segment-sum collective (production scale-out of
     the Spark shuffle, SURVEY §2.7).
     """
-    return plan_distinct(keys, use_device, mesh)()
+    return plan_distinct(keys, use_device, mesh, mask)()
