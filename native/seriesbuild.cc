@@ -1,158 +1,319 @@
 // Native series builder: group flow rows by an integer key tuple into
 // padded per-series time arrays — the host tensorize step of the TAD
 // job (theia_tpu/analytics/series.py). Replaces two numpy lexsorts
-// (group_reduce + _pack_and_pad) with one hash-group pass + per-group
-// sorts; semantics are bit-identical to the numpy path:
+// (group_reduce + _pack_and_pad) with one hash-group pass over the
+// columns where they lie and one pass that writes each point straight
+// into the padded tensors; semantics are bit-identical to the numpy
+// path:
 //
 //   * duplicate (key, time) rows reduce with op (0 = max, 1 = sum) —
 //     the reference job's max(throughput)/sum(throughput) stage
 //     (plugins/anomaly-detection/anomaly_detection.py:507-614);
-//   * series are emitted in lexicographic key order, points in time
-//     order, padded to the longest series with a validity mask.
+//   * series are emitted in lexicographic key order (keys compared as
+//     int64 values), points in time order, padded to the longest
+//     series with a validity mask.
 //
-// Exposed via ctypes (no pybind11 in the image) from the same shared
-// object as the flowblock decoder; see theia_tpu/ingest/native.py.
+// C API (ctypes; same .so as flowblock/groupsum):
+//   sb_new(k, op)              handle for series keyed by k columns
+//   sb_add(h, cols, widths, strides, mask, n)
+//       n rows of k + 2 columns: the k key columns, the time column,
+//       the value column. A column is a pointer, its element size in
+//       bytes (4 = int32, 8 = int64) and its byte stride (0 = one
+//       constant cell); no row-major staging, no widened or masked
+//       copy. mask: n bytes, 0 = row filtered out; null = every row.
+//       The columns must stay alive until sb_fill. May be called more
+//       than once (the pod mode's two sides).
+//   sb_finish(h, &S, &T)       number of series, longest series
+//   sb_fill(h, out_keys, out_values, value_width, out_times, out_mask)
+//       out_keys [S,k] int64; out_values [S,T] float32 (4) or float64
+//       (8); out_times [S,T] int64; out_mask [S,T] bytes. Caller-
+//       allocated, need not be zeroed.
+//   sb_free(h)
 
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <utility>
 #include <vector>
 
 namespace {
 
-struct Builder {
-  int64_t S = 0, T = 0, k = 0;
-  std::vector<int64_t> group_keys;  // S*k, lexicographically sorted
-  // per series: (time, value), time-sorted, duplicate times merged
-  std::vector<std::vector<std::pair<int64_t, int64_t>>> series;
+struct Column {
+  const char* base;
+  int64_t stride;
+  int32_t width;
 };
 
-inline uint64_t hash_row(const int64_t* row, int64_t k) {
-  uint64_t h = 1469598103934665603ull;  // FNV offset basis
-  for (int64_t i = 0; i < k; ++i) {
-    uint64_t x = static_cast<uint64_t>(row[i]);
-    x *= 0xff51afd7ed558ccdull;  // splitmix-style scramble per word
-    x ^= x >> 33;
-    h ^= x;
-    h *= 1099511628211ull;
+inline int64_t cell(const Column& c, int64_t r) {
+  const char* p = c.base + r * c.stride;
+  if (c.width == 8) {
+    int64_t x;
+    memcpy(&x, p, sizeof x);
+    return x;
   }
-  return h;
+  int32_t x;  // width == 4
+  memcpy(&x, p, sizeof x);
+  return x;
+}
+
+inline uint64_t mix(uint64_t x) {
+  x *= 0xff51afd7ed558ccdull;  // splitmix-style scramble per word
+  x ^= x >> 33;
+  return x;
+}
+
+inline int64_t reduce(int32_t op, int64_t a, int64_t b) {
+  if (op == 0) return std::max(a, b);
+  // wraps like numpy's int64 sum
+  return static_cast<int64_t>(static_cast<uint64_t>(a) +
+                              static_cast<uint64_t>(b));
+}
+
+using Point = std::pair<int64_t, int64_t>;  // (time, value)
+
+// How a group's times have arrived so far.
+enum Arrival : uint8_t { kIncreasing = 0, kTies = 1, kUnsorted = 2 };
+
+struct Group {
+  int64_t rows = 0;    // rows of the group
+  int64_t points = 0;  // distinct times, exact unless kUnsorted
+  int64_t last = 0;    // newest time seen
+  uint8_t arrival = kIncreasing;
+  int32_t loose = -1;  // kUnsorted: index into Builder::loose
+};
+
+// One sb_add: where its rows' points lie and each row's group.
+struct Part {
+  Column times, values;
+  std::vector<int32_t> gid;  // -1 = filtered out
+};
+
+struct Builder {
+  int32_t k = 0, op = 0;
+  std::vector<int64_t> keys;     // a group's key: k words, first row's
+  std::vector<uint64_t> hashes;  // and its hash
+  std::vector<Group> groups;
+  std::vector<int32_t> slots;  // open addressing: group id or -1
+  std::vector<Part> parts;
+  // sb_finish
+  int64_t T = 0;
+  std::vector<int32_t> order;            // output row -> group
+  std::vector<int64_t> begin;            // group -> output row * T
+  std::vector<std::vector<Point>> loose;  // sorted, merged
+
+  int32_t group_of(const int64_t* row, uint64_t hv) {
+    size_t s = hv & (slots.size() - 1);
+    for (;;) {
+      const int32_t g = slots[s];
+      if (g < 0) break;
+      if (hashes[g] == hv &&
+          !memcmp(keys.data() + static_cast<size_t>(g) * k, row,
+                  static_cast<size_t>(k) * sizeof(int64_t)))
+        return g;
+      s = (s + 1) & (slots.size() - 1);
+    }
+    const int32_t g = static_cast<int32_t>(groups.size());
+    slots[s] = g;
+    keys.insert(keys.end(), row, row + k);
+    hashes.push_back(hv);
+    groups.emplace_back();
+    if (groups.size() * 2 > slots.size()) grow();
+    return g;
+  }
+
+  // The table follows the number of groups, not of rows: 80 series of
+  // 43,200 points probe 1,024 slots, not 8 M.
+  void grow() {
+    slots.assign(slots.size() * 2, -1);
+    for (size_t g = 0; g < hashes.size(); ++g) {
+      size_t s = hashes[g] & (slots.size() - 1);
+      while (slots[s] >= 0) s = (s + 1) & (slots.size() - 1);
+      slots[s] = static_cast<int32_t>(g);
+    }
+  }
+};
+
+template <typename V>
+void fill(const Builder& b, V* out_values, int64_t* out_times,
+          uint8_t* out_mask) {
+  const int64_t T = b.T;
+  // A group whose times arrived non-decreasing is written as its rows
+  // are met: a new time takes the next cell, a repeated one reduces
+  // into the cell before it (in int64, converted after).
+  struct Cursor {
+    int64_t first, next, last, acc;
+  };
+  std::vector<Cursor> cur(b.groups.size());
+  for (size_t g = 0; g < cur.size(); ++g) {
+    cur[g].first = b.begin[g];
+    cur[g].next = b.groups[g].loose < 0 ? b.begin[g] : -1;
+  }
+  for (const Part& p : b.parts) {
+    const int64_t n = static_cast<int64_t>(p.gid.size());
+    for (int64_t r = 0; r < n; ++r) {
+      const int32_t g = p.gid[r];
+      if (g < 0) continue;
+      Cursor& c = cur[g];
+      if (c.next < 0) continue;
+      const int64_t t = cell(p.times, r), v = cell(p.values, r);
+      if (c.next > c.first && c.last == t) {
+        c.acc = reduce(b.op, c.acc, v);
+        out_values[c.next - 1] = static_cast<V>(static_cast<double>(c.acc));
+      } else {
+        c.last = t;
+        c.acc = v;
+        out_times[c.next] = t;
+        out_values[c.next] = static_cast<V>(static_cast<double>(v));
+        out_mask[c.next] = 1;
+        ++c.next;
+      }
+    }
+  }
+  for (size_t g = 0; g < cur.size(); ++g) {
+    int64_t at = cur[g].next;
+    if (at < 0) {
+      at = cur[g].first;
+      for (const Point& pt : b.loose[b.groups[g].loose]) {
+        out_times[at] = pt.first;
+        out_values[at] = static_cast<V>(static_cast<double>(pt.second));
+        out_mask[at] = 1;
+        ++at;
+      }
+    }
+    // the padding: only what a series leaves of its row
+    const size_t pad = static_cast<size_t>(cur[g].first + T - at);
+    std::fill_n(out_values + at, pad, V(0));
+    std::fill_n(out_times + at, pad, int64_t(0));
+    memset(out_mask + at, 0, pad);
+  }
 }
 
 }  // namespace
 
 extern "C" {
 
-// keys: [n, k] row-major int64; times/values: [n] int64.
-// op: 0 = max, 1 = sum for duplicate (key, time) rows.
-void* sb_build(const int64_t* keys, const int64_t* times,
-               const int64_t* values, int64_t n, int64_t k, int32_t op) {
+void* sb_new(int32_t k, int32_t op) {
   auto* b = new Builder();
   b->k = k;
-  if (n == 0) return b;
+  b->op = op;
+  b->slots.assign(1024, -1);
+  return b;
+}
 
-  // Open-addressing map: slot -> (representative row, group id).
-  size_t cap = 1;
-  while (cap < static_cast<size_t>(n) * 2) cap <<= 1;
-  std::vector<int64_t> slot_row(cap, -1);
-  std::vector<int32_t> slot_gid(cap, -1);
-  std::vector<int64_t> rep_rows;
-  std::vector<std::vector<std::pair<int64_t, int64_t>>> groups;
-
+void sb_add(void* h, const void** cols, const int32_t* widths,
+            const int64_t* strides, const uint8_t* mask, int64_t n) {
+  auto* b = static_cast<Builder*>(h);
+  const int32_t k = b->k;
+  std::vector<Column> key(k + 2);
+  for (int32_t i = 0; i < k + 2; ++i)
+    key[i] = {static_cast<const char*>(cols[i]), strides[i], widths[i]};
+  b->parts.emplace_back();
+  Part& part = b->parts.back();
+  part.times = key[k];
+  part.values = key[k + 1];
+  part.gid.resize(n);
+  std::vector<int64_t> row(k);
   for (int64_t r = 0; r < n; ++r) {
-    const int64_t* row = keys + r * k;
-    uint64_t h = hash_row(row, k) & (cap - 1);
-    int32_t gid = -1;
-    for (;;) {
-      if (slot_row[h] < 0) {
-        gid = static_cast<int32_t>(groups.size());
-        slot_row[h] = r;
-        slot_gid[h] = gid;
-        rep_rows.push_back(r);
-        groups.emplace_back();
-        break;
-      }
-      if (!memcmp(keys + slot_row[h] * k, row,
-                  static_cast<size_t>(k) * sizeof(int64_t))) {
-        gid = slot_gid[h];
-        break;
-      }
-      h = (h + 1) & (cap - 1);
+    if (mask && !mask[r]) {
+      part.gid[r] = -1;
+      continue;
     }
-    groups[gid].emplace_back(times[r], values[r]);
+    uint64_t hv = 1469598103934665603ull;  // FNV offset basis
+    for (int32_t i = 0; i < k; ++i) {
+      row[i] = cell(key[i], r);
+      hv = (hv ^ mix(static_cast<uint64_t>(row[i]))) * 1099511628211ull;
+    }
+    const int32_t g = b->group_of(row.data(), hv);
+    part.gid[r] = g;
+    Group& grp = b->groups[g];
+    const int64_t t = cell(part.times, r);
+    if (!grp.rows || t > grp.last)
+      ++grp.points;
+    else
+      grp.arrival = std::max<uint8_t>(grp.arrival,
+                                      t == grp.last ? kTies : kUnsorted);
+    grp.last = t;
+    ++grp.rows;
+  }
+}
+
+void sb_finish(void* h, int64_t* S, int64_t* T) {
+  auto* b = static_cast<Builder*>(h);
+  const int32_t k = b->k;
+  const size_t G = b->groups.size();
+
+  // Only a group whose times arrived out of order is gathered, sorted
+  // and merged; its rows are counted, so its vector never regrows.
+  for (Group& grp : b->groups) {
+    if (grp.arrival != kUnsorted) continue;
+    grp.loose = static_cast<int32_t>(b->loose.size());
+    b->loose.emplace_back();
+    b->loose.back().reserve(grp.rows);
+  }
+  if (!b->loose.empty()) {
+    for (const Part& p : b->parts) {
+      const int64_t n = static_cast<int64_t>(p.gid.size());
+      for (int64_t r = 0; r < n; ++r) {
+        const int32_t g = p.gid[r];
+        if (g >= 0 && b->groups[g].loose >= 0)
+          b->loose[b->groups[g].loose].emplace_back(cell(p.times, r),
+                                                     cell(p.values, r));
+      }
+    }
+    for (Group& grp : b->groups) {
+      if (grp.loose < 0) continue;
+      auto& pts = b->loose[grp.loose];
+      std::sort(pts.begin(), pts.end(),
+                [](const Point& x, const Point& y) {
+                  return x.first < y.first;
+                });
+      size_t w = 0;
+      for (size_t i = 0; i < pts.size(); ++i) {
+        if (w && pts[w - 1].first == pts[i].first)
+          pts[w - 1].second =
+              reduce(b->op, pts[w - 1].second, pts[i].second);
+        else
+          pts[w++] = pts[i];
+      }
+      pts.resize(w);
+      grp.points = static_cast<int64_t>(w);
+    }
   }
 
   // Emit groups in lexicographic key order (np.lexsort parity).
-  const int64_t S = static_cast<int64_t>(groups.size());
-  std::vector<int32_t> order(S);
-  for (int64_t i = 0; i < S; ++i) order[i] = static_cast<int32_t>(i);
-  std::sort(order.begin(), order.end(), [&](int32_t a, int32_t c) {
-    const int64_t* ra = keys + rep_rows[a] * k;
-    const int64_t* rc = keys + rep_rows[c] * k;
-    for (int64_t i = 0; i < k; ++i)
+  b->order.resize(G);
+  for (size_t g = 0; g < G; ++g) b->order[g] = static_cast<int32_t>(g);
+  const int64_t* keys = b->keys.data();
+  std::sort(b->order.begin(), b->order.end(), [&](int32_t a, int32_t c) {
+    const int64_t* ra = keys + static_cast<size_t>(a) * k;
+    const int64_t* rc = keys + static_cast<size_t>(c) * k;
+    for (int32_t i = 0; i < k; ++i)
       if (ra[i] != rc[i]) return ra[i] < rc[i];
     return false;
   });
 
-  b->S = S;
-  b->group_keys.resize(static_cast<size_t>(S) * k);
-  b->series.resize(S);
-  int64_t T = 0;
-  for (int64_t gi = 0; gi < S; ++gi) {
-    const int32_t g = order[gi];
-    memcpy(&b->group_keys[gi * k], keys + rep_rows[g] * k,
-           static_cast<size_t>(k) * sizeof(int64_t));
-    auto& pts = groups[g];
-    std::sort(pts.begin(), pts.end(),
-              [](const std::pair<int64_t, int64_t>& x,
-                 const std::pair<int64_t, int64_t>& y) {
-                return x.first < y.first;
-              });
-    auto& out = b->series[gi];
-    out.reserve(pts.size());
-    for (const auto& p : pts) {
-      if (!out.empty() && out.back().first == p.first) {
-        if (op == 0)
-          out.back().second = std::max(out.back().second, p.second);
-        else
-          out.back().second += p.second;
-      } else {
-        out.push_back(p);
-      }
-    }
-    T = std::max<int64_t>(T, static_cast<int64_t>(out.size()));
-  }
-  b->T = T;
-  return b;
-}
-
-void sb_dims(void* h, int64_t* S, int64_t* T) {
-  auto* b = static_cast<Builder*>(h);
-  *S = b->S;
+  b->T = 0;
+  for (const Group& grp : b->groups) b->T = std::max(b->T, grp.points);
+  b->begin.resize(G);
+  for (size_t s = 0; s < G; ++s)
+    b->begin[b->order[s]] = static_cast<int64_t>(s) * b->T;
+  *S = static_cast<int64_t>(G);
   *T = b->T;
 }
 
-// out_keys: [S, k] int64; out_values: [S, T] double;
-// out_times: [S, T] int64; out_mask: [S, T] uint8. Caller-allocated.
-void sb_fill(void* h, int64_t* out_keys, double* out_values,
-             int64_t* out_times, uint8_t* out_mask) {
-  auto* b = static_cast<Builder*>(h);
-  const int64_t S = b->S, T = b->T, k = b->k;
-  if (S && k)
-    memcpy(out_keys, b->group_keys.data(),
-           static_cast<size_t>(S) * k * sizeof(int64_t));
-  if (!S || !T) return;
-  memset(out_values, 0, static_cast<size_t>(S) * T * sizeof(double));
-  memset(out_times, 0, static_cast<size_t>(S) * T * sizeof(int64_t));
-  memset(out_mask, 0, static_cast<size_t>(S) * T);
-  for (int64_t s = 0; s < S; ++s) {
-    const auto& pts = b->series[s];
-    for (size_t t = 0; t < pts.size(); ++t) {
-      out_values[s * T + t] = static_cast<double>(pts[t].second);
-      out_times[s * T + t] = pts[t].first;
-      out_mask[s * T + t] = 1;
-    }
-  }
+void sb_fill(void* h, int64_t* out_keys, void* out_values,
+             int32_t value_width, int64_t* out_times,
+             uint8_t* out_mask) {
+  const auto* b = static_cast<const Builder*>(h);
+  const int32_t k = b->k;
+  for (size_t s = 0; s < b->order.size(); ++s)
+    memcpy(out_keys + s * k,
+           b->keys.data() + static_cast<size_t>(b->order[s]) * k,
+           static_cast<size_t>(k) * sizeof(int64_t));
+  if (value_width == 4)
+    fill(*b, static_cast<float*>(out_values), out_times, out_mask);
+  else
+    fill(*b, static_cast<double*>(out_values), out_times, out_mask);
 }
 
 void sb_free(void* h) { delete static_cast<Builder*>(h); }

@@ -765,3 +765,58 @@ def test_the_dbscan_cells_metrics_reduce_to_a_jobs_figures():
     traced["trace"]["ops"] = traced["trace"]["ops"][1:]
     assert read("job.dbscan_device_ms", traced) is None
     assert read("dbscan_noise_roofline", traced) is None
+
+
+TAD_CELLS = ["parts-fused.tad-ewma", "parts-fused-12h.tad-arima",
+             "parts-fused-12h-ns.tad-dbscan"]
+
+
+def test_the_direct_rows_metric_reduces_to_a_jobs_rows(monkeypatch):
+    """`job.tensorize_direct_rows` (PR 46) reads the program's own
+    exposition around one TAD job: the rows the native builder grouped
+    from the batch's columns in place, all of a job's in the three TAD
+    cells; 0 where the numpy path took them (its rows count under
+    `path="numpy"`) and nothing from a manager without the counter
+    (the parent)."""
+    import time
+
+    from benchmarks import prom
+    from theia_tpu.analytics import TadQuerySpec, run_tad
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.ingest.native import native_available
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+    from theia_tpu.store import FlowDatabase
+
+    name = "job.tensorize_direct_rows"
+    m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+    reader = BENCH.reader("per_layer", name)
+    assert (m["layer"], m["moves"], m["workloads"], m["source"]) == (
+        "TAD host path", "job_turnaround_s", TAD_CELLS, "program_counter")
+    assert (reader["layer"], reader["moves"], reader["source"]) == (
+        m["layer"], m["moves"], m["source"])
+    if not native_available():
+        pytest.skip("native library unavailable")
+
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=3, points_per_series=48, seed=2)))
+
+    def around_a_job(flag):
+        monkeypatch.setenv("THEIA_NATIVE_SERIES", flag)
+        before = prom.parse(exposition.render())
+        run_tad(db, "EWMA", TadQuerySpec(), now=int(time.time()),
+                progress=JobProgress("job", TAD_STAGES, kind="tad"))
+        return {"metrics_before": before,
+                "metrics_after": prom.parse(exposition.render())}
+
+    def read(data):
+        return extend.resolve("reduction", reader["reduce"])(data, reader)
+
+    data = around_a_job("1")
+    assert reader["series"] in data["metrics_after"]
+    assert read(data) == 3 * 48
+    assert read(around_a_job("0")) == 0
+    for side in data.values():
+        side.pop(reader["series"], None)
+    assert read(data) is None

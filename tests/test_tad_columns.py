@@ -258,3 +258,44 @@ def test_rows_written_counter_is_the_answers_rows():
     assert nbytes == sum(a.nbytes for a in result.columns.values())
     assert f'theia_job_rows_written_total{{kind="tad"}} {value(names[0]):g}' \
         in prom.render().replace(".0\n", "\n")
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_tensorize_rows_counter_names_the_path(monkeypatch, mode):
+    """A TAD job through `JobProgress` raises
+    `theia_job_tensorize_rows_total{kind="tad",path="columns"}` by the
+    rows its filters kept (both sides' in the pod modes), and
+    `{path="numpy"}` instead, and by as many, with the native builder
+    disabled; a job that keeps no row raises neither."""
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+
+    batch = flows()
+    spec, none = _modes(batch)[mode]
+    db = FlowDatabase()
+    db.insert_flows(batch)
+    counter = metrics.REGISTRY.get("theia_job_tensorize_rows_total")
+    points = np.count_nonzero(build_series(db.flows.scan(), spec).mask)
+
+    def value(path):
+        return counter.labels(kind="tad", path=path).value()
+
+    def rise(flag, path, other, job_spec):
+        monkeypatch.setenv("THEIA_NATIVE_SERIES", flag)
+        before, before_other = value(path), value(other)
+        run_tad(db, "EWMA", job_spec, now=NOW,
+                progress=JobProgress("job", TAD_STAGES, kind="tad"))
+        assert value(other) == before_other
+        return value(path) - before
+
+    rows = rise("1", "columns", "numpy", spec)
+    assert rows == rise("0", "numpy", "columns", spec)
+    # every kept row is a point or merges into one
+    assert len(batch) * (2 if "pod" in mode else 1) >= rows >= points > 0
+    if mode == "connection":
+        assert rows == len(batch)
+    assert rise("1", "columns", "numpy", none) == 0
+    assert rise("0", "numpy", "columns", none) == 0
+    text = prom.render()
+    for path in ("columns", "numpy"):
+        assert (f'theia_job_tensorize_rows_total{{kind="tad",'
+                f'path="{path}"}} ') in text

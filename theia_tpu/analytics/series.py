@@ -27,7 +27,7 @@ import contextlib
 import dataclasses
 import json
 import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -199,27 +199,56 @@ def _pack_and_pad(key_mat: np.ndarray, t: np.ndarray, v: np.ndarray,
     return sk[starts], values, times, mask
 
 
-def _group_and_pad(key_mat: np.ndarray, t: np.ndarray, v: np.ndarray,
-                   op: str, dtype):
-    """Stage-1 (key,time) reduction + ragged→padded packing.
+class SeriesRows(NamedTuple):
+    """Rows to group, where they lie: the key columns, the time and
+    the value column of one batch, each in its stored dtype, and the
+    filters' row mask (None = every row). Nothing is copied to make
+    one."""
+    key_cols: Sequence[np.ndarray]
+    times: np.ndarray
+    values: np.ndarray
+    mask: Optional[np.ndarray]
 
-    One seam with two equivalent implementations: the native C++
-    builder (native/seriesbuild.cc — one hash-group pass; the host
-    tensorize hot path) and the numpy lexsort pipeline. Selected by
-    THEIA_NATIVE_SERIES=auto/1/0 (auto = native when available)."""
+    @property
+    def kept(self) -> int:
+        """Rows the mask keeps."""
+        if self.mask is None:
+            return len(self.times)
+        return int(np.count_nonzero(self.mask))
+
+
+def _group_and_pad(parts: Sequence[SeriesRows], op: str, dtype):
+    """Stage-1 (key,time) reduction + ragged→padded packing of the
+    rows of `parts` as one table; returns the four tensors and the
+    path that made them.
+
+    One seam with two equivalent implementations: `columns`, the native
+    C++ builder (native/seriesbuild.cc — one hash-group pass over the
+    columns in place; the host tensorize hot path), and `numpy`, the
+    lexsort pipeline over an [n, k] matrix it builds itself. Selected
+    by THEIA_NATIVE_SERIES=auto/1/0 (auto = native when available and
+    the columns are ones it takes)."""
     flag = os.environ.get("THEIA_NATIVE_SERIES", "auto").lower()
     if flag not in ("0", "off", "false"):
         from ..ingest.native import build_padded_series
 
-        res = build_padded_series(key_mat, t, v, op, dtype)
+        res = build_padded_series(parts, op, dtype)
         if res is not None:
-            return res
+            return res, "columns"
         if flag in ("1", "on", "true"):
-            raise RuntimeError("THEIA_NATIVE_SERIES=1 but the native "
-                               "library is unavailable")
-    stage1 = np.concatenate([key_mat, t[:, None]], axis=1)
-    gk, gv = group_reduce(stage1, v[:, None], op)
-    return _pack_and_pad(gk[:, :-1], gk[:, -1], gv[:, 0], dtype)
+            raise RuntimeError(
+                "THEIA_NATIVE_SERIES=1 but the native builder is "
+                "unavailable or was handed a column it does not take")
+    stage1, values = [], []
+    for key_cols, t, v, mask in parts:
+        rows = slice(None) if mask is None else mask
+        stage1.append(np.stack(
+            [np.asarray(c, np.int64)[rows] for c in (*key_cols, t)],
+            axis=1))
+        values.append(np.asarray(v, np.int64)[rows])
+    gk, gv = group_reduce(np.concatenate(stage1),
+                          np.concatenate(values)[:, None], op)
+    return _pack_and_pad(gk[:, :-1], gk[:, -1], gv[:, 0], dtype), "numpy"
 
 
 def remove_meaningless_labels(labels_json: str) -> str:
@@ -256,74 +285,75 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
                  dtype=np.float64, progress=None) -> SeriesBatch:
     """Build the padded series batch for one TAD query. `progress`
     (the job's, in its `tensorize` stage) times the stage's three
-    parts, named by what each produces: `keys` (the filter masks, the
-    row selection and the key matrix), `group` (the value and time
-    columns through `_group_and_pad` into the padded tensors) and
-    `decode` (the series' keys as the result rows show them)."""
-    if spec.agg_flow == "pod":
-        return _build_pod_series(flows, spec, dtype, progress)
-
+    parts, named by what each produces: `keys` (the filter masks: the
+    key columns are handed on as the batch holds them), `group` (those
+    columns, the time and the value column and the mask through
+    `_group_and_pad` into the padded tensors) and `decode` (the series'
+    keys as the result rows show them), and counts the rows grouped by
+    the path that grouped them."""
+    pod = spec.agg_flow == "pod"
     with job_part(progress, "keys"):
-        base = _base_mask(flows, spec)
-        if spec.start_time is not None:
-            base &= (np.asarray(flows["flowStartSeconds"])
-                     >= spec.start_time)
-        if spec.end_time is not None:
-            base &= np.asarray(flows["flowEndSeconds"]) < spec.end_time
-
-        key_names, op = _group_key(spec)
-        if spec.agg_flow == "external":
-            base &= np.asarray(flows["flowType"]) == 3
-            if spec.external_ip:
-                code = flows.dicts["destinationIP"].lookup(
-                    spec.external_ip)
-                base &= (np.asarray(flows["destinationIP"])
-                         == (-1 if code is None else code))
-        elif spec.agg_flow == "svc":
-            if spec.svc_port_name:
-                code = flows.dicts["destinationServicePortName"].lookup(
-                    spec.svc_port_name)
-                base &= (np.asarray(flows["destinationServicePortName"])
-                         == (-1 if code is None else code))
-            else:
-                base &= np.asarray(
-                    flows["destinationServicePortName"]) != 0
-
-        # Materialize only the columns this query touches (masking all
-        # 52 through ColumnarBatch.filter costs more than the grouping
-        # itself on the tensorize hot path).
-        col = flows.column_selector(base)
-
-        key_cols = np.stack([col(c) for c in key_names], axis=1)
+        if pod:
+            parts, op = _pod_rows(flows, spec), "sum"
+        else:
+            key_names, op = _group_key(spec)
+            parts = [_rows(flows, [flows[c] for c in key_names],
+                           _filter_mask(flows, spec))]
     with job_part(progress, "group"):
-        key_mat, values, times, mask = _group_and_pad(
-            key_cols, col("flowEndSeconds"), col("throughput"), op,
-            dtype)
+        (key_mat, values, times, mask), path = _group_and_pad(
+            parts, op, dtype)
     with job_part(progress, "decode"):
-        keys = _decode_keys(flows, key_names, key_mat)
+        if pod:
+            key_names, keys = _decode_pod_keys(flows, spec, key_mat)
+        else:
+            keys = _decode_keys(flows, key_names, key_mat)
+    if progress:
+        progress.tensorized(sum(p.kept for p in parts), path)
     return SeriesBatch(key_names, keys, values, times, mask, spec.agg_type)
 
 
-def _build_pod_series(flows: ColumnarBatch, spec: TadQuerySpec,
-                      dtype, progress=None) -> SeriesBatch:
-    """Inbound ∪ outbound pod aggregation (reference :511-565)."""
-    with job_part(progress, "keys"):
-        all_keys, all_t, all_v = _pod_rows(flows, spec)
-    with job_part(progress, "group"):
-        key_mat, values, times, mask = _group_and_pad(
-            all_keys, all_t, all_v, "sum", dtype)
-    with job_part(progress, "decode"):
-        key_names, keys = _decode_pod_keys(flows, spec, key_mat)
-    return SeriesBatch(key_names, keys, values, times, mask, "pod")
+def _rows(flows: ColumnarBatch, key_cols: Sequence[np.ndarray],
+          mask: np.ndarray) -> SeriesRows:
+    """`flows`' rows under `mask`, keyed by `key_cols`, in place."""
+    return SeriesRows(key_cols, flows["flowEndSeconds"],
+                      flows["throughput"], None if mask.all() else mask)
 
 
-def _pod_rows(flows: ColumnarBatch, spec: TadQuerySpec):
-    """The rows of both sides as (keys [n, 3], time, throughput): a
-    side's namespace and name-or-labels codes and its direction."""
+def _filter_mask(flows: ColumnarBatch, spec: TadQuerySpec) -> np.ndarray:
+    """The non-pod modes' WHERE clause as one row mask."""
+    base = _base_mask(flows, spec)
+    if spec.start_time is not None:
+        base &= np.asarray(flows["flowStartSeconds"]) >= spec.start_time
+    if spec.end_time is not None:
+        base &= np.asarray(flows["flowEndSeconds"]) < spec.end_time
+
+    if spec.agg_flow == "external":
+        base &= np.asarray(flows["flowType"]) == 3
+        if spec.external_ip:
+            code = flows.dicts["destinationIP"].lookup(spec.external_ip)
+            base &= (np.asarray(flows["destinationIP"])
+                     == (-1 if code is None else code))
+    elif spec.agg_flow == "svc":
+        if spec.svc_port_name:
+            code = flows.dicts["destinationServicePortName"].lookup(
+                spec.svc_port_name)
+            base &= (np.asarray(flows["destinationServicePortName"])
+                     == (-1 if code is None else code))
+        else:
+            base &= np.asarray(flows["destinationServicePortName"]) != 0
+    return base
+
+
+def _pod_rows(flows: ColumnarBatch, spec: TadQuerySpec
+              ) -> List[SeriesRows]:
+    """Inbound ∪ outbound pod aggregation (reference :511-565): the
+    rows of each side, keyed by the side's namespace and
+    name-or-labels codes and its direction (0 inbound, 1 outbound: a
+    constant column)."""
     base = _base_mask(flows, spec)
     by_name = bool(spec.pod_name)
-    parts = []  # (keys [n,2], time, thr, direction_id)
-    for direction, ns_col, id_col in _pod_sides(by_name):
+    parts = []
+    for direction, (_, ns_col, id_col) in enumerate(_pod_sides(by_name)):
         m = base.copy()
         if by_name:
             code = flows.dicts[id_col].lookup(spec.pod_name)
@@ -338,20 +368,11 @@ def _pod_rows(flows: ColumnarBatch, spec: TadQuerySpec):
             code = flows.dicts[ns_col].lookup(spec.pod_namespace)
             m &= np.asarray(flows[ns_col]) == (
                 -1 if code is None else code)
-        col = flows.column_selector(m)
-
-        keys = np.stack([col(ns_col), col(id_col)], axis=1)
-        parts.append((keys, col("flowEndSeconds"), col("throughput"),
-                      direction))
-
-    dir_code = {"inbound": 0, "outbound": 1}
-    all_keys = np.concatenate(
-        [np.concatenate(
-            [k, np.full((k.shape[0], 1), dir_code[d], np.int64)], axis=1)
-         for k, _, _, d in parts], axis=0)
-    all_t = np.concatenate([t for _, t, _, _ in parts])
-    all_v = np.concatenate([v for _, _, v, _ in parts])
-    return all_keys, all_t, all_v
+        parts.append(_rows(
+            flows,
+            [flows[ns_col], flows[id_col],
+             np.broadcast_to(np.int64(direction), len(flows))], m))
+    return parts
 
 
 def _decode_pod_keys(flows: ColumnarBatch, spec: TadQuerySpec,

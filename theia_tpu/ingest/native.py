@@ -139,21 +139,18 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64,
         ctypes.POINTER(ctypes.c_int64)]
     lib.fb_free.argtypes = [ctypes.c_void_p]
-    lib.sb_build.restype = ctypes.c_void_p
-    lib.sb_build.argtypes = [
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
-    lib.sb_dims.argtypes = [ctypes.c_void_p,
-                            ctypes.POINTER(ctypes.c_int64),
-                            ctypes.POINTER(ctypes.c_int64)]
+    lib.sb_new.restype = ctypes.c_void_p
+    lib.sb_new.argtypes = [ctypes.c_int32, ctypes.c_int32]
+    lib.sb_add.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_void_p, ctypes.c_int64]
+    lib.sb_finish.argtypes = [ctypes.c_void_p,
+                              ctypes.POINTER(ctypes.c_int64),
+                              ctypes.POINTER(ctypes.c_int64)]
     lib.sb_fill.argtypes = [
-        ctypes.c_void_p,
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_double),
-        ctypes.POINTER(ctypes.c_int64),
-        ctypes.POINTER(ctypes.c_uint8)]
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p]
     lib.sb_free.argtypes = [ctypes.c_void_p]
     lib.gs_build.restype = ctypes.c_void_p
     lib.gs_build.argtypes = [
@@ -617,50 +614,75 @@ def encode_tsv(batch: ColumnarBatch, schema=FLOW_SCHEMA) -> bytes:
     return ("\n".join(rows) + "\n").encode()
 
 
-def build_padded_series(keys: np.ndarray, times: np.ndarray,
-                        values: np.ndarray, op: str,
-                        dtype=np.float64):
-    """Native tensorize: group rows by [n, k] int64 key tuples into
+_INT_DTYPES = (np.dtype(np.int32), np.dtype(np.int64))
+
+
+def build_padded_series(parts, op: str, dtype=np.float64):
+    """Native tensorize: group rows by their integer key columns into
     padded per-series time arrays (native/seriesbuild.cc).
+
+    `parts`: a sequence of (key_cols, times, values, mask): rows given
+    as 1-D int32/int64 columns of one length, read where they lie in
+    their stored width and stride (a `np.broadcast_to` scalar is a
+    constant column), and an optional bool row mask (None = every
+    row). Several parts (the pod mode's two sides) are grouped as one
+    table; all have the same number of key columns.
 
     Returns (key_mat [S,k] int64, values [S,T] dtype, times [S,T] int64,
     mask [S,T] bool) with series in lexicographic key order and points
     in time order — bit-identical to the numpy group_reduce +
     _pack_and_pad path in analytics/series.py. Duplicate (key, time)
-    rows reduce with `op` ("max" or "sum"). Returns None when the
-    native library is unavailable (caller falls back to numpy).
+    rows reduce with `op` ("max" or "sum"). Returns None (the caller
+    falls back to numpy) when the native library is unavailable or a
+    column is of another dtype, shape or length.
     """
     lib = _load_library()
     if lib is None:
         return None
-    keys = np.ascontiguousarray(keys, np.int64)
-    times = np.ascontiguousarray(times, np.int64)
-    values = np.ascontiguousarray(values, np.int64)
-    n, k = keys.shape
-    handle = lib.sb_build(
-        keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        times.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        values.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        n, k, 0 if op == "max" else 1)
+    taken = []
+    for key_cols, times, values, mask in parts:
+        cols = [np.asarray(c) for c in (*key_cols, times, values)]
+        n = len(cols[-1])
+        if n >= 2 ** 31 or any(
+                c.ndim != 1 or len(c) != n or c.dtype not in _INT_DTYPES
+                for c in cols):
+            return None
+        if mask is not None:
+            mask = np.ascontiguousarray(mask, bool)
+            if mask.shape != (n,):
+                return None
+        taken.append((cols, mask))
+    k = len(taken[0][0]) - 2
+    if any(len(cols) != k + 2 for cols, _ in taken):
+        raise ValueError("parts differ in their number of key columns")
+
+    # values are written in the asked dtype where the builder has it
+    fill = np.dtype(dtype)
+    if fill not in (np.dtype(np.float32), np.dtype(np.float64)):
+        fill = np.dtype(np.float64)
+    handle = lib.sb_new(k, 0 if op == "max" else 1)
     try:
+        for cols, mask in taken:
+            lib.sb_add(
+                handle,
+                (ctypes.c_void_p * (k + 2))(*[c.ctypes.data for c in cols]),
+                (ctypes.c_int32 * (k + 2))(*[c.itemsize for c in cols]),
+                (ctypes.c_int64 * (k + 2))(*[c.strides[0] for c in cols]),
+                None if mask is None else mask.ctypes.data,
+                len(cols[-1]))
         S = ctypes.c_int64()
         T = ctypes.c_int64()
-        lib.sb_dims(handle, ctypes.byref(S), ctypes.byref(T))
+        lib.sb_finish(handle, ctypes.byref(S), ctypes.byref(T))
         s, t = S.value, T.value
         key_mat = np.empty((s, k), np.int64)
-        vals = np.empty((s, t), np.float64)
+        vals = np.empty((s, t), fill)
         ts = np.empty((s, t), np.int64)
-        mask = np.empty((s, t), np.uint8)
-        lib.sb_fill(
-            handle,
-            key_mat.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-            ts.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            mask.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
+        out_mask = np.empty((s, t), bool)
+        lib.sb_fill(handle, key_mat.ctypes.data, vals.ctypes.data,
+                    fill.itemsize, ts.ctypes.data, out_mask.ctypes.data)
     finally:
         lib.sb_free(handle)
-    return key_mat, vals.astype(dtype, copy=False), ts, \
-        mask.astype(bool)
+    return key_mat, vals.astype(dtype, copy=False), ts, out_mask
 
 
 def native_group_sum(key_cols, value_cols):

@@ -91,6 +91,12 @@ _M_READ_COLUMNS = _metrics.counter(
 _M_READ_BYTES = _metrics.counter(
     "theia_job_read_bytes_total",
     "Column bytes of those batches", labelnames=("kind",))
+_M_TENSORIZE_ROWS = _metrics.counter(
+    "theia_job_tensorize_rows_total",
+    "Rows a job's tensorize stage grouped into series, by the path "
+    "that grouped them: columns (the native builder, from the batch's "
+    "columns in place) or numpy (the fallback and its key matrix)",
+    labelnames=("kind", "path"))
 _M_ROWS_WRITTEN = _metrics.counter(
     "theia_job_rows_written_total",
     "Result rows a job inserted into its result table as one batch",
@@ -149,6 +155,11 @@ class JobProgress:
         _M_READ_ROWS.labels(kind=self.kind).inc(len(batch))
         _M_READ_COLUMNS.labels(kind=self.kind).inc(len(batch.columns))
         _M_READ_BYTES.labels(kind=self.kind).inc(_column_bytes(batch))
+
+    def tensorized(self, rows: int, path: str) -> None:
+        """Count the rows the `tensorize` stage grouped and the path
+        (`columns` or `numpy`) that grouped them."""
+        _M_TENSORIZE_ROWS.labels(kind=self.kind, path=path).inc(rows)
 
     def scored(self, algo: str, series: int, points: int,
                fits: int = 0, loop_iterations: int = 0,
