@@ -266,11 +266,11 @@ class Manager:
 def phase_build(ctx) -> dict:
     """Build the native library here, from native/*.cc as committed."""
     for so in glob.glob(os.path.join(
-            HERE, "theia_tpu", "ingest", "_build", "*.so")):
+            HERE, "theia_tpu", "utils", "_build", "*.so")):
         os.remove(so)
     log = os.path.join(WORK, "build.log")
     secs = run([sys.executable, "-c",
-                "import sys; from theia_tpu.ingest.native import "
+                "import sys; from theia_tpu.utils.native import "
                 "native_status; s = native_status(); print(s); "
                 "sys.exit(0 if s == 'loaded' else 1)"],
                child_env("cpu"), log, 300)

@@ -35,7 +35,7 @@
 //                                the Python StringDictionary)
 //   fb_free(h)
 //
-// Build: g++ -O3 -shared -fPIC (driven by theia_tpu/ingest/native.py).
+// Build: g++ -O3 -shared -fPIC (driven by theia_tpu/utils/native.py).
 
 #include <cstdint>
 #include <cstdlib>

@@ -10,7 +10,9 @@ acquires, RLock reentrancy, Condition.wait held-set discipline,
 disabled-mode zero-cost contract).
 """
 
+import ast
 import json
+import pathlib
 import threading
 import time
 import urllib.error
@@ -568,6 +570,75 @@ def test_stale_waiver_reported():
         [Finding(check="torn-read", key="torn-read:real:K:a,b",
                  message="m")], w)
     assert len(unwaived) == 1 and not waived and stale == w
+
+
+# -- the package's layering ----------------------------------------------
+
+#: the host's group-by: defined once, in ``theia_tpu/utils/native.py``
+GROUP_BY = {"group_reduce", "group_sum", "group_sum_fast"}
+
+#: (importing package, imported package, the modules that may): an
+#: arrow that points up. The one known exception is the store's two
+#: hooks into ``query.rollup``: ROADMAP D14 (they go with D4's decision)
+ARROWS = [
+    ("ops", "analytics", ()),
+    ("analytics", "ingest", ()),
+    ("store", "ingest", ()),
+    ("query", "ingest", ()),
+    ("utils", "ingest", ()),
+    ("store", "query", ("store/flow_store.py", "store/replicated.py")),
+]
+
+
+def _imports(path):
+    """(line, module, names) of every import in a module of the
+    package, function-level ones included, relative ones resolved."""
+    package = list(path.relative_to(REPO).parent.parts)
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                yield node.lineno, alias.name, ()
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - node.level + 1] \
+                if node.level else []
+            module = ".".join(base + ([node.module] if node.module else []))
+            yield node.lineno, module, tuple(a.name for a in node.names)
+
+
+def _package_imports(sub=""):
+    for path in sorted(pathlib.Path(REPO, "theia_tpu", sub).rglob("*.py")):
+        rel = path.relative_to(pathlib.Path(REPO, "theia_tpu")).as_posix()
+        for line, module, names in _imports(path):
+            yield rel, line, module, names
+
+
+@pytest.mark.parametrize("src,dst,may", ARROWS,
+                         ids=[f"{s}->{d}" for s, d, _ in ARROWS])
+def test_no_import_points_up(src, dst, may):
+    target = f"theia_tpu.{dst}"
+    found = [
+        f"{rel}:{line} imports {module}"
+        for rel, line, module, names in _package_imports(src)
+        if rel not in may and any(
+            m == target or m.startswith(target + ".")
+            for m in (module, *(f"{module}.{n}" for n in names)))]
+    assert not found, found
+
+
+def test_the_hosts_group_by_has_one_home():
+    """Nothing imports it from ``store.views`` (or through the
+    ``store`` package), and nothing else defines it."""
+    borrowed = [
+        f"{rel}:{line} imports {sorted(GROUP_BY & set(names))} from {module}"
+        for rel, line, module, names in _package_imports()
+        if GROUP_BY & set(names) and module != "theia_tpu.utils.native"]
+    assert not borrowed, borrowed
+    homes = {
+        path.relative_to(REPO).as_posix()
+        for path in pathlib.Path(REPO, "theia_tpu").rglob("*.py")
+        for node in ast.walk(ast.parse(path.read_text()))
+        if isinstance(node, ast.FunctionDef) and node.name in GROUP_BY}
+    assert homes == {"theia_tpu/utils/native.py"}
 
 
 # -- lint fixtures -------------------------------------------------------

@@ -783,7 +783,8 @@ def test_the_direct_rows_metric_reduces_to_a_jobs_rows(monkeypatch):
     from benchmarks import prom
     from theia_tpu.analytics import TadQuerySpec, run_tad
     from theia_tpu.data.synth import SynthConfig, generate_flows
-    from theia_tpu.ingest.native import native_available
+    from theia_tpu.analytics import series as series_mod
+    from theia_tpu.utils.native import native_available
     from theia_tpu.obs import prom as exposition
     from theia_tpu.runner.progress import TAD_STAGES, JobProgress
     from theia_tpu.store import FlowDatabase
@@ -802,8 +803,10 @@ def test_the_direct_rows_metric_reduces_to_a_jobs_rows(monkeypatch):
     db.insert_flows(generate_flows(SynthConfig(
         n_series=3, points_per_series=48, seed=2)))
 
-    def around_a_job(flag):
-        monkeypatch.setenv("THEIA_NATIVE_SERIES", flag)
+    def around_a_job(native):
+        if not native:      # as in a process without the library
+            monkeypatch.setattr(series_mod, "build_padded_series",
+                                lambda parts, op, dtype: None)
         before = prom.parse(exposition.render())
         run_tad(db, "EWMA", TadQuerySpec(), now=int(time.time()),
                 progress=JobProgress("job", TAD_STAGES, kind="tad"))
@@ -813,10 +816,10 @@ def test_the_direct_rows_metric_reduces_to_a_jobs_rows(monkeypatch):
     def read(data):
         return extend.resolve("reduction", reader["reduce"])(data, reader)
 
-    data = around_a_job("1")
+    data = around_a_job(True)
     assert reader["series"] in data["metrics_after"]
     assert read(data) == 3 * 48
-    assert read(around_a_job("0")) == 0
+    assert read(around_a_job(False)) == 0
     for side in data.values():
         side.pop(reader["series"], None)
     assert read(data) is None

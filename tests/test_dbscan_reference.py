@@ -30,9 +30,7 @@ from theia_tpu.store import FlowDatabase
 
 REL = 1e-12
 HERE = pathlib.Path(__file__).resolve().parent
-#: (series, steps, spike rate): the cell's law at sizes a test holds;
-#: 2,304 pads over ops.dbscan_pallas.PALLAS_MAX_T, so its dispatch is
-#: the 43,200-point cell's
+#: (series, steps, spike rate): the cell's law at sizes a test holds
 SHAPES = [(6, 700, 0.02), (3, 2304, 0.003), (4, 130, 0.05)]
 
 
@@ -135,11 +133,7 @@ def test_the_benchmarks_reference_is_this_one():
 
 @pytest.mark.parametrize("n_series,n_steps,spike_rate", SHAPES)
 def test_scores_are_the_references(n_series, n_steps, spike_rate):
-    """The kernel alone, both formulations: the XLA form's decisions
-    are the reference's exactly, and where the Pallas kernel takes the
-    length (interpreted here, float32 inside) it decides as the XLA
-    form does on the same float32 data."""
-    from theia_tpu.ops.dbscan_pallas import PALLAS_MAX_T, padded_length
+    """The kernel alone: its decisions are the reference's exactly."""
     x, spike = throughputs(n_series, n_steps, spike_rate, 23)
     mask = np.ones(x.shape, bool)
     mask[1, n_steps * 2 // 3:] = False
@@ -149,20 +143,12 @@ def test_scores_are_the_references(n_series, n_steps, spike_rate):
     assert min(noise, border, core) > 0, (noise, border, core)
     _, std, anom = ref.dbscan_scores(x, mask)
     calc, got_std, got = (np.asarray(a) for a in
-                          dbscan_scores(x, mask, use_pallas=False))
+                          dbscan_scores(x, mask))
     np.testing.assert_array_equal(got, anom)
     np.testing.assert_allclose(got_std, std, rtol=REL)
     assert not calc.any()
     if n_steps == 130:
         assert anom[2, :3].all()     # three points: none core, all noise
-    if padded_length(n_steps) > PALLAS_MAX_T:
-        with pytest.raises(ValueError, match="PALLAS_MAX_T"):
-            dbscan_scores(x, mask, use_pallas=True)
-        return
-    x32 = x.astype(np.float32)
-    np.testing.assert_array_equal(
-        np.asarray(dbscan_scores(x32, mask, use_pallas=True)[2]),
-        np.asarray(dbscan_noise(x32, mask)))
 
 
 def _law(n_series, n_steps, spike_rate):
@@ -349,12 +335,11 @@ def test_job_rows_are_the_references_decisions_with_its_deviation(
             std[s], rel=REL)
 
 
-def test_a_job_counts_the_points_it_sorts(monkeypatch):
+def test_a_job_counts_the_points_it_sorts():
     """A DBSCAN job through `run_tad` raises
-    `theia_job_dbscan_sorted_points_total` by its valid points, an EWMA
-    job by nothing, and a DBSCAN job whose batch the Pallas kernel
-    takes (forced here, interpreted) by nothing either, while the pair
-    tests of the definition are counted all the same."""
+    `theia_job_dbscan_sorted_points_total` by its valid points and
+    `theia_job_dbscan_pair_tests_total` by the definition's pair
+    tests, an EWMA job by nothing."""
     from theia_tpu.obs import metrics
     from theia_tpu.runner.progress import TAD_STAGES, JobProgress
 
@@ -362,23 +347,17 @@ def test_a_job_counts_the_points_it_sorts(monkeypatch):
         run_tad(db, algo, TadQuerySpec(), now=int(time.time()),
                 progress=JobProgress(algo, TAD_STAGES, kind="tad"))
 
-    monkeypatch.delenv("THEIA_TPU_PALLAS", raising=False)
     db, _ = database(*SHAPES[2])
     points = metrics.REGISTRY.get("theia_job_dbscan_sorted_points_total")
     pairs = metrics.REGISTRY.get("theia_job_dbscan_pair_tests_total")
     valid = 130 + 130 + 86 + 3
-    before = points.value()
+    before, pairs_before = points.value(), pairs.value()
     job("EWMA")
-    assert points.value() == before
+    assert (points.value(), pairs.value()) == (before, pairs_before)
     job("DBSCAN")
     assert points.value() - before == valid
     series = build_series(db.flows.scan(), TadQuerySpec())
     assert sorted_points(series.mask) == valid
-    monkeypatch.setenv("THEIA_TPU_PALLAS", "1")
-    assert sorted_points(series.mask) == 0
-    before, pairs_before = points.value(), pairs.value()
-    job("DBSCAN")
-    assert points.value() == before
     assert pairs.value() - pairs_before == pair_tests(series.mask)
 
 

@@ -17,9 +17,10 @@ import numpy as np
 import pytest
 
 from theia_tpu.data.synth import SynthConfig, generate_flows
-from theia_tpu.ingest import BlockEncoder, native_available
+from theia_tpu.ingest import BlockEncoder
 from theia_tpu.manager.ingest import IngestManager
 from theia_tpu.store import FlowDatabase
+from theia_tpu.utils.native import native_available
 
 
 def _strip(conn_alerts):

@@ -7,9 +7,10 @@ import pytest
 
 from theia_tpu.data.synth import SynthConfig, generate_flows
 from theia_tpu.ingest import BLOCK_MAGIC, BlockEncoder, TsvDecoder, \
-    encode_tsv, native_available
+    encode_tsv
 from theia_tpu.schema import FLOW_SCHEMA
 from theia_tpu.store import FlowDatabase
+from theia_tpu.utils.native import native_available
 
 
 @pytest.fixture(scope="module")

@@ -152,58 +152,6 @@ def test_kernels_all_padding_safe(rng, algo):
     np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
 
 
-def test_dbscan_pallas_kernel_matches_xla(rng):
-    # The Pallas kernel (interpret mode on CPU; Mosaic on real TPU) must
-    # be bit-identical to the XLA formulation across shapes/padding.
-    from theia_tpu.ops.dbscan_pallas import dbscan_noise_pallas
-    for s, t in [(5, 7), (16, 128), (33, 40), (1, 1), (131, 260)]:
-        x = rng.uniform(1e5, 1e9, size=(s, t)).astype(np.float32)
-        x[:, :max(t // 2, 1)] = rng.normal(
-            2e8, 1e7, size=(s, max(t // 2, 1)))
-        m = rng.random(size=(s, t)) > 0.2
-        ref = np.asarray(dbscan_noise(x, m))
-        pal = np.asarray(dbscan_noise_pallas(x, m, interpret=True))
-        np.testing.assert_array_equal(ref, pal, err_msg=f"{s}x{t}")
-
-
-def test_dbscan_pallas_dispatch_is_a_rule_not_an_except(monkeypatch):
-    # auto-selection is decided from the backend and the series
-    # length, never from a caught compile error (ops/dbscan._use_pallas)
-    import jax
-    import pytest
-
-    from theia_tpu.ops import dbscan
-    from theia_tpu.ops.dbscan_pallas import (PALLAS_MAX_T,
-                                             dbscan_noise_pallas)
-
-    monkeypatch.delenv("THEIA_TPU_PALLAS", raising=False)
-    assert dbscan._use_pallas(128) is False          # CPU backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert dbscan._use_pallas(4) is True
-    assert dbscan._use_pallas(PALLAS_MAX_T) is True
-    assert dbscan._use_pallas(PALLAS_MAX_T + 1) is False   # → XLA
-    monkeypatch.setenv("THEIA_TPU_PALLAS", "0")
-    assert dbscan._use_pallas(4) is False
-    # the kernel refuses what it cannot take instead of mis-tiling it
-    with pytest.raises(ValueError, match="PALLAS_MAX_T"):
-        dbscan_noise_pallas(np.zeros((2, PALLAS_MAX_T + 1), np.float32),
-                            np.ones((2, PALLAS_MAX_T + 1), bool),
-                            interpret=True)
-
-
-def test_dbscan_scores_pallas_toggle(rng):
-    # use_pallas=True must produce the same scores as the XLA branch
-    # (off-TPU the kernel runs in interpreter mode automatically).
-    from theia_tpu.ops.dbscan import dbscan_scores
-    x = rng.uniform(1e5, 1e9, size=(4, 16)).astype(np.float32)
-    m = np.ones((4, 16), bool)
-    calc_x, std_x, anom_x = dbscan_scores(x, m, use_pallas=False)
-    calc_p, std_p, anom_p = dbscan_scores(x, m, use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(anom_x),
-                                  np.asarray(anom_p))
-    np.testing.assert_allclose(np.asarray(std_x), np.asarray(std_p))
-
-
 def test_arima_grouped_refit_long_series():
     """refit_every>1 (the 24h@1s-scale path) still flags spikes and
     matches the exact path closely away from refit boundaries; memory

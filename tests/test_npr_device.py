@@ -11,11 +11,16 @@ from theia_tpu.analytics.npr_device import (
     make_sharded_distinct,
 )
 from theia_tpu.parallel import make_rows_mesh
-from theia_tpu.store.views import group_reduce
+from theia_tpu.utils.native import group_reduce
 
 
 def _random_keys(rng, n, k=9, card=17):
     return rng.integers(0, card, size=(n, k)).astype(np.int64)
+
+
+def _columns(keys):
+    """The K columns of an [N, K] matrix, as the program takes them."""
+    return list(keys.T)
 
 
 def _numpy_distinct(keys):
@@ -52,14 +57,15 @@ def test_device_distinct_wrapper_parity_both_paths():
     rng = np.random.default_rng(6)
     keys = _random_keys(rng, 1000, k=4, card=9)
     ref_u, ref_c = _numpy_distinct(keys)
-    for flag in ("0", "1"):
-        u, c = device_distinct(keys, use_device=flag)
+    for use_device in (False, True):
+        u, c = device_distinct(_columns(keys), use_device=use_device)
         np.testing.assert_array_equal(u, ref_u)
         np.testing.assert_array_equal(c, ref_c)
 
 
 def test_device_distinct_empty():
-    u, c = device_distinct(np.zeros((0, 9), np.int64), use_device="1")
+    u, c = device_distinct(_columns(np.zeros((0, 9), np.int64)),
+                           use_device=True)
     assert u.shape == (0, 9) and c.shape == (0,)
 
 
@@ -101,8 +107,10 @@ def test_npr_job_unchanged_with_device_distinct(monkeypatch):
     from theia_tpu.data.synth import SynthConfig, generate_flows
     from theia_tpu.store import FlowDatabase
 
-    def policies(flag):
-        monkeypatch.setenv("THEIA_NPR_DEVICE", flag)
+    from theia_tpu.analytics import npr_device
+
+    def policies(threshold):
+        monkeypatch.setattr(npr_device, "_AUTO_THRESHOLD", threshold)
         db = FlowDatabase()
         db.insert_flows(generate_flows(SynthConfig(
             n_series=16, points_per_series=4, seed=9)))
@@ -111,7 +119,8 @@ def test_npr_job_unchanged_with_device_distinct(monkeypatch):
         return sorted(zip(rows.strings("kind"),
                           rows.strings("policy")))
 
-    assert policies("1") == policies("0")
+    # every row count reaches the device, then none does
+    assert policies(0) == policies(1 << 62)
 
 
 # -- packed keys, bucketed rows (PR 42) -----------------------------------
@@ -149,10 +158,10 @@ def test_packed_distinct_is_group_reduce_bit_for_bit(maxima, n):
     assert n < npr_device._AUTO_THRESHOLD
     rng = np.random.default_rng(n)
     keys = _codes(rng, n, maxima)
-    layout = npr_device.KeyLayout.of(keys)
+    layout = npr_device.KeyLayout.of(_columns(keys))
     assert layout.bits == sum(int(m).bit_length() for m in maxima)
     assert layout.words == max(-(-layout.bits // 32), 1)
-    u, c = device_distinct(keys, use_device="1")
+    u, c = device_distinct(_columns(keys), use_device=True)
     ref_u, ref_c = _numpy_distinct(keys)
     assert u.dtype == ref_u.dtype == c.dtype == np.int64
     np.testing.assert_array_equal(u, ref_u)
@@ -166,7 +175,7 @@ def test_packed_distinct_is_group_reduce_bit_for_bit(maxima, n):
     np.full((700, 2), 65535, np.int64),
 ], ids=["all-equal", "all-distinct", "all-ones"])
 def test_packed_distinct_of_degenerate_tables(keys):
-    u, c = device_distinct(keys, use_device="1")
+    u, c = device_distinct(_columns(keys), use_device=True)
     ref_u, ref_c = _numpy_distinct(keys)
     np.testing.assert_array_equal(u, ref_u)
     np.testing.assert_array_equal(c, ref_c)
@@ -178,8 +187,8 @@ def test_a_layout_packs_in_the_columns_order_and_back():
     rng = np.random.default_rng(3)
     for maxima in LAYOUTS.values():
         keys = _codes(rng, 500, maxima)
-        layout = KeyLayout.of(keys)
-        words = layout.pack(keys, 512)
+        layout = KeyLayout.of(_columns(keys))
+        words = layout.pack(_columns(keys), 512)
         assert words.shape == (layout.words, 512)
         assert words.dtype == np.uint32
         assert (words[:, 500:] == 0xFFFFFFFF).all()
@@ -189,9 +198,9 @@ def test_a_layout_packs_in_the_columns_order_and_back():
         by_columns = np.lexsort(keys.T[::-1])
         np.testing.assert_array_equal(keys[by_words], keys[by_columns])
     with pytest.raises(ValueError, match="codes"):
-        KeyLayout.of(np.array([[1, -1]]))
+        KeyLayout.of(_columns(np.array([[1, -1]])))
     with pytest.raises(ValueError, match="codes"):
-        KeyLayout.of(np.array([[1, 1 << 31]]))
+        KeyLayout.of(_columns(np.array([[1, 1 << 31]])))
 
 
 def test_the_bucket_rule():
@@ -229,12 +238,12 @@ def test_two_store_sizes_of_one_bucket_run_one_program(monkeypatch):
     monkeypatch.setattr(npr_device, "distinct_rows", counting)
     for n in (5200, 5500, 6144):                 # one bucket: 6,144
         keys = _codes(rng, n, maxima)
-        u, c = device_distinct(keys, use_device="1")
+        u, c = device_distinct(_columns(keys), use_device=True)
         np.testing.assert_array_equal(u, _numpy_distinct(keys)[0])
     # another layout of three words, in the same bucket
-    device_distinct(_codes(rng, 5999, LAYOUTS["crosses"] + (3, 1 << 20)),
-                    use_device="1")
-    device_distinct(_codes(rng, 6145, maxima), use_device="1")
+    device_distinct(_columns(_codes(
+        rng, 5999, LAYOUTS["crosses"] + (3, 1 << 20))), use_device=True)
+    device_distinct(_columns(_codes(rng, 6145, maxima)), use_device=True)
     shapes = [s for s, _ in traced]
     assert shapes == [(6144, 3)] * 4 + [(7168, 3)]
     sizes = [k for _, k in traced] + [jitted._cache_size()]
@@ -341,9 +350,10 @@ def test_the_column_packer_is_the_matrix_packer_bit_for_bit(
         got = layout.pack(columns, n_rows, mask)
         assert got.dtype == np.uint32 and got.flags.c_contiguous
         np.testing.assert_array_equal(got, want)
-    # the matrix entry points hand their strided columns to that code
-    assert KeyLayout.of(counted) == layout
-    np.testing.assert_array_equal(layout.pack(counted, 512), want)
+    # a matrix's strided columns are columns like any others
+    assert KeyLayout.of(_columns(counted)) == layout
+    np.testing.assert_array_equal(
+        layout.pack(_columns(counted), 512), want)
     np.testing.assert_array_equal(
         layout.unpack(want[:, :len(counted)].T), counted)
 
@@ -362,14 +372,14 @@ def test_a_code_out_of_range_under_the_mask_is_a_value_error(dtype, code):
         with pytest.raises(ValueError, match="codes"):
             KeyLayout.of(columns, m)
         with pytest.raises(ValueError, match="codes"):
-            device_distinct(columns, use_device="1", mask=m)
+            device_distinct(columns, use_device=True, mask=m)
     columns[1][[200, 201]] = 5, code             # 201 % 3 == 0: not
     assert KeyLayout.of(columns, mask).widths == (9, 3)
     with pytest.raises(ValueError, match="codes"):
         KeyLayout.of(columns)
 
 
-@pytest.mark.parametrize("use_device", ["1", "0", "8 shards"],
+@pytest.mark.parametrize("use_device", [True, False, "8 shards"],
                          ids=["device", "host", "sharded"])
 @pytest.mark.parametrize("mask_kind", ["all", "all-true", "partial", "none"])
 @pytest.mark.parametrize("dtype", [np.int32, np.int64],

@@ -196,8 +196,8 @@ def test_run_tad_sharded_rows_match_single_device(eight_devices):
 
 
 def test_run_npr_sharded_policies_match_single_device(eight_devices):
-    # An explicitly passed mesh opts into the sharded device distinct
-    # (no THEIA_NPR_DEVICE needed).
+    # An explicitly passed mesh opts into the sharded device distinct,
+    # whatever the row count.
     from theia_tpu.analytics import run_npr
     from theia_tpu.data.synth import SynthConfig, generate_flows
     from theia_tpu.store import FlowDatabase
@@ -224,7 +224,7 @@ def test_sharded_distinct_with_sentinel_padding(eight_devices, rng):
     from theia_tpu.parallel import make_rows_mesh
 
     mesh = make_rows_mesh(8)
-    keys = rng.integers(0, 5, size=(61, 4)).astype(np.int64)
+    keys = list(rng.integers(0, 5, size=(4, 61)).astype(np.int64))
     u_sh, c_sh = device_distinct(keys, use_device=True, mesh=mesh)
     u_lo, c_lo = device_distinct(keys, use_device=False)
     np.testing.assert_array_equal(u_sh, u_lo)

@@ -14,7 +14,7 @@ from theia_tpu.analytics import TadQuerySpec, build_series
 from theia_tpu.analytics import series as series_mod
 from theia_tpu.analytics.series import SeriesRows, _group_and_pad
 from theia_tpu.data.synth import SynthConfig, generate_flows
-from theia_tpu.ingest.native import build_padded_series, native_available
+from theia_tpu.utils.native import build_padded_series, native_available
 
 pytestmark = pytest.mark.skipif(
     not native_available(), reason="native library unavailable")
@@ -35,7 +35,9 @@ def _random_rows(rng, n, k=5, card=7, t_card=12, widths=None):
 
 
 def _numpy(monkeypatch, parts, op, dtype=np.float64):
-    monkeypatch.setenv("THEIA_NATIVE_SERIES", "0")
+    # the path a process without the library takes
+    monkeypatch.setattr(series_mod, "build_padded_series",
+                        lambda parts, op, dtype: None)
     res, path = _group_and_pad(parts, op, dtype)
     assert path == "numpy"
     return res
@@ -182,7 +184,7 @@ def test_native_groups_several_parts_as_one_table(monkeypatch):
 
 @pytest.mark.parametrize("fault", ["float_key", "float_value",
                                    "short_key", "short_mask", "matrix"])
-def test_native_refuses_what_it_cannot_read(monkeypatch, fault):
+def test_native_refuses_what_it_cannot_read(fault):
     """Another dtype, shape or length: None from the builder, so the
     seam takes the numpy path, whose answer (or whose own refusal of a
     ragged table) the caller gets."""
@@ -202,7 +204,6 @@ def test_native_refuses_what_it_cannot_read(monkeypatch, fault):
     parts = [SeriesRows(keys, t, v, mask)]
     assert build_padded_series(parts, "max") is None
 
-    monkeypatch.setenv("THEIA_NATIVE_SERIES", "auto")
     if fault.startswith("float"):
         res, path = _group_and_pad(parts, "max", np.float64)
         assert path == "numpy"
@@ -212,9 +213,6 @@ def test_native_refuses_what_it_cannot_read(monkeypatch, fault):
     else:
         with pytest.raises((ValueError, IndexError)):
             _group_and_pad(parts, "max", np.float64)
-    monkeypatch.setenv("THEIA_NATIVE_SERIES", "1")
-    with pytest.raises(RuntimeError, match="THEIA_NATIVE_SERIES=1"):
-        _group_and_pad(parts, "max", np.float64)
 
 
 SPECS = {
@@ -258,12 +256,21 @@ def test_build_series_identical_on_both_paths(monkeypatch, flows, mode,
     numpy path's matrix."""
     spec = _filtered(SPECS[mode], flows, filters)
 
-    def series(flag):
-        monkeypatch.setenv("THEIA_NATIVE_SERIES", flag)
-        return build_series(flows, spec)
+    def series(native):
+        answered = []
 
-    a = series("1")
-    b = series("0")
+        def builder(parts, op, dtype):
+            res = build_padded_series(parts, op, dtype) if native else None
+            answered.append(res is not None)
+            return res
+
+        monkeypatch.setattr(series_mod, "build_padded_series", builder)
+        out = build_series(flows, spec)
+        assert all(a == native for a in answered)
+        return out
+
+    a = series(True)
+    b = series(False)
     assert a.key_names == b.key_names and a.agg_type == b.agg_type
     assert a.values.dtype == b.values.dtype
     np.testing.assert_array_equal(a.values, b.values)
@@ -297,14 +304,12 @@ def test_build_series_builds_no_key_matrix_on_the_native_path(
                 else getattr(np, name)
 
     handed = []
-    from theia_tpu.ingest import native
 
     def spy(parts, op, dtype=np.float64):
         handed.extend(parts)
         return build_padded_series(parts, op, dtype)
 
-    monkeypatch.setenv("THEIA_NATIVE_SERIES", "1")
-    monkeypatch.setattr(native, "build_padded_series", spy)
+    monkeypatch.setattr(series_mod, "build_padded_series", spy)
     want = build_series(flows, spec)
     monkeypatch.setattr(series_mod, "np", NoMatrices())
     got = build_series(flows, spec)

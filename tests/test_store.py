@@ -5,7 +5,9 @@ import pytest
 
 from theia_tpu.data.synth import SynthConfig, generate_flows
 from theia_tpu.schema import FLOW_SCHEMA, ColumnarBatch
-from theia_tpu.store import FlowDatabase, group_sum
+from theia_tpu.store import FlowDatabase
+from theia_tpu.utils.native import (group_sum, group_sum_fast,
+                                    native_available, native_group_sum)
 
 
 def _db_with_flows(n_series=8, points=10, **kw):
@@ -58,6 +60,28 @@ def test_group_sum_matches_naive():
     assert gk.shape[0] == len(expect)
     for k, v in zip(map(tuple, gk), gv):
         np.testing.assert_array_equal(expect[k], v)
+
+
+@pytest.mark.parametrize("widths", [(np.int64,) * 3,
+                                    (np.int32, np.int64, np.int32)],
+                         ids=["int64", "stored"])
+def test_a_views_block_group_bys_are_group_sum(widths):
+    """What a view's insert takes, `native_group_sum` over the columns
+    where the library loaded (it answers then, with whole groups) and
+    `group_sum_fast` over their matrix, re-grouped as a read does:
+    `group_sum` of the rows."""
+    rng = np.random.default_rng(1)
+    cols = [rng.integers(0, 4, size=500).astype(w) for w in widths]
+    vals = [rng.integers(0, 10, size=500).astype(np.int64) for _ in "ab"]
+    keys = np.stack([c.astype(np.int64) for c in cols], axis=1)
+    want = group_sum(keys, np.stack(vals, axis=1))
+    outs = [group_sum_fast(keys, np.stack(vals, axis=1))]
+    if native_available():
+        outs.append(native_group_sum(cols, vals))
+        assert len(outs[-1][0]) == len(want[0])      # no group split
+    for out in outs:
+        for got, ref in zip(group_sum(*out), want):
+            np.testing.assert_array_equal(got, ref)
 
 
 def test_pod_view_aggregates_inserts():

@@ -266,8 +266,11 @@ def test_tensorize_rows_counter_names_the_path(monkeypatch, mode):
     `theia_job_tensorize_rows_total{kind="tad",path="columns"}` by the
     rows its filters kept (both sides' in the pod modes), and
     `{path="numpy"}` instead, and by as many, with the native builder
-    disabled; a job that keeps no row raises neither."""
+    away (as in a process without the library); a job that keeps no
+    row raises neither."""
+    from theia_tpu.analytics import series as series_mod
     from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+    from theia_tpu.utils.native import build_padded_series
 
     batch = flows()
     spec, none = _modes(batch)[mode]
@@ -279,22 +282,25 @@ def test_tensorize_rows_counter_names_the_path(monkeypatch, mode):
     def value(path):
         return counter.labels(kind="tad", path=path).value()
 
-    def rise(flag, path, other, job_spec):
-        monkeypatch.setenv("THEIA_NATIVE_SERIES", flag)
+    def rise(path, other, job_spec):
+        monkeypatch.setattr(
+            series_mod, "build_padded_series",
+            build_padded_series if path == "columns"
+            else lambda parts, op, dtype: None)
         before, before_other = value(path), value(other)
         run_tad(db, "EWMA", job_spec, now=NOW,
                 progress=JobProgress("job", TAD_STAGES, kind="tad"))
         assert value(other) == before_other
         return value(path) - before
 
-    rows = rise("1", "columns", "numpy", spec)
-    assert rows == rise("0", "numpy", "columns", spec)
+    rows = rise("columns", "numpy", spec)
+    assert rows == rise("numpy", "columns", spec)
     # every kept row is a point or merges into one
     assert len(batch) * (2 if "pod" in mode else 1) >= rows >= points > 0
     if mode == "connection":
         assert rows == len(batch)
-    assert rise("1", "columns", "numpy", none) == 0
-    assert rise("0", "numpy", "columns", none) == 0
+    assert rise("columns", "numpy", none) == 0
+    assert rise("numpy", "columns", none) == 0
     text = prom.render()
     for path in ("columns", "numpy"):
         assert (f'theia_job_tensorize_rows_total{{kind="tad",'

@@ -12,10 +12,10 @@ the program it ran before:
     a function of the column maxima alone, never part of a compiled
     program; the job's nine columns need 73 bits, W = 3). The packer
     takes the K columns as they lie in the store's batch, int32 each,
-    and a mask of the rows that count (an [N, K] matrix is its K
-    strided columns): `_PACK_ROWS` rows at a time through one 64-bit
-    accumulator, each finished word written under the mask, so no
-    column is copied whole and no [N, K] matrix is ever built;
+    and a mask of the rows that count: `_PACK_ROWS` rows at a time
+    through one 64-bit accumulator, each finished word written under
+    the mask, so no column is copied whole and no [N, K] matrix is
+    ever built;
   * bucketed rows — N is padded up to `bucket_rows(N)` with all-ones
     rows that sort last and weigh nothing, so a program is compiled
     once a bucket and once a number of words, not once a store size;
@@ -43,23 +43,21 @@ it. Dictionary codes are below 2^31.
 
 from __future__ import annotations
 
-import os
-from typing import (Callable, Iterator, NamedTuple, Sequence, Tuple,
-                    Union)
+from typing import Callable, Iterator, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..parallel.mesh import ROWS_AXIS
+from ..utils.native import group_reduce
 
 _U32 = jnp.uint32
 _WORD = 32
 _ALL_ONES = 0xFFFFFFFF
 
-# Host-side switch: "auto" uses the device path for large inputs only
-# (the host numpy lexsort wins under ~64k rows once transfer overhead is
-# counted), "1"/"0" force it on/off.
+# The device path is for large inputs only: the host numpy lexsort
+# wins under ~64k rows once transfer overhead is counted.
 _AUTO_THRESHOLD = 65536
 
 # Rows the packer handles at a time: its accumulator and the shifted
@@ -67,14 +65,8 @@ _AUTO_THRESHOLD = 65536
 # stream through once.
 _PACK_ROWS = 1 << 16
 
-#: K key columns: an [N, K] matrix of codes, or K arrays of N codes
-Keys = Union[np.ndarray, Sequence[np.ndarray]]
-
-
-def _columns(keys) -> Tuple[np.ndarray, ...]:
-    """The K key columns: those of an [N, K] matrix (strided views),
-    or the sequence of K equal-length arrays itself."""
-    return tuple(keys.T if isinstance(keys, np.ndarray) else keys)
+#: K key columns: K arrays of N codes each
+Keys = Sequence[np.ndarray]
 
 
 def _blocks(n: int) -> Iterator[slice]:
@@ -100,12 +92,11 @@ class KeyLayout(NamedTuple):
     widths: Tuple[int, ...]
 
     @classmethod
-    def of(cls, keys: Keys, mask: np.ndarray | None = None
+    def of(cls, columns: Keys, mask: np.ndarray | None = None
            ) -> "KeyLayout":
-        """The layout of the rows of `keys` under `mask` (all of them
-        without one): rows outside it neither widen a column nor fail
-        the range check."""
-        columns = _columns(keys)
+        """The layout of the rows of `columns` under `mask` (all of
+        them without one): rows outside it neither widen a column nor
+        fail the range check."""
         smallest, largest = 0, [0] * len(columns)
         for rows in _blocks(len(columns[0])):
             for c, column in enumerate(columns):
@@ -126,16 +117,15 @@ class KeyLayout(NamedTuple):
     def words(self) -> int:
         return max(-(-self.bits // _WORD), 1)
 
-    def pack(self, keys: Keys, n_rows: int,
+    def pack(self, columns: Keys, n_rows: int,
              mask: np.ndarray | None = None) -> np.ndarray:
-        """[W, n_rows] uint32: the rows of `keys` under `mask` (all of
-        them without one) packed in their order, then all-ones
+        """[W, n_rows] uint32: the rows of `columns` under `mask` (all
+        of them without one) packed in their order, then all-ones
         padding. `_PACK_ROWS` rows at a time, the columns go in from
         the least significant end through a 64-bit accumulator that
         is emptied a word at a time, so a column may cross a word;
         what a row outside the mask leaves in the accumulator is never
         written."""
-        columns = _columns(keys)
         total = len(columns[0])
         n = total if mask is None else int(np.count_nonzero(mask))
         out = np.empty((self.words, n_rows), np.uint32)
@@ -324,18 +314,12 @@ def make_sharded_distinct(mesh: jax.sharding.Mesh):
     return sharded_distinct
 
 
-def _wants_device(n: int, use_device) -> bool:
-    if use_device is None:
-        use_device = os.environ.get("THEIA_NPR_DEVICE", "auto")
-    if use_device in ("0", False, "off", "false"):
-        return False
-    if use_device in ("1", True, "on", "true"):
-        return True
-    return n >= _AUTO_THRESHOLD
+def _wants_device(n: int, use_device: bool | None) -> bool:
+    return n >= _AUTO_THRESHOLD if use_device is None else use_device
 
 
-def plan_distinct(keys: Keys,
-                  use_device: str | bool | None = None,
+def plan_distinct(columns: Keys,
+                  use_device: bool | None = None,
                   mesh: jax.sharding.Mesh | None = None,
                   mask: np.ndarray | None = None
                   ) -> Callable[[], Tuple[np.ndarray, np.ndarray]]:
@@ -345,7 +329,6 @@ def plan_distinct(keys: Keys,
     the other half: call it for (uniq, counts) — the transfer, the
     jitted call until ready, the fetch of the distinct rows and their
     unpacking."""
-    columns = _columns(keys)
     n = len(columns[0])
     if mask is not None:
         kept = int(np.count_nonzero(mask))
@@ -357,8 +340,6 @@ def plan_distinct(keys: Keys,
                         np.zeros((0,), np.int64))
     if not _wants_device(n, use_device):
         def on_host():
-            from ..store.views import group_reduce
-
             rows = np.stack([np.asarray(c if mask is None else c[mask],
                                         np.int64) for c in columns], axis=1)
             uniq, counts = group_reduce(rows, np.ones((n, 1), np.int64))
@@ -389,21 +370,20 @@ def plan_distinct(keys: Keys,
     return on_device
 
 
-def device_distinct(keys: Keys,
-                    use_device: str | bool | None = None,
+def device_distinct(columns: Keys,
+                    use_device: bool | None = None,
                     mesh: jax.sharding.Mesh | None = None,
                     mask: np.ndarray | None = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
-    """Host wrapper: DISTINCT + counts over K columns of int codes (an
-    [N, K] matrix or K arrays), of the rows under `mask` if one is
-    given.
+    """Host wrapper: DISTINCT + counts over K columns of int codes, of
+    the rows under `mask` if one is given.
 
     Returns (uniq [U, K] int64, counts [U] int64) in lexicographic row
     order — bit-identical to the numpy group_reduce path. `use_device`
-    defaults to the THEIA_NPR_DEVICE env switch ("auto"/"1"/"0").
-    With `mesh` (a rows-axis mesh with >1 device), the device path
-    shards input rows over the mesh and merges per-chip distincts with
-    the all_gather + segment-sum collective (production scale-out of
-    the Spark shuffle, SURVEY §2.7).
+    None chooses by the row count (`_AUTO_THRESHOLD`), True / False
+    force a side. With `mesh` (a rows-axis mesh with >1 device), the
+    device path shards input rows over the mesh and merges per-chip
+    distincts with the all_gather + segment-sum collective (production
+    scale-out of the Spark shuffle, SURVEY §2.7).
     """
-    return plan_distinct(keys, use_device, mesh, mask)()
+    return plan_distinct(columns, use_device, mesh, mask)()

@@ -26,13 +26,12 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import json
-import os
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..schema import ColumnarBatch
-from ..store.views import group_reduce
+from ..utils.native import build_padded_series, group_reduce
 
 MEANINGLESS_LABELS = (
     "pod-template-hash",
@@ -225,20 +224,12 @@ def _group_and_pad(parts: Sequence[SeriesRows], op: str, dtype):
     One seam with two equivalent implementations: `columns`, the native
     C++ builder (native/seriesbuild.cc — one hash-group pass over the
     columns in place; the host tensorize hot path), and `numpy`, the
-    lexsort pipeline over an [n, k] matrix it builds itself. Selected
-    by THEIA_NATIVE_SERIES=auto/1/0 (auto = native when available and
-    the columns are ones it takes)."""
-    flag = os.environ.get("THEIA_NATIVE_SERIES", "auto").lower()
-    if flag not in ("0", "off", "false"):
-        from ..ingest.native import build_padded_series
-
-        res = build_padded_series(parts, op, dtype)
-        if res is not None:
-            return res, "columns"
-        if flag in ("1", "on", "true"):
-            raise RuntimeError(
-                "THEIA_NATIVE_SERIES=1 but the native builder is "
-                "unavailable or was handed a column it does not take")
+    lexsort pipeline over an [n, k] matrix it builds itself: the
+    builder whenever it answers, numpy when it returns None (no
+    library, or a column it does not take)."""
+    res = build_padded_series(parts, op, dtype)
+    if res is not None:
+        return res, "columns"
     stage1, values = [], []
     for key_cols, t, v, mask in parts:
         rows = slice(None) if mask is None else mask

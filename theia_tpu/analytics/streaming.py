@@ -46,6 +46,7 @@ import numpy as np
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..ops.ewma import DEFAULT_ALPHA
+from ..ops.stream_state import StreamState, _update
 from ..schema import ColumnarBatch
 
 CONNECTION_KEY_COLUMNS = (
@@ -84,38 +85,10 @@ H2D_BYTES = _metrics.counter(
     "device step, power-of-two padding included")
 
 
-class StreamState(NamedTuple):
-    ewma: jnp.ndarray    # [S]
-    count: jnp.ndarray   # [S] int32  points seen
-    mean: jnp.ndarray    # [S]       running mean (Welford)
-    m2: jnp.ndarray      # [S]       running sum of squared deviations
-
-
 def init_state(capacity: int, dtype=jnp.float32) -> StreamState:
     z = jnp.zeros(capacity, dtype)
     return StreamState(ewma=z, count=jnp.zeros(capacity, jnp.int32),
                        mean=z, m2=z)
-
-
-def _update(state: StreamState, x: jnp.ndarray, active: jnp.ndarray,
-            alpha) -> Tuple[StreamState, jnp.ndarray]:
-    """Elementwise detector recurrence (any shape): anomaly iff the
-    slot is active, has seen ≥2 points, and |x − ewma| exceeds the
-    running sample stddev (the streaming analogue of
-    calculate_ewma_anomaly)."""
-    xa = jnp.where(active, x, 0.0)
-    count = state.count + active.astype(jnp.int32)
-    delta = xa - state.mean
-    mean = jnp.where(active,
-                     state.mean + delta / jnp.maximum(count, 1),
-                     state.mean)
-    m2 = jnp.where(active, state.m2 + delta * (xa - mean), state.m2)
-    ewma = jnp.where(active,
-                     (1.0 - alpha) * state.ewma + alpha * xa,
-                     state.ewma)
-    std = jnp.sqrt(m2 / jnp.maximum(count - 1, 1))
-    anomaly = active & (count >= 2) & (jnp.abs(xa - ewma) > std)
-    return StreamState(ewma, count, mean, m2), anomaly
 
 
 @jax.jit

@@ -29,7 +29,7 @@ import numpy as np
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..store import FlowDatabase
-from ..store.views import group_reduce
+from ..utils.native import group_reduce
 
 FLOW_TYPE_TO_EXTERNAL = 3
 

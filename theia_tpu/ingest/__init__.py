@@ -11,10 +11,9 @@ from .native import (
     TsvDecoder,
     decode_tblk,
     encode_tsv,
-    native_available,
 )
 
 __all__ = ["BLOCK_MAGIC", "TBLK_MAGIC", "BlockEncoder", "TblkEncoder",
            "TsvDecoder", "decode_tblk", "encode_tsv",
-           "native_available", "IngestClient", "IngestError",
+           "IngestClient", "IngestError",
            "default_ingest_format", "make_block_encoder"]

@@ -29,7 +29,7 @@ every time. This engine replaces that hot loop with a pipeline:
      out scoring instead of growing an invisible backlog.
 
 Alert parity: the per-shard math is the sharded engine's own
-(ops/fused_detector.py reuses streaming._update and the sketch
+(ops/fused_detector.py reuses stream_state._update and the sketch
 helpers), the host-side slot mapping and tick bucketing are the same
 code (StreamingDetector.build_plan), and shards are thresholded in
 index order against the same eventually-consistent cross-shard totals

@@ -81,7 +81,7 @@ import numpy as np
 
 from ..analytics.heavy_hitters import HeavyHitterDetector
 from ..analytics.streaming import DETECTOR_STAGE, StreamingDetector
-from ..ingest.native import BLOCK_MAGIC, TsvDecoder, native_available
+from ..ingest.native import BLOCK_MAGIC, TsvDecoder
 from ..store import wire as _wire
 from ..store.wal import RECORD_MAGIC
 from ..obs import metrics as _metrics
@@ -89,6 +89,7 @@ from ..obs import trace as _trace
 from ..schema import ColumnarBatch, DictionaryMapper, StringDictionary
 from ..utils import get_logger
 from ..utils.env import env_int
+from ..utils.native import native_available
 from . import admission as _admission
 from .admission import (
     LEVEL_NAMES,

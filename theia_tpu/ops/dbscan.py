@@ -19,14 +19,12 @@ core point within eps of x_i, if there is one, is the nearest core
 value below it or the nearest above (`dbscan_noise`). The definition
 over all pairs stays, in numpy, as `noise_by_pairs` of
 tests/dbscan_reference.py, which the tests hold this form to bit for
-bit. The Pallas kernel (`ops/dbscan_pallas.py`, short series on a TPU)
-still tests pairs.
+bit.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -115,70 +113,27 @@ def pair_tests(mask) -> int:
     for the neighbour counts: the sum over series of (valid points)^2.
     It says what the job's answer is worth in pair tests, not what the
     program does: `dbscan_noise` sorts and tests none of them
-    (`sorted_points`); the Pallas kernel makes two such passes."""
+    (`sorted_points`)."""
     n = np.count_nonzero(np.asarray(mask), axis=-1).astype(np.int64)
     return int(np.sum(n * n))
 
 
 def sorted_points(mask) -> int:
-    """Valid points of a padded [S, T] batch that `dbscan_scores`
-    sends to `dbscan_noise`, which sorts them: all of them, or none
-    where `_use_pallas` gives the length to the Pallas kernel."""
-    mask = np.asarray(mask)
-    return 0 if _use_pallas(mask.shape[-1]) else int(np.count_nonzero(mask))
-
-
-def _interpret() -> bool:
-    """Pallas interpreter mode: on for any backend that can't lower
-    Mosaic (everything but real TPU)."""
-    return jax.default_backend() != "tpu"
-
-
-def _use_pallas(t: int) -> bool:
-    """The dispatch rule for `dbscan_scores(use_pallas=None)`, decided
-    from what the process can observe — never from a caught compile
-    error: the Pallas kernel on a TPU backend for series that pad to
-    at most `PALLAS_MAX_T` steps (the length its blocks fit VMEM
-    for), the sorting form `dbscan_noise` everywhere else.
-    THEIA_TPU_PALLAS=1/0 forces either side (1 off-TPU runs the
-    interpreter)."""
-    from .dbscan_pallas import PALLAS_MAX_T, padded_length
-
-    flag = os.environ.get("THEIA_TPU_PALLAS", "auto").lower()
-    if flag in ("0", "off", "false"):
-        return False
-    if flag in ("1", "on", "true"):
-        return True
-    return (jax.default_backend() == "tpu"
-            and padded_length(t) <= PALLAS_MAX_T)
+    """Valid points of a padded [S, T] batch: what `dbscan_scores`
+    sends to `dbscan_noise`, which sorts them."""
+    return int(np.count_nonzero(np.asarray(mask)))
 
 
 def dbscan_scores(x: jnp.ndarray, mask: jnp.ndarray,
                   eps: float = DEFAULT_EPS,
-                  min_samples: int = DEFAULT_MIN_SAMPLES,
-                  use_pallas: bool | None = None):
-    """(algoCalc placeholder zeros, stddev, anomaly) for DBSCAN.
+                  min_samples: int = DEFAULT_MIN_SAMPLES):
+    """(algoCalc placeholder zeros, stddev, anomaly) for DBSCAN, by
+    `dbscan_noise` on every backend and length.
 
     stddev is still emitted to fill the tadetector row shape (the
     reference computes it in the groupby regardless of algorithm).
-
-    use_pallas=None auto-selects per `_use_pallas`: the tiled Pallas
-    kernel on TPU (pair tests in VMEM tiles), the sorting form
-    `dbscan_noise` elsewhere and for series too long for the kernel.
     """
-    if use_pallas is None:
-        use_pallas = _use_pallas(x.shape[-1])
-    if use_pallas:
-        from .dbscan_pallas import dbscan_noise_pallas
-
-        # Off-TPU, an explicit use_pallas=True runs the kernel in
-        # interpreter mode (same code path, testable on the CPU mesh).
-        anomaly = dbscan_noise_pallas(
-            x, mask, eps=eps, min_samples=min_samples,
-            interpret=_interpret())
-    else:
-        anomaly = dbscan_noise(x, mask, eps=eps,
-                               min_samples=min_samples)
+    anomaly = dbscan_noise(x, mask, eps=eps, min_samples=min_samples)
     calc = jnp.zeros_like(x)
     with jax.named_scope("stddev"):
         std = masked_stddev_samp(x, mask)

@@ -11,7 +11,7 @@ across EVERY shard's coalesced slice into ONE jitted computation: one
 dispatch, one fetch, per coalesced micro-batch.
 
 Parity contract: the per-shard math is literally the sharded engine's
-— the streaming scan applies `analytics.streaming._update` tick by
+— the streaming scan applies `ops.stream_state._update` tick by
 tick, and the heavy-hitter half composes the same
 `ops.sketch.cms_update/cms_query/kmeans_step` helpers — so on the same
 backend, the same per-shard input order produces bit-identical alert
@@ -37,9 +37,9 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..analytics.streaming import StreamState, _update as _stream_tick
 from .ewma import DEFAULT_ALPHA
 from .sketch import CmsState, KMeansState, cms_query, cms_update, kmeans_step
+from .stream_state import StreamState, _update as _stream_tick
 
 #: Pallas lane width: the tile scan kernel blocks the slot axis by this.
 #: Slot tiles arrive padded to powers of two >= 64; the one 64-wide
@@ -102,7 +102,7 @@ def _scan_tile_pallas(sub: StreamState, x: jnp.ndarray,
     """Pallas version of `_scan_tile`: grid over 128-lane slot blocks,
     the (small, static) tick loop unrolled with state held in
     registers/VMEM — no per-tick HLO loop, one pass over the tile.
-    Math is kept line-for-line identical to streaming._update."""
+    Math is kept line-for-line identical to stream_state._update."""
     from jax.experimental import pallas as pl
 
     t, u_in = x.shape

@@ -145,10 +145,9 @@ def make_sharded_arima(mesh: Mesh, refit_every: int = 1):
 def make_sharded_dbscan(mesh: Mesh, eps: float, min_samples: int):
     """Sharded per-series DBSCAN noise scoring over the series axis.
 
-    Each series is decided on its own (a sort along its time axis, or
-    the Pallas kernel's pair tests), so series shards run the
-    single-device formulation locally — same auto-selection as
-    `ops.dbscan.dbscan_scores`.
+    Each series is decided on its own (a sort along its time axis),
+    so series shards run the single-device formulation,
+    `ops.dbscan.dbscan_scores`, locally.
     """
     from ..ops.dbscan import dbscan_scores
 

@@ -94,6 +94,7 @@ from ..obs import metrics as _metrics
 from ..schema import FLOW_SCHEMA, Column, ColumnKind, ColumnarBatch
 from ..store.views import MATERIALIZED_VIEWS
 from ..utils.logging import get_logger
+from ..utils.native import native_group_sum
 from .plan import (Aggregate, Filter, PlanError, QueryPlan,
                    _parse_aggregate, _parse_filter)
 from .reference import filter_mask, materialize_keys
@@ -833,10 +834,9 @@ class RollupManager:
         if uniq is None and all(
                 op in ("count", "sum") for _, op, _ in view.specs):
             # sum/count-only views take the MV hot path: one native
-            # single-pass hash group-sum (ingest/native.py — the
+            # single-pass hash group-sum (utils/native.py — the
             # GIL-releasing kernel the legacy ViewTable fan-out uses;
             # count rides as a summed ones column)
-            from ..ingest.native import native_group_sum
             vals = [(np.ones(n, np.int64) if op == "count"
                      else np.asarray(sel[col], np.int64))
                     for _, op, col in view.specs]

@@ -63,7 +63,7 @@ _M_DBSCAN_SORTED_POINTS = _metrics.counter(
     "theia_job_dbscan_sorted_points_total",
     "Valid points of the batches DBSCAN jobs gave to the sorting "
     "kernel (ops.dbscan.sorted_points): it decides each from its "
-    "neighbours in sorted order; 0 for a batch the Pallas kernel took")
+    "neighbours in sorted order")
 _M_NPR_ROWS_SORTED = _metrics.counter(
     "theia_job_npr_rows_sorted_total",
     "Rows that passed a policy-recommendation job's WHERE clause and "

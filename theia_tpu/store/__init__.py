@@ -11,8 +11,7 @@ from .replicated import (AllReplicasDownError, ReplicaRepairLoop,
                          ReplicatedFlowDatabase)
 from .sharded import (DistributedTable, DistributedView,
                       ShardedFlowDatabase)
-from .views import (MATERIALIZED_VIEWS, ViewSpec, ViewTable, group_reduce,
-                    group_sum)
+from .views import MATERIALIZED_VIEWS, ViewSpec, ViewTable
 from .wal import (SyncPolicy, WalCorruption, WalError, WriteAheadLog,
                   default_sync_policy)
 
@@ -23,7 +22,7 @@ __all__ = [
     "RetentionLoop", "RetentionMonitor", "SnapshotCorruption", "Table",
     "boundary_from_meta", "default_store_engine",
     "DistributedTable", "DistributedView", "ShardedFlowDatabase",
-    "MATERIALIZED_VIEWS", "ViewSpec", "ViewTable", "group_reduce", "group_sum",
+    "MATERIALIZED_VIEWS", "ViewSpec", "ViewTable",
     "SyncPolicy", "WalCorruption", "WalError", "WriteAheadLog",
     "default_sync_policy", "read_snapshot", "write_snapshot",
 ]

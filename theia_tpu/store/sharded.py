@@ -33,9 +33,10 @@ import numpy as np
 
 from ..schema import ColumnarBatch
 from ..utils.logging import get_logger
+from ..utils.native import group_sum
 from ..utils.pool import get_pool
 from .flow_store import FlowDatabase, RetentionMonitor, write_snapshot
-from .views import MATERIALIZED_VIEWS, group_sum, materialize_view_batch
+from .views import MATERIALIZED_VIEWS, materialize_view_batch
 from ..analysis.lockdep import named_lock
 
 _logger = get_logger("sharded")

@@ -26,67 +26,7 @@ import numpy as np
 
 from ..schema import ColumnarBatch, StringDictionary
 from ..analysis.lockdep import named_lock
-
-
-def group_reduce(keys: np.ndarray, values: np.ndarray, op: str = "sum"
-                 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Vectorized GROUP BY: `keys` [n,k] int64, `values` [n,m].
-
-    `op` is "sum" or "max". Returns (unique_keys [g,k], reduced [g,m])
-    with groups in lexicographic order. This is the host-side analogue of
-    the on-device segment reductions the analytics jobs use; lexsort +
-    reduceat keeps it allocation-lean.
-    """
-    n = keys.shape[0]
-    if n == 0:
-        return keys, values
-    order = np.lexsort(keys.T[::-1])
-    sk = keys[order]
-    sv = values[order]
-    boundary = np.empty(n, dtype=bool)
-    boundary[0] = True
-    boundary[1:] = np.any(sk[1:] != sk[:-1], axis=1)
-    starts = np.flatnonzero(boundary)
-    ufunc = np.add if op == "sum" else np.maximum
-    reduced = ufunc.reduceat(sv, starts, axis=0)
-    return sk[starts], reduced
-
-
-def group_sum(keys: np.ndarray, values: np.ndarray
-              ) -> Tuple[np.ndarray, np.ndarray]:
-    return group_reduce(keys, values, "sum")
-
-
-def group_sum_fast(keys: np.ndarray, values: np.ndarray
-                   ) -> Tuple[np.ndarray, np.ndarray]:
-    """Per-insert-block GROUP BY for the MV hot path: sort by a single
-    64-bit row hash instead of lexsorting 15-20 key columns (~20x less
-    sort work). Output group ORDER is arbitrary, and a hash collision
-    between distinct keys may split a group into two rows — both are
-    fine for a SummingMergeTree part: `compact()`/`_merged` re-groups
-    exactly (lexsort) at read time, which is also where ClickHouse
-    collapses part rows. Do NOT use where callers rely on lexicographic
-    group order (use group_reduce)."""
-    n = keys.shape[0]
-    if n == 0:
-        return keys, values
-    h = np.full(n, 0xcbf29ce484222325, np.uint64)
-    for i in range(keys.shape[1]):
-        x = keys[:, i].astype(np.uint64)
-        x *= np.uint64(0xff51afd7ed558ccd)
-        x ^= x >> np.uint64(33)
-        h ^= x
-        h *= np.uint64(0x100000001b3)
-    order = np.argsort(h, kind="stable")
-    sk = keys[order]
-    sv = values[order]
-    boundary = np.empty(n, dtype=bool)
-    boundary[0] = True
-    # Full-row compare: equal keys are adjacent (equal hash); colliding
-    # distinct keys interleaved in a run just produce extra boundaries.
-    boundary[1:] = np.any(sk[1:] != sk[:-1], axis=1)
-    starts = np.flatnonzero(boundary)
-    return sk[starts], np.add.reduceat(sv, starts, axis=0)
+from ..utils.native import group_sum, group_sum_fast, native_group_sum
 
 
 def materialize_view_batch(spec: "ViewSpec", keys: np.ndarray,
@@ -187,7 +127,6 @@ class ViewTable:
         grouping when available (native/groupsum.cc); numpy hash-sort
         otherwise — both emit unordered SummingMergeTree parts that
         compact() re-groups exactly at read time."""
-        from ..ingest.native import native_group_sum
         out = native_group_sum(
             [block[c] for c in self.spec.key_columns],
             [block[c] for c in self.spec.sum_columns])

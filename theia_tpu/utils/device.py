@@ -11,6 +11,8 @@ from __future__ import annotations
 import os
 from typing import Optional
 
+from .native import native_status
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -62,8 +64,6 @@ def runtime_banner() -> str:
     process told to use a platform it cannot get fails here, at
     start, not at its first request."""
     import jax
-
-    from ..ingest.native import native_status
 
     devs = jax.devices()
     cache = jax.config.jax_compilation_cache_dir or "off"
