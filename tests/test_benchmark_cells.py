@@ -6,6 +6,7 @@ this suite and not the driver's check."""
 
 import os
 
+import numpy as np
 import pytest
 
 from benchmarks import check, extend, harness, manifest
@@ -72,6 +73,21 @@ from benchmarks.tests.test_npr_policies import (         # noqa: F401
     test_a_request_the_reference_cannot_stand_for_is_a_broken_run,
     test_the_kernels_bytes_at_the_cells_shape_and_here,
     test_the_references_own_documents_are_correct,
+)
+from benchmarks.tests.test_tad_agg_pod import (          # noqa: F401
+    test_a_job_that_did_not_complete_and_an_answer_that_is_missing
+    as test_an_aggpod_job_that_did_not_complete,
+    test_a_job_that_found_nothing_where_the_reference_did
+    as test_an_aggpod_job_that_found_nothing,
+    test_a_perturbed_answer_is_not_correct
+    as test_a_perturbed_aggpod_answer_is_not_correct,
+    test_float32_in_the_programs_place_is_correct
+    as test_float32_in_the_aggpod_jobs_place_is_correct,
+    test_the_bfloat16_control_fails_a_limit_and_float64_none
+    as test_the_bfloat16_aggpod_control_fails_a_limit,
+    test_the_kernels_bytes_at_the_cells_shape_and_here
+    as test_the_aggpod_kernels_bytes_at_the_cells_shape_and_here,
+    test_the_references_own_rows_are_correct_and_rows_were_summed,
 )
 
 BENCH = manifest.load()
@@ -419,8 +435,13 @@ def test_the_dbscan_cell_holds_a_whole_retained_day_of_80_connections():
     assert old - layer == {"job.arima_fits", "job.arima_loop_iterations"}
     for name in layer - old:
         m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+        # the pod cell (PR 48) runs the same kernel: it reports the two
+        # that do not take a series for a connection
+        shared = name in ("job.dbscan_device_ms", "job.dbscan_sorted_points")
         assert (m["layer"], m["moves"], m["workloads"]) == (
-            "DBSCAN kernel", "job_turnaround_s", [cell])
+            "DBSCAN kernel", "job_turnaround_s",
+            [cell, "parts-fused-aggpod.tad-dbscan-pod"] if shared
+            else [cell])
 
 
 def test_the_dbscan_cell_is_rehearsed_on_the_cpu_backend():
@@ -508,7 +529,186 @@ def test_the_npr_cell_holds_the_documented_clusters_connections():
         assert (reader["layer"], reader["moves"], reader["source"]) == (
             m["layer"], m["moves"], m["source"])
         assert 'kind="tad"' not in str(reader)
-    assert len(BENCH.doc["workloads"]) == 8
+
+
+AGGPOD_CELL = "parts-fused-aggpod.tad-dbscan-pod"
+AGGPOD_METRICS = {"aggpod.series", "aggpod.rows_merged",
+                  "aggpod.dbscan_noise_roofline"}
+
+
+def test_the_aggpod_cell_is_the_npr_cells_store_under_the_pod_query():
+    """Connections are whole (4,000, every one in every block, a block
+    one 8 s commit) and the cut is in time, as in the NPR cell, whose
+    store this is; the spikes are the DBSCAN cell's; the query, the
+    poll, the check and three readers are this cell's own."""
+    cfg = BENCH.config("theia-parts-fused-aggpod-1x1")
+    sib = BENCH.config("theia-parts-fused-1x1")
+    npr = BENCH.config("theia-parts-fused-npr-1x1")
+    assert (cfg["env"], cfg["manager_args"], cfg["expect"]) \
+        == (sib["env"], sib["manager_args"], sib["expect"])
+    entry = next(c for c in BENCH.doc["configs"]
+                 if c["name"] == cfg["name"])
+    assert entry["reduced"] == ["retained_window_rows",
+                                "checkpoint_interval_s"]
+    assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    for word in ("throughput-anomaly-detection.md", "--agg-flow pod",
+                 "anomaly_detection.py:511-565"):
+        assert word in cfg["source"]
+    assert set(sib["guarantees"]) < set(cfg["guarantees"])
+    assert "every one and no other" in cfg["guarantees"]["job_result"]
+    assert {"spike_law", "poll", "connection_population"} \
+        <= set(cfg["assumed"])
+    for key in ("retained_window_rows", "source_retained_window_rows",
+                "retained_connections", "source_retained_connections"):
+        assert cfg[key] == npr[key]
+    t = BENCH.traffic("tad-dbscan-pod")
+    dbscan = BENCH.traffic("tad-dbscan")
+    nprt = BENCH.traffic("npr-initial")
+    assert t["generator"] == {
+        **dbscan["generator"], "connections_per_producer": 4000,
+        "conns_per_block": 4000, "points_per_conn": 8}
+    assert {k: v for k, v in t["generator"].items()
+            if k in nprt["generator"] and k != "law"} \
+        == {k: v for k, v in nprt["generator"].items() if k != "law"}
+    producer, jobs = t["workers"]
+    assert producer == nprt["workers"][0]
+    assert producer["preload_blocks"] * 32000 \
+        == cfg["retained_window_rows"] == 3456000
+    assert jobs["job"] == {
+        "resource": "throughputanomalydetectors",
+        "spec": {"jobType": "DBSCAN", "aggFlow": "pod"},
+        "poll_interval_s": 0.01}
+    assert "10 ms" in t["what"] and t["trace_seconds"] == 8
+    assert t["checks"] == ["acks", "store_totals", "detector_series",
+                           "tad_agg_pod"]
+    check_file = extend.module("check", "tad_agg_pod")
+    assert set(t["limits"]) == set(check_file.limits) == {
+        "aggpod_decision_mismatch", "aggpod_stddev_gap"}
+    assert set(t["limits_why"]) == set(t["limits"]) | {"exact"}
+    cell = BENCH.cell(AGGPOD_CELL)
+    assert cell["chips"] == 1 and len(cell["why"]) <= 200
+    assert not any(w["chips"] == 4 for w in BENCH.doc["workloads"])
+    assert {m["name"] for m in BENCH.metrics_of(AGGPOD_CELL, "end_to_end")} \
+        == {"job_turnaround_s", "setup_s"}
+    layer = {m["name"] for m in BENCH.metrics_of(AGGPOD_CELL, "per_layer")}
+    old = {m["name"] for m in BENCH.metrics_of(
+        "parts-fused-12h-ns.tad-dbscan", "per_layer")}
+    assert layer - old == AGGPOD_METRICS
+    # `roofline.series_shape` takes a series for a connection; pair
+    # tests are what the answer would be worth, which the sibling says
+    assert old - layer == {"dbscan_noise_roofline", "job.dbscan_pair_tests"}
+    for name in AGGPOD_METRICS:
+        m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+        reader = BENCH.reader("per_layer", name)
+        assert (m["moves"], m["workloads"]) == ("job_turnaround_s",
+                                                [AGGPOD_CELL])
+        assert (reader["layer"], reader["moves"], reader["source"]) == (
+            m["layer"], m["moves"], m["source"])
+    assert BENCH.doc["per_layer"][-1]["name"] \
+        == "aggpod.dbscan_noise_roofline"
+    assert BENCH.doc["workloads"][-1] == cell
+    assert len(BENCH.doc["workloads"]) == 9
+
+
+def test_the_aggpod_cell_is_rehearsed_on_the_cpu_backend():
+    """`benchmarks/selftest.py`'s rehearsal of the cell at a tiny size,
+    with no edit to it: manager child, preload, warm-up job, window,
+    checks, and every host-side reader of a traced run; plumbing only,
+    no number of it is a result. 64 connections x 128 points fall on
+    118 pod series and 384 of 15,488 row contributions are merged."""
+    from benchmarks import selftest
+
+    cell = BENCH.cell(AGGPOD_CELL)
+    plain, traced = selftest.rehearse(cell, trace=True)
+    for out in (plain, traced):
+        assert out["correct"] and out["failed"] == 0
+        assert {"aggpod_decision_mismatch", "aggpod_stddev_gap",
+                "aggpod_throughput_gap", "aggpod_kind_gap",
+                "aggpod_series_gap", "aggpod_rows_merged_gap",
+                "jobs_not_completed", "detector_series_gap"} \
+            <= set(out["checks"])
+    assert set(plain["metrics"]) == {"job_turnaround_s", "setup_s"}
+    got = {k: v["value"] for k, v in traced["metrics"].items()}
+    device_only = {"job.dbscan_device_ms", "aggpod.dbscan_noise_roofline"}
+    assert set(got) == {m["name"] for m in BENCH.metrics_of(
+        cell["name"], "per_layer")} - device_only
+    assert got["aggpod.series"] == 118
+    assert got["aggpod.rows_merged"] == 384
+    assert got["job.tensorize_direct_rows"] == 15488
+    assert got["job.dbscan_sorted_points"] == 15488 - 384
+    # the two sides' namespace and labels, the seconds, the value
+    assert got["job.read_columns"] == 6
+    assert got["job.tensorize_ms"] >= got["job.tensorize_keys_ms"] \
+        + got["job.tensorize_group_ms"] + got["job.tensorize_decode_ms"] \
+        - 1e-6
+
+
+def test_the_aggpod_cells_counters_reduce_to_a_jobs_figures():
+    """`aggpod.series` and `aggpod.rows_merged` read the program's own
+    exposition around one pod-mode job; a connection-mode job moves
+    neither (its series count under `agg="None"`, its merged rows are
+    0 there), and a manager without the counters (the parent) gives
+    nothing. `aggpod.dbscan_noise_roofline` reads a trace's
+    `module:jit_dbscan_noise` line with the bytes of the population's
+    1,985 series."""
+    import time
+
+    from benchmarks import prom
+    from theia_tpu.analytics import TadQuerySpec, build_series, run_tad
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+    from theia_tpu.store import FlowDatabase
+
+    traffic = BENCH.traffic("tad-dbscan-pod")
+    harness.resolve_all(BENCH, AGGPOD_CELL, traffic)
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=40, points_per_series=12, n_namespaces=2,
+        pods_per_namespace=3, seed=2)))
+    flows = db.flows.scan()
+    series = build_series(flows, TadQuerySpec(agg_flow="pod"))
+    # a row counts once for each side that has labels (code 0 is '')
+    both_sides = sum(int(np.count_nonzero(flows[f"{side}PodLabels"]))
+                     for side in ("source", "destination"))
+
+    def around(spec):
+        before = prom.parse(exposition.render())
+        run_tad(db, "DBSCAN", spec, now=int(time.time()),
+                progress=JobProgress("job", TAD_STAGES, kind="tad"))
+        return {"metrics_before": before,
+                "metrics_after": prom.parse(exposition.render())}
+
+    def read(name, data):
+        reader = BENCH.reader("per_layer", name)
+        return extend.resolve("reduction", reader["reduce"])(data, reader)
+
+    pod = around(TadQuerySpec(agg_flow="pod"))
+    assert read("aggpod.series", pod) == series.n_series <= 12
+    merged = both_sides - int(series.mask.sum())
+    assert read("aggpod.rows_merged", pod) == merged > 0
+    conn = around(TadQuerySpec())
+    assert read("aggpod.series", conn) == 0
+    assert read("aggpod.rows_merged", conn) == 0
+    none = 'theia_job_series_rows_merged_total{kind="tad",agg="None"}'
+    assert conn["metrics_after"][none] == 0
+    for name in ("aggpod.series", "aggpod.rows_merged"):
+        key = BENCH.reader("per_layer", name)["series"]
+        assert key in pod["metrics_after"]
+        pod["metrics_after"].pop(key), pod["metrics_before"].pop(key, None)
+        assert read(name, pod) is None
+
+    traced = {
+        "traffic": traffic, "device": {"kind": "TPU v5 lite"},
+        "specs": [{"role": "producer", "preload_blocks": 108, "seed": 7,
+                   "producer": 0}, {"role": "jobs"}],
+        "trace": {"ops": [("module:jit_dbscan_noise(123)", 0.01, 2)],
+                  "busy_s": 0.02, "window_s": 8.0}}
+    assert read("job.dbscan_device_ms", traced) == pytest.approx(5.0)
+    assert read("aggpod.dbscan_noise_roofline", traced) == pytest.approx(
+        100 * (10298180 / 819e9) / 0.005)
+    traced["trace"]["ops"] = []
+    assert read("aggpod.dbscan_noise_roofline", traced) is None
 
 
 def test_the_npr_cell_is_rehearsed_on_the_cpu_backend():
@@ -793,7 +993,8 @@ def test_the_direct_rows_metric_reduces_to_a_jobs_rows(monkeypatch):
     m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
     reader = BENCH.reader("per_layer", name)
     assert (m["layer"], m["moves"], m["workloads"], m["source"]) == (
-        "TAD host path", "job_turnaround_s", TAD_CELLS, "program_counter")
+        "TAD host path", "job_turnaround_s",
+        TAD_CELLS + ["parts-fused-aggpod.tad-dbscan-pod"], "program_counter")
     assert (reader["layer"], reader["moves"], reader["source"]) == (
         m["layer"], m["moves"], m["source"])
     if not native_available():

@@ -281,7 +281,9 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
     columns, the time and the value column and the mask through
     `_group_and_pad` into the padded tensors) and `decode` (the series'
     keys as the result rows show them), and counts the rows grouped by
-    the path that grouped them."""
+    the path that grouped them, the series built and the rows merged
+    into a point another row held (rows grouped less the mask's
+    points: no pass over the rows)."""
     pod = spec.agg_flow == "pod"
     with job_part(progress, "keys"):
         if pod:
@@ -299,7 +301,9 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
         else:
             keys = _decode_keys(flows, key_names, key_mat)
     if progress:
-        progress.tensorized(sum(p.kept for p in parts), path)
+        progress.tensorized(sum(p.kept for p in parts), path,
+                            spec.agg_type, len(key_mat),
+                            int(np.count_nonzero(mask)))
     return SeriesBatch(key_names, keys, values, times, mask, spec.agg_type)
 
 

@@ -97,6 +97,18 @@ _M_TENSORIZE_ROWS = _metrics.counter(
     "that grouped them: columns (the native builder, from the batch's "
     "columns in place) or numpy (the fallback and its key matrix)",
     labelnames=("kind", "path"))
+_M_SERIES_BUILT = _metrics.counter(
+    "theia_job_series_built_total",
+    "Series a job's tensorize stage built, by the query's aggregation "
+    "(None: a connection a series; pod, external, svc)",
+    labelnames=("kind", "agg"))
+_M_SERIES_ROWS_MERGED = _metrics.counter(
+    "theia_job_series_rows_merged_total",
+    "Of the rows that stage grouped, those that fell into a (key, "
+    "time) cell another row already held and were reduced into it "
+    "(sum, or max over a connection's rows): rows grouped less the "
+    "series' points",
+    labelnames=("kind", "agg"))
 _M_ROWS_WRITTEN = _metrics.counter(
     "theia_job_rows_written_total",
     "Result rows a job inserted into its result table as one batch",
@@ -156,10 +168,20 @@ class JobProgress:
         _M_READ_COLUMNS.labels(kind=self.kind).inc(len(batch.columns))
         _M_READ_BYTES.labels(kind=self.kind).inc(_column_bytes(batch))
 
-    def tensorized(self, rows: int, path: str) -> None:
+    def tensorized(self, rows: int, path: str, agg: str, series: int,
+                   points: int) -> None:
         """Count the rows the `tensorize` stage grouped and the path
-        (`columns` or `numpy`) that grouped them."""
+        (`columns` or `numpy`) that grouped them; under the query's
+        aggregation `agg` (which the enclosing `job.run` span also
+        gets), the series it built and the rows that were merged into
+        a point another row already held."""
         _M_TENSORIZE_ROWS.labels(kind=self.kind, path=path).inc(rows)
+        _M_SERIES_BUILT.labels(kind=self.kind, agg=agg).inc(series)
+        _M_SERIES_ROWS_MERGED.labels(kind=self.kind, agg=agg).inc(
+            rows - points)
+        span = _trace.current_span()
+        if span is not None:
+            span.attrs["agg"] = agg
 
     def scored(self, algo: str, series: int, points: int,
                fits: int = 0, loop_iterations: int = 0,
