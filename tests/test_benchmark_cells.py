@@ -170,7 +170,8 @@ TRIM_METRICS = {
     "trim.total_ms", "trim.boundary_ms", "trim.delete_flows_ms",
     "trim.delete_views_ms", "trim.rows_before", "trim.rows_deleted",
     "trim.bytes_freed", "trim.append_wait_ms_per_block",
-    "trim.longest_ack_gap_ms", "trim.acked_rows_per_s_during"}
+    "trim.longest_ack_gap_ms", "trim.acked_rows_per_s_during",
+    "trim.batches_cut", "trim.bytes_copied"}
 
 
 def test_the_trim_cell_is_saturate_with_the_trim_and_nothing_else():
@@ -282,6 +283,71 @@ def test_the_trim_cell_is_rehearsed_on_the_cpu_backend():
     assert got["trim.bytes_freed"] == got["trim.rows_deleted"] * 284
     assert got["trim.total_ms"] >= got["trim.boundary_ms"] \
         + got["trim.delete_flows_ms"] + got["trim.delete_views_ms"] - 1e-6
+
+
+def test_the_walk_metrics_reduce_to_a_rounds_figures():
+    """`trim.batches_cut` and `trim.bytes_copied` (PR 49) read the
+    program's own exposition around one round that trimmed: the
+    batches of `flows` the round filtered under the table's lock and
+    the bytes of their kept rows, as the round's record says them;
+    nothing from a manager without the series (the parent). The trim
+    cell's alone: no other cell runs a round that deletes."""
+    from benchmarks import prom
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.schema import ColumnarBatch
+    from theia_tpu.store import FlowDatabase, RetentionLoop
+
+    readers = {}
+    for name, unit in (("trim.batches_cut", "batches"),
+                       ("trim.bytes_copied", "bytes")):
+        m = next(m for m in BENCH.doc["per_layer"] if m["name"] == name)
+        readers[name] = reader = BENCH.reader("per_layer", name)
+        assert (m["layer"], m["moves"], m["workloads"], m["source"],
+                m["unit"], m["better"]) == (
+            "retention", "acked_rows_per_s", [TRIM_CELL],
+            "program_counter", unit, "lower")
+        assert (reader["layer"], reader["moves"], reader["source"],
+                reader["reduce"], reader["per"]) == (
+            m["layer"], m["moves"], m["source"], "counter_rise_per",
+            'theia_retention_rounds_total{result="trimmed"}')
+    # appended behind PR 48's last, the 127th and 128th
+    assert [m["name"] for m in BENCH.doc["per_layer"][126:128]] \
+        == list(readers)
+
+    db = FlowDatabase()
+    one = generate_flows(SynthConfig(n_series=8, points_per_series=4,
+                                     seed=5))
+    for i in range(6):      # blocks in time order, two seconds each
+        cols = dict(one.columns)
+        cols["timeInserted"] = (1_700_000_000 + 2 * i
+                                + np.arange(32) // 16).astype(
+            cols["timeInserted"].dtype)
+        db.insert_flows(ColumnarBatch(cols, one.dicts))
+    loop = RetentionLoop(db.monitor(capacity_bytes=db.flows.nbytes,
+                                    delete_percentage=0.45),
+                         interval=3600)
+    before = prom.parse(exposition.render())
+    assert loop.run_once() == 64 + 16
+    rec = loop.last_round
+    assert (rec["batchesDropped"], rec["batchesCut"], rec["batchesKept"],
+            rec["bytesCopied"]) == (2, 1, 3, 16 * 284)
+    data = {"metrics_before": before,
+            "metrics_after": prom.parse(exposition.render())}
+
+    def read(name):
+        reader = readers[name]
+        return extend.resolve("reduction", reader["reduce"])(data, reader)
+
+    for reader in readers.values():
+        assert reader["series"] in data["metrics_after"]
+        assert reader["per"] in data["metrics_after"]
+    assert read("trim.batches_cut") == 1
+    assert read("trim.bytes_copied") == 16 * 284
+    for name, reader in readers.items():
+        for side in data.values():
+            side.pop(reader["series"], None)
+        assert read(name) is None
 
 
 def test_a_trimmer_no_manager_answers_ends_in_the_warm_up():
@@ -604,7 +670,8 @@ def test_the_aggpod_cell_is_the_npr_cells_store_under_the_pod_query():
                                                 [AGGPOD_CELL])
         assert (reader["layer"], reader["moves"], reader["source"]) == (
             m["layer"], m["moves"], m["source"])
-    assert BENCH.doc["per_layer"][-1]["name"] \
+    # entries are only ever appended: PR 48's last stays the 126th
+    assert BENCH.doc["per_layer"][125]["name"] \
         == "aggpod.dbscan_noise_roofline"
     assert BENCH.doc["workloads"][-1] == cell
     assert len(BENCH.doc["workloads"]) == 9

@@ -20,13 +20,16 @@ from theia_tpu.manager import TheiaManagerServer
 from theia_tpu.obs import metrics, trace
 from theia_tpu.schema import ColumnarBatch
 from theia_tpu.store import FlowDatabase, RetentionLoop, wire
-from theia_tpu.store.flow_store import (RETENTION_STAGES,
+from theia_tpu.store.flow_store import (RETENTION_STAGES, TRIM_WALK,
                                         RetentionUnavailable)
 from theia_tpu.store.views import MATERIALIZED_VIEWS
 
 RECORD_KEYS = {"result", "usageBefore", "rowsBefore", "deleteN",
                "boundary", "rowsDeleted", "viewRowsDeleted",
-               "bytesFreed", "rowsAfter", "seconds", "stagesMs"}
+               "bytesFreed", "rowsAfter", "seconds", "stagesMs",
+               # what the flat table's walk did with its batches
+               "batchesDropped", "batchesCut", "batchesKept",
+               "bytesCopied"}
 
 
 def _batch(seed, n_series=12, points=6, times=None):
@@ -246,6 +249,57 @@ def test_post_admin_retention_answers_the_rounds_record(server):
         # and the same however the parts lie: a read merges them
         assert len(db.views[name]) > 0
     assert _get(srv.port, "/debug/retention")["views"] == doc2["views"]
+
+
+def _walk_counters():
+    batches = "theia_retention_batches_total"
+    return {"batchesDropped": _counter(batches, fate="dropped"),
+            "batchesCut": _counter(batches, fate="cut"),
+            "batchesKept": _counter(batches, fate="kept"),
+            "bytesCopied": _counter("theia_retention_bytes_copied_total")}
+
+
+def test_the_rounds_record_says_what_the_walk_did_and_metrics_count_it(
+        monkeypatch):
+    """Five blocks of 72 rows lie in the table as they were appended,
+    in time order, the third over two seconds (20 rows and 52): the
+    180th oldest row is one of its 52, so the round drops two blocks
+    whole, cuts the third (52 rows copied) and keeps two; its record
+    says so, and the two /metrics families rise by the same numbers."""
+    monkeypatch.setenv("THEIA_RETENTION_INTERVAL", "3600")
+    t0 = 1_700_000_000
+    db = FlowDatabase()
+    for i, times in enumerate([np.full(72, t0), np.full(72, t0 + 1),
+                               np.repeat([t0 + 2, t0 + 3], [20, 52]),
+                               np.full(72, t0 + 4), np.full(72, t0 + 5)]):
+        db.insert_flows(_batch(i, times=times))
+    srv = TheiaManagerServer(db, port=0, ingest_shards=2,
+                             capacity_bytes=db.flows.nbytes)
+    srv.start_background()
+    try:
+        before = _walk_counters()
+        doc = _post(srv.port, "/admin/retention")
+        assert set(doc) == RECORD_KEYS and doc["boundary"] == t0 + 3
+        assert {k: doc[k] for k in TRIM_WALK} == {
+            "batchesDropped": 2, "batchesCut": 1, "batchesKept": 2,
+            "bytesCopied": 52 * 284}
+        assert doc["rowsDeleted"] == 164 \
+            and doc["bytesFreed"] == 164 * 284
+        after = _walk_counters()
+        assert {k: after[k] - before[k] for k in TRIM_WALK} \
+            == {k: doc[k] for k in TRIM_WALK}
+        assert _get(srv.port, "/healthz")["retention"]["lastRound"] == doc
+        text = urllib.request.urlopen(
+            f"http://127.0.0.1:{srv.port}/metrics",
+            timeout=30).read().decode()
+        for fate in ("dropped", "cut", "kept"):
+            assert f'theia_retention_batches_total{{fate="{fate}"}}' in text
+        assert "\ntheia_retention_bytes_copied_total " in text
+        # a round that sits out walks nothing
+        assert not TRIM_WALK & _post(srv.port, "/admin/retention").keys()
+        assert _walk_counters() == after
+    finally:
+        srv.shutdown()
 
 
 def test_409_with_the_loop_off(monkeypatch):

@@ -112,10 +112,27 @@ _M_RET_VIEW_DELETED = _metrics.counter(
     "retention rounds, as the view's parts held them (per insert "
     "block; a read merges equal keys across blocks)",
     labelnames=("view",))
+_M_RET_BATCHES = _metrics.counter(
+    "theia_retention_batches_total",
+    "Batches of the flat flows table that a delete by timeInserted "
+    "(a retention round, TTL eviction) met, by what became of them: "
+    "dropped whole, cut (the kept rows copied), kept as they were",
+    labelnames=("fate",))
+_M_RET_COPIED = _metrics.counter(
+    "theia_retention_bytes_copied_total",
+    "Column bytes of the kept rows of the batches such a delete cut: "
+    "what it copied with the table's lock held")
+#: a retention record's key -> the series that counts it
+_M_RET_WALK = {
+    "batchesDropped": _M_RET_BATCHES.labels(fate="dropped"),
+    "batchesCut": _M_RET_BATCHES.labels(fate="cut"),
+    "batchesKept": _M_RET_BATCHES.labels(fate="kept"),
+    "bytesCopied": _M_RET_COPIED}
 _M_TABLE_LOCK_WAIT = _metrics.histogram(
     "theia_ingest_table_lock_wait_seconds",
     "Wait of one flows append for the table's lock (flat engine): a "
-    "retention round's delete holds it while it copies the table")
+    "retention round's delete holds it while it cuts the batches "
+    "that straddle the boundary")
 _M_SNAP_FALLBACK = _metrics.counter(
     "theia_snapshot_fallbacks_total",
     "Snapshot loads that failed verification on the primary file and "
@@ -144,9 +161,9 @@ _M_RET_STAGE = _trace.StageSeries(
     "Stages of one retention round that trims (RetentionMonitor.tick): "
     "usage = flows.nbytes, boundary = the metadata walk and the "
     "partition over the candidate batches, delete_flows = the table's "
-    "lock taken, the batches concatenated, the mask, the kept rows "
-    "filtered out, delete_views = the same boundary on the three "
-    "materialized views",
+    "lock taken, the batches walked by their cached (min, max), those "
+    "that straddle the boundary filtered, the dropped ones released, "
+    "delete_views = the same boundary on the three materialized views",
     labelnames=("stage",))
 #: the stages of one round, in order; on the `bg.retention` span (and
 #: the profiler's host lines) each is `retention.<stage>`
@@ -172,6 +189,16 @@ def _view_pool() -> concurrent.futures.ThreadPoolExecutor:
     """Shared pool for parallel MV fan-out (native group-sum releases
     the GIL, so the three aggregations genuinely overlap)."""
     return get_pool("mv-fanout", 4)
+
+
+#: what a flat table's `delete_older_than` makes of the batches it
+#: walks and the bytes it copies, under a retention record's names:
+#: the keys of `Table.last_walk()`
+TRIM_WALK = ("batchesDropped", "batchesCut", "batchesKept", "bytesCopied")
+
+
+def _nbytes(batch: ColumnarBatch) -> int:
+    return sum(v.nbytes for v in batch.columns.values())
 
 
 class Table:
@@ -211,7 +238,10 @@ class Table:
         self._lock_wait = contextlib.nullcontext \
             if lock_wait_hist is None else functools.partial(
                 _trace.stage, "store.table_lock_wait", lock_wait_hist)
-        self._appended = threading.local()
+        # Beside it, what the calling thread's last
+        # `delete_older_than` did with the batches it walked
+        # (`last_walk`).
+        self._last = threading.local()
         # Cached source-dict → table-dict code mappings: a producer
         # streaming blocks with its own dictionaries pays string
         # re-encode only for NEW entries, not per block.
@@ -237,8 +267,7 @@ class Table:
 
     @property
     def nbytes(self) -> int:
-        return sum(v.nbytes for b in self._batches
-                   for v in b.columns.values())
+        return sum(_nbytes(b) for b in self._batches)
 
     def _adopt(self, batch: ColumnarBatch,
                columns: Optional[Sequence[str]] = None
@@ -318,14 +347,14 @@ class Table:
 
     def _append_adopted(self, adopted: ColumnarBatch) -> None:
         """Make an already-adopted batch visible (the memory apply)."""
-        nbytes = sum(a.nbytes for a in adopted.columns.values())
+        nbytes = _nbytes(adopted)
         with self._lock_wait() as wait:
             self._lock.acquire()
         try:
             self._append_locked(adopted, nbytes)
         finally:
             self._lock.release()
-        self._appended.wait = wait
+        self._last.wait = wait
 
     def _append_locked(self, adopted: ColumnarBatch,
                        nbytes: int) -> None:
@@ -343,7 +372,7 @@ class Table:
         thread's last append (the wait as that thread spent it); None
         before its first, on a table whose appends are not timed, and
         on the parts engine, whose memtable append is its own."""
-        return getattr(self._appended, "wait", None)
+        return getattr(self._last, "wait", None)
 
     def _row_count_locked(self) -> int:
         """Row count; caller holds self._lock (the sharded facade
@@ -371,9 +400,11 @@ class Table:
         """Whole-table view as one batch (concat of the append log).
 
         Compacts the log as a side effect; the swap only happens if no
-        insert raced in between (otherwise the next scan compacts)."""
+        insert or delete raced in between (otherwise the next scan
+        compacts)."""
         with self._lock:
             batches = list(self._batches)
+            generation = self.generation
         if not batches:
             return ColumnarBatch(
                 {c.name: np.zeros(0, c.host_dtype) for c in self.schema},
@@ -382,8 +413,10 @@ class Table:
             return batches[0]
         merged = ColumnarBatch.concat(batches)
         with self._lock:
-            if len(self._batches) == len(batches) and \
-                    self._batches[-1] is batches[-1]:
+            # every append, delete and truncate bumps the generation
+            # (a delete that cuts one old batch leaves the list's
+            # length and its last batch as they were)
+            if self.generation == generation:
                 self._batches = [merged]
                 if self._time_column is not None:
                     self._batch_meta = [
@@ -479,30 +512,75 @@ class Table:
 
     def delete_older_than(self, boundary: int,
                           column: str = "timeInserted") -> int:
-        """Atomic `column < boundary` delete (mask computed under the
-        lock, so it cannot race with inserts). Batches whose cached
-        max is already >= boundary skip the column scan."""
+        """Atomic `column < boundary` delete over the batches as they
+        lie (under the lock, so it cannot race with inserts): the kept
+        rows stay in append order. A batch whose rows all stay is kept
+        as the object it is, one whose rows all go is dropped whole,
+        and only a batch that straddles the boundary is filtered, on
+        its own. For the time column the cached (min, max) decides
+        the first two without reading a row; for any other column (or
+        a table without a time column) the batch's own mask does.
+
+        The lock is held for the walk over the batches plus one
+        `filter` of each straddling batch; what was dropped is
+        released after the lock. A table that a `scan()` compacted
+        into one batch straddles as a whole: the round then costs one
+        filter of it (the kept rows copied once), no concatenation.
+
+        `generation` is bumped only if a row went; `bytes_trimmed_total`
+        rises by the resident bytes of the rows that went, and
+        `last_walk()` says what the walk did."""
+        timed = column == self._time_column
+        batches: List[ColumnarBatch] = []
+        meta: List[Tuple[int, int]] = []
+        walk = dict.fromkeys(TRIM_WALK, 0)
+        deleted = freed = 0
         with self._lock:
-            if not self._batches:
-                return 0
-            if column == self._time_column and self._batch_meta and \
-                    min(m[0] for m in self._batch_meta) >= boundary:
-                return 0   # metadata proves nothing is evictable
-            data = (self._batches[0] if len(self._batches) == 1
-                    else ColumnarBatch.concat(self._batches))
-            mask = np.asarray(data[column]) < boundary
-            if not mask.any():
-                self._batches = [data]
-                self._refresh_meta_locked()
-                return 0
-            kept = data.filter(~mask)
-            self._batches = [kept] if len(kept) else []
-            self._refresh_meta_locked()
-            self.generation += 1
-            self.bytes_trimmed_total += (
-                sum(v.nbytes for v in data.columns.values())
-                - sum(v.nbytes for v in kept.columns.values()))
-        return int(mask.sum())
+            # the old list and the batches it alone holds die with
+            # this frame, after the lock: their release is most of a
+            # large round's time
+            gone = self._batches
+            pairs = self._batch_meta or [None] * len(gone)
+            for batch, pair in zip(gone, pairs):
+                n = None
+                if timed:
+                    n = (0 if pair[0] >= boundary else
+                         len(batch) if pair[1] < boundary else None)
+                if n is None:
+                    mask = np.asarray(batch[column]) < boundary
+                    n = int(np.count_nonzero(mask))
+                deleted += n
+                if n == 0:
+                    walk["batchesKept"] += 1
+                elif n == len(batch):
+                    walk["batchesDropped"] += 1
+                    freed += _nbytes(batch)
+                    continue
+                else:
+                    whole, batch = _nbytes(batch), batch.filter(~mask)
+                    copied = _nbytes(batch)
+                    walk["batchesCut"] += 1
+                    walk["bytesCopied"] += copied
+                    freed += whole - copied
+                    if pair is not None:
+                        t = batch[self._time_column]
+                        pair = (int(t.min()), int(t.max()))
+                batches.append(batch)
+                if pair is not None:
+                    meta.append(pair)
+            if deleted:
+                self._batches, self._batch_meta = batches, meta
+                self.generation += 1
+                self.bytes_trimmed_total += freed
+        self._last.walk = walk
+        return deleted
+
+    def last_walk(self) -> Dict[str, int]:
+        """What the calling thread's last `delete_older_than` did with
+        the batches it met (`TRIM_WALK`: dropped whole, cut, kept as
+        they were, the bytes of the cut batches' kept rows)."""
+        return getattr(self._last, "walk", None) \
+            or dict.fromkeys(TRIM_WALK, 0)
 
     #: columns whose (min, max) the cluster heartbeat piggybacks so a
     #: query coordinator can prune peers against a plan's time window
@@ -617,7 +695,9 @@ class RetentionMonitor:
     threshold, `trimmed`, `skipped` inside `skip_rounds`),
     `usageBefore`, and for a round that went on to delete `rowsBefore`,
     `deleteN`, `boundary`, `rowsDeleted`, `viewRowsDeleted` by view,
-    `bytesFreed`, `rowsAfter` (`bytesDemoted` where parts went cold).
+    `bytesFreed`, `rowsAfter` (`bytesDemoted` where parts went cold;
+    on the flat engine `batchesDropped`, `batchesCut`, `batchesKept`,
+    `bytesCopied`: what `Table.delete_older_than`'s walk did).
     """
 
     def __init__(self, db: "FlowDatabase", capacity_bytes: int,
@@ -1102,8 +1182,7 @@ class FlowDatabase:
             # replays reach here too, re-deriving identical state)
             rollups.apply_insert_block(adopted)
         _M_INS_ROWS.inc(len(adopted))
-        _M_INS_BYTES.inc(sum(a.nbytes
-                             for a in adopted.columns.values()))
+        _M_INS_BYTES.inc(_nbytes(adopted))
         if self.ttl_seconds is not None:
             now = int(now if now is not None
                       else np.max(adopted["timeInserted"]))
@@ -1556,15 +1635,24 @@ class FlowDatabase:
         stages of the enclosing span (`retention.delete_flows`,
         `retention.delete_views`) and the record gains `bytesFreed`
         (resident bytes of the deleted flow rows) and
-        `viewRowsDeleted` by view; both add up over the shards of a
-        sharded store."""
+        `viewRowsDeleted` by view, and on the flat engine what the
+        table's walk did with its batches (`batchesDropped`,
+        `batchesCut`, `batchesKept`, `bytesCopied`); all add up over
+        the shards of a sharded store. The walk is counted on
+        /metrics whoever asked (a round, TTL eviction)."""
         staged = retention_stage if detail is not None \
             else (lambda name: contextlib.nullcontext())
         flows = self.flows
         before = flows.bytes_trimmed_total
         with staged("delete_flows"):
             deleted = flows.delete_older_than(boundary)
-        freed = flows.bytes_trimmed_total - before
+        did = {"bytesFreed": flows.bytes_trimmed_total - before}
+        # the flat table's walk; the parts engine's parts are its own
+        walk = getattr(flows, "last_walk", None)
+        if callable(walk):
+            did.update(walk())
+            for key, series in _M_RET_WALK.items():
+                series.inc(did[key])
         with staged("delete_views"):
             dropped = {name: view.delete_older_than(boundary)
                        for name, view in self.views.items()}
@@ -1576,7 +1664,8 @@ class FlowDatabase:
             # exactly
             rollups.apply_delete(boundary)
         if detail is not None:
-            detail["bytesFreed"] = detail.get("bytesFreed", 0) + int(freed)
+            for key, n in did.items():
+                detail[key] = detail.get(key, 0) + int(n)
             views = detail.setdefault("viewRowsDeleted", {})
             for name, rows in dropped.items():
                 views[name] = views.get(name, 0) + rows
