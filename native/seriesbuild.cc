@@ -13,6 +13,21 @@
 //     int64 values), points in time order, padded to the longest
 //     series with a validity mask.
 //
+// How a series is written follows from how its times arrived, series
+// by series, with no argument that chooses:
+//   cursor  times never stepped back (a connection's rows): each row
+//           is written as sb_fill meets it, a repeated time reduces
+//           into the cell before it;
+//   cells   times stepped back (a pod's rows: one connection's seconds
+//           after another's) and the series' span of whole seconds
+//           [lo, hi] costs no more memory than a copy of its rows
+//           would: every row reduces into cell t - lo of hi - lo + 1
+//           cells in one pass, and the cells are read out in order.
+//           Nothing is gathered, sorted or merged;
+//   sorted  times stepped back over a span far wider than the rows
+//           (sparse samples, a record years off): the rows are
+//           gathered, sorted and merged.
+//
 // C API (ctypes; same .so as flowblock/groupsum):
 //   sb_new(k, op)              handle for series keyed by k columns
 //   sb_add(h, cols, widths, strides, mask, n)
@@ -23,7 +38,9 @@
 //       copy. mask: n bytes, 0 = row filtered out; null = every row.
 //       The columns must stay alive until sb_fill. May be called more
 //       than once (the pod mode's two sides).
-//   sb_finish(h, &S, &T)       number of series, longest series
+//   sb_finish(h, &S, &T, ways)
+//       number of series, longest series, and ways[3]: the series
+//       written by the cursor, from cells, sorted
 //   sb_fill(h, out_keys, out_values, value_width, out_times, out_mask)
 //       out_keys [S,k] int64; out_values [S,T] float32 (4) or float64
 //       (8); out_times [S,T] int64; out_mask [S,T] bytes. Caller-
@@ -74,13 +91,30 @@ using Point = std::pair<int64_t, int64_t>;  // (time, value)
 // How a group's times have arrived so far.
 enum Arrival : uint8_t { kIncreasing = 0, kTies = 1, kUnsorted = 2 };
 
+// How a group is written (sb_finish decides; the header has the rule).
+enum Way : uint8_t { kCursor = 0, kCells = 1, kSorted = 2 };
+
 struct Group {
   int64_t rows = 0;    // rows of the group
   int64_t points = 0;  // distinct times, exact unless kUnsorted
   int64_t last = 0;    // newest time seen
+  int64_t lo = 0;      // smallest and
+  int64_t hi = 0;      // largest time seen
+  int64_t at = 0;      // kCells: first cell; kSorted: index into loose
   uint8_t arrival = kIncreasing;
-  int32_t loose = -1;  // kUnsorted: index into Builder::loose
+  uint8_t way = kCursor;
 };
+
+// A kUnsorted group takes cells when they cost no more memory than
+// the gather they replace: 9 B a cell (an int64 sum and a seen byte)
+// against 16 B a row (a (time, value) pair). Dense seconds pass at any
+// length (a pod's 864 or 43,200 seconds under several connections'
+// rows); a span far over its rows keeps the sort.
+inline bool takes_cells(const Group& g) {
+  const uint64_t steps =  // hi - lo, exact however far apart
+      static_cast<uint64_t>(g.hi) - static_cast<uint64_t>(g.lo);
+  return steps < static_cast<uint64_t>(g.rows) * 16 / 9;
+}
 
 // One sb_add: where its rows' points lie and each row's group.
 struct Part {
@@ -99,7 +133,10 @@ struct Builder {
   int64_t T = 0;
   std::vector<int32_t> order;            // output row -> group
   std::vector<int64_t> begin;            // group -> output row * T
-  std::vector<std::vector<Point>> loose;  // sorted, merged
+  std::vector<std::vector<Point>> loose;  // kSorted: sorted, merged
+  std::vector<int64_t> cells;             // kCells: a cell's reduction
+  std::vector<uint8_t> seen;              // and whether a row met it
+  int64_t ways[3] = {0, 0, 0};            // groups by Way
 
   int32_t group_of(const int64_t* row, uint64_t hv) {
     size_t s = hv & (slots.size() - 1);
@@ -146,34 +183,52 @@ void fill(const Builder& b, V* out_values, int64_t* out_times,
   std::vector<Cursor> cur(b.groups.size());
   for (size_t g = 0; g < cur.size(); ++g) {
     cur[g].first = b.begin[g];
-    cur[g].next = b.groups[g].loose < 0 ? b.begin[g] : -1;
+    cur[g].next = b.groups[g].way == kCursor ? b.begin[g] : -1;
   }
-  for (const Part& p : b.parts) {
-    const int64_t n = static_cast<int64_t>(p.gid.size());
-    for (int64_t r = 0; r < n; ++r) {
-      const int32_t g = p.gid[r];
-      if (g < 0) continue;
-      Cursor& c = cur[g];
-      if (c.next < 0) continue;
-      const int64_t t = cell(p.times, r), v = cell(p.values, r);
-      if (c.next > c.first && c.last == t) {
-        c.acc = reduce(b.op, c.acc, v);
-        out_values[c.next - 1] = static_cast<V>(static_cast<double>(c.acc));
-      } else {
-        c.last = t;
-        c.acc = v;
-        out_times[c.next] = t;
-        out_values[c.next] = static_cast<V>(static_cast<double>(v));
-        out_mask[c.next] = 1;
-        ++c.next;
+  // the rows are walked only if some group is written from them
+  if (b.ways[kCursor]) {
+    for (const Part& p : b.parts) {
+      const int64_t n = static_cast<int64_t>(p.gid.size());
+      for (int64_t r = 0; r < n; ++r) {
+        const int32_t g = p.gid[r];
+        if (g < 0) continue;
+        Cursor& c = cur[g];
+        if (c.next < 0) continue;
+        const int64_t t = cell(p.times, r), v = cell(p.values, r);
+        if (c.next > c.first && c.last == t) {
+          c.acc = reduce(b.op, c.acc, v);
+          out_values[c.next - 1] =
+              static_cast<V>(static_cast<double>(c.acc));
+        } else {
+          c.last = t;
+          c.acc = v;
+          out_times[c.next] = t;
+          out_values[c.next] = static_cast<V>(static_cast<double>(v));
+          out_mask[c.next] = 1;
+          ++c.next;
+        }
       }
     }
   }
   for (size_t g = 0; g < cur.size(); ++g) {
+    const Group& grp = b.groups[g];
     int64_t at = cur[g].next;
-    if (at < 0) {
+    if (grp.way == kCells) {
+      // the cells in order are the points in time order
       at = cur[g].first;
-      for (const Point& pt : b.loose[b.groups[g].loose]) {
+      const int64_t* acc = b.cells.data() + grp.at;
+      const uint8_t* seen = b.seen.data() + grp.at;
+      const int64_t span = grp.hi - grp.lo + 1;  // takes_cells bounds it
+      for (int64_t i = 0; i < span; ++i) {
+        if (!seen[i]) continue;
+        out_times[at] = grp.lo + i;
+        out_values[at] = static_cast<V>(static_cast<double>(acc[i]));
+        out_mask[at] = 1;
+        ++at;
+      }
+    } else if (grp.way == kSorted) {
+      at = cur[g].first;
+      for (const Point& pt : b.loose[grp.at]) {
         out_times[at] = pt.first;
         out_values[at] = static_cast<V>(static_cast<double>(pt.second));
         out_mask[at] = 1;
@@ -227,56 +282,91 @@ void sb_add(void* h, const void** cols, const int32_t* widths,
     part.gid[r] = g;
     Group& grp = b->groups[g];
     const int64_t t = cell(part.times, r);
-    if (!grp.rows || t > grp.last)
+    if (!grp.rows) {
+      grp.lo = grp.hi = t;
       ++grp.points;
-    else
+    } else if (t > grp.last) {
+      // only a later time can pass hi, only an earlier one lo
+      grp.hi = std::max(grp.hi, t);
+      ++grp.points;
+    } else {
+      grp.lo = std::min(grp.lo, t);
       grp.arrival = std::max<uint8_t>(grp.arrival,
                                       t == grp.last ? kTies : kUnsorted);
+    }
     grp.last = t;
     ++grp.rows;
   }
 }
 
-void sb_finish(void* h, int64_t* S, int64_t* T) {
+void sb_finish(void* h, int64_t* S, int64_t* T, int64_t* ways) {
   auto* b = static_cast<Builder*>(h);
   const int32_t k = b->k;
   const size_t G = b->groups.size();
 
-  // Only a group whose times arrived out of order is gathered, sorted
-  // and merged; its rows are counted, so its vector never regrows.
+  // A group whose times arrived out of order is summed into cells, or
+  // gathered, sorted and merged (its rows are counted, so its vector
+  // never regrows): takes_cells has the rule.
+  int64_t n_cells = 0;
   for (Group& grp : b->groups) {
-    if (grp.arrival != kUnsorted) continue;
-    grp.loose = static_cast<int32_t>(b->loose.size());
-    b->loose.emplace_back();
-    b->loose.back().reserve(grp.rows);
+    if (grp.arrival == kUnsorted && takes_cells(grp)) {
+      grp.way = kCells;
+      grp.at = n_cells;
+      n_cells += grp.hi - grp.lo + 1;
+    } else if (grp.arrival == kUnsorted) {
+      grp.way = kSorted;
+      grp.at = static_cast<int64_t>(b->loose.size());
+      b->loose.emplace_back();
+      b->loose.back().reserve(grp.rows);
+    }
+    ++b->ways[grp.way];
   }
-  if (!b->loose.empty()) {
+  if (b->ways[kCells] || b->ways[kSorted]) {
+    // A cell starts as the reduction's identity, so every touch is a
+    // reduce: max and the wrapping sum are order-free, and the result
+    // is the sort's bit for bit.
+    b->cells.assign(n_cells, b->op == 0 ? INT64_MIN : 0);
+    b->seen.assign(n_cells, 0);
     for (const Part& p : b->parts) {
       const int64_t n = static_cast<int64_t>(p.gid.size());
       for (int64_t r = 0; r < n; ++r) {
         const int32_t g = p.gid[r];
-        if (g >= 0 && b->groups[g].loose >= 0)
-          b->loose[b->groups[g].loose].emplace_back(cell(p.times, r),
-                                                     cell(p.values, r));
+        if (g < 0 || b->groups[g].way == kCursor) continue;
+        const Group& grp = b->groups[g];
+        const int64_t t = cell(p.times, r), v = cell(p.values, r);
+        if (grp.way == kCells) {
+          // t - lo is exact however far from 0 the span lies
+          const uint64_t c = static_cast<uint64_t>(grp.at) +
+                             (static_cast<uint64_t>(t) -
+                              static_cast<uint64_t>(grp.lo));
+          b->cells[c] = reduce(b->op, b->cells[c], v);
+          b->seen[c] = 1;
+        } else {
+          b->loose[grp.at].emplace_back(t, v);
+        }
       }
     }
     for (Group& grp : b->groups) {
-      if (grp.loose < 0) continue;
-      auto& pts = b->loose[grp.loose];
-      std::sort(pts.begin(), pts.end(),
-                [](const Point& x, const Point& y) {
-                  return x.first < y.first;
-                });
-      size_t w = 0;
-      for (size_t i = 0; i < pts.size(); ++i) {
-        if (w && pts[w - 1].first == pts[i].first)
-          pts[w - 1].second =
-              reduce(b->op, pts[w - 1].second, pts[i].second);
-        else
-          pts[w++] = pts[i];
+      if (grp.way == kCells) {
+        const auto s = b->seen.begin() + grp.at;
+        grp.points = std::count(s, s + (grp.hi - grp.lo + 1), 1);
+      } else if (grp.way == kSorted) {
+        auto& pts = b->loose[grp.at];
+        std::sort(pts.begin(), pts.end(),
+                  [](const Point& x, const Point& y) {
+                    return x.first < y.first;
+                  });
+        size_t w = 0;
+        for (size_t i = 0; i < pts.size(); ++i) {
+          if (w && pts[w - 1].first == pts[i].first)
+            pts[w - 1].second =
+                reduce(b->op, pts[w - 1].second, pts[i].second);
+          else
+            pts[w++] = pts[i];
+        }
+        pts.resize(w);
+        grp.points = static_cast<int64_t>(w);
       }
-      pts.resize(w);
-      grp.points = static_cast<int64_t>(w);
     }
   }
 
@@ -299,6 +389,7 @@ void sb_finish(void* h, int64_t* S, int64_t* T) {
     b->begin[b->order[s]] = static_cast<int64_t>(s) * b->T;
   *S = static_cast<int64_t>(G);
   *T = b->T;
+  std::copy(b->ways, b->ways + 3, ways);
 }
 
 void sb_fill(void* h, int64_t* out_keys, void* out_values,

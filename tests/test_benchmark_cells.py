@@ -1034,6 +1034,66 @@ def test_the_dbscan_cells_metrics_reduce_to_a_jobs_figures():
     assert read("dbscan_noise_roofline", traced) is None
 
 
+def test_the_series_ways_readers_reduce_to_a_jobs_series():
+    """`job.tensorize_series_cells` and `job.tensorize_series_sorted`
+    (PR 50; readers only: `per_layer` is at its 128 entries, so a
+    `benchmark` PR declares them) read the program's own exposition
+    around one job: a pod-mode job's series of more than one
+    connection under `cells`, none under `sorted`; a connection-mode
+    job 0 under both; nothing from a manager without the family (the
+    parent)."""
+    import time
+
+    from benchmarks import prom
+    from theia_tpu.analytics import TadQuerySpec, run_tad
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+    from theia_tpu.store import FlowDatabase
+    from theia_tpu.utils.native import native_available
+
+    names = ("job.tensorize_series_cells", "job.tensorize_series_sorted")
+    readers = {name: BENCH.reader("per_layer", name) for name in names}
+    pattern = BENCH.reader("per_layer", "aggpod.series")
+    for name, reader in readers.items():
+        assert {k: v for k, v in reader.items() if k != "series"} \
+            == {k: v for k, v in pattern.items() if k != "series"}
+        assert reader["series"] == (
+            'theia_job_tensorize_series_total{kind="tad",how="%s"}'
+            % name.rsplit("_", 1)[1])
+    if not native_available():
+        pytest.skip("native library unavailable")
+
+    db = FlowDatabase()
+    db.insert_flows(generate_flows(SynthConfig(
+        n_series=40, points_per_series=12, n_namespaces=2,
+        pods_per_namespace=3, seed=2)))
+
+    def around(spec):
+        before = prom.parse(exposition.render())
+        run_tad(db, "DBSCAN", spec, now=int(time.time()),
+                progress=JobProgress("job", TAD_STAGES, kind="tad"))
+        return {"metrics_before": before,
+                "metrics_after": prom.parse(exposition.render())}
+
+    def read(name, data):
+        reader = readers[name]
+        return extend.resolve("reduction", reader["reduce"])(data, reader)
+
+    pod = around(TadQuerySpec(agg_flow="pod"))
+    built = BENCH.reader("per_layer", "aggpod.series")
+    built = extend.resolve("reduction", built["reduce"])(pod, built)
+    assert 0 < read(names[0], pod) <= built
+    assert read(names[1], pod) == 0
+    conn = around(TadQuerySpec())
+    assert read(names[0], conn) == read(names[1], conn) == 0
+    for name in names:
+        key = readers[name]["series"]
+        assert key in pod["metrics_after"]
+        pod["metrics_after"].pop(key), pod["metrics_before"].pop(key, None)
+        assert read(name, pod) is None
+
+
 TAD_CELLS = ["parts-fused.tad-ewma", "parts-fused-12h.tad-arima",
              "parts-fused-12h-ns.tad-dbscan"]
 

@@ -38,8 +38,8 @@ def _numpy(monkeypatch, parts, op, dtype=np.float64):
     # the path a process without the library takes
     monkeypatch.setattr(series_mod, "build_padded_series",
                         lambda parts, op, dtype: None)
-    res, path = _group_and_pad(parts, op, dtype)
-    assert path == "numpy"
+    res, path, ways = _group_and_pad(parts, op, dtype)
+    assert path == "numpy" and ways is None
     return res
 
 
@@ -83,7 +83,8 @@ def test_native_empty_input():
     out = build_padded_series(
         [SeriesRows([np.zeros(0, np.int32)] * 4, np.zeros(0, np.int64),
                     np.zeros(0, np.int64), None)], "max")
-    key_mat, values, times, mask = out
+    key_mat, values, times, mask, ways = out
+    assert ways == (0, 0, 0)
     assert key_mat.shape == (0, 4)
     assert values.shape == times.shape == mask.shape == (0, 0)
     assert mask.dtype == bool
@@ -94,13 +95,13 @@ def test_native_single_group_duplicate_times():
     t = np.array([5, 5, 5, 7, 7, 6], np.int64)
     v = np.array([10, 30, 20, 1, 2, 9], np.int64)
     parts = [SeriesRows(keys, t, v, None)]
-    key_mat, values, times, mask = build_padded_series(parts, "max")
+    key_mat, values, times, mask, ways = build_padded_series(parts, "max")
     assert key_mat.shape == (1, 2)
     np.testing.assert_array_equal(times[0], [5, 6, 7])
     np.testing.assert_array_equal(values[0], [30.0, 9.0, 2.0])
     assert mask.all()
 
-    _, values, _, _ = build_padded_series(parts, "sum")
+    _, values, _, _, _ = build_padded_series(parts, "sum")
     np.testing.assert_array_equal(values[0], [60.0, 9.0, 3.0])
 
 
@@ -133,6 +134,123 @@ def test_native_orders_and_merges_however_times_arrive(
     native = build_padded_series(parts, op)
     _assert_same(native, _numpy(monkeypatch, parts, op))
     assert (np.diff(native[2], axis=1)[native[3][:, 1:]] > 0).all()
+
+
+def _out_of_order(rng, lo, seconds, rows):
+    """`rows` times that meet each of `seconds` after `lo`, shuffled
+    until some step goes back."""
+    t = lo + np.resize(seconds, rows)
+    while not (np.diff(t) < 0).any():
+        t = rng.permutation(t)
+    return t
+
+
+def _cells_case(case, rng, time_dtype):
+    """(parts, ways) of a table whose out-of-order series the builder
+    must sum into cells; `ways` is (cursor, cells, sorted)."""
+    wide = time_dtype == np.int64
+    lo = {"negative_lo": -2**62 if wide else -2**31,
+          "large_lo": 2**62 if wide else 2**31 - 61}.get(case, 1000)
+    n_series, rows = 6, 80
+    if case == "one_second":
+        # a repeated second alone never steps back (the cursor's);
+        # two seconds taking turns are the narrowest span in cells
+        key = np.repeat([0, 1], 6)
+        t = np.array([7] * 6 + [5, 4, 5, 4, 4, 5], np.int64)
+        v = rng.integers(1, 10**9, key.size)
+        return [SeriesRows([key], t.astype(time_dtype), v, None)], (1, 1, 0)
+    if case == "two_parts":
+        # each part in order; the second starts over, so both parts'
+        # rows meet in one cell
+        parts = []
+        for side in range(2):
+            key = np.repeat(np.arange(n_series), 20).astype(np.int32)
+            t = np.tile(np.arange(20) * 2 + lo, n_series)
+            parts.append(SeriesRows(
+                [key], t.astype(time_dtype),
+                rng.integers(1, 10**9, key.size), None))
+        return parts, (0, n_series, 0)
+    # 60 seconds of which a series meets 35: the other cells stay unseen
+    seconds = np.sort(rng.choice(60, size=35, replace=False))
+    seconds[[0, -1]] = 0, 59
+    key = np.repeat(np.arange(n_series), rows)
+    t = np.concatenate([_out_of_order(rng, lo, seconds, rows)
+                        for _ in range(n_series)])
+    mask = None
+    if case == "masked_widener":
+        # a row years off that the filters drop: counted, it would
+        # widen every span past the rule and send the series to the sort
+        t[::rows] = lo + (2**30 if wide else 2**20)
+        mask = np.ones(key.size, bool)
+        mask[::rows] = False
+    order = rng.permutation(key.size)        # the series interleaved
+    order = order[np.argsort(key[order] // 2, kind="stable")]
+    key, t = key[order], t[order]
+    if mask is not None:
+        mask = mask[order]
+    v = rng.integers(-10**9, 10**9, key.size)
+    return ([SeriesRows([key.astype(np.int32)], t.astype(time_dtype), v,
+                        mask)], (0, n_series, 0))
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("time_dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("case", ["gaps", "negative_lo", "large_lo",
+                                  "one_second", "two_parts",
+                                  "masked_widener"])
+@pytest.mark.parametrize("op", ["max", "sum"])
+def test_native_sums_out_of_order_series_into_cells(
+        monkeypatch, op, case, time_dtype, dtype):
+    """A series whose times step back over dense seconds is reduced
+    into time-indexed cells and read out in order: the numpy path's
+    tensors bit for bit, wherever the span lies, whatever it leaves
+    unseen, and the counts say that no series was sorted."""
+    parts, ways = _cells_case(case, np.random.default_rng(23), time_dtype)
+    native = build_padded_series(parts, op, dtype)
+    _assert_same(native, _numpy(monkeypatch, parts, op, dtype))
+    assert native[4] == ways
+    assert (np.diff(native[2], axis=1)[native[3][:, 1:]] > 0).all()
+    if case == "gaps":
+        assert native[3].sum(axis=1).tolist() == [35] * 6
+    if case == "masked_widener":
+        assert build_padded_series(
+            [p._replace(mask=None) for p in parts], op, dtype)[4] \
+            == (0, 0, 6)
+
+
+@pytest.mark.parametrize("span", ["at_the_rule", "past_the_rule",
+                                  "far_apart", "whole_int64"])
+@pytest.mark.parametrize("op", ["max", "sum"])
+def test_native_sorts_a_series_whose_span_is_far_over_its_rows(
+        monkeypatch, op, span):
+    """The rule is read off each series: nine rows take cells up to a
+    span of 16 seconds (9 B a cell against 16 B a row) and the sort
+    beyond, beside series that take cells and series in order, all
+    three as numpy gives them; no cell of the benchmark enters the
+    sort, so the counts hold it here."""
+    rng = np.random.default_rng(29)
+    last = {"at_the_rule": 15, "past_the_rule": 16, "far_apart": 10**12,
+            "whole_int64": 2**63 - 1}[span]
+    first = -2**63 if span == "whole_int64" else 0
+    loose = np.array([3, last, 1, first, 3, 2, last, 4, 2], np.int64)
+    dense = np.concatenate([_out_of_order(rng, 50, np.arange(30), 40)
+                            for _ in range(3)])
+    in_order = np.tile(np.arange(25) // 2, 4)
+    key = np.concatenate([np.full(loose.size, 3), np.repeat([0, 2, 5], 40),
+                          np.repeat([1, 4, 6, 7], 25)])
+    t = np.concatenate([loose, dense, in_order])
+    # the series interleaved, each in its own order of arrival
+    turn, order = rng.permutation(key), np.empty(key.size, np.int64)
+    for g in range(8):
+        order[turn == g] = np.flatnonzero(key == g)
+    key, t = key[order], t[order]
+    v = rng.integers(1, 10**9, key.size)
+    parts = [SeriesRows([key.astype(np.int32)], t, v, None)]
+    native = build_padded_series(parts, op)
+    _assert_same(native, _numpy(monkeypatch, parts, op))
+    sorts = span != "at_the_rule"
+    assert native[4] == (4, 4 - sorts, int(sorts))
+    assert native[4]._fields == ("cursor", "cells", "sorted")
 
 
 def test_native_orders_keys_as_int64_values(monkeypatch):
@@ -205,7 +323,7 @@ def test_native_refuses_what_it_cannot_read(fault):
     assert build_padded_series(parts, "max") is None
 
     if fault.startswith("float"):
-        res, path = _group_and_pad(parts, "max", np.float64)
+        res, path, _ = _group_and_pad(parts, "max", np.float64)
         assert path == "numpy"
         whole = [SeriesRows([np.asarray(c, np.int64) for c in keys], t,
                             np.asarray(v, np.int64), mask)]

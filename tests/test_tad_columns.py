@@ -305,3 +305,47 @@ def test_tensorize_rows_counter_names_the_path(monkeypatch, mode):
     for path in ("columns", "numpy"):
         assert (f'theia_job_tensorize_rows_total{{kind="tad",'
                 f'path="{path}"}} ') in text
+
+
+@pytest.mark.parametrize("mode", MODES + ("pod",))
+def test_tensorize_series_counter_names_the_way(monkeypatch, mode):
+    """A TAD job through `JobProgress` raises
+    `theia_job_tensorize_series_total{kind="tad",how=...}` by the
+    series the native builder wrote each way: a connection's rows lie
+    in time order, so a connection-mode job's series are all `cursor`
+    and `cells` reads 0; a pod's connections follow one another, so
+    its series with more than one go to `cells`; nothing here is
+    `sorted`; the ways add up to the series built; and the numpy path
+    (no builder) moves none of them."""
+    from theia_tpu.analytics import series as series_mod
+    from theia_tpu.runner.progress import TAD_STAGES, JobProgress
+
+    batch = flows()
+    spec = (TadQuerySpec(agg_flow="pod") if mode == "pod"
+            else _modes(batch)[mode][0])
+    db = FlowDatabase()
+    db.insert_flows(batch)
+    counter = metrics.REGISTRY.get("theia_job_tensorize_series_total")
+    n_series = build_series(db.flows.scan(), spec).n_series
+
+    def rise():
+        hows = ("cursor", "cells", "sorted")
+        before = [counter.labels(kind="tad", how=h).value() for h in hows]
+        run_tad(db, "EWMA", spec, now=NOW,
+                progress=JobProgress("job", TAD_STAGES, kind="tad"))
+        return tuple(counter.labels(kind="tad", how=h).value() - b
+                     for h, b in zip(hows, before))
+
+    cursor, cells, in_sort = rise()
+    assert cursor + cells == n_series > 0 and in_sort == 0
+    if mode in ("connection", "external"):
+        assert cells == 0
+    else:
+        assert cells > 0
+    text = prom.render()
+    for how in ("cursor", "cells", "sorted"):
+        assert (f'theia_job_tensorize_series_total{{kind="tad",'
+                f'how="{how}"}} ') in text
+    monkeypatch.setattr(series_mod, "build_padded_series",
+                        lambda parts, op, dtype: None)
+    assert rise() == (0, 0, 0)

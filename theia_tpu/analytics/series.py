@@ -218,8 +218,9 @@ class SeriesRows(NamedTuple):
 
 def _group_and_pad(parts: Sequence[SeriesRows], op: str, dtype):
     """Stage-1 (key,time) reduction + ragged→padded packing of the
-    rows of `parts` as one table; returns the four tensors and the
-    path that made them.
+    rows of `parts` as one table; returns the four tensors, the path
+    that made them and, from the builder, its `SeriesWays` (how it
+    wrote the series; None from numpy).
 
     One seam with two equivalent implementations: `columns`, the native
     C++ builder (native/seriesbuild.cc — one hash-group pass over the
@@ -229,7 +230,7 @@ def _group_and_pad(parts: Sequence[SeriesRows], op: str, dtype):
     library, or a column it does not take)."""
     res = build_padded_series(parts, op, dtype)
     if res is not None:
-        return res, "columns"
+        return res[:4], "columns", res[4]
     stage1, values = [], []
     for key_cols, t, v, mask in parts:
         rows = slice(None) if mask is None else mask
@@ -239,7 +240,8 @@ def _group_and_pad(parts: Sequence[SeriesRows], op: str, dtype):
         values.append(np.asarray(v, np.int64)[rows])
     gk, gv = group_reduce(np.concatenate(stage1),
                           np.concatenate(values)[:, None], op)
-    return _pack_and_pad(gk[:, :-1], gk[:, -1], gv[:, 0], dtype), "numpy"
+    return (_pack_and_pad(gk[:, :-1], gk[:, -1], gv[:, 0], dtype),
+            "numpy", None)
 
 
 def remove_meaningless_labels(labels_json: str) -> str:
@@ -281,9 +283,10 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
     columns, the time and the value column and the mask through
     `_group_and_pad` into the padded tensors) and `decode` (the series'
     keys as the result rows show them), and counts the rows grouped by
-    the path that grouped them, the series built and the rows merged
-    into a point another row held (rows grouped less the mask's
-    points: no pass over the rows)."""
+    the path that grouped them, the series built (and, from the
+    builder, by the way it wrote them) and the rows merged into a
+    point another row held (rows grouped less the mask's points: no
+    pass over the rows)."""
     pod = spec.agg_flow == "pod"
     with job_part(progress, "keys"):
         if pod:
@@ -293,7 +296,7 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
             parts = [_rows(flows, [flows[c] for c in key_names],
                            _filter_mask(flows, spec))]
     with job_part(progress, "group"):
-        (key_mat, values, times, mask), path = _group_and_pad(
+        (key_mat, values, times, mask), path, ways = _group_and_pad(
             parts, op, dtype)
     with job_part(progress, "decode"):
         if pod:
@@ -303,7 +306,7 @@ def build_series(flows: ColumnarBatch, spec: TadQuerySpec,
     if progress:
         progress.tensorized(sum(p.kept for p in parts), path,
                             spec.agg_type, len(key_mat),
-                            int(np.count_nonzero(mask)))
+                            int(np.count_nonzero(mask)), ways)
     return SeriesBatch(key_names, keys, values, times, mask, spec.agg_type)
 
 

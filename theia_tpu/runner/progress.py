@@ -102,6 +102,15 @@ _M_SERIES_BUILT = _metrics.counter(
     "Series a job's tensorize stage built, by the query's aggregation "
     "(None: a connection a series; pod, external, svc)",
     labelnames=("kind", "agg"))
+_M_TENSORIZE_SERIES = _metrics.counter(
+    "theia_job_tensorize_series_total",
+    "Series the native builder wrote, by how: cursor (times never "
+    "stepped back: written as the rows are met), cells (times out of "
+    "order over dense seconds: each row reduced into its second's "
+    "cell, nothing sorted) or sorted (out of order over a span far "
+    "wider than the rows: gathered, sorted, merged); nothing from the "
+    "numpy path",
+    labelnames=("kind", "how"))
 _M_SERIES_ROWS_MERGED = _metrics.counter(
     "theia_job_series_rows_merged_total",
     "Of the rows that stage grouped, those that fell into a (key, "
@@ -169,13 +178,18 @@ class JobProgress:
         _M_READ_BYTES.labels(kind=self.kind).inc(_column_bytes(batch))
 
     def tensorized(self, rows: int, path: str, agg: str, series: int,
-                   points: int) -> None:
+                   points: int, ways) -> None:
         """Count the rows the `tensorize` stage grouped and the path
         (`columns` or `numpy`) that grouped them; under the query's
         aggregation `agg` (which the enclosing `job.run` span also
         gets), the series it built and the rows that were merged into
-        a point another row already held."""
+        a point another row already held; and the series by how the
+        native builder wrote them (`ways`, its `SeriesWays`; None from
+        numpy)."""
         _M_TENSORIZE_ROWS.labels(kind=self.kind, path=path).inc(rows)
+        if ways is not None:
+            for how, n in ways._asdict().items():
+                _M_TENSORIZE_SERIES.labels(kind=self.kind, how=how).inc(n)
         _M_SERIES_BUILT.labels(kind=self.kind, agg=agg).inc(series)
         _M_SERIES_ROWS_MERGED.labels(kind=self.kind, agg=agg).inc(
             rows - points)

@@ -15,7 +15,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -125,6 +125,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_void_p, ctypes.c_int64]
     lib.sb_finish.argtypes = [ctypes.c_void_p,
                               ctypes.POINTER(ctypes.c_int64),
+                              ctypes.POINTER(ctypes.c_int64),
                               ctypes.POINTER(ctypes.c_int64)]
     lib.sb_fill.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
@@ -160,6 +161,15 @@ def native_status() -> str:
 _INT_DTYPES = (np.dtype(np.int32), np.dtype(np.int64))
 
 
+class SeriesWays(NamedTuple):
+    """How many series the native builder wrote by each way
+    (native/seriesbuild.cc's header has the rule): as their rows were
+    met, from time-indexed cells, after a sort."""
+    cursor: int
+    cells: int
+    sorted: int
+
+
 def build_padded_series(parts, op: str, dtype=np.float64):
     """Native tensorize: group rows by their integer key columns into
     padded per-series time arrays (native/seriesbuild.cc).
@@ -172,10 +182,11 @@ def build_padded_series(parts, op: str, dtype=np.float64):
     table; all have the same number of key columns.
 
     Returns (key_mat [S,k] int64, values [S,T] dtype, times [S,T] int64,
-    mask [S,T] bool) with series in lexicographic key order and points
-    in time order — bit-identical to the numpy group_reduce +
-    _pack_and_pad path in analytics/series.py. Duplicate (key, time)
-    rows reduce with `op` ("max" or "sum"). Returns None (the caller
+    mask [S,T] bool, ways) with series in lexicographic key order and
+    points in time order — bit-identical to the numpy group_reduce +
+    _pack_and_pad path in analytics/series.py — and `ways`, the
+    `SeriesWays` that say how the builder wrote them. Duplicate (key,
+    time) rows reduce with `op` ("max" or "sum"). Returns None (the caller
     falls back to numpy) when the native library is unavailable or a
     column is of another dtype, shape or length.
     """
@@ -215,7 +226,8 @@ def build_padded_series(parts, op: str, dtype=np.float64):
                 len(cols[-1]))
         S = ctypes.c_int64()
         T = ctypes.c_int64()
-        lib.sb_finish(handle, ctypes.byref(S), ctypes.byref(T))
+        ways = (ctypes.c_int64 * 3)()
+        lib.sb_finish(handle, ctypes.byref(S), ctypes.byref(T), ways)
         s, t = S.value, T.value
         key_mat = np.empty((s, k), np.int64)
         vals = np.empty((s, t), fill)
@@ -225,7 +237,8 @@ def build_padded_series(parts, op: str, dtype=np.float64):
                     fill.itemsize, ts.ctypes.data, out_mask.ctypes.data)
     finally:
         lib.sb_free(handle)
-    return key_mat, vals.astype(dtype, copy=False), ts, out_mask
+    return (key_mat, vals.astype(dtype, copy=False), ts, out_mask,
+            SeriesWays(*ways))
 
 
 def native_group_sum(key_cols, value_cols):
