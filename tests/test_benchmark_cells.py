@@ -673,8 +673,8 @@ def test_the_aggpod_cell_is_the_npr_cells_store_under_the_pod_query():
     # entries are only ever appended: PR 48's last stays the 126th
     assert BENCH.doc["per_layer"][125]["name"] \
         == "aggpod.dbscan_noise_roofline"
-    assert BENCH.doc["workloads"][-1] == cell
-    assert len(BENCH.doc["workloads"]) == 9
+    # appended behind the trim cell's, the ninth
+    assert BENCH.doc["workloads"][8] == cell
 
 
 def test_the_aggpod_cell_is_rehearsed_on_the_cpu_backend():
@@ -1151,3 +1151,303 @@ def test_the_direct_rows_metric_reduces_to_a_jobs_rows(monkeypatch):
     for side in data.values():
         side.pop(reader["series"], None)
     assert read(data) is None
+
+
+VOL_CELL = "default-vol.dashboards-volume"
+DASH_CELL = "default.dashboards-retained"
+
+
+def _reader_panels(traffic):
+    import urllib.parse
+    out = {}
+    for p in next(g for g in traffic["workers"]
+                  if g["role"] == "reader")["panels"]:
+        url = urllib.parse.urlsplit(p["path"])
+        q = {k: int(v[0]) for k, v in
+             urllib.parse.parse_qs(url.query).items()}
+        out[p["name"]] = (url.path, p["closed"], q)
+    return out
+
+
+def test_the_volume_cell_is_the_dashboards_cell_on_the_volumes_store():
+    """The two cells ask the same eight panels over as many rows each
+    and take the same ingest; they differ in the store around a
+    panel's range (13.6 M rows of four streams against 1.0 M of one)
+    and in nothing else. The configuration is the trim cell's
+    deployment, read."""
+    from benchmarks.gen import DEFAULT_START
+
+    dash = BENCH.traffic("dashboards-retained")
+    vol = BENCH.traffic("dashboards-volume")
+    assert vol["generator"] == {"law": "slices", **dash["generator"]}
+    assert (vol["checks"], vol["limits"], vol["trace_seconds"]) == (
+        dash["checks"], dash["limits"], dash["trace_seconds"])
+    grafana, producers, reader = vol["workers"]
+    # first, so that the harness reads its answer to a phase first
+    assert grafana == {"role": "grafana", "count": 1}
+    assert producers == {
+        "role": "producer", "count": 4, "preload_blocks": 105,
+        "warm_blocks": 1, "prepared_blocks": 116, "probe_blocks": 4,
+        "schedule": {"period_s": 32.0, "stagger_s": 8.0}}
+    # preload, warm-up, a window of up to 96 s, the probes
+    assert producers["prepared_blocks"] >= 105 + 1 + 3 + 4
+    # one block every 8 s at the manager: the documented 4,000 records/s
+    old = dash["workers"][0]
+    sched = producers["schedule"]
+    assert sched["period_s"] / producers["count"] == sched["stagger_s"] \
+        == old["schedule"]["period_s"] / old["count"] == 8.0
+    assert 32000 / sched["stagger_s"] == 4000
+    assert (reader["role"], reader["count"]) == ("reader", 1)
+    # a data second holds 32,000 rows here and 8,000 there
+    want = _reader_panels(dash)
+    got = _reader_panels(vol)
+    assert list(got) == list(want) == [
+        "homepage", "flow_records", "pod_to_pod", "pod_to_service",
+        "pod_to_external", "node_to_node", "networkpolicy",
+        "network_topology"]
+    offsets = {}
+    for name, (path, closed, q) in got.items():
+        old_path, old_closed, old_q = want[name]
+        assert (path, closed) == (old_path, old_closed)
+        assert {k: v for k, v in q.items() if k not in ("start", "end")} \
+            == {k: v for k, v in old_q.items()
+                if k not in ("start", "end")}
+        if "start" in q:
+            assert (q["end"] - q["start"]) * 32000 \
+                == (old_q["end"] - old_q["start"]) * 8000
+            offsets[name] = (q["start"] - DEFAULT_START,
+                             q["end"] - DEFAULT_START)
+    assert offsets == {
+        "flow_records": (96, 128), "pod_to_pod": (388, 420),
+        "pod_to_service": (404, 420), "pod_to_external": (66, 82),
+        "node_to_node": (388, 420), "networkpolicy": (64, 96),
+        "network_topology": (96, 104)}
+    # every range lies inside the preloaded seconds; the old ones inside
+    # the rehearsal's 128, and one of them cuts the 4 s parts
+    assert all(0 <= a < b <= 4 * producers["preload_blocks"]
+               for a, b in offsets.values())
+    assert offsets["pod_to_external"][0] % 4 \
+        and offsets["pod_to_external"][1] % 4
+
+    cfg = BENCH.config("theia-default-vol-1x1")
+    trim = BENCH.config("theia-default-trim-1x1")
+    entry = next(c for c in BENCH.doc["configs"]
+                 if c["name"] == cfg["name"])
+    # appended: the ninth configuration, the tenth cell
+    assert BENCH.doc["configs"][8] is entry
+    assert entry["reduced"] == ["checkpoint_interval_s"]
+    assert entry["source"] == cfg["source"] and len(cfg["source"]) <= 200
+    assert (cfg["env"], cfg["manager_args"], cfg["expect"]) == (
+        {}, trim["manager_args"], trim["expect"])
+    assert cfg["monitor"] == {**trim["monitor"],
+                              "note": cfg["monitor"]["note"]}
+    assert cfg["capacity_bytes"] == cfg["source_capacity_bytes"] == 8 << 30
+    assert {"exactly_once", "durability", "readable", "panel_exact",
+            "range_contract"} == set(cfg["guarantees"])
+    assert {"ttl", "capacity_counted_in", "data_clock", "streams",
+            "panel_ranges", "refresh"} <= set(cfg["assumed"])
+    # rows are not cut: 89.7 % of the trigger at the window's open and
+    # 94.6 % after the last probe block, so no round trims in a run
+    trigger = cfg["capacity_bytes"] * cfg["monitor"]["threshold"] // 284
+    assert cfg["retained_window_rows"] == trigger == 15123124
+    at_open = producers["count"] * 32000 * (
+        producers["preload_blocks"] + producers["warm_blocks"])
+    at_end = at_open + 32000 * (7 + producers["count"]
+                                * producers["probe_blocks"])
+    assert (at_open, at_end) == (13568000, 14304000)
+    assert round(100 * at_open / trigger, 1) == 89.7
+    assert round(100 * at_end / trigger, 1) == 94.6
+
+    cell = BENCH.cell(VOL_CELL)
+    assert BENCH.doc["workloads"][9] is cell
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        cfg["name"], "dashboards-volume", 1)
+    assert len(cell["why"]) <= 200
+    # appended to every list the present cell is on, and to no other;
+    # `per_layer` is at its 128 entries, so the two new readers are
+    # files without an entry
+    for section in ("end_to_end", "per_layer"):
+        assert [m["name"] for m in BENCH.metrics_of(VOL_CELL, section)] \
+            == [m["name"] for m in BENCH.metrics_of(DASH_CELL, section)]
+    for m in BENCH.doc["end_to_end"] + BENCH.doc["per_layer"]:
+        if VOL_CELL in m.get("workloads", []):
+            assert m["workloads"][-2:] == [DASH_CELL, VOL_CELL]
+    assert len(BENCH.doc["per_layer"]) == 128
+    assert not {"dash.parts_read", "dash.parts_pruned"} & {
+        m["name"] for m in BENCH.doc["per_layer"]}
+
+
+def test_the_volume_cell_is_rehearsed_on_the_cpu_backend():
+    """The cell at `rehearsal.TINY_RETAINED` with no edit to it: the
+    range contract held in the preload phase, four producers, the
+    reader over the eight panels, the five checks (the three newest
+    ranges lie beyond the rehearsal's 128 seconds and answer empty,
+    equal to the reference's), every host-side reader of a traced run;
+    plumbing only, no number of it is a result."""
+    from benchmarks import selftest
+
+    plain, traced = selftest.rehearse(BENCH.cell(VOL_CELL), trace=True)
+    for out in (plain, traced):
+        assert out["correct"] and out["failed"] == 0
+        assert {"acks_not_whole", "store_rows_gap", "store_octets_gap",
+                "detector_series_gap", "alert_probe_block_gap",
+                "panel_requests_failed",
+                "panels_changed_over_closed_range",
+                "panels_differ_from_reference"} <= set(out["checks"])
+    assert set(plain["metrics"]) == {"dashboard_panel_p50_ms", "setup_s"}
+    got = {k: v["value"] for k, v in traced["metrics"].items()}
+    assert {"dash.slowest_panel_p50_ms", "dash.server_ms_networkpolicy",
+            "dash.server_ms_pod_to_pod", "dash.scan_ms",
+            "dash.aggregate_ms", "dash.encode_ms", "dash.rows_scanned",
+            "dash.scan_cpu_ms", "dash.aggregate_cpu_ms",
+            "dash.scan_faults", "preload_s", "warmup_s"} <= set(got)
+    assert got["dash.rows_scanned"] > 0
+
+
+def test_the_parts_readers_reduce_to_a_windows_figures():
+    """`dash.parts_read` and `dash.parts_pruned` (PR 51; readers only:
+    `per_layer` is at its 128 entries, so a `benchmark` PR declares
+    them) read the program's own exposition around a window's panel
+    requests: the batches of `flows` and the parts of the views that
+    the reads opened and those they skipped by their bounds, over all
+    tables; nothing from a manager without the family (the parent)."""
+    from benchmarks import prom
+    from theia_tpu.dashboards import queries
+    from theia_tpu.data.synth import SynthConfig, generate_flows
+    from theia_tpu.obs import prom as exposition
+    from theia_tpu.store import FlowDatabase
+
+    names = ("dash.parts_read", "dash.parts_pruned")
+    readers = {name: BENCH.reader("per_layer", name) for name in names}
+    pattern = BENCH.reader("per_layer", "dash.rows_scanned")
+    for name, reader in readers.items():
+        assert {k: reader[k] for k in ("source", "layer", "moves")} \
+            == {k: pattern[k] for k in ("source", "layer", "moves")}
+        assert (reader["reduce"], reader["series"], reader["where"]) == (
+            "counter_rise_where", "theia_dashboard_parts_total",
+            {"how": name.rsplit("_", 1)[1]})
+
+    t0 = 1_700_000_000
+    db = FlowDatabase()
+    for i in range(20):         # twenty blocks of four seconds each
+        db.insert_flows(generate_flows(SynthConfig(
+            n_series=8, points_per_series=4, start_time=t0 + 4 * i,
+            seed=1)))
+    before = prom.parse(exposition.render())
+    # a view over two blocks' seconds, flows over one block's and a
+    # half, and the page without a range
+    queries.panel_json(db, "pod_to_pod", {"start": str(t0 + 4),
+                                          "end": str(t0 + 12)})
+    queries.panel_json(db, "network_topology", {"start": str(t0 + 14),
+                                                "end": str(t0 + 20)})
+    queries.panel_json(db, "homepage", {})
+    data = {"metrics_before": before,
+            "metrics_after": prom.parse(exposition.render())}
+
+    def read(name):
+        reader = readers[name]
+        return extend.resolve("reduction", reader["reduce"])(data, reader)
+
+    assert read("dash.parts_read") == 2 + 2 + 20
+    assert read("dash.parts_pruned") == 18 + 18 + 0
+    rows = BENCH.reader("per_layer", "dash.rows_scanned")
+    assert extend.resolve("reduction", rows["reduce"])(data, rows) \
+        == 2 * 32 + 2 * 32 + 20 * 32
+    for side in data.values():
+        for key in [k for k in side
+                    if k.startswith("theia_dashboard_parts_total")]:
+            del side[key]
+    assert read("dash.parts_read") is None
+    assert read("dash.parts_pruned") is None
+
+
+def test_an_export_that_sends_no_range_ends_the_run_in_the_preload():
+    """The parent commit's export carries `urlPath` only: the role
+    must end its worker in the preload phase with a message that names
+    the panel, as it must for a dashboard the manager does not export
+    and for a range on `homepage`; this commit's own export passes."""
+    import http.server
+    import json
+    import threading
+
+    from theia_tpu.dashboards import grafana_dashboard
+
+    served = {}
+
+    class Canned(http.server.BaseHTTPRequestHandler):
+        def do_GET(self):                                # noqa: N802
+            name = self.path.split("?")[0].rsplit("/", 1)[1]
+            assert self.path.endswith("?format=grafana")
+            status, doc = served.get(name, (404, {}))
+            body = json.dumps(doc).encode()
+            self.send_response(status)
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
+
+        def log_message(self, *args):
+            pass
+
+    def without_params(doc):
+        doc = json.loads(json.dumps(doc))
+        for panel in doc["panels"]:
+            for target in panel["targets"]:
+                target.pop("params", None)
+        return doc
+
+    httpd = http.server.HTTPServer(("127.0.0.1", 0), Canned)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    try:
+        extend.use(manifest.HERE)
+        traffic = BENCH.traffic("dashboards-volume")
+        role = extend.resolve("role", "grafana")({
+            "addr": f"http://127.0.0.1:{httpd.server_address[1]}",
+            "traffic": traffic})
+        assert role.ranged == {
+            name: name != "homepage" for name in _reader_panels(traffic)}
+        ours = {name: (200, grafana_dashboard(name))
+                for name in role.ranged}
+        served.update(ours)
+        assert role.handle(["preload"]) == {
+            "event": "preloaded", "records": [], "dashboards": 8,
+            "targets": sum(len(p["targets"]) for _, d in ours.values()
+                           for p in d["panels"])}
+        assert role.handle(["warm"]) == {"event": "warmed", "records": []}
+        assert role.handle(["run", "0", "1"]) == {"event": "done",
+                                                 "records": []}
+        assert role.handle(["probe", "4"]) == {"event": "probed",
+                                               "records": []}
+        # the parent's export
+        served.update({n: (200, without_params(d))
+                       for n, (_, d) in ours.items()})
+        with pytest.raises(SystemExit, match="dashboard flow_records, "
+                           "panel 'Flow records'.*start=None"):
+            role.handle(["preload"])
+        served.update(ours)
+        half = json.loads(json.dumps(ours["pod_to_pod"][1]))
+        half["panels"][1]["targets"][0]["params"][1][1] = "${__to}"
+        served["pod_to_pod"] = (200, half)
+        with pytest.raises(SystemExit, match="dashboard pod_to_pod, "
+                           "panel 'Throughput'.*end='\\$\\{__to\\}'"):
+            role.handle(["preload"])
+        served.update(ours)
+        home = json.loads(json.dumps(ours["homepage"][1]))
+        home["panels"][0]["targets"][0]["params"] = [["start", "1"]]
+        served["homepage"] = (200, home)
+        with pytest.raises(SystemExit, match="dashboard homepage.*"
+                           "carry start where the panel takes no range"):
+            role.handle(["preload"])
+        served.update(ours)
+        del served["networkpolicy"]
+        with pytest.raises(SystemExit, match="networkpolicy.*404"):
+            role.handle(["preload"])
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+    # a traffic file that asks a panel with half a range is broken
+    broken = json.loads(json.dumps(traffic))
+    broken["workers"][2]["panels"][2]["path"] = \
+        "/dashboards/api/pod_to_pod?start=1"
+    with pytest.raises(SystemExit, match="pod_to_pod"):
+        extend.resolve("role", "grafana")({"addr": "http://127.0.0.1:1",
+                                           "traffic": broken})

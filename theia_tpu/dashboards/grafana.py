@@ -10,12 +10,21 @@ and a JSON API datasource) can import the export and point it at this
 manager's `/dashboards/api/<name>` endpoints, which serve the
 underlying data.
 
+Every target of a dashboard whose query takes a range (`start`, `end`:
+all but `homepage`) sends the dashboard's time range with its request,
+as the reference's panels put `$__timeFilter` into their SQL: the JSON
+API datasource appends `params` to the URL, and Grafana fills
+`${__from:date:seconds}` / `${__to:date:seconds}` from the time picker.
+A target without them would ask for the whole store at every refresh,
+whatever the picker says.
+
 Served as `GET /dashboards/api/<name>?format=grafana`.
 """
 
 from __future__ import annotations
 
 import hashlib
+import inspect
 from typing import Dict, List
 
 from . import queries
@@ -69,6 +78,14 @@ def _uid(name: str) -> str:
     return "theia-" + hashlib.sha1(name.encode()).hexdigest()[:8]
 
 
+#: the dashboard's time range as a ranged panel's query parameters
+#: (the JSON API datasource's `params`: [name, value] pairs, the
+#: values Grafana's global variables for the picker's range in epoch
+#: seconds)
+RANGE_PARAMS = (("start", "${__from:date:seconds}"),
+                ("end", "${__to:date:seconds}"))
+
+
 def grafana_dashboard(name: str) -> Dict[str, object]:
     """One dashboard as a Grafana-importable JSON document. A
     dashboard present in queries.DASHBOARDS but without a curated
@@ -78,10 +95,22 @@ def grafana_dashboard(name: str) -> Dict[str, object]:
         raise KeyError(name)
     layout = _PANELS.get(
         name, [(name.replace("_", " "), "table", "")])
+    accepted = inspect.signature(queries.DASHBOARDS[name]).parameters
+    params = [[k, v] for k, v in RANGE_PARAMS if k in accepted]
     panels = []
     y = 0
     for i, (title, ptype, field) in enumerate(layout):
         h, w = (10, 12) if ptype != "table" else (16, 24)
+        target = {
+            "refId": "A",
+            # the JSON API datasource fetches this path relative
+            # to its configured base URL (the manager address)
+            "urlPath": f"/dashboards/api/{name}",
+            "fields": [{"jsonPath": f"$.data.{field}" if field
+                        else "$.data"}],
+        }
+        if params:
+            target["params"] = params
         panels.append({
             "id": i + 1,
             "title": title,
@@ -90,14 +119,7 @@ def grafana_dashboard(name: str) -> Dict[str, object]:
                         "x": (i % 2) * 12, "y": y},
             "datasource": {"type": "marcusolsson-json-datasource",
                            "uid": "theia-manager"},
-            "targets": [{
-                "refId": "A",
-                # the JSON API datasource fetches this path relative
-                # to its configured base URL (the manager address)
-                "urlPath": f"/dashboards/api/{name}",
-                "fields": [{"jsonPath": f"$.data.{field}" if field
-                            else "$.data"}],
-            }],
+            "targets": [target],
         })
         if i % 2 == 1:
             y += h

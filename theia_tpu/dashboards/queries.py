@@ -29,7 +29,7 @@ import numpy as np
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..store import FlowDatabase
-from ..utils.native import group_reduce
+from ..store.views import read_tally
 
 FLOW_TYPE_TO_EXTERNAL = 3
 
@@ -47,49 +47,85 @@ _M_AGGREGATE = _M_STAGE.labels(stage="aggregate")
 _M_ENCODE = _M_STAGE.labels(stage="encode")
 _M_ROWS = _metrics.counter(
     "theia_dashboard_rows_scanned_total",
-    "Rows of the scans behind dashboard panels (per panel: the "
-    "`rows` attribute of its dashboard.panel span)")
+    "Rows of the reads behind dashboard panels: the rows of the "
+    "batches and view parts a read opened, before its mask (per "
+    "panel: the `rows` attribute of its dashboard.panel span)")
+_M_PARTS = _metrics.counter(
+    "theia_dashboard_parts_total",
+    "Batches of `flows` and parts of a materialized view that the "
+    "reads behind dashboard panels met, by what the read did with "
+    "them: `read` (opened; its rows are in "
+    "theia_dashboard_rows_scanned_total) or `pruned` (skipped unread "
+    "by its cached bounds of flowEndSeconds)",
+    labelnames=("table", "how"))
 
 
-def _scanned(batch):
-    """Count one scan's rows, in total and on the enclosing span."""
-    n = len(batch)
-    _M_ROWS.inc(n)
+def _scanned(table: str, seen: Mapping[str, int]) -> None:
+    """Count one read (a table's or a view's `last_read()`): its rows
+    in total and on the enclosing span, its parts by what became of
+    them."""
+    _M_ROWS.inc(seen["rows"])
+    _M_PARTS.labels(table=table, how="read").inc(seen["read"])
+    _M_PARTS.labels(table=table, how="pruned").inc(seen["pruned"])
     sp = _trace.current_span()
     if sp is not None:
-        sp.attrs["rows"] = sp.attrs.get("rows", 0) + n
-    return batch
+        sp.attrs["rows"] = sp.attrs.get("rows", 0) + seen["rows"]
 
 
-def _flows_scan(db):
+def _flows_pieces(db, columns, start=None, end=None):
+    """`columns` of the flows rows with `start <= flowEndSeconds < end`
+    as the batches they lie in (Table.pieces): for a panel that
+    reduces batch by batch and never needs the rows as one."""
     with _trace.stage("dash.scan", _M_SCAN):
-        return _scanned(db.flows.scan())
+        out = db.flows.pieces(start, end, "flowEndSeconds",
+                              "flowEndSeconds", columns)
+        _scanned("flows", db.flows.last_read())
+        return out
 
 
-def _view_scan(db, name: str):
+def _flows_select(db, columns, start=None, end=None):
+    """The same rows as one batch, in append order (Table.select)."""
     with _trace.stage("dash.scan", _M_SCAN):
-        return _scanned(_view_batch(db, name))
+        batch = db.flows.select(start, end, "flowEndSeconds",
+                                "flowEndSeconds", columns)
+        _scanned("flows", db.flows.last_read())
+        return batch
 
 
-def _view_batch(db, name: str):
-    """One materialized view in the ViewTable.scan() shape, routed by
-    THEIA_DASHBOARD_ROLLUP: unset/0 reads the legacy in-memory view
-    table; `1` reads the rollup-backed `__rollup__:<view>` aggregate
-    parts (query/rollup.py — the view must be declared, e.g. via
+def _view_scan(db, name: str, columns, start=None, end=None):
+    with _trace.stage("dash.scan", _M_SCAN):
+        batch, seen = _view_batch(db, name, columns, start, end)
+        _scanned(name, seen)
+        return batch
+
+
+def _view_batch(db, name: str, columns, start, end):
+    """One materialized view in the ViewTable.scan() shape and what
+    the read opened, routed by THEIA_DASHBOARD_ROLLUP: unset/0 reads
+    the legacy in-memory view table, the panel's range and columns
+    pushed down (ViewTable.select: only the parts the range touches,
+    only the asked sums summed); `1` reads
+    the rollup-backed `__rollup__:<view>` aggregate parts whole
+    (query/rollup.py — the view must be declared, e.g. via
     THEIA_ROLLUP_DEFAULTS=1, else legacy serves); `assert` reads the
     rollup path AND verifies it group-for-group against the legacy
-    table (the migration parity gate — raises on divergence)."""
+    table (the migration parity gate — raises on divergence). The
+    panel masks on its range either way."""
+    def legacy():
+        view = db.views[name]
+        return view.select(start, end, columns), view.last_read()
+
     mode = os.environ.get("THEIA_DASHBOARD_ROLLUP",
                           "").strip().lower()
     if mode in ("", "0", "off", "false", "no"):
-        return db.views[name].scan()
+        return legacy()
     from ..query import rollup as _rollup
     batch = _rollup.view_scan_batch(db, name)
     if batch is None:
-        return db.views[name].scan()
+        return legacy()
     if mode == "assert":
         _rollup.assert_view_parity(batch, db.views[name].scan(), name)
-    return batch
+    return batch, dict(read_tally(), rows=len(batch))
 
 # NetworkPolicy rule-action codes (reference schema: 0 none, 1 allow,
 # 2 drop, 3 reject) — single source for every dashboard consumer.
@@ -97,14 +133,33 @@ RULE_ACTION_LABELS = {0: "none", 1: "allow", 2: "drop", 3: "reject"}
 DENY_RULE_ACTIONS = (2, 3)
 
 
-def _top_links(keys: np.ndarray, values: np.ndarray, names_a, names_b,
-               k: int) -> List[Dict[str, object]]:
-    """Aggregate (a, b) → sum(value), return the top-k as sankey links."""
-    gk, gv = group_reduce(keys, values[:, None])
-    order = np.argsort(-gv[:, 0])[:k]
-    return [{"source": str(names_a[gk[i, 0]]),
-             "target": str(names_b[gk[i, 1]]),
-             "value": int(gv[i, 0])} for i in order]
+def _distinct(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """`np.unique(x, return_inverse=True)` of non-empty integers. Where
+    they lie close together (dictionary codes, the seconds of a range,
+    a pair of codes packed into a word) they are counted in place, one
+    pass and no sort; a span far wider than its rows is sorted."""
+    lo, hi = int(x.min()), int(x.max())
+    if hi - lo > 4 * len(x) + 1024:
+        return np.unique(x, return_inverse=True)
+    x = x - lo
+    met = np.bincount(x, minlength=hi - lo + 1) > 0
+    return np.flatnonzero(met) + lo, (np.cumsum(met) - 1)[x]
+
+
+def _top_links(a: np.ndarray, b: np.ndarray, values: np.ndarray,
+               names_a, names_b, k: int) -> List[Dict[str, object]]:
+    """Aggregate (a, b) → sum(value), return the top-k as sankey links.
+    The two codes (below 2**31) are packed into one word, so the groups
+    come in (a, b) order from one pass over it."""
+    if len(a) == 0:
+        return []
+    span = int(b.max()) + 1
+    pairs, inv = _distinct(a * span + b)
+    sums = np.zeros(len(pairs), np.int64)
+    np.add.at(sums, inv, values)
+    return [{"source": str(names_a[pairs[i] // span]),
+             "target": str(names_b[pairs[i] % span]),
+             "value": int(sums[i])} for i in np.argsort(-sums)[:k]]
 
 
 def _decode_table(dicts, name):
@@ -130,10 +185,10 @@ def _throughput_series(times: np.ndarray, groups: np.ndarray,
     if len(times) == 0:
         return {"times": [], "series": {}}
     values = np.asarray(values, np.float64)
-    uniq_g, g_inv = np.unique(groups, return_inverse=True)
+    uniq_g, g_inv = _distinct(groups)
     totals = np.bincount(g_inv, weights=values)
     top = np.argsort(-totals)[:k]
-    t_axis, t_inv = np.unique(times, return_inverse=True)
+    t_axis, t_inv = _distinct(times)
     series = {}
     for gi in top:
         sel = g_inv == gi
@@ -143,53 +198,99 @@ def _throughput_series(times: np.ndarray, groups: np.ndarray,
     return {"times": t_axis.tolist(), "series": series}
 
 
+#: homepage's stats of distinct values: (stat, the column counted)
+_HOME_DISTINCT = (("podCount", "sourcePodName"),
+                  ("namespaceCount", "sourcePodNamespace"),
+                  ("nodeCount", "sourceNodeName"),
+                  ("serviceCount", "destinationServicePortName"),
+                  ("clusterCount", "clusterUUID"))
+#: all that homepage reads of a flows row
+_HOME_COLUMNS = tuple(col for _, col in _HOME_DISTINCT) + (
+    "octetDeltaCount", "throughput", "timeInserted", "flowEndSeconds",
+    "ingressNetworkPolicyRuleAction", "egressNetworkPolicyRuleAction")
+
+
+def _grown(acc: np.ndarray, n: int) -> np.ndarray:
+    """`acc` with at least `n` cells, the new ones zero."""
+    if len(acc) >= n:
+        return acc
+    out = np.zeros(max(n, 2 * len(acc)), acc.dtype)
+    out[:len(acc)] = acc
+    return out
+
+
 def homepage(db: FlowDatabase) -> Dict[str, object]:
     """Cluster summary (reference homepage.json: 12 stat panels +
     bargauge of top namespaces + cluster-throughput timeseries +
-    dashlist — the dashlist is the nav bar on every page)."""
-    flows = _flows_scan(db)
+    dashlist — the dashlist is the nav bar on every page). It takes no
+    range, so it reads every batch of `flows`; each is reduced where
+    it lies (counts, integer sums, which codes were met) and only the
+    reductions are gathered: the table is never copied."""
     out: Dict[str, object] = {
-        "flowCount": len(flows),
+        "flowCount": 0,
         "tadAnomalies": 0,
         "recommendations": 0,
         "droppedFlowCount": 0,
         "topNamespaces": [],
         "throughput": {"times": [], "series": {}},
     }
-    if len(flows):
-        for stat, col in (("podCount", "sourcePodName"),
-                          ("namespaceCount", "sourcePodNamespace"),
-                          ("nodeCount", "sourceNodeName"),
-                          ("serviceCount", "destinationServicePortName"),
-                          ("clusterCount", "clusterUUID")):
-            codes = np.unique(np.asarray(flows[col]))
-            out[stat] = int((codes != 0).sum())
-        out["totalBytes"] = int(flows["octetDeltaCount"].sum())
-        out["currentThroughput"] = int(
-            flows["throughput"][flows["timeInserted"]
-                                == flows["timeInserted"].max()].sum())
-        ingress = np.asarray(flows["ingressNetworkPolicyRuleAction"])
-        egress = np.asarray(flows["egressNetworkPolicyRuleAction"])
-        out["droppedFlowCount"] = int(
-            (np.isin(ingress, DENY_RULE_ACTIONS)
-             | np.isin(egress, DENY_RULE_ACTIONS)).sum())
-        # bargauge: top namespaces by traffic volume
+    rows = total_bytes = dropped = newest_throughput = ns_codes = 0
+    newest = names = None
+    met = {col: np.zeros(0, bool) for _, col in _HOME_DISTINCT}
+    ns_octets = np.zeros(0, np.float64)
+    by_second: Dict[int, float] = {}
+    for flows in _flows_pieces(db, _HOME_COLUMNS):
+        rows += len(flows)
+        names = flows.dicts["sourcePodNamespace"]    # the table's own
+        for col, seen in met.items():
+            codes = np.asarray(flows[col])
+            met[col] = seen = _grown(seen, int(codes.max()) + 1)
+            seen[codes] = True
+        total_bytes += int(flows["octetDeltaCount"].sum())
+        inserted = np.asarray(flows["timeInserted"])
+        last = int(inserted.max())
+        if newest is None or last > newest:
+            newest, newest_throughput = last, 0
+        if last == newest:
+            newest_throughput += int(
+                flows["throughput"][inserted == last].sum())
+        dropped += int(
+            (np.isin(flows["ingressNetworkPolicyRuleAction"],
+                     DENY_RULE_ACTIONS)
+             | np.isin(flows["egressNetworkPolicyRuleAction"],
+                       DENY_RULE_ACTIONS)).sum())
         ns = np.asarray(flows["sourcePodNamespace"], np.int64)
-        octets = np.asarray(flows["octetDeltaCount"], np.float64)
-        names = flows.dicts["sourcePodNamespace"]
-        totals = np.bincount(ns, weights=octets)
-        if len(totals):
-            totals[0] = 0              # code 0 == '' (no namespace)
+        part = np.bincount(ns, weights=np.asarray(
+            flows["octetDeltaCount"], np.float64))
+        ns_codes = max(ns_codes, len(part))
+        ns_octets = _grown(ns_octets, len(part))
+        ns_octets[:len(part)] += part
+        seconds, inv = _distinct(np.asarray(flows["flowEndSeconds"]))
+        sums = np.bincount(inv, weights=np.asarray(
+            flows["throughput"], np.float64), minlength=len(seconds))
+        for sec, v in zip(seconds.tolist(), sums.tolist()):
+            by_second[sec] = by_second.get(sec, 0.0) + v
+    out["flowCount"] = rows
+    if rows:
+        for stat, col in _HOME_DISTINCT:
+            out[stat] = int(met[col][1:].sum())    # code 0 == ''
+        out["totalBytes"] = total_bytes
+        out["currentThroughput"] = newest_throughput
+        out["droppedFlowCount"] = dropped
+        # bargauge: top namespaces by traffic volume
+        totals = ns_octets[:ns_codes]
+        totals[0] = 0                  # code 0 == '' (no namespace)
         top = np.argsort(-totals)[:8]
         out["topNamespaces"] = [
             {"name": names.decode_one(int(g)), "value": int(totals[g])}
             for g in top if totals[g] > 0]
         # timeseries: cluster-wide throughput (one constant group)
-        out["throughput"] = _throughput_series(
-            np.asarray(flows["flowEndSeconds"], np.int64),
-            np.zeros(len(flows), np.int64),
-            np.asarray(flows["throughput"], np.int64),
-            {0: "cluster"}, 1)
+        times = sorted(by_second)
+        out["throughput"] = {
+            "times": times,
+            "series": {"cluster": np.asarray(
+                [by_second[t] for t in times],
+                np.float64).astype(np.int64).tolist()}}
     tad = db.tadetector.scan()
     if len(tad):
         out["tadAnomalies"] = int(
@@ -203,23 +304,23 @@ def flow_records(db: FlowDatabase, limit: int = 100,
                  start: Optional[int] = None,
                  end: Optional[int] = None) -> List[Dict[str, object]]:
     """Raw recent records (reference flow_records_dashboard.json:90)."""
-    flows = _flows_scan(db)
-    mask = _time_window(np.asarray(flows["flowEndSeconds"]), start, end)
-    sub = flows.filter(mask)
-    order = np.argsort(-np.asarray(sub["flowEndSeconds"]))[:limit]
     cols = ("flowEndSeconds", "sourcePodNamespace", "sourcePodName",
             "destinationPodNamespace", "destinationPodName",
             "destinationIP", "destinationTransportPort",
             "destinationServicePortName", "protocolIdentifier",
             "throughput", "octetDeltaCount",
             "ingressNetworkPolicyName", "egressNetworkPolicyName")
-    picked = sub.take(order).select(list(cols))
-    return picked.to_rows()
+    sub = _flows_select(db, cols, start, end)
+    order = np.argsort(-np.asarray(sub["flowEndSeconds"]))[:limit]
+    return sub.take(order).to_rows()
 
 
 def _pair_view(db: FlowDatabase, a_col: str, b_col: str,
                row_filter, k: int, start, end) -> Dict[str, object]:
-    view = _view_scan(db, "flows_pod_view")
+    view = _view_scan(
+        db, "flows_pod_view",
+        (a_col, b_col, "flowType", "flowEndSeconds", "throughput",
+         "octetDeltaCount"), start, end)
     mask = _time_window(np.asarray(view["flowEndSeconds"]), start, end)
     mask &= row_filter(view)
     a = np.asarray(view[a_col], np.int64)[mask]
@@ -230,8 +331,7 @@ def _pair_view(db: FlowDatabase, a_col: str, b_col: str,
     names_a = _decode_table(view.dicts, a_col)
     names_b = _decode_table(view.dicts, b_col)
 
-    links = _top_links(np.stack([a, b], axis=1), octets,
-                       names_a, names_b, k)
+    links = _top_links(a, b, octets, names_a, names_b, k)
     ts = _throughput_series(t, a, thr, names_a, k)
     totals_a: Dict[str, int] = {}
     for code, v in zip(a.tolist(), octets.tolist()):
@@ -265,7 +365,10 @@ def pod_to_external(db: FlowDatabase, k: int = 10, start=None,
 
 
 def node_to_node(db: FlowDatabase, k: int = 10, start=None, end=None):
-    view = _view_scan(db, "flows_node_view")
+    view = _view_scan(
+        db, "flows_node_view",
+        ("sourceNodeName", "destinationNodeName", "flowEndSeconds",
+         "throughput", "octetDeltaCount"), start, end)
     mask = _time_window(np.asarray(view["flowEndSeconds"]), start, end)
     mask &= (np.asarray(view["sourceNodeName"]) != 0) \
         & (np.asarray(view["destinationNodeName"]) != 0)
@@ -276,15 +379,18 @@ def node_to_node(db: FlowDatabase, k: int = 10, start=None, end=None):
     t = np.asarray(view["flowEndSeconds"], np.int64)[mask]
     names_a = _decode_table(view.dicts, "sourceNodeName")
     names_b = _decode_table(view.dicts, "destinationNodeName")
-    return {"links": _top_links(np.stack([a, b], axis=1), octets,
-                                names_a, names_b, k),
+    return {"links": _top_links(a, b, octets, names_a, names_b, k),
             "throughput": _throughput_series(t, a, thr, names_a, k)}
 
 
 def networkpolicy(db: FlowDatabase, k: int = 10, start=None, end=None):
     """Policy traffic chord (reference networkpolicy_dashboard.json):
     bytes per (egress policy, ingress policy) pair + allow/deny split."""
-    view = _view_scan(db, "flows_policy_view")
+    view = _view_scan(
+        db, "flows_policy_view",
+        ("egressNetworkPolicyName", "ingressNetworkPolicyName",
+         "egressNetworkPolicyRuleAction", "flowEndSeconds",
+         "octetDeltaCount"), start, end)
     mask = _time_window(np.asarray(view["flowEndSeconds"]), start, end)
     eg = np.asarray(view["egressNetworkPolicyName"], np.int64)[mask]
     ing = np.asarray(view["ingressNetworkPolicyName"], np.int64)[mask]
@@ -294,8 +400,8 @@ def networkpolicy(db: FlowDatabase, k: int = 10, start=None, end=None):
     names_e = _decode_table(view.dicts, "egressNetworkPolicyName")
     names_i = _decode_table(view.dicts, "ingressNetworkPolicyName")
     has_policy = (eg != 0) | (ing != 0)
-    links = _top_links(np.stack([eg[has_policy], ing[has_policy]], axis=1), octets[has_policy],
-                       names_e, names_i, k)
+    links = _top_links(eg[has_policy], ing[has_policy],
+                       octets[has_policy], names_e, names_i, k)
     by_action: Dict[str, int] = {}
     for act, v in zip(eg_act.tolist(), octets.tolist()):
         label = RULE_ACTION_LABELS.get(act, str(act))
@@ -308,13 +414,13 @@ def networkpolicy(db: FlowDatabase, k: int = 10, start=None, end=None):
 def network_topology(db: FlowDatabase, start=None, end=None):
     """Namespace-level dependency edges (reference
     network_topology_dashboard's mermaid graph, DependencyPanel.tsx)."""
-    flows = _flows_scan(db)
-    mask = _time_window(np.asarray(flows["flowEndSeconds"]), start, end)
-    src = np.asarray(flows["sourcePodNamespace"], np.int64)[mask]
-    dst_ns = np.asarray(flows["destinationPodNamespace"],
-                        np.int64)[mask]
-    ftype = np.asarray(flows["flowType"])[mask]
-    octets = np.asarray(flows["octetDeltaCount"], np.int64)[mask]
+    flows = _flows_select(
+        db, ("sourcePodNamespace", "destinationPodNamespace",
+             "flowType", "octetDeltaCount"), start, end)
+    src = np.asarray(flows["sourcePodNamespace"], np.int64)
+    dst_ns = np.asarray(flows["destinationPodNamespace"], np.int64)
+    ftype = np.asarray(flows["flowType"])
+    octets = np.asarray(flows["octetDeltaCount"], np.int64)
     names = _decode_table(flows.dicts, "sourcePodNamespace")
     dst_names = _decode_table(flows.dicts, "destinationPodNamespace")
 

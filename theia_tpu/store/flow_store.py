@@ -71,7 +71,8 @@ from ..utils.faults import fire as _fire_fault
 from ..utils.logging import get_logger
 from ..utils.pool import get_pool
 from ..utils.rounds import AskedRounds
-from .views import MATERIALIZED_VIEWS, ViewTable
+from .views import (MATERIALIZED_VIEWS, ViewTable, read_tally,
+                    window_fate)
 from ..analysis.lockdep import named_lock
 
 _logger = get_logger("store")
@@ -248,14 +249,21 @@ class Table:
         self._adopt_maps: Dict[str, DictionaryMapper] = {
             name: DictionaryMapper(d) for name, d in self.dicts.items()}
         self._adopt_lock = named_lock("store.table_adopt")
-        # Cached per-batch (min, max) of the time column, aligned with
-        # _batches: TTL's min_value() probe runs per insert and the
-        # retention boundary runs per monitor round — both become
-        # O(batches) metadata walks instead of O(rows) column scans.
+        # Cached per-batch {column: (min, max)} of the time columns the
+        # schema has (TIME_BOUND_COLUMNS), aligned with _batches. TTL's
+        # min_value() probe runs per insert and the retention boundary
+        # per monitor round: both are O(batches) metadata walks instead
+        # of O(rows) column scans; a delete by time drops or keeps a
+        # batch whole and a ranged `select` skips a batch that cannot
+        # meet its window, none of them reading a row. A table with
+        # none of the columns keeps empty entries and masks every batch.
         self._time_column: Optional[str] = (
             "timeInserted" if any(c.name == "timeInserted"
                                   for c in schema) else None)
-        self._batch_meta: List[Tuple[int, int]] = []
+        self._bound_columns: Tuple[str, ...] = tuple(
+            c for c in self.TIME_BOUND_COLUMNS
+            if any(col.name == c for col in schema))
+        self._batch_bounds: List[Dict[str, Tuple[int, int]]] = []
         # Durability hook, installed by FlowDatabase.attach_wal:
         # called as hook(table_name, adopted, apply_fn) so the WAL can
         # journal the store-coded batch BEFORE apply_fn makes it
@@ -360,9 +368,7 @@ class Table:
                        nbytes: int) -> None:
         """Body of _append_adopted; caller holds self._lock."""
         self._batches.append(adopted)
-        if self._time_column is not None:
-            a = adopted[self._time_column]
-            self._batch_meta.append((int(a.min()), int(a.max())))
+        self._batch_bounds.append(self._bounds_of(adopted))
         self.generation += 1
         self.rows_inserted_total += len(adopted)
         self.bytes_inserted_total += nbytes
@@ -379,15 +385,23 @@ class Table:
         computes per-shard mask offsets under every shard's lock)."""
         return sum(len(b) for b in self._batches)
 
+    def _bounds_of(self, batch: ColumnarBatch
+                   ) -> Dict[str, Tuple[int, int]]:
+        """(min, max) of each cached column of one non-empty batch."""
+        return {c: (int(batch[c].min()), int(batch[c].max()))
+                for c in self._bound_columns}
+
+    @property
+    def _batch_meta(self) -> List[Tuple[int, int]]:
+        """The time column's (min, max) of each batch (none without
+        one)."""
+        col = self._time_column
+        return [] if col is None else [b[col] for b in self._batch_bounds]
+
     def _refresh_meta_locked(self) -> None:
         """Rebuild the per-batch time metadata after a bulk rewrite of
         _batches (delete paths — already O(kept rows))."""
-        if self._time_column is None:
-            return
-        self._batch_meta = [
-            (int(b[self._time_column].min()),
-             int(b[self._time_column].max()))
-            for b in self._batches]
+        self._batch_bounds = [self._bounds_of(b) for b in self._batches]
 
     def insert_rows(self, rows: Sequence[Mapping[str, object]]) -> int:
         if not rows:
@@ -418,11 +432,55 @@ class Table:
             # length and its last batch as they were)
             if self.generation == generation:
                 self._batches = [merged]
-                if self._time_column is not None:
-                    self._batch_meta = [
-                        (min(m[0] for m in self._batch_meta),
-                         max(m[1] for m in self._batch_meta))]
+                self._batch_bounds = [{
+                    c: (min(b[c][0] for b in self._batch_bounds),
+                        max(b[c][1] for b in self._batch_bounds))
+                    for c in self._bound_columns}]
         return merged
+
+    def pieces(self, start_time: Optional[int] = None,
+               end_time: Optional[int] = None,
+               time_column: str = "flowStartSeconds",
+               end_column: str = "flowEndSeconds",
+               columns: Optional[Sequence[str]] = None
+               ) -> List[ColumnarBatch]:
+        """The rows of `select`, as the batches they lie in: one piece
+        a batch that holds a row of the window, in append order, with
+        the asked columns only. The batches are walked as they lie: one
+        whose cached bounds cannot meet the window is skipped unread,
+        one that lies inside it is handed on as it is (its columns'
+        arrays, no copy), and only one that the window cuts is masked,
+        on its own. Nothing is concatenated and `_batches` is left as
+        it is. `last_read()` says what the walk opened."""
+        with self._lock:
+            batches = list(self._batches)
+            bounds = list(self._batch_bounds)
+        read = read_tally()
+        out: List[ColumnarBatch] = []
+        for batch, known in zip(batches, bounds):
+            fate = window_fate(start_time, end_time,
+                               known.get(time_column),
+                               known.get(end_column))
+            if fate is False:
+                read["pruned"] += 1
+                continue
+            read["read"] += 1
+            read["rows"] += len(batch)
+            mask = None
+            if fate is None:
+                mask = np.ones(len(batch), dtype=bool)
+                if start_time is not None:
+                    mask &= batch[time_column] >= start_time
+                if end_time is not None:
+                    mask &= batch[end_column] < end_time
+                if not mask.any():
+                    continue
+            if columns is not None:
+                batch = batch.select(columns)
+            out.append(batch if mask is None or mask.all()
+                       else batch.filter(mask))
+        self._last.read = read
+        return out
 
     def select(self, start_time: Optional[int] = None,
                end_time: Optional[int] = None,
@@ -435,18 +493,30 @@ class Table:
         policy_recommendation_job.py:796-798). `columns` projects the
         result to that subset (the window mask still evaluates on the
         full time columns) — the flat half of the parts engine's
-        column-subset read path, so query callers are engine-agnostic."""
-        data = self.scan()
-        if start_time is None and end_time is None:
-            return data if columns is None else data.select(columns)
-        mask = np.ones(len(data), dtype=bool)
-        if start_time is not None:
-            mask &= data[time_column] >= start_time
-        if end_time is not None:
-            mask &= data[end_column] < end_time
-        if columns is not None:
-            data = data.select(columns)
-        return data.filter(mask)
+        column-subset read path, so query callers are engine-agnostic.
+
+        The rows are `scan()`'s under the mask, in append order, but
+        the table is never concatenated for them: `pieces` skips the
+        batches outside the window by their cached bounds and gathers
+        the asked columns of the rest. Without a window and without
+        `columns` it is `scan()`."""
+        if start_time is None and end_time is None and columns is None:
+            return self.scan()
+        out = self.pieces(start_time, end_time, time_column, end_column,
+                          columns)
+        if not out:
+            dtypes = {c.name: c.host_dtype for c in self.schema}
+            return ColumnarBatch(
+                {n: np.zeros(0, dtypes[n])
+                 for n in (dtypes if columns is None else columns)},
+                self.dicts)
+        return out[0] if len(out) == 1 else ColumnarBatch.concat(out)
+
+    def last_read(self) -> Dict[str, int]:
+        """What the calling thread's last `pieces` / `select` opened:
+        batches `read` and `pruned` by their bounds, and the `rows` of
+        those read (before the mask)."""
+        return getattr(self._last, "read", None) or read_tally()
 
     def delete_where(self, mask: np.ndarray) -> int:
         """Delete rows matching `mask` over the current table contents.
@@ -517,9 +587,10 @@ class Table:
         rows stay in append order. A batch whose rows all stay is kept
         as the object it is, one whose rows all go is dropped whole,
         and only a batch that straddles the boundary is filtered, on
-        its own. For the time column the cached (min, max) decides
-        the first two without reading a row; for any other column (or
-        a table without a time column) the batch's own mask does.
+        its own. For a column whose (min, max) is cached a batch (the
+        time column; `TIME_BOUND_COLUMNS`) the pair decides the first
+        two without reading a row; for any other column the batch's
+        own mask does.
 
         The lock is held for the walk over the batches plus one
         `filter` of each straddling batch; what was dropped is
@@ -530,9 +601,8 @@ class Table:
         `generation` is bumped only if a row went; `bytes_trimmed_total`
         rises by the resident bytes of the rows that went, and
         `last_walk()` says what the walk did."""
-        timed = column == self._time_column
         batches: List[ColumnarBatch] = []
-        meta: List[Tuple[int, int]] = []
+        bounds: List[Dict[str, Tuple[int, int]]] = []
         walk = dict.fromkeys(TRIM_WALK, 0)
         deleted = freed = 0
         with self._lock:
@@ -540,12 +610,12 @@ class Table:
             # this frame, after the lock: their release is most of a
             # large round's time
             gone = self._batches
-            pairs = self._batch_meta or [None] * len(gone)
-            for batch, pair in zip(gone, pairs):
+            for batch, known in zip(gone, self._batch_bounds):
                 n = None
-                if timed:
-                    n = (0 if pair[0] >= boundary else
-                         len(batch) if pair[1] < boundary else None)
+                if column in known:
+                    lo, hi = known[column]
+                    n = (0 if lo >= boundary else
+                         len(batch) if hi < boundary else None)
                 if n is None:
                     mask = np.asarray(batch[column]) < boundary
                     n = int(np.count_nonzero(mask))
@@ -562,14 +632,11 @@ class Table:
                     walk["batchesCut"] += 1
                     walk["bytesCopied"] += copied
                     freed += whole - copied
-                    if pair is not None:
-                        t = batch[self._time_column]
-                        pair = (int(t.min()), int(t.max()))
+                    known = self._bounds_of(batch)
                 batches.append(batch)
-                if pair is not None:
-                    meta.append(pair)
+                bounds.append(known)
             if deleted:
-                self._batches, self._batch_meta = batches, meta
+                self._batches, self._batch_bounds = batches, bounds
                 self.generation += 1
                 self.bytes_trimmed_total += freed
         self._last.walk = walk
@@ -591,17 +658,20 @@ class Table:
                     ) -> Dict[str, Tuple[int, int]]:
         """{column: (min, max)} over the resident rows for the
         standard query-window columns — the heartbeat piggyback behind
-        cluster peer pruning (query/distributed.py). On this flat
-        engine it is an O(rows) numpy scan, so the caller throttles
-        (THEIA_CLUSTER_BOUNDS_INTERVAL); PartTable overrides with its
-        resident part metadata. Columns absent from the schema (or an
-        empty table) are omitted — 'unknown', never 'empty range'."""
+        cluster peer pruning (query/distributed.py). The standard
+        columns' pairs are cached a batch, so for them it is an
+        O(batches) walk; any other column is an O(rows) numpy scan
+        (the caller throttles: THEIA_CLUSTER_BOUNDS_INTERVAL);
+        PartTable overrides with its resident part metadata. Columns
+        absent from the schema (or an empty table) are omitted —
+        'unknown', never 'empty range'."""
         with self._lock:
-            batches = list(self._batches)
+            batches = list(zip(self._batches, self._batch_bounds))
         out: Dict[str, Tuple[int, int]] = {}
         for col in columns:
-            pairs = [(int(b[col].min()), int(b[col].max()))
-                     for b in batches if col in b and len(b)]
+            pairs = [known[col] if col in known
+                     else (int(b[col].min()), int(b[col].max()))
+                     for b, known in batches if col in b and len(b)]
             if pairs:
                 out[col] = (min(p[0] for p in pairs),
                             max(p[1] for p in pairs))
@@ -612,9 +682,10 @@ class Table:
         For the time column this is an O(batches) walk over cached
         per-batch minima — the TTL fast path runs it every insert."""
         with self._lock:
-            if column == self._time_column:
-                return (min(m[0] for m in self._batch_meta)
-                        if self._batch_meta else None)
+            if column in self._bound_columns:
+                return min((known[column][0]
+                            for known in self._batch_bounds),
+                           default=None)
             batches = list(self._batches)
         mins = [int(b[column].min()) for b in batches if len(b)]
         return min(mins) if mins else None
@@ -639,7 +710,7 @@ class Table:
     def truncate(self) -> None:
         with self._lock:
             self._batches = []
-            self._batch_meta = []
+            self._batch_bounds = []
             self.generation += 1
 
 

@@ -117,6 +117,7 @@ from ..utils.env import env_int
 from ..utils.logging import get_logger
 from . import wal as _wal
 from .flow_store import Table
+from .views import read_tally
 from ..analysis.lockdep import named_lock
 
 logger = get_logger("parts")
@@ -1128,6 +1129,9 @@ class PartTable(Table):
         _M_PRUNED.inc(len(parts) - len(live))
         if live:
             _M_SCANNED.inc(len(live))
+        self._last.read = {
+            "read": len(live) + len(mem), "pruned": len(parts) - len(live),
+            "rows": sum(p.rows for p in live) + sum(len(b) for b in mem)}
         out: List[ColumnarBatch] = []
         decoded = [self._decode_part(p, columns=decode_cols)
                    for p in live]
@@ -1151,6 +1155,22 @@ class PartTable(Table):
                 {c.name: np.zeros(0, c.host_dtype)
                  for c in schema}, self.dicts)
         return out[0] if len(out) == 1 else ColumnarBatch.concat(out)
+
+    def pieces(self, start_time: Optional[int] = None,
+               end_time: Optional[int] = None,
+               time_column: str = "flowStartSeconds",
+               end_column: str = "flowEndSeconds",
+               columns: Optional[Sequence[str]] = None
+               ) -> List[ColumnarBatch]:
+        """`Table.pieces` for this engine: its rows lie in encoded
+        parts, which `select` prunes, decodes and gathers itself, so
+        the window's rows come as one piece."""
+        self._last.read = None
+        batch = self.select(start_time, end_time, time_column,
+                            end_column, columns)
+        if self._last.read is None:        # `select` went to `scan()`
+            self._last.read = dict(read_tally(), rows=len(batch))
+        return [batch] if len(batch) else []
 
     # -- deletes -----------------------------------------------------------
 

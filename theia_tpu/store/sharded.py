@@ -49,6 +49,13 @@ def _shard_pool() -> concurrent.futures.ThreadPoolExecutor:
     return get_pool("shard-insert", min(8, os.cpu_count() or 1))
 
 
+def _summed_reads(shards: Sequence) -> Dict[str, int]:
+    """What the calling thread's last ranged read opened (a table's or
+    a view's `last_read()`), summed over the shards it asked in turn."""
+    reads = [s.last_read() for s in shards]
+    return {k: sum(r[k] for r in reads) for k in reads[0]}
+
+
 class DistributedTable:
     """Read/write facade over one table across all shards."""
 
@@ -113,6 +120,16 @@ class DistributedTable:
     def select(self, *a, **kw) -> ColumnarBatch:
         return ColumnarBatch.concat(
             [t.select(*a, **kw) for t in self.tables])
+
+    def pieces(self, *a, **kw) -> List[ColumnarBatch]:
+        """`Table.pieces` over the shards: one piece, since every
+        shard codes its strings by dictionaries of its own and only
+        `select`'s concatenation brings them to one."""
+        batch = self.select(*a, **kw)
+        return [batch] if len(batch) else []
+
+    def last_read(self) -> Dict[str, int]:
+        return _summed_reads(self.tables)
 
     def delete_where(self, mask: np.ndarray) -> int:
         """Delete by a mask over the scan() row order (shard order).
@@ -184,7 +201,23 @@ class DistributedView:
         """Concat shard views, then collapse identical group keys (the
         SummingMergeTree merge across shards happens at read time for
         Distributed views)."""
-        merged = ColumnarBatch.concat([v.scan() for v in self.views])
+        return self._collapsed([v.scan() for v in self.views])
+
+    def select(self, start: Optional[int] = None,
+               end: Optional[int] = None,
+               columns: Optional[Sequence[str]] = None) -> ColumnarBatch:
+        """`ViewTable.select` over the shards: each shard's rows of
+        the range, collapsed across shards (which takes every key),
+        then projected."""
+        batch = self._collapsed([v.select(start, end) for v in self.views])
+        return batch if columns is None else batch.select(
+            [c for c in batch.column_names if c in columns])
+
+    def last_read(self) -> Dict[str, int]:
+        return _summed_reads(self.views)
+
+    def _collapsed(self, batches: List[ColumnarBatch]) -> ColumnarBatch:
+        merged = ColumnarBatch.concat(batches)
         if len(merged) == 0:
             return merged
         keys = np.stack([np.asarray(merged[c], np.int64)
