@@ -575,7 +575,8 @@ def test_stale_waiver_reported():
 # -- the package's layering ----------------------------------------------
 
 #: the host's group-by: defined once, in ``theia_tpu/utils/native.py``
-GROUP_BY = {"group_reduce", "group_sum", "group_sum_fast"}
+GROUP_BY = {"group_reduce", "group_sum", "group_sum_fast",
+            "group_sum_exact"}
 
 #: (importing package, imported package, the modules that may): an
 #: arrow that points up. The one known exception is the store's two

@@ -21,6 +21,7 @@ from theia_tpu.schema import ColumnarBatch
 from theia_tpu.store import FlowDatabase
 from theia_tpu.store import views as views_mod
 from theia_tpu.store.views import MATERIALIZED_VIEWS, ViewTable
+from theia_tpu.utils.native import native_available
 from theia_tpu.store.wire import decode_block
 
 T0 = 1_700_000_000
@@ -40,6 +41,13 @@ RANGES = {
     "open_start": (None, 6),
     "open_end": (10, None),
 }
+
+
+def _regroup(rows):
+    """What `last_read()` says of a re-group of `rows` rows."""
+    return {"regrouped": rows,
+            "how": ("hash" if native_available() else "sort") if rows
+            else None}
 
 
 def _compacts(parts, opened):
@@ -116,8 +124,9 @@ def test_view_select_is_scan_then_mask(name, r, compacted):
     if _compacts(parts, opened):
         # most of the view: answered from its compaction, swapped in
         assert r in ("everything", "open_end")
-        assert seen == {"read": len(parts), "pruned": 0,
-                        "rows": sum(len(k) for k, _, _ in parts)}
+        rows = sum(len(k) for k, _, _ in parts)
+        assert seen == {"read": len(parts), "pruned": 0, "rows": rows,
+                        **_regroup(rows)}
         assert len(view._parts) == 1 and view._parts[0][2]
     else:
         # a small share: nothing swapped in, the walk's figures are
@@ -126,6 +135,13 @@ def test_view_select_is_scan_then_mask(name, r, compacted):
         assert all(a is b for a, b in zip(parts, view._parts))
         assert seen["rows"] == sum(len(k) for k in opened)
         assert seen["read"] == len(opened)
+        # one exact part answers as it is; several are re-grouped, the
+        # rows the range took of them
+        assert seen["regrouped"] <= seen["rows"]
+        assert (seen["how"] is None) == (seen["regrouped"] == 0)
+        assert (seen["regrouped"] > 0) == (
+            len(opened) > 1
+            or len(opened) == 1 and not all(p[2] for p in parts))
     whole = view.scan()
     want = whole.filter(_window(np.asarray(whole["flowEndSeconds"]),
                                 start, end))
@@ -137,8 +153,9 @@ def test_view_select_is_scan_then_mask(name, r, compacted):
     if r == "empty":
         assert len(got) == 0 and seen["read"] == 0
     if r == "aligned_to_parts" and not compacted:
+        rows = sum(len(k) for k, _, _ in parts[1:4])
         assert seen == {"read": 3, "pruned": len(STARTS) - 3,
-                        "rows": sum(len(k) for k, _, _ in parts[1:4])}
+                        "rows": rows, **_regroup(rows)}
     if r == "equal_keys_in_two_parts" and not compacted:
         # the block sent twice collapsed: half the rows, twice the sums
         assert len(got) * 2 == seen["rows"]
@@ -153,7 +170,9 @@ def test_view_select_is_scan_then_mask(name, r, compacted):
 def test_view_select_projects_to_the_asked_columns(name, r):
     """`columns` keeps those columns of the same rows: the rows are
     grouped by every key whichever are asked, and only the asked sums
-    are summed."""
+    are summed. (Column by column, row for row: a view's rows come in
+    no stated order, but two reads of the same parts in the same order
+    group them in the same order, whatever sums they carry.)"""
     view = _db().views[name]
     start, end = _bounds(r)
     asked = ("throughput", "flowEndSeconds", "clusterUUID",
@@ -190,7 +209,8 @@ def test_view_select_rejoins_a_hash_split_key(name, monkeypatch):
     view._bounds[1] = view._bounds_of(split[0])
     got = view.select(T0 + 4, T0 + 8)
     assert view.last_read() == {"read": 2, "pruned": len(STARTS) - 5,
-                                "rows": 2 * len(keys) + 5}
+                                "rows": 2 * len(keys) + 5,
+                                **_regroup(2 * len(keys) + 5)}
     assert len(got) == len(keys)
     total = (split[1].sum(axis=0) + view._parts[2][1].sum(axis=0))
     assert [int(got[c].sum()) for c in view.spec.sum_columns] \
@@ -213,14 +233,15 @@ def test_a_compaction_that_raced_a_delete_is_not_swapped_in(
         db.insert_flows(_block(start))
     view = db.views[name]
     boundary = T0 + 2
-    real, raced = views_mod.group_sum, []
+    real, raced = views_mod.group_sum_exact, []
 
-    def group_sum_after_a_delete(keys, values):
+    def group_sum_after_a_delete(parts):
         if not raced:       # between the read of the parts and the swap
             raced.append(view.delete_older_than(boundary))
-        return real(keys, values)
+        return real(parts)
 
-    monkeypatch.setattr(views_mod, "group_sum", group_sum_after_a_delete)
+    monkeypatch.setattr(views_mod, "group_sum_exact",
+                        group_sum_after_a_delete)
     count, last = len(view._parts), view._parts[-1]
     view.compact()
     assert raced and raced[0] > 0

@@ -58,15 +58,27 @@ _M_PARTS = _metrics.counter(
     "theia_dashboard_rows_scanned_total) or `pruned` (skipped unread "
     "by its cached bounds of flowEndSeconds)",
     labelnames=("table", "how"))
+_M_REGROUP = _metrics.counter(
+    "theia_dashboard_regroup_rows_total",
+    "Rows of a materialized view that the reads behind dashboard "
+    "panels re-grouped exactly (ViewTable.select: the rows a range "
+    "took of several parts, or the whole view where it compacted it; "
+    "none where one exact part answered), by how: `hash` (the native "
+    "pass that compares full keys) or `sort` (numpy's lexsort, "
+    "without the native library)",
+    labelnames=("table", "how"))
 
 
-def _scanned(table: str, seen: Mapping[str, int]) -> None:
+def _scanned(table: str, seen: Mapping[str, object]) -> None:
     """Count one read (a table's or a view's `last_read()`): its rows
     in total and on the enclosing span, its parts by what became of
-    them."""
+    them, a view's re-grouped rows by how."""
     _M_ROWS.inc(seen["rows"])
     _M_PARTS.labels(table=table, how="read").inc(seen["read"])
     _M_PARTS.labels(table=table, how="pruned").inc(seen["pruned"])
+    if seen.get("regrouped"):
+        _M_REGROUP.labels(table=table, how=seen["how"]).inc(
+            seen["regrouped"])
     sp = _trace.current_span()
     if sp is not None:
         sp.attrs["rows"] = sp.attrs.get("rows", 0) + seen["rows"]
@@ -337,7 +349,8 @@ def _pair_view(db: FlowDatabase, a_col: str, b_col: str,
     for code, v in zip(a.tolist(), octets.tolist()):
         key = str(names_a[code])
         totals_a[key] = totals_a.get(key, 0) + v
-    pie = sorted(totals_a.items(), key=lambda kv: -kv[1])[:k]
+    # ties by name: a view's rows come in no stated order
+    pie = sorted(totals_a.items(), key=lambda kv: (-kv[1], kv[0]))[:k]
     return {"links": links, "throughput": ts,
             "topSources": [{"name": n, "value": v} for n, v in pie]}
 

@@ -53,7 +53,10 @@ def _summed_reads(shards: Sequence) -> Dict[str, int]:
     """What the calling thread's last ranged read opened (a table's or
     a view's `last_read()`), summed over the shards it asked in turn."""
     reads = [s.last_read() for s in shards]
-    return {k: sum(r[k] for r in reads) for k in reads[0]}
+    summed = {k: sum(r[k] for r in reads) for k in reads[0] if k != "how"}
+    if "how" in reads[0]:      # a view's: one library serves every shard
+        summed["how"] = next((r["how"] for r in reads if r["how"]), None)
+    return summed
 
 
 class DistributedTable:

@@ -10,10 +10,16 @@ Re-provides the reference's three SummingMergeTree materialized views
 Semantics match ClickHouse: each *insert block* is grouped by the view's
 key columns with the metric columns summed (the MV GROUP BY runs per
 block); further collapsing of identical keys across blocks happens at
-"merge" time — here `compact()`, called automatically on read. All group
-keys are integers (dictionary codes for strings), so the per-block group-by
-is one lexsort + reduceat over fixed-width arrays — no Python-object work
-on the ingest path.
+"merge" time — here at read time: `scan()` compacts the view, a ranged
+`select()` re-groups only the rows it takes. All group keys are integers
+(dictionary codes for strings), so both group-bys are one native hash
+pass over fixed-width arrays that compares the full key on every hash
+match (`utils/native.py`: `native_group_sum` an insert block,
+`group_sum_exact` a read; numpy's hash-sort and lexsort without the
+library) — no Python-object work on either path. A read returns the view
+grouped exactly, **in no stated order** (a `SELECT` without `ORDER BY`):
+the order is deterministic for given parts in a given order and nothing
+more; a consumer that needs one sorts.
 """
 
 from __future__ import annotations
@@ -26,7 +32,8 @@ import numpy as np
 
 from ..schema import ColumnarBatch, StringDictionary
 from ..analysis.lockdep import named_lock
-from ..utils.native import group_sum, group_sum_fast, native_group_sum
+from ..utils.native import (group_sum_exact, group_sum_fast,
+                            native_group_sum)
 
 
 def materialize_view_batch(spec: "ViewSpec", keys: np.ndarray,
@@ -150,10 +157,10 @@ class ViewTable:
         # same dictionaries.
         self.dicts = dicts
         # Parts are (keys, values, exact). `exact` records whether the
-        # part is known collision-free (native memcmp grouping, or a
-        # read-time lexsort compaction); group_sum_fast parts are not —
-        # a 64-bit row-hash collision can split one key across rows.
-        # No part is empty.
+        # part is known collision-free (a grouping that compared full
+        # keys: native_group_sum's, or a read-time group_sum_exact);
+        # group_sum_fast parts are not — a 64-bit row-hash collision
+        # can split one key across rows. No part is empty.
         self._parts: List[Tuple[np.ndarray, np.ndarray, bool]] = []
         # Aligned with _parts: {column: (min, max)} of each part's keys
         # for the BOUND_COLUMNS the view has. A ranged read skips a
@@ -209,19 +216,24 @@ class ViewTable:
             self.generation += 1
 
     def _merged(self) -> Tuple[np.ndarray, np.ndarray]:
+        return self._compacted()[:2]
+
+    def _compacted(self) -> Tuple[np.ndarray, np.ndarray, int,
+                                  Optional[str]]:
+        """The view as one exact part (keys, values), the rows that
+        were re-grouped for it and how (`group_sum_exact`'s word; 0 and
+        None where the view was one exact part, or empty, already)."""
         with self._lock:
             parts = list(self._parts)
             generation = self.generation
         if not parts:
-            return self._empty()
+            return (*self._empty(), 0, None)
         if len(parts) == 1 and parts[0][2]:
-            return parts[0][0], parts[0][1]
+            return parts[0][0], parts[0][1], 0, None
         # Re-group even a lone inexact part: group_sum_fast may have
         # split a hash-colliding key into two rows, and scan() promises
         # exact re-grouping at read time.
-        keys = np.concatenate([p[0] for p in parts], axis=0)
-        values = np.concatenate([p[1] for p in parts], axis=0)
-        gk, gv = group_sum(keys, values)
+        gk, gv, how = group_sum_exact([p[:2] for p in parts])
         bounds = self._bounds_of(gk)
         with self._lock:
             # Swap in the compacted part only if no insert, delete,
@@ -229,13 +241,14 @@ class ViewTable:
             if self.generation == generation:
                 self._parts = [(gk, gv, True)]
                 self._bounds = [bounds]
-        return gk, gv
+        return gk, gv, sum(len(p[0]) for p in parts), how
 
     def compact(self) -> None:
         self._merged()
 
     def scan(self) -> ColumnarBatch:
-        """The view as a ColumnarBatch (keys + summed metrics)."""
+        """The view as a ColumnarBatch (keys + summed metrics): grouped
+        exactly, its rows in no stated order."""
         keys, values = self._merged()
         return materialize_view_batch(self.spec, keys, values,
                                       self.dicts)
@@ -249,9 +262,10 @@ class ViewTable:
                columns: Optional[Sequence[str]] = None) -> ColumnarBatch:
         """The view's rows with `start <= flowEndSeconds < end` (the
         panels' `$__timeFilter`), in `scan()`'s row shape and grouped
-        as exactly: what `scan()` then that mask gives; `columns`
-        projects the result to that subset (the rows are still grouped
-        by every key; only the asked sums are gathered and summed).
+        as exactly: the rows `scan()` then that mask gives, like them
+        in no stated order; `columns` projects the result to that
+        subset (the rows are still grouped by every key; only the
+        asked sums are gathered and summed).
 
         The parts are walked by their cached bounds: one that cannot
         meet the range is skipped unread. What the walk opens decides
@@ -259,17 +273,18 @@ class ViewTable:
         view** (under `COMPACT_SHARE` of its rows: the last minutes of
         a store that holds an hour) takes each opened part whole, or
         masked on its own where the range cuts it, and re-groups only
-        the rows taken (`group_sum`: equal keys of different insert
-        blocks collapse and a `group_sum_fast` part's hash-split key
-        is rejoined; the column is a key, so every row of a key is on
-        one side of the range); the view is left as it lies. **A range
+        the rows taken (`group_sum_exact`, the parts read where they
+        lie: equal keys of different insert blocks collapse and a
+        `group_sum_fast` part's hash-split key is rejoined; the column
+        is a key, so every row of a key is on one side of the range);
+        the view is left as it lies. **A range
         that opens most of the view** gains little from the walk and
         would re-group nearly the whole view at every request, so it
         compacts the view as `scan()` does (once an insert block; the
         copy is swapped in only at the generation it was read at) and
         masks the one exact part, which needs no re-grouping; every
         later read of the view then finds that part. `last_read()`
-        says what was opened."""
+        says what was opened and what was re-grouped."""
         col = self._bound_index["flowEndSeconds"]
         sums = [i for i, _ in self.spec.asked_sums(columns)]
         with self._lock:
@@ -282,14 +297,15 @@ class ViewTable:
         rows = sum(len(part[0]) for part, _ in opened)
         total = sum(len(part[0]) for part in parts)
         if len(parts) > 1 and rows >= self.COMPACT_SHARE * total > 0:
-            self._last.read = dict(read=len(parts), pruned=0, rows=total)
-            keys, values = self._merged()
+            seen = dict(read=len(parts), pruned=0, rows=total)
+            keys, values, regrouped, how = self._compacted()
             pair = (int(keys[:, col].min()), int(keys[:, col].max()))
             opened = [((keys, values, True),
                        window_fate(start, end, pair, pair))]
         else:
-            self._last.read = dict(read=len(opened), rows=rows,
-                                   pruned=len(parts) - len(opened))
+            seen = dict(read=len(opened), rows=rows,
+                        pruned=len(parts) - len(opened))
+            regrouped, how = 0, None
         taken: List[Tuple[np.ndarray, np.ndarray]] = []
         for (keys, values, _), fate in opened:
             if fate is None:
@@ -308,17 +324,25 @@ class ViewTable:
         elif len(opened) == 1 and opened[0][0][2]:
             gk, gv = taken[0]          # one exact part: grouped already
         else:
-            gk, gv = group_sum(
-                np.concatenate([k for k, _ in taken], axis=0),
-                np.concatenate([v for _, v in taken], axis=0))
+            # (`values[:, sums]` is column-major: the one-part path
+            # above hands its columns on as they are, this one pays
+            # the row-major copy the grouping reads in place)
+            gk, gv, how = group_sum_exact(
+                [(k, np.ascontiguousarray(v)) for k, v in taken])
+            regrouped = sum(len(k) for k, _ in taken)
+        self._last.read = dict(seen, regrouped=regrouped, how=how)
         return materialize_view_batch(self.spec, gk, gv, self.dicts,
                                       columns)
 
-    def last_read(self) -> Dict[str, int]:
+    def last_read(self) -> Dict[str, object]:
         """What the calling thread's last `select` opened: parts `read`
-        and `pruned` by their bounds, and the `rows` of those read
-        (before the mask and the re-grouping)."""
-        return getattr(self._last, "read", None) or read_tally()
+        and `pruned` by their bounds, the `rows` of those read (before
+        the mask and the re-grouping), the rows it `regrouped` (those
+        handed to `group_sum_exact`, by the compaction or for the rows
+        taken; 0 where one exact part answered) and `how` (`hash`, the
+        native pass, or `sort`, the lexsort; None with 0 rows)."""
+        return getattr(self._last, "read", None) or dict(
+            read_tally(), regrouped=0, how=None)
 
     def restore(self, keys: np.ndarray, values: np.ndarray) -> None:
         """Install persisted (keys, values) aggregates wholesale — the

@@ -2,12 +2,17 @@
 
 One shared object holds every native routine (`native/flowblock.cc`,
 the wire decoders `ingest/native.py` drives; `native/seriesbuild.cc`,
-`build_padded_series`; `native/groupsum.cc`, `native_group_sum`),
-loaded via ctypes (no pybind11 in the image) and compiled on first use
-with g++ -O3 into `_build/` beside this file. How the host groups rows
-is one decision and lives here, below the store: the native routines
-and their numpy twins `group_reduce` / `group_sum` / `group_sum_fast`,
-which are the only path without the library and the tests' reference.
+`build_padded_series`; `native/groupsum.cc`, `native_group_sum` and
+the native pass of `group_sum_exact`), loaded via ctypes (no pybind11
+in the image) and compiled on first use with g++ -O3 into `_build/`
+beside this file. How the host groups rows is one decision and lives
+here, below the store: the native routines and their numpy twins
+`group_reduce` / `group_sum` / `group_sum_fast`, which are the only
+path without the library and the tests' reference. A materialized
+view is grouped by `native_group_sum` (or `group_sum_fast`) an insert
+block and re-grouped exactly at read time by `group_sum_exact`: one
+hash pass with a full-key comparison where the library is loaded, the
+lexsort otherwise — the same groups, in no stated order.
 """
 
 from __future__ import annotations
@@ -15,7 +20,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -137,6 +142,11 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int64, ctypes.c_int32,
         ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_int32),
         ctypes.c_int32]
+    lib.gs_build_rows.restype = ctypes.c_void_p
+    lib.gs_build_rows.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_void_p),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_int32]
     lib.gs_dims.argtypes = [ctypes.c_void_p,
                             ctypes.POINTER(ctypes.c_int64)]
     lib.gs_fill.argtypes = [ctypes.c_void_p,
@@ -245,7 +255,8 @@ def native_group_sum(key_cols, value_cols):
     """Native GROUP BY...SUM over column arrays (native/groupsum.cc):
     one hash pass, no sort, no row-major staging in Python — the
     materialized-view insert hot path. Group order is arbitrary
-    (SummingMergeTree parts are re-grouped exactly at read time).
+    (SummingMergeTree parts are re-grouped exactly at read time, by
+    `group_sum_exact`).
 
     key_cols / value_cols: sequences of 1-D int32/int64 arrays of equal
     length. Returns (keys [g,k] int64, sums [g,m] int64), or None when
@@ -271,7 +282,13 @@ def native_group_sum(key_cols, value_cols):
         *[a.ctypes.data for a in value_cols])
     vw = (ctypes.c_int32 * max(m, 1))(
         *[a.dtype.itemsize for a in value_cols])
-    handle = lib.gs_build(kp, kw, n, k, vp, vw, m)
+    return _gs_result(lib, lib.gs_build(kp, kw, n, k, vp, vw, m), k, m)
+
+
+def _gs_result(lib, handle, k: int, m: int
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """The groups of a `gs_build*` handle as (keys [g,k], sums [g,m]);
+    frees the handle."""
     try:
         g = ctypes.c_int64()
         lib.gs_dims(handle, ctypes.byref(g))
@@ -284,6 +301,47 @@ def native_group_sum(key_cols, value_cols):
     finally:
         lib.gs_free(handle)
     return keys, sums
+
+
+def _row_major_int64(a: np.ndarray, rows: int, cols: int) -> bool:
+    return (a.dtype == np.int64 and a.shape == (rows, cols)
+            and a.flags["C_CONTIGUOUS"])
+
+
+def group_sum_exact(parts: Sequence[Tuple[np.ndarray, np.ndarray]]
+                    ) -> Tuple[np.ndarray, np.ndarray, str]:
+    """Exact GROUP BY...SUM of view parts at read time: `parts` is a
+    non-empty sequence of (keys [n_i,k], values [n_i,m]) grouped as
+    one table (equal keys of different parts collapse, a
+    `group_sum_fast` part's hash-split key is rejoined). Returns
+    (keys [g,k], sums [g,m], how).
+
+    `how` is `hash` where the native pass ran (native/groupsum.cc
+    `gs_build_rows`: the library is loaded and every array is a
+    C-contiguous int64 matrix, read where it lies: no concatenation,
+    no copy a column; groups in order of first appearance) and `sort`
+    where `group_sum` did (the lexsort; groups in lexicographic
+    order). The groups and their int64 sums (numpy's wrap-around) are
+    the same either way; their order is not part of the contract."""
+    k, m = parts[0][0].shape[1], parts[0][1].shape[1]
+    lib = _load_library()
+    if (lib is None or sum(len(keys) for keys, _ in parts) >= 2 ** 31
+            or not all(_row_major_int64(keys, len(keys), k)
+                       and _row_major_int64(values, len(keys), m)
+                       for keys, values in parts)):
+        keys, sums = group_sum(
+            np.concatenate([keys for keys, _ in parts], axis=0),
+            np.concatenate([values for _, values in parts], axis=0))
+        return keys, sums, "sort"
+    count = len(parts)
+    handle = lib.gs_build_rows(
+        (ctypes.c_void_p * count)(*[keys.ctypes.data for keys, _ in parts]),
+        (ctypes.c_void_p * count)(*[values.ctypes.data
+                                    for _, values in parts]),
+        (ctypes.c_int64 * count)(*[len(keys) for keys, _ in parts]),
+        count, k, m)
+    # `parts` outlives the handle, which reads them until gs_fill
+    return (*_gs_result(lib, handle, k, m), "hash")
 
 
 def group_reduce(keys: np.ndarray, values: np.ndarray, op: str = "sum"
@@ -321,10 +379,10 @@ def group_sum_fast(keys: np.ndarray, values: np.ndarray
     64-bit row hash instead of lexsorting 15-20 key columns (~20x less
     sort work). Output group ORDER is arbitrary, and a hash collision
     between distinct keys may split a group into two rows — both are
-    fine for a SummingMergeTree part: `compact()`/`_merged` re-groups
-    exactly (lexsort) at read time, which is also where ClickHouse
-    collapses part rows. Do NOT use where callers rely on lexicographic
-    group order (use group_reduce)."""
+    fine for a SummingMergeTree part: a read re-groups exactly
+    (`group_sum_exact`), which is also where ClickHouse collapses part
+    rows. Do NOT use where callers rely on lexicographic group order
+    (use group_reduce)."""
     n = keys.shape[0]
     if n == 0:
         return keys, values
